@@ -5,7 +5,7 @@ prefixes ("ValueError: ...") onto registered Python exception types via
 ``register_error``.  There is no C ABI here (errors are ordinary Python
 exceptions end-to-end), so ``register`` keeps the registry purely for
 API parity: code that registers custom error types and code that looks
-them up by name keeps working, and the standard taxonomy
+them up by name keeps working, and the standard hierarchy
 (``InternalError``, ``NotImplementedForTPU`` alias, builtin
 ValueError/TypeError/AttributeError/IndexError) is pre-registered.
 """

@@ -5,9 +5,10 @@ historically paid stock XLA paging: ``kv[li, block_table]`` materializes
 every lane's full ``(B, max_pages·page_size, KV, D)`` context in HBM each
 step, and an int8 arena additionally materializes a full fp32 dequantized
 copy before attention starts.  This module is the vLLM/PagedAttention
-pattern instead: a Pallas kernel whose grid walks ``(batch-lane, kv-head,
-page)``, prefetches the block table as scalars so each step DMAs exactly
-one ``(page_size, D)`` page tile into VMEM, dequantizes in-register off
+pattern instead: pages are stored ``(P, KV, page_size, D)``, a Pallas
+kernel's grid walks ``(batch-lane, kv-head, page)`` and prefetches the
+block table as scalars, so each step DMAs exactly one kv-head's
+contiguous ``(page_size, D)`` page tile into VMEM, dequantizes in-register off
 the per-(layer, page) scale, and accumulates flash-style online softmax.
 HBM traffic drops from O(ctx·KV·D) gathered+dequantized per step to the
 pages actually stored, and GQA never replicates K/V ``H/KV``-fold — the
@@ -23,10 +24,12 @@ scheduler discards anyway).  Fully-masked query rows return 0.
 
 Registered as ``_contrib_paged_attention`` so the op-consistency harness
 and mxlint cover it like any other op; ``use_kernel`` picks the path:
-``0`` = pure-jnp reference, ``1`` = force the Pallas kernel (compiled on
-TPU, interpreter elsewhere — CI parity runs), unset/``auto`` = kernel on
-TPU, reference elsewhere (the interpreter is correct but slow; off-TPU
-production decode should take the XLA reference, not emulation).
+``0`` = pure-jnp reference, ``1`` = the Pallas kernel (compiled on TPU,
+interpreter elsewhere — CI parity runs), unset/``auto`` = kernel on TPU,
+reference elsewhere (the interpreter is correct but slow; off-TPU
+production decode should take the XLA reference, not emulation).  The
+choice follows the platform and ``use_kernel`` only: a kernel the TPU
+compiler refuses is an error, never a reason to take the reference.
 """
 from __future__ import annotations
 
@@ -37,7 +40,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..base import MXNetError
-from .pallas_kernels import _EAGER_JIT_CACHE, _LANES, _platform_pick
+from .pallas_kernels import _LANES, _platform_pick
 from .registry import register
 
 
@@ -50,18 +53,18 @@ def _paged_ref(q, k_pages, v_pages, block_table, positions, *scales,
     to zero output.
     """
     b, k1, h, d = q.shape
-    s_page, kv = k_pages.shape[1], k_pages.shape[2]
+    kv, s_page = k_pages.shape[1], k_pages.shape[2]
     maxp = block_table.shape[1]
     grp = h // kv
     ctx = maxp * s_page
-    keys = k_pages[block_table].astype(jnp.float32)  # (B, maxp, S, KV, D)
+    keys = k_pages[block_table].astype(jnp.float32)  # (B, maxp, KV, S, D)
     vals = v_pages[block_table].astype(jnp.float32)
     if scales:
         ks, vs = scales
         keys = keys * ks[block_table][..., None, None, None]
         vals = vals * vs[block_table][..., None, None, None]
-    keys = keys.reshape(b, ctx, kv, d)
-    vals = vals.reshape(b, ctx, kv, d)
+    keys = keys.transpose(0, 1, 3, 2, 4).reshape(b, ctx, kv, d)
+    vals = vals.transpose(0, 1, 3, 2, 4).reshape(b, ctx, kv, d)
     qg = q.astype(jnp.float32).reshape(b, k1, kv, grp, d)
     s = jnp.einsum("bkvgd,bcvd->bkvgc", qg, keys) * scale
     posk = positions[:, None] + jnp.arange(k1)[None, :]      # (B, k1)
@@ -106,8 +109,8 @@ def _paged_kernel(tbl_ref, pos_ref, *refs, grp, page, scale, quantized):
 
     pid = tbl_ref[b, p]
     q = q_ref[0, 0].astype(jnp.float32) * scale              # (QG, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)                # (S, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                      # (S, D)
+    v = v_ref[0, 0].astype(jnp.float32)
     if quantized:
         k = k * ks_ref[pid]
         v = v * vs_ref[pid]
@@ -149,7 +152,7 @@ def _paged_pallas(q, k_pages, v_pages, block_table, positions, *scales,
     from jax.experimental.pallas import tpu as pltpu
 
     b, k1, h, d = q.shape
-    s_page, kv = k_pages.shape[1], k_pages.shape[2]
+    kv, s_page = k_pages.shape[1], k_pages.shape[2]
     maxp = block_table.shape[1]
     qg = k1 * grp
     # fold GQA into the query: (B, k1, H, D) -> (B, KV, k1*G, D) with
@@ -165,10 +168,10 @@ def _paged_pallas(q, k_pages, v_pages, block_table, positions, *scales,
         grid=(b, kv, maxp),
         in_specs=[
             pl.BlockSpec((1, 1, qg, d), lambda b, kv, p, *s: (b, kv, 0, 0)),
-            pl.BlockSpec((1, s_page, 1, d),
-                         lambda b, kv, p, *s: (s[0][b, p], 0, kv, 0)),
-            pl.BlockSpec((1, s_page, 1, d),
-                         lambda b, kv, p, *s: (s[0][b, p], 0, kv, 0)),
+            pl.BlockSpec((1, 1, s_page, d),
+                         lambda b, kv, p, *s: (s[0][b, p], kv, 0, 0)),
+            pl.BlockSpec((1, 1, s_page, d),
+                         lambda b, kv, p, *s: (s[0][b, p], kv, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, qg, d),
                                lambda b, kv, p, *s: (b, kv, 0, 0)),
@@ -194,7 +197,7 @@ def paged_attention(query, k_pages, v_pages, block_table, positions,
                     k_scale=None, v_scale=None, scale=None,
                     use_kernel=None):
     """Paged attention over block tables: ``(B, k1, H, D)`` queries
-    against ``(P, S, KV, D)`` K/V pages addressed by a ``(B, maxp)``
+    against ``(P, KV, S, D)`` K/V pages addressed by a ``(B, maxp)``
     int32 block table, one scalar position per lane.
 
     ``k1`` is the query width — 1 for decode, ``spec_k + 1`` for
@@ -202,12 +205,14 @@ def paged_attention(query, k_pages, v_pages, block_table, positions,
     ``<= positions[b] + j``.  Page 0 is the reserved null page and is
     always masked.  ``k_scale``/``v_scale`` ``(P,)`` f32, when given,
     dequantize int8 pages in-register.  ``scale`` defaults to
-    ``1/sqrt(D)``.  ``use_kernel``: ``0`` reference, ``1`` force the
-    Pallas kernel (interpreter off-TPU), unset = kernel on TPU only.
+    ``1/sqrt(D)``.  ``use_kernel``: ``0`` reference, ``1`` the Pallas
+    kernel (interpreter off-TPU), unset = kernel on TPU, reference
+    elsewhere.
 
-    TPU note: the kernel's page tile is ``(page_size, D)`` per kv-head —
-    compiled Mosaic wants ``page_size`` a multiple of 8 and ``D`` of
-    128; smaller geometries (tests) run the interpreter or reference.
+    TPU note: the kernel's K/V block is one kv-head's whole
+    ``(page_size, D)`` tile, so the lowering takes any page size and
+    head dim; tiles pad to (8, 128) f32, (16, 128) bf16, (32, 128) int8,
+    so a ``page_size`` below those wastes the padding.
     """
     if (k_scale is None) != (v_scale is None):
         raise MXNetError("_contrib_paged_attention needs both k_scale "
@@ -215,10 +220,10 @@ def paged_attention(query, k_pages, v_pages, block_table, positions,
     if query.ndim != 4 or k_pages.ndim != 4:
         raise MXNetError(
             "_contrib_paged_attention wants query (B, k1, H, D) and "
-            "pages (P, S, KV, D); got %s / %s"
+            "pages (P, KV, S, D); got %s / %s"
             % (query.shape, k_pages.shape))
     h, d = query.shape[2], query.shape[3]
-    kv = k_pages.shape[2]
+    kv = k_pages.shape[1]
     if h % kv or k_pages.shape[3] != d:
         raise MXNetError(
             "_contrib_paged_attention: %d query heads do not group over "
@@ -238,34 +243,9 @@ def paged_attention(query, k_pages, v_pages, block_table, positions,
     rrun = functools.partial(_paged_ref, scale=scale)
     if mode == "0":
         return rrun(*args)
-    # Platform is resolved from the backend, NOT via
-    # jax.lax.platform_dependent: on this jax version the cond over the
-    # platform index still LOWERS every branch, and the compiled-pallas
-    # branch refuses to lower for cpu — so a traced platform_dependent
-    # poisons every CPU jit that touches the op (the serving graphs).
-    # default_backend() is a host-side query, safe under trace; serving
-    # executables are always compiled for the default backend anyway.
-    from jax import core as _core
-
-    traced = any(isinstance(a, _core.Tracer) for a in args)
-    on_tpu = jax.default_backend() == "tpu"
-    if mode == "1":
-        # forced kernel: compiled on TPU, interpreter elsewhere (the
-        # interpreter traces to plain jax ops, so it serializes into
-        # AOT bundles — the CI parity path)
-        if traced:
-            return krun(*args, interpret=not on_tpu)
-        return _platform_pick(krun, *args)
-    # auto: compiled kernel on TPU, XLA reference elsewhere (the
-    # interpreter is for parity tests, not production CPU decode)
-    if on_tpu:
-        return krun(*args, interpret=False) if traced \
-            else _platform_pick(krun, *args)
-    if traced:
-        return rrun(*args)
-    key = (_paged_ref, ("scale", scale), "ref")
-    fn = _EAGER_JIT_CACHE.get(key)
-    if fn is None:
-        fn = jax.jit(rrun)
-        _EAGER_JIT_CACHE[key] = fn
-    return fn(*args)
+    # "1": compiled kernel on TPU, interpreter elsewhere (it traces to
+    # plain jax ops, so it serializes into AOT bundles — the CI parity
+    # path).  auto: off the TPU take the XLA reference; the interpreter
+    # is for parity tests, not production CPU decode.
+    return _platform_pick(krun, *args,
+                          off_tpu=None if mode == "1" else rrun)

@@ -16,19 +16,24 @@ direction, so long-context TRAINING runs at O(T·D) memory; ring attention
 (parallel/ring_attention.py) composes on top to shard T across chips.
 
 On non-TPU backends the kernels run through the Pallas interpreter
-(tests), or fall back to plain jnp attention when shapes don't tile.
+(tests).  Shapes that do not tile take plain jnp attention on every
+platform; a kernel the TPU compiler refuses is an error.
 """
 from __future__ import annotations
 
 import functools
+import math
+import warnings
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
+from .. import sharding as _sharding
 from .registry import register
 
-_EAGER_JIT_CACHE = {}
+_JIT_CACHE = {}
 
 # minor-dim width for per-row scalars (lse, delta): TPU Mosaic tiles
 # require the minor block dim to be a multiple of 128, so row scalars
@@ -37,42 +42,28 @@ _EAGER_JIT_CACHE = {}
 _LANES = 128
 
 
-def _platform_pick(run, *args):
-    """Compiled kernel ONLY on tpu; every other platform (cpu, and
-    untested cuda/rocm) goes through the interpreter.
+def _platform_pick(run, *args, off_tpu=None):
+    """Compiled kernel on tpu; off it the Pallas interpreter, or
+    ``off_tpu`` when given.
 
-    The platform is resolved from the backend at TRACE time, NOT via
-    ``jax.lax.platform_dependent``: on this jax version the cond over
-    the platform index still LOWERS every branch, and the compiled-
-    pallas branch refuses to lower for cpu — so a traced
-    ``platform_dependent`` poisons every CPU jit that touches the op
-    (the same bug ``ops/paged_attention.py`` works around, and the
-    exact failure ``tests/test_forward[_contrib_flash_attention]``
-    used to hit).  ``jax.default_backend()`` is a host-side query,
-    safe under trace; committed-device placement off the default
-    backend is not a supported mix for these kernels.
+    ``jax.lax.platform_dependent`` picks when the computation is lowered
+    (jax 0.9.0 lowers only the branch of the platform it compiles for),
+    so the choice follows the devices the arrays live on or the jit is
+    compiled for, not the process's default backend.  Always jitted,
+    cached per kernel+attrs: un-jitted interpret-mode pallas dispatches
+    one tiny executable per inner op per grid point — minutes instead of
+    milliseconds.
     """
-    from jax import core as _core
-
-    interpret = jax.default_backend() != "tpu"
-    if not any(isinstance(a, _core.Tracer) for a in args):
-        for a in args:
-            devs = getattr(a, "devices", None)
-            if callable(devs):
-                ds = list(devs())
-                if ds:
-                    interpret = ds[0].platform != "tpu"
-                    break
-        # jit the eager call (cached per kernel+attrs): un-jitted
-        # interpret-mode pallas dispatches one tiny executable per inner
-        # op per grid point — minutes instead of milliseconds
-        key = (run.func, tuple(sorted(run.keywords.items())), interpret)
-        fn = _EAGER_JIT_CACHE.get(key)
-        if fn is None:
-            fn = jax.jit(functools.partial(run, interpret=interpret))
-            _EAGER_JIT_CACHE[key] = fn
-        return fn(*args)
-    return run(*args, interpret=interpret)
+    key = (run.func, tuple(sorted(run.keywords.items())), off_tpu and
+           (off_tpu.func, tuple(sorted(off_tpu.keywords.items()))))
+    fn = _JIT_CACHE.get(key)
+    if fn is None:
+        fn = jax.jit(functools.partial(
+            lax.platform_dependent,
+            tpu=functools.partial(run, interpret=False),
+            default=off_tpu or functools.partial(run, interpret=True)))
+        _JIT_CACHE[key] = fn
+    return fn(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -345,6 +336,40 @@ def _flash_bwd(scale, causal, block_q, block_k, res, g):
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _flash_per_shard(mesh, axes, q, k, v, *static):
+    """The kernel under ``shard_map`` on (B, H, T, D) over ``axes``, the
+    context mesh's axes that GSPMD would otherwise partition: batch over
+    ``sharding.batch_axis`` (the step's data axis), heads over the others
+    (where Megatron rules put them), each only where it divides.  The
+    specs decide how much GSPMD has to move, never the result; an axis
+    left out of them makes every device along it run the same shard, and
+    is warned about."""
+    b, h = q.shape[:2]
+    batch_axis = _sharding.batch_axis.value
+    if batch_axis not in axes or b % mesh.shape[batch_axis]:
+        batch_axis = None
+    head_axes = tuple(a for a in axes if a != batch_axis)
+    if h % math.prod(mesh.shape[a] for a in head_axes):
+        head_axes = ()
+    idle = [a for a in axes if a != batch_axis and a not in head_axes]
+    if idle:
+        warnings.warn(
+            "flash_attention: batch %d / heads %d do not divide over mesh "
+            "axes %s of %s (batch axis %r); the kernel runs replicated "
+            "along them" % (b, h, idle, dict(mesh.shape),
+                            _sharding.batch_axis.value), stacklevel=3)
+    spec = P(batch_axis, head_axes or None, None, None)
+
+    def body(q, k, v):
+        b, h, t, d = q.shape
+        out = _flash_attention(*(x.reshape(b * h, -1, d) for x in (q, k, v)),
+                               *static)
+        return out.reshape(b, h, t, d)
+
+    return jax.shard_map(body, in_specs=(spec,) * 3, out_specs=spec,
+                         axis_names=set(axes), check_vma=False)(q, k, v)
+
+
 def _tiles(t, preferred):
     """Largest workable block: divides ``t`` AND satisfies the Mosaic
     sublane rule (multiple of 8, or the full axis).  A user-preferred
@@ -395,10 +420,26 @@ def flash_attention(query, key, value, scale=None, causal=False,
     bq = _tiles(t_q, int(block_q) if block_q else min(t_q, 512))
     bk = _tiles(t_kv, int(block_k) if block_k else min(t_kv, 512))
     if bq is None or bk is None:
+        # a shape rule, on every platform: T has no block that both
+        # divides it and satisfies the sublane rule
         out3 = _attention_ref(q3, k3, v3, scale, causal)
     else:
-        out3 = _flash_attention(q3, k3, v3, float(scale), bool(causal),
-                                bq, bk)
+        static = (float(scale), bool(causal), bq, bk)
+        # a program traced under a context mesh (JitTrainStep with a
+        # mesh) will be partitioned by GSPMD, and the TPU lowering
+        # refuses a Mosaic kernel there ("cannot be automatically
+        # partitioned. Please wrap the call in a shard_map").  jax keys
+        # its trace caches on the context mesh, so an eager trace of this
+        # op is never reused inside such a program.
+        # Axes a caller's own shard_map has already made manual are
+        # not GSPMD's any more: there the kernel runs as it is.
+        mesh = jax.sharding.get_abstract_mesh()
+        axes = tuple(a for a in mesh.axis_names
+                     if a not in mesh.manual_axes and mesh.shape[a] > 1)
+        if axes:
+            out3 = _flash_per_shard(mesh, axes, query, key, value, *static)
+        else:
+            out3 = _flash_attention(q3, k3, v3, *static)
     return _finish(out3, b, h, t_q, d, squeeze)
 
 

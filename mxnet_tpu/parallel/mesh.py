@@ -22,19 +22,11 @@ from .. import sharding as _sharding
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """Version-portable ``shard_map``: newer jax exposes it as
-    ``jax.shard_map(..., check_vma=)``, older releases only ship
-    ``jax.experimental.shard_map`` with the ``check_rep=`` spelling.
-
-    Accepts a framework ``sharding.Mesh`` or a raw jax mesh."""
-    mesh = _sharding.as_jax_mesh(mesh)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    """``jax.shard_map`` over a framework ``sharding.Mesh`` or a raw jax
+    mesh."""
+    return jax.shard_map(f, mesh=_sharding.as_jax_mesh(mesh),
+                         in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_vma)
 
 
 def make_mesh(axes=None, devices=None):

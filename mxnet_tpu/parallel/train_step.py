@@ -17,6 +17,8 @@ executable — the compiled equivalent of KVStore device mode.
 """
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -122,19 +124,26 @@ class JitTrainStep:
         from ..context import _best_context
 
         self._device = _best_context().jax_device
-        dev = self._device
-        self._weights = [jax.device_put(jnp.array(p.data().data()), dev)
-                         for p in self._params]
-        self._opt_state = [
-            jax.tree_util.tree_map(
-                lambda a: jax.device_put(a, dev),
-                self._opt.create_state(i, self._weights[i]))
-            if i in self._train_set else None
-            for i in range(len(self._params))]
         if self._rules is not None:
             self._param_rule = self._resolve_rules(batch_nd)
         if self._mesh is not None:
-            self._place_on_mesh(self._param_rule)
+            # one parameter at a time straight to its shards: a model
+            # that needs the mesh does not fit weights + optimizer state
+            # on one device first
+            self._param_shardings = self._mesh_shardings(self._param_rule)
+            places = self._param_shardings
+            put = self._put_global if self._multiprocess else jax.device_put
+        else:
+            places = [self._device] * len(self._params)
+            put = jax.device_put
+        self._weights, self._opt_state = [], []
+        for i, (p, s) in enumerate(zip(self._params, places)):
+            w = jnp.array(p.data().data())
+            st = self._opt.create_state(i, w) \
+                if i in self._train_set else None
+            self._weights.append(put(w, s))
+            self._opt_state.append(
+                jax.tree_util.tree_map(lambda a, s=s: put(a, s), st))
         self._tag_weights()
 
     def _tag_weights(self):
@@ -214,27 +223,39 @@ class JitTrainStep:
         return jax.make_array_from_callback(
             host.shape, sharding, lambda idx: host[idx])
 
-    def _place_on_mesh(self, param_rule):
+    def _mesh_shardings(self, param_rule):
+        """One NamedSharding per parameter: ``param_rule``'s spec, else
+        replicated."""
         from .. import sharding as _sharding
 
         mesh = self._mesh
+
         def spec_for(p):
             s = param_rule(p.name, tuple(p.shape)) if param_rule else None
             return s if s is not None else P()
-        self._param_shardings = [
-            NamedSharding(mesh, spec_for(p)) for p in self._params]
+        shardings = [NamedSharding(mesh, spec_for(p)) for p in self._params]
         if _sharding.verify_enabled():
-            for p, sh in zip(self._params, self._param_shardings):
+            for p, sh in zip(self._params, shardings):
                 _sharding.verify_spec(mesh, sh.spec, shape=tuple(p.shape),
                                       what="param[%s]" % p.name)
-        put = self._put_global if self._multiprocess else jax.device_put
-        self._weights = [
-            put(w, s)
-            for w, s in zip(self._weights, self._param_shardings)]
-        self._opt_state = [
-            None if st is None else jax.tree_util.tree_map(
-                lambda a: put(a, sh), st)
-            for st, sh in zip(self._opt_state, self._param_shardings)]
+        return shardings
+
+    @contextlib.contextmanager
+    def _mesh_scope(self):
+        """The step's mesh as jax's context mesh, and its data axis as
+        ``sharding.batch_axis``, while a step program is traced and run.
+        An op that has to know it is being partitioned — the Pallas
+        attention kernels, which GSPMD cannot split — reads them with
+        ``jax.sharding.get_abstract_mesh()`` and ``batch_axis.value``;
+        jax keys its trace caches on both."""
+        from .. import sharding as _sharding
+
+        if self._mesh is None:
+            yield
+            return
+        with jax.set_mesh(self._mesh), \
+                _sharding.batch_axis(self._data_axis):
+            yield
 
     def _batch_sharding(self, arr):
         return NamedSharding(
@@ -390,8 +411,9 @@ class JitTrainStep:
             _random.next_key(),
             jnp.asarray(self._opt.learning_rate, jnp.float32),
             jnp.asarray(self._t, jnp.int32))
-        self._weights, self._opt_state, loss = self._step_fn(
-            key, lr, self._weights, self._opt_state, t, *arrays)
+        with self._mesh_scope():
+            self._weights, self._opt_state, loss = self._step_fn(
+                key, lr, self._weights, self._opt_state, t, *arrays)
         self._tag_weights()
         self._last_loss = loss
         return loss
@@ -484,8 +506,9 @@ class JitTrainStep:
             _random.next_key(),
             jnp.asarray(self._opt.learning_rate, jnp.float32),
             jnp.asarray(self._t, jnp.int32))
-        self._weights, self._opt_state, loss = fn(
-            key, lr, self._weights, self._opt_state, t, *arrays)
+        with self._mesh_scope():
+            self._weights, self._opt_state, loss = fn(
+                key, lr, self._weights, self._opt_state, t, *arrays)
         self._tag_weights()
         self._t += n
         self._last_loss = loss

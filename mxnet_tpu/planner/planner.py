@@ -259,7 +259,7 @@ def plan_serving(net, geometry, mesh, data_axis="data", **kwargs):
     for axis, size in axes.items():
         if axis != data_axis and size > 1 \
                 and geometry.num_kv_heads % size == 0:
-            kv_spec[3] = axis        # (L, P, page, KV-heads, head-dim)
+            kv_spec[2] = axis        # (L, P, KV-heads, page, head-dim)
             break
     doc = pl.as_dict()
     doc["kv_spec"] = kv_spec

@@ -1,7 +1,7 @@
 """Paged KV-cache arena: fixed-size pages, block tables, liveness-safe
 reuse.
 
-The whole cache is two NDArrays shaped ``(L, P, page, KV, D)`` (one for
+The whole cache is two NDArrays shaped ``(L, P, KV, page, D)`` (one for
 K, one for V).  Sequences own pages through host-side block tables —
 int32 rows mapping ``token_position // page_size`` to a page index — so
 admission never copies or reshapes cache memory: allocating a sequence
@@ -18,8 +18,7 @@ before any arena call.  Do not add locks here; add state to the
 scheduler if another thread ever needs it.
 
 Reuse safety rides on the engine's var-dependency tracking.  The decode
-/prefill executables *donate* the KV buffers on accelerator backends
-(XLA deletes them; see model._donate_kv for the CPU exception), and a
+/prefill executables *donate* the KV buffers (XLA deletes them), and a
 freed page may be handed to a new sequence while imperative NDArray ops
 — a debug checksum, an eviction scorer — sit deferred in an open bulk
 segment that captured the old buffer as an ext input.  Before any
@@ -58,7 +57,7 @@ class PagedKVArena:
         # device_put, NOT nd.zeros: a serving process must not push ops
         # (zero live compiles — the tentpole claim of the AOT warm start)
         # With mesh=/kv_spec= the arena buffers live sharded on the mesh
-        # — KV heads (dim 3) on the tp axis is the canonical spec; the
+        # — KV heads (dim 2) on the tp axis is the canonical spec; the
         # serving executables' kv arguments then inherit the placement.
         placement = None
         if mesh is not None or kv_spec is not None:
@@ -303,11 +302,10 @@ class PagedKVArena:
                          "segment still read the KV arena").inc()
 
     def adopt(self, new_k, new_v, new_k_scale=None, new_v_scale=None):
-        """Swap in the post-call arena buffers (when donation is on the
-        executables delete the old ones, so this is the only live
-        reference handoff; without donation the old buffers simply drop
-        their last reference here).  Quantized arenas must hand the two
-        scale arrays back too — they are executable state."""
+        """Swap in the post-call arena buffers (the donating executables
+        deleted the old ones, so this is the only live reference
+        handoff).  Quantized arenas must hand the two scale arrays back
+        too — they are executable state."""
         self.kv_k._set_data(new_k)
         self.kv_v._set_data(new_v)
         # re-attribute: the swap is the only place fresh arena storage

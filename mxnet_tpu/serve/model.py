@@ -34,11 +34,9 @@ token-for-token identical at int8.  Page reuse is safe for free: a new
 owner's first write to a page is always that page's slot 0 (positions
 are written in order), which resets the scale.
 
-On accelerator backends both donate the KV arena buffers (argnums 0/1),
-so the steady-state decode loop updates the cache in place with zero
-copies; on CPU donation is off by default because donated aliasing does
-not survive executable serialization there (see _donate_kv).  The compiled
-executables ship in a PR 7 ``MXAOT1`` bundle whose meta carries the
+All of them donate the KV arena buffers (argnums 0/1), so the
+steady-state decode loop updates the cache in place with zero copies.
+The compiled executables ship in a PR 7 ``MXAOT1`` bundle whose meta carries the
 KV-page geometry; a serving process deserializes them at startup and
 performs **zero live jits** (asserted by the serve-smoke CI job).
 
@@ -203,9 +201,15 @@ class KVGeometry:
         return cls(**d)
 
     def kv_shape(self):
-        """Arena buffer shape: (L, P, page, KV-heads, head-dim)."""
-        return (self.num_layers, self.num_pages, self.page_size,
-                self.num_kv_heads, self.head_dim)
+        """Arena buffer shape: (L, P, KV-heads, page, head-dim).
+
+        A page of one kv-head is the contiguous ``(page, head-dim)`` tile
+        the paged-attention kernel DMAs: the TPU lowering takes a block
+        only if its last two dims are the array's own or multiples of
+        (8, 128), which a block of 1 on a kv-head axis in second-minor
+        position is not."""
+        return (self.num_layers, self.num_pages, self.num_kv_heads,
+                self.page_size, self.head_dim)
 
     # the fields a replacement bundle must agree on for an in-place
     # hot-swap (``LlamaServer.reload``): everything the scheduler and
@@ -380,8 +384,7 @@ def build_step_fn(weights, geometry, k1):
 
     This is the shared body of ``decode`` (``k1=1``) and ``verify``
     (``k1=spec_k+1``).  Signature (all positional; kv buffers — and the
-    scale arrays for int8 — donated by the AOT compile when the backend
-    supports it, see ``_donate_kv``):
+    scale arrays for int8 — donated by the AOT compile):
 
     - fp32: ``(kv_k, kv_v, tokens (B, k1) i32, positions (B,) i32,
       block_table (B, maxp) i32) -> (kv_k, kv_v, logits (B, k1, V))``
@@ -432,7 +435,7 @@ def build_step_fn(weights, geometry, k1):
         """Scatter ``rows`` (B, k1, KV, D) at (li, pid, slot); quantize
         against per-page scales when the arena is int8."""
         if not int8:
-            return kv.at[li, pid, slot].set(rows), sc
+            return kv.at[li, pid, :, slot].set(rows.astype(kv.dtype)), sc
         r32 = rows.astype(jnp.float32)
         amax = jnp.max(jnp.abs(r32), axis=(2, 3))            # (B, k1)
         # in-call page starts: token j's page began at call offset
@@ -449,16 +452,16 @@ def build_step_fn(weights, geometry, k1):
                      -_INT8_QMAX, _INT8_QMAX).astype(jnp.int8)
         # rows of one page all write the page's resolved scale — equal
         # values, so duplicate scatter order cannot matter
-        return kv.at[li, pid, slot].set(q), sc.at[li, pid].set(news)
+        return kv.at[li, pid, :, slot].set(q), sc.at[li, pid].set(news)
 
     def gather(kv, sc, li, block_table, b, dt):
         """This lane's pages as (B, C, KV, D) in the model dtype."""
-        pages = kv[li, block_table]            # (B, maxp, S, KV, D)
+        pages = kv[li, block_table]            # (B, maxp, KV, S, D)
         if int8:
             ps = sc[li, block_table]           # (B, maxp)
             pages = (pages.astype(jnp.float32)
                      * ps[..., None, None, None]).astype(dt)
-        return pages.reshape(b, ctx, KV, D)
+        return pages.transpose(0, 1, 3, 2, 4).reshape(b, ctx, KV, D)
 
     def step(kv_k, kv_v, *rest):
         if int8:
@@ -495,9 +498,9 @@ def build_step_fn(weights, geometry, k1):
             else:
                 # XLA reference: still gathers the context, but attends
                 # grouped heads (B, k1, KV, G, ctx) directly — K/V are
-                # never replicated H/KV-fold (bitwise-identical logits
-                # to the old jnp.repeat form, tests/test_paged_attention
-                # .py::test_grouped_einsum_matches_repeat_bitwise)
+                # never replicated H/KV-fold (equal to the jnp.repeat
+                # form to float rounding, tests/test_paged_attention
+                # .py::test_grouped_einsum_matches_repeat)
                 keys = gather(kv_k, k_sc, li, block_table, b, x.dtype)
                 vals = gather(kv_v, v_sc, li, block_table, b, x.dtype)
                 qg = q.reshape(b, k1, KV, H // KV, D)
@@ -593,7 +596,8 @@ def build_prefill_fn(weights, geometry, bucket):
 
         def append(kv, sc, li, rows):
             if not int8:
-                return kv.at[li, pid, slot].set(rows), sc
+                return kv.at[li, pid, :, slot].set(
+                    rows.astype(kv.dtype)), sc
             r32 = rows.astype(jnp.float32)
             amax = jnp.max(jnp.abs(r32), axis=(1, 2))        # (T,)
             # every page start is in-call during prefill: row (p//S)*S
@@ -604,7 +608,7 @@ def build_prefill_fn(weights, geometry, bucket):
                                _INT8_MIN_SCALE)
             q = jnp.clip(jnp.round(r32 / news[:, None, None]),
                          -_INT8_QMAX, _INT8_QMAX).astype(jnp.int8)
-            return kv.at[li, pid, slot].set(q), sc.at[li, pid].set(news)
+            return kv.at[li, pid, :, slot].set(q), sc.at[li, pid].set(news)
 
         for li, lw in enumerate(layers):
             h = _rmsnorm(x, lw["attn_norm"], g.eps)
@@ -614,8 +618,8 @@ def build_prefill_fn(weights, geometry, bucket):
             kv_k, k_sc = append(kv_k, k_sc, li, k)
             kv_v, v_sc = append(kv_v, v_sc, li, v)
             # grouped-head attention: queries fold to (T, KV, G, D) so
-            # K/V are never replicated H/KV-fold (bitwise-identical to
-            # the old jnp.repeat form; head h = kv*G + g ordering)
+            # K/V are never replicated H/KV-fold (equal to the
+            # jnp.repeat form to float rounding; head h = kv*G + g)
             qg = q.reshape(t, KV, H // KV, D)
             scores = jnp.einsum("tvgd,uvd->vgtu", qg, k) * scale
             scores = jnp.where(causal[None, None, :, :],
@@ -637,40 +641,16 @@ def build_prefill_fn(weights, geometry, bucket):
     return prefill
 
 
-def _donate_kv():
-    """Should the serving executables donate the KV buffers (args 0, 1)?
-
-    ``MXNET_SERVE_AOT_DONATE`` = ``1`` forces on, ``0`` forces off,
-    unset/``auto`` donates everywhere EXCEPT the CPU backend.  On CPU
-    (jax 0.4.37) an executable that carries input-output aliasing does
-    not survive ``serialize_executable`` → ``deserialize_and_load``:
-    the reloaded binary's aliasing metadata is wrong and every run
-    corrupts the allocator heap — results stay correct but the process
-    dies with ``corrupted double-linked list`` / SIGSEGV at teardown
-    (~50% of runs; bisected fresh-vs-deserialized × donate-vs-not, only
-    the deserialized+donated cell fails).  Donation-free decode costs
-    one KV-arena copy per step, which CPU serving (tests, smoke CI)
-    can afford; accelerator backends keep the zero-copy path.
-    """
-    mode = os.environ.get("MXNET_SERVE_AOT_DONATE", "auto").lower()
-    if mode in ("1", "true"):
-        return True
-    if mode in ("0", "false"):
-        return False
-    import jax
-
-    return jax.default_backend() != "cpu"
-
-
 def _aot_compile(fn, avals, n_state=2):
     """jit → lower → compile; the first ``n_state`` args (KV buffers,
-    plus the two scale arrays for int8) donated when the backend
-    supports aliasing across serialization (_donate_kv)."""
+    plus the two scale arrays for int8) are donated, so the decode loop
+    updates the cache in place.  Aliasing survives executable
+    serialization on every backend under jax 0.9.0 (the CPU exception
+    made for 0.4.37 was re-tested and dropped)."""
     import jax
 
-    kwargs = {"donate_argnums": tuple(range(n_state))} \
-        if _donate_kv() else {}
-    return jax.jit(fn, **kwargs).lower(*avals).compile()
+    return jax.jit(fn, donate_argnums=tuple(range(n_state))) \
+        .lower(*avals).compile()
 
 
 def compile_serving_executables(net, geometry):

@@ -8,7 +8,7 @@ serve-smoke CI job asserts exactly this from the telemetry dump).
 
 The runner is the only jax-touching layer: it drains pending bulk
 segments that still read the arena (the executables donate the KV
-buffers on accelerator backends — see model._donate_kv), calls the
+buffers), calls the
 deserialized executable, adopts the new buffers into
 the arena, and hands numpy logits back to the jax-free scheduler.
 Sampling is host-side numpy, so the decode loop's device work is exactly

@@ -20,7 +20,7 @@ the legacy per-module mesh plumbing.
 from .spec import (  # noqa: F401
     Mesh, NamedSharding, PartitionSpec, P,
     as_jax_mesh, canonicalize_spec, named_sharding, spec_axes_label,
-    current_mesh, current_jax_mesh, push_mesh, pop_mesh,
+    current_mesh, current_jax_mesh, push_mesh, pop_mesh, batch_axis,
 )
 from .verify import enabled as verify_enabled  # noqa: F401
 from .verify import maybe_verify, verify_spec  # noqa: F401

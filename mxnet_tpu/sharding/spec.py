@@ -179,6 +179,14 @@ def current_jax_mesh():
     return as_jax_mesh(current_mesh())
 
 
+# The axis of jax's context mesh (``jax.set_mesh``) that carries the
+# batch, for ops that choose shard_map specs while a step program is
+# traced.  A jax user context, so it is part of every jit cache key like
+# the context mesh itself: ``with batch_axis("dp"): ...``,
+# ``batch_axis.value``.
+batch_axis = jax.make_user_context("data")
+
+
 # ---------------------------------------------------------------------------
 # adapters
 # ---------------------------------------------------------------------------

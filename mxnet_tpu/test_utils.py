@@ -13,13 +13,12 @@ TPU-native mechanism: instead of perturbing executor buffers in place
 (the reference mutates ``executor.arg_arrays``), both sides are pure
 functions built from the Symbol; the finite-difference loop re-runs ONE
 jitted scalar projection ``f(args) = Σ out·proj`` under
-``jax.experimental.enable_x64`` so the FD arithmetic happens in float64
+``jax.enable_x64`` so the FD arithmetic happens in float64
 even though the framework default is float32, and the analytic side is
 the very same ``jax.vjp`` path the real executors use.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 
 import numpy as np
@@ -261,16 +260,6 @@ def _parse_aux_states(sym, aux_states, dtype=np.float64):
     return out
 
 
-@contextlib.contextmanager
-def _x64():
-    # jax moved/removed the top-level alias; the supported spelling is
-    # jax.experimental.enable_x64 (present since 0.4.x).
-    from jax.experimental import enable_x64
-
-    with enable_x64(True):
-        yield
-
-
 def _project_fn(sym, bindings_names, projs, mode="train"):
     """Scalar f(grad_args, other_args) = Σ_i sum(out_i · proj_i)."""
     raw = sym._make_fn(bindings_names, mode=mode)
@@ -309,7 +298,7 @@ def check_numeric_gradient(sym, location, aux_states=None, numeric_eps=1e-4,
     grad_nodes = list(grad_nodes)
     mode = "train" if use_forward_train else "predict"
 
-    with _x64():
+    with jax.enable_x64(True):
         key = jax.random.PRNGKey(0)
         # fixed random projection per output
         probe = sym._make_fn(sym.list_inputs(), mode=mode)

@@ -1,15 +1,18 @@
 """The driver records bench.py's stdout verbatim; this pins the JSON
-contract (platform/fallback provenance fields + the multi-metric array)
+contract (the platform provenance field + the multi-metric array)
 without running the heavy benchmarks.
 
 Round-3 lesson: a CPU-fallback number with no machine-readable platform
 field was indistinguishable from a 300x chip regression in the recorded
-artifact.  These tests make that shape impossible to lose silently.
+artifact.  There is no fallback any more (ISSUE 22): no TPU, or a metric
+that fails, fails the run — and every record still names its platform.
 """
 import importlib.util
 import json
 import os
 import sys
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -23,7 +26,10 @@ def _load_bench():
 
 
 def _stub(mod, monkeypatch, values):
-    monkeypatch.setattr(mod, "_init_backend", lambda: ("cpu", False))
+    monkeypatch.setattr(mod, "_init_backend", lambda: "cpu")
+    # the default mode runs each metric as `python bench.py <name>`; the
+    # stubs live in this process, so measure here
+    monkeypatch.setattr(mod, "_measure_in_child", mod._measure)
     specs = {}
     for name, (_, metric, unit, baseline) in mod._SPECS.items():
         specs[name] = (lambda platform, v=values[name]: v,
@@ -118,7 +124,7 @@ def test_single_metric_line(monkeypatch, capsys):
     assert rec["metric"] == "bert_base_train_throughput"
     assert rec["value"] == 300.0
     assert rec["platform"] == "cpu"
-    assert rec["fallback"] is False
+    assert "fallback" not in rec
     # ISSUE 9: every record carries the device-memory high-water mark
     assert isinstance(rec["peak_device_bytes"], int)
     assert rec["peak_device_bytes"] >= 0
@@ -137,7 +143,7 @@ def test_default_mode_emits_all_metrics_in_one_line(monkeypatch, capsys):
     assert rec["metric"] == "resnet50_train_throughput"
     assert rec["value"] == 100.0
     assert rec["vs_baseline"] > 0
-    assert rec["platform"] == "cpu" and rec["fallback"] is False
+    assert rec["platform"] == "cpu" and "fallback" not in rec
     # every metric in the array, each with provenance
     names = [m["metric"] for m in rec["metrics"]]
     assert names == ["resnet50_train_throughput",
@@ -158,7 +164,7 @@ def test_default_mode_emits_all_metrics_in_one_line(monkeypatch, capsys):
                      "resnet50_cold_start_seconds",
                      "bert_cold_start_seconds",
                      "llama_cold_start_seconds"]
-    assert all("platform" in m and "fallback" in m for m in rec["metrics"])
+    assert all(m["platform"] == "cpu" for m in rec["metrics"])
     # ISSUE 9: memory provenance in every row, headline included
     assert isinstance(rec["peak_device_bytes"], int)
     assert all(isinstance(m["peak_device_bytes"], int)
@@ -267,48 +273,74 @@ def test_budget_exhaustion_marks_skipped(monkeypatch, capsys):
     assert all(m["value"] == 0.0 for m in skipped)
 
 
-def test_failed_benchmark_emits_zero_not_crash(monkeypatch, capsys):
+def test_failed_benchmark_fails_the_run(monkeypatch, capsys):
     mod = _load_bench()
 
     def boom(platform):
         raise RuntimeError("synthetic failure")
 
-    monkeypatch.setattr(mod, "_init_backend", lambda: ("cpu", True))
-    monkeypatch.setattr(mod.time, "sleep", lambda s: None)  # retry pauses
-    monkeypatch.setattr(mod, "_SPECS", {
-        "train": (boom, "resnet50_train_throughput", "images/sec", 363.69),
-        "infer": (boom, "resnet50_infer_throughput", "images/sec", 2085.51),
-        "bert": (boom, "bert_base_train_throughput", "samples/sec", None),
-        "llama": (boom, "llama_decoder_train_throughput", "tokens/sec",
-                  None),
-        "dispatch_eager": (boom, "imperative_dispatch_eager", "ops/sec",
-                           None),
-        "dispatch_eager_notelemetry": (
-            boom, "imperative_dispatch_eager_notelemetry", "ops/sec",
-            None),
-        "dispatch_bulked": (boom, "imperative_dispatch_bulked", "ops/sec",
-                            None),
-        "dispatch_bulked_train": (
-            boom, "imperative_dispatch_bulked_train", "ops/sec", None),
-        "dispatch_bulked_long": (
-            boom, "imperative_dispatch_bulked_long", "ops/sec", None),
-        "serve": (boom, "llama_serve_tok_s", "tokens/sec", None),
-        "serve_spec": (boom, "llama_serve_spec_tok_s", "tokens/sec",
-                       None),
-        "serve_paged": (boom, "llama_serve_paged_tok_s", "tokens/sec",
-                        None),
-        "prefix": (boom, "llama_serve_prefix_tok_s", "tokens/sec",
-                   None),
-        "fleet": (boom, "fleet_serve_tok_s", "tokens/sec", None),
-        "planner": (boom, "planner_seconds", "seconds", None),
-        "cold_resnet50": (boom, "resnet50_cold_start_seconds", "seconds",
-                          None),
-        "cold_bert": (boom, "bert_cold_start_seconds", "seconds", None),
-        "cold_llama": (boom, "llama_cold_start_seconds", "seconds", None),
-    })
-    monkeypatch.setattr(sys, "argv", ["bench.py"])
-    mod.main()
-    rec = json.loads([ln for ln in capsys.readouterr().out.splitlines()
-                      if ln.startswith("{")][-1])
-    assert rec["value"] == 0.0 and rec["fallback"] is True
-    assert len(rec["metrics"]) == 18
+    values = dict(_STUB_VALUES)
+    _stub(mod, monkeypatch, values)
+    specs = dict(mod._SPECS)
+    specs["bert"] = (boom,) + specs["bert"][1:]
+    monkeypatch.setattr(mod, "_SPECS", specs)
+    # no retry, no value 0: the metric's exception ends the run, in
+    # single-metric mode and from the middle of the default mode alike
+    for argv in (["bench.py", "bert"], ["bench.py"]):
+        monkeypatch.setattr(sys, "argv", argv)
+        with pytest.raises(RuntimeError, match="synthetic failure"):
+            mod.main()
+        assert not [ln for ln in capsys.readouterr().out.splitlines()
+                    if ln.startswith("{")]
+
+
+def test_spawning_metric_initialises_no_backend_before_its_probes(
+        monkeypatch, capsys):
+    mod = _load_bench()
+    order = []
+    _stub(mod, monkeypatch, _STUB_VALUES)
+    monkeypatch.setattr(mod, "_init_backend",
+                        lambda: order.append("backend") or "cpu")
+    specs = dict(mod._SPECS)
+    for name in ("serve", "llama"):
+        specs[name] = (lambda platform, n=name: order.append(n) or 1.0,) \
+            + specs[name][1:]
+    monkeypatch.setattr(mod, "_SPECS", specs)
+    for name in ("serve", "llama"):
+        monkeypatch.setattr(sys, "argv", ["bench.py", name])
+        mod.main()
+    # a chip belongs to one process: the parent of probe processes that
+    # need it touches jax only after they are done
+    assert order == ["serve", "backend", "backend", "llama"]
+
+
+def test_no_tpu_is_an_error_unless_told_cpu(monkeypatch, capsys):
+    from mxnet_tpu import context
+    from mxnet_tpu.base import MXNetError
+
+    mod = _load_bench()
+    # this process was told JAX_PLATFORMS=cpu; take that away (in
+    # process: a child with the variable unset would load libtpu)
+    monkeypatch.setattr(context, "_told_cpu", lambda: False)
+    monkeypatch.setattr(sys, "argv", ["bench.py", "llama"])
+    with pytest.raises(MXNetError, match="no accelerator"):
+        mod.main()
+    assert not capsys.readouterr().out.strip()
+
+
+def test_cold_start_probes_never_see_the_machines_cache(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins over MXNET_COMPILE_CACHE_DIR; a
+    machine that exports it would hand the 'cold' probe a warm cache."""
+    mod = _load_bench()
+    envs = []
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/shared/warm/cache")
+    monkeypatch.setattr(
+        mod, "_probe_subprocess",
+        lambda argv, env, marker, what: envs.append(dict(env)) or "1.0")
+    out = mod._run_cold_start("llama")
+    assert out["value"] == 1.0 and len(envs) == 2
+    for env in envs:
+        assert "JAX_COMPILATION_CACHE_DIR" not in env
+        assert env["MXNET_COMPILE_CACHE_DIR"] == \
+            envs[0]["MXNET_COMPILE_CACHE_DIR"]
+    assert not os.path.exists(envs[0]["MXNET_COMPILE_CACHE_DIR"])

@@ -244,3 +244,60 @@ def test_o0_and_o2_artifacts_never_cross_hit(tmp_path):
     run(record=True)                       # same recorded chain again
     assert set(os.listdir(cache)) == after_o0, \
         "third process re-wrote entries instead of hitting the cache"
+
+
+# -- the one cache rule (ISSUE 22) ---------------------------------------
+
+def _configure_recording(monkeypatch, env):
+    """configure(env) with jax.config.update recorded, not applied."""
+    import jax
+    from mxnet_tpu import compile_cache as cc
+
+    updates = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.__setitem__(k, v))
+    monkeypatch.setattr(cc.os, "makedirs", lambda *a, **k: None)
+    monkeypatch.setattr(cc, "_state", dict(cc._state, atexit=True))
+    return cc.configure(env), cc.cache_dir(), updates
+
+
+def test_jax_cache_dir_env_is_left_to_jax(monkeypatch, tmp_path):
+    want = str(tmp_path / "from_jax")
+    on, where, updates = _configure_recording(monkeypatch, {
+        "JAX_COMPILATION_CACHE_DIR": want,
+        "MXNET_COMPILE_CACHE_DIR": str(tmp_path / "ignored")})
+    assert on and where == want   # recorded for counters and budget
+    assert "jax_compilation_cache_dir" not in updates
+    # MXNET_COMPILE_CACHE=0 keeps its meaning below that rule
+    on, _, updates = _configure_recording(monkeypatch, {
+        "JAX_COMPILATION_CACHE_DIR": want, "MXNET_COMPILE_CACHE": "0"})
+    assert not on and updates == {"jax_enable_compilation_cache": False}
+
+
+def test_default_cache_dir_is_the_checkout(monkeypatch):
+    on, where, updates = _configure_recording(
+        monkeypatch, {"MXNET_COMPILE_CACHE": "1"})
+    assert on and where == os.path.join(REPO, ".jax_cache")
+    assert updates["jax_compilation_cache_dir"] == where
+    # a CPU process (this one) stays off unless it opts in
+    on, _, updates = _configure_recording(monkeypatch, {})
+    assert not on and not updates
+
+
+def test_unset_platform_is_not_read_as_cpu():
+    # a stock TPU host sets no JAX_PLATFORMS: the cache must come on, at
+    # import, without initialising a backend to find out
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_PLATFORMS", "JAX_COMPILATION_CACHE_DIR")
+           and not k.startswith("MXNET_COMPILE_CACHE")}
+    code = ("import jax, mxnet_tpu\n"
+            "from mxnet_tpu import compile_cache as cc\n"
+            "from jax._src import xla_bridge as xb\n"
+            "print(cc.enabled(), cc.cache_dir(),"
+            " jax.config.jax_compilation_cache_dir,"
+            " xb.backends_are_initialized())")
+    r = subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
+                       capture_output=True, text=True, timeout=240)
+    assert r.returncode == 0, r.stderr
+    where = os.path.join(REPO, ".jax_cache")
+    assert r.stdout.split() == ["True", where, where, "False"]

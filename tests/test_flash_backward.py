@@ -125,3 +125,109 @@ def test_flash_gluon_training_path():
     loss.backward()
     g = q.grad.asnumpy()
     assert np.isfinite(g).all() and np.abs(g).max() > 0
+
+
+def test_flash_per_shard_on_a_mesh_matches_one_device():
+    """Traced under a context mesh (JitTrainStep with a mesh does that)
+    the kernel runs per shard inside shard_map — batch over `data`, heads
+    over `model` — and must give what the one-device kernel gives,
+    forward and grads; an eager trace of the op made before must not be
+    reused there."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                ("data", "model"))
+    rs = np.random.RandomState(3)
+    q, k, v, w = (jnp.asarray(rs.randn(2, 4, 32, 8), jnp.float32) * 0.5
+                  for _ in range(4))
+
+    def loss(q, k, v):
+        out = pk.flash_attention(q, k, v, causal=True, block_q=8,
+                                 block_k=16)
+        return (out * w).sum()
+
+    both = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+    want = both(q, k, v)
+    sharded = NamedSharding(mesh, P("data", "model", None, None))
+    with jax.set_mesh(mesh):
+        got = both(*(jax.device_put(x, sharded) for x in (q, k, v)))
+        text = both.lower(q, k, v).as_text()
+    assert "shard_map" in text or "manual" in text
+    assert got[1][0].sharding.is_equivalent_to(sharded, 4)
+    for g, r in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("manual", [("data", "model"), ("data",)],
+                         ids=["all-axes-manual", "data-manual-model-auto"])
+def test_flash_inside_a_callers_shard_map(manual):
+    """Inside a caller's own shard_map (parallel.shard_map, a gpipe
+    stage, moe) the mesh's axes are already manual: the op must not wrap
+    itself over them a second time, only over what is left to GSPMD."""
+    from jax.sharding import Mesh, PartitionSpec as P
+    from mxnet_tpu import parallel
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                ("data", "model"))
+    rs = np.random.RandomState(4)
+    q, k, v, w = (jnp.asarray(rs.randn(2, 4, 32, 8), jnp.float32) * 0.5
+                  for _ in range(4))
+
+    def attend(q, k, v):
+        return pk.flash_attention(q, k, v, causal=True, block_q=8,
+                                  block_k=16)
+
+    spec = P("data", "model" if "model" in manual else None, None, None)
+    if len(manual) == 2:
+        mapped = parallel.shard_map(attend, mesh, (spec,) * 3, spec)
+    else:
+        mapped = jax.shard_map(attend, mesh=mesh, in_specs=(spec,) * 3,
+                               out_specs=spec, axis_names=set(manual),
+                               check_vma=False)
+
+    def loss(f, q, k, v):
+        return (f(q, k, v) * w).sum()
+
+    want = jax.jit(jax.value_and_grad(
+        lambda *a: loss(attend, *a), argnums=(0, 1, 2)))(q, k, v)
+    got = jax.jit(jax.value_and_grad(
+        lambda *a: loss(mapped, *a), argnums=(0, 1, 2)))(q, k, v)
+    for g, r in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_flash_per_shard_takes_the_steps_batch_axis():
+    """The batch goes over `sharding.batch_axis` (JitTrainStep sets it to
+    its data_axis), whatever the axis is called; an axis that neither
+    batch nor heads divide over is warned about, not silently
+    replicated."""
+    import warnings
+    from jax.sharding import Mesh
+    from mxnet_tpu import sharding
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
+    rs = np.random.RandomState(5)
+    q = jnp.asarray(rs.randn(2, 2, 32, 8), jnp.float32) * 0.5
+
+    def attend(q):
+        return pk.flash_attention(q, q, q, causal=True, block_q=8,
+                                  block_k=16)
+
+    want = attend(q)
+    with jax.set_mesh(mesh), sharding.batch_axis("dp"), \
+            warnings.catch_warnings():
+        warnings.simplefilter("error")
+        text = jax.jit(attend).lower(q).as_text()
+        got = jax.jit(attend)(q)
+    assert '{"dp"}, {"tp"}' in text, text[:2000]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    # no axis called "data" here, and 2 heads do not divide over 4
+    with jax.set_mesh(mesh), pytest.warns(UserWarning, match="replicated"):
+        got = jax.jit(attend)(q)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)

@@ -1,7 +1,7 @@
 """mx.name (NameManager/Prefix) and mx.error / mx.executor parity.
 
 Reference: ``python/mxnet/name.py`` (auto-naming manager stack),
-``python/mxnet/error.py`` (registered error taxonomy),
+``python/mxnet/error.py`` (registered error hierarchy),
 ``python/mxnet/executor.py`` (Executor exposure).
 """
 import numpy as np

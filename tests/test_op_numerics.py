@@ -674,10 +674,10 @@ SPECS["_contrib_flash_attention"] = S(
 
 def _paged_attn_ref(q, kp, vp, tbl, pos):
     b, k1, h, d = q.shape
-    s_page, kv = kp.shape[1], kp.shape[2]
+    kv, s_page = kp.shape[1], kp.shape[2]           # pages (P, KV, S, D)
     grp, ctx = h // kv, tbl.shape[1] * s_page
-    keys = kp[tbl].reshape(b, ctx, kv, d)
-    vals = vp[tbl].reshape(b, ctx, kv, d)
+    keys = kp[tbl].transpose(0, 1, 3, 2, 4).reshape(b, ctx, kv, d)
+    vals = vp[tbl].transpose(0, 1, 3, 2, 4).reshape(b, ctx, kv, d)
     s = np.einsum("bkvgd,bcvd->bkvgc", q.reshape(b, k1, kv, grp, d),
                   keys) / np.sqrt(d)
     posk = pos[:, None] + np.arange(k1)[None, :]

@@ -24,15 +24,15 @@ def _case(seed, b=2, k1=1, h=2, kv=2, d=4, pages=6, s_page=4, int8=False):
     rng = np.random.default_rng(seed)
     q = rng.standard_normal((b, k1, h, d)).astype(np.float32)
     if int8:
-        kp = rng.integers(-127, 128, size=(pages, s_page, kv, d),
+        kp = rng.integers(-127, 128, size=(pages, kv, s_page, d),
                           dtype=np.int64).astype(np.int8)
-        vp = rng.integers(-127, 128, size=(pages, s_page, kv, d),
+        vp = rng.integers(-127, 128, size=(pages, kv, s_page, d),
                           dtype=np.int64).astype(np.int8)
         scales = (rng.uniform(0.01, 0.05, size=pages).astype(np.float32),
                   rng.uniform(0.01, 0.05, size=pages).astype(np.float32))
     else:
-        kp = rng.standard_normal((pages, s_page, kv, d)).astype(np.float32)
-        vp = rng.standard_normal((pages, s_page, kv, d)).astype(np.float32)
+        kp = rng.standard_normal((pages, kv, s_page, d)).astype(np.float32)
+        vp = rng.standard_normal((pages, kv, s_page, d)).astype(np.float32)
         scales = ()
     tbl = np.array([[1, 2, 0], [3, 4, 5]], np.int32)
     pos = np.array([4, 7], np.int32)        # pos + k1 - 1 stays in-page
@@ -106,10 +106,12 @@ def test_paged_attention_validates_inputs():
 
 # -- satellite: grouped-einsum GQA fallback ------------------------------
 
-def test_grouped_einsum_matches_repeat_bitwise():
-    """The serving fallback's grouped einsums vs the old jnp.repeat
-    formulation — bitwise, decode/verify AND prefill shapes, through
-    the full mask + softmax + value pipeline on the CPU backend."""
+def test_grouped_einsum_matches_repeat():
+    """The serving fallback's grouped einsums vs the jnp.repeat
+    formulation — decode/verify AND prefill shapes, through the full
+    mask + softmax + value pipeline on the CPU backend.  The two
+    contract in a different order, so they agree to float32 rounding
+    (one ulp under the installed XLA:CPU), not bitwise."""
     rng = np.random.default_rng(11)
     b, k1, h, kv, d, ctx = 2, 3, 4, 1, 4, 12
     grp = h // kv
@@ -138,8 +140,9 @@ def test_grouped_einsum_matches_repeat_bitwise():
         return jnp.einsum("bkvgc,bcvd->bkvgd", p, vals) \
             .reshape(b, k1, h, d)
 
-    assert np.array_equal(np.asarray(old(q, keys, vals)),
-                          np.asarray(new(q, keys, vals)))
+    np.testing.assert_allclose(np.asarray(old(q, keys, vals)),
+                               np.asarray(new(q, keys, vals)),
+                               rtol=1e-6, atol=1e-6)
 
     # prefill shapes: (t, H, D) queries against (u, KV, D) keys
     t, u = 6, 8
@@ -165,8 +168,9 @@ def test_grouped_einsum_matches_repeat_bitwise():
         p = jax.nn.softmax(s, axis=-1)
         return jnp.einsum("vgtu,uvd->tvgd", p, v).reshape(t, h * d)
 
-    assert np.array_equal(np.asarray(old_pre(q2, k2, v2)),
-                          np.asarray(new_pre(q2, k2, v2)))
+    np.testing.assert_allclose(np.asarray(old_pre(q2, k2, v2)),
+                               np.asarray(new_pre(q2, k2, v2)),
+                               rtol=1e-6, atol=1e-6)
 
 
 # -- geometry plumbing ---------------------------------------------------

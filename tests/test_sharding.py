@@ -552,7 +552,7 @@ def test_kv_arena_shards_on_mesh(eight_devices, monkeypatch):
         return KVGeometry(**kw)
 
     mesh = Mesh({"model": 2})
-    spec = P(None, None, None, "model", None)   # KV heads on tp axis
+    spec = P(None, None, "model", None, None)   # KV heads on tp axis
     arena = PagedKVArena(geom(), mesh=mesh, kv_spec=spec)
     for buf in (arena.kv_k, arena.kv_v):
         assert isinstance(buf.sharding, NamedSharding)

@@ -31,14 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-cache_dir = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), ".jax_cache")
-try:
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-except Exception:
-    pass
+import mxnet_tpu  # noqa: E402,F401 - applies the compile-cache rule
 
 VOCAB, D, FFN, LAYERS, HEADS, KV_HEADS = 32000, 768, 2048, 12, 12, 4
 HD = D // HEADS  # 64

@@ -35,15 +35,6 @@ sys.path.insert(0, %(root)r)
 import jax, jax.numpy as jnp
 
 cfg = json.loads(os.environ["SWEEP_CFG"])
-try:
-    cache = os.path.join(%(root)r, ".jax_cache")
-    os.makedirs(cache, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-except Exception:
-    pass
-
 import mxnet_tpu as mx
 from mxnet_tpu import gluon, parallel
 from mxnet_tpu.gluon.model_zoo import vision

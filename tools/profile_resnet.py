@@ -17,13 +17,6 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    cache_dir = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), ".jax_cache")
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-
     import mxnet_tpu as mx
     from mxnet_tpu import gluon, parallel, amp
     from mxnet_tpu.gluon.model_zoo import vision
