@@ -185,6 +185,7 @@ def _paged_pallas(q, k_pages, v_pages, block_table, positions, *scales,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, qg, d), q.dtype),
         interpret=interpret,
+        name="mx_paged_attention",
     )(block_table, positions, *scales, q4, k_pages, v_pages)
     return out4.reshape(b, kv, k1, grp, d).transpose(0, 2, 1, 3, 4) \
         .reshape(b, k1, h, d)

@@ -145,6 +145,7 @@ def _flash_pallas(q, k, v, scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((bh, t_q, _LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="mx_flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -264,6 +265,7 @@ def _flash_bwd_pallas(q, k, v, do, lse, delta, scale, causal, block_q,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t_q, d), q.dtype),
         interpret=interpret,
+        name="mx_flash_bwd_dq",
     )(q, k, v, do, lse, delta)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
@@ -286,6 +288,7 @@ def _flash_bwd_pallas(q, k, v, do, lse, delta, scale, causal, block_q,
             jax.ShapeDtypeStruct((bh, t_kv, d), v.dtype),
         ],
         interpret=interpret,
+        name="mx_flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

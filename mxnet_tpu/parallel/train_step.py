@@ -30,6 +30,7 @@ from .. import random as _random
 from .. import autograd as _autograd
 from .. import optimizer as _opt_mod
 from ..gluon import block as _block_mod
+from ..profiler import span as _span
 
 
 class JitTrainStep:
@@ -395,28 +396,46 @@ class JitTrainStep:
                 self._put_global(lr, self._mh_rep),
                 self._put_global(t, self._mh_rep))
 
-    def step(self, *batch):
-        """Run one train step; returns the (device, async) scalar loss."""
+    def _placed(self, batch):
+        """``batch`` as device arrays where the step wants them."""
         batch_nd = [b if isinstance(b, NDArray) else nd.array(b)
                     for b in batch]
         self._ensure_init(batch_nd)
-        arrays = self._place_batch(batch_nd)
-        self._batch_avals = tuple(
-            jax.ShapeDtypeStruct(a.shape, a.dtype) for a in arrays)
-        if self._step_fn is None:
-            self._step_fn = self._build(arrays)
-        self._t += 1
-        self._opt.num_update = self._t
-        key, lr, t = self._scalar_args(
+        return self._place_batch(batch_nd)
+
+    def _scalars(self):
+        """key/lr/t of the next dispatch (``t`` is ``self._t`` as it
+        stands)."""
+        return self._scalar_args(
             _random.next_key(),
             jnp.asarray(self._opt.learning_rate, jnp.float32),
             jnp.asarray(self._t, jnp.int32))
-        with self._mesh_scope():
-            self._weights, self._opt_state, loss = self._step_fn(
-                key, lr, self._weights, self._opt_state, t, *arrays)
-        self._tag_weights()
-        self._last_loss = loss
-        return loss
+
+    def step(self, *batch):
+        """Run one train step; returns the (device, async) scalar loss.
+
+        Four spans (``profiler.span``) partition the call, under
+        ``mx:train_step``: ``.place_batch``, ``.scalars``, ``.call`` (the
+        first call's trace and compile fall here) and ``.tag``."""
+        with _span("train_step"):
+            with _span("train_step.place_batch"):
+                arrays = self._placed(batch)
+                self._batch_avals = tuple(
+                    jax.ShapeDtypeStruct(a.shape, a.dtype) for a in arrays)
+            self._t += 1
+            self._opt.num_update = self._t
+            with _span("train_step.scalars"):
+                key, lr, t = self._scalars()
+            with _span("train_step.call"):
+                if self._step_fn is None:
+                    self._step_fn = self._build(arrays)
+                with self._mesh_scope():
+                    self._weights, self._opt_state, loss = self._step_fn(
+                        key, lr, self._weights, self._opt_state, t, *arrays)
+            with _span("train_step.tag"):
+                self._tag_weights()
+            self._last_loss = loss
+            return loss
 
     def step_n(self, n, *batch):
         """Run ``n`` train steps as ONE device-side loop (single dispatch).
@@ -433,8 +452,6 @@ class JitTrainStep:
         single-dispatch methodology works on a pod the same as on one
         chip.
         """
-        from jax import lax
-
         sched = getattr(self._opt, "lr_scheduler", None)
         sched_traced = None
         if sched is not None:
@@ -457,10 +474,27 @@ class JitTrainStep:
             for _ in range(int(n)):
                 loss = self.step(*batch)
             return loss
-        batch_nd = [b if isinstance(b, NDArray) else nd.array(b)
-                    for b in batch]
-        self._ensure_init(batch_nd)
-        arrays = self._place_batch(batch_nd)
+        with _span("train_step_n"):
+            with _span("train_step.place_batch"):
+                arrays = self._placed(batch)
+            self._opt.num_update = self._t + n
+            with _span("train_step.scalars"):
+                key, lr, t = self._scalars()
+            with _span("train_step.call"):
+                fn = self._step_n_fn(n, sched, sched_traced, arrays)
+                with self._mesh_scope():
+                    self._weights, self._opt_state, loss = fn(
+                        key, lr, self._weights, self._opt_state, t, *arrays)
+            with _span("train_step.tag"):
+                self._tag_weights()
+            self._t += n
+            self._last_loss = loss
+            return loss
+
+    def _step_n_fn(self, n, sched, sched_traced, arrays):
+        """The jitted ``n``-step loop, built once per ``(n, scheduler)``."""
+        from jax import lax
+
         if self._step_fn is None:
             self._step_fn = self._build(arrays)
         if not hasattr(self, "_raw_step"):
@@ -475,44 +509,34 @@ class JitTrainStep:
         # still won't retrace — schedules are constants of the executable)
         sched_key = (n, id(sched) if sched_traced is not None else None)
         fn = self._step_n_cache.get(sched_key)
-        if fn is None:
-            raw = self._raw_step
+        if fn is not None:
+            return fn
+        raw = self._raw_step
 
-            def loop(key, lr, weights, state, t, *arrs):
-                def body(i, carry):
-                    w, s, _ = carry
-                    # t is the count BEFORE this window; iteration i runs
-                    # update number t+i+1 (step() uses 1-based counts —
-                    # Adam's bias correction divides by 1-beta^t, so a
-                    # 0-based counter would produce 0/0 on step one)
-                    # scheduled lr is evaluated device-side per iteration
-                    lr_i = (sched_traced(t + i + 1).astype(jnp.float32)
-                            if sched_traced is not None else lr)
-                    nw, ns, loss = raw(jax.random.fold_in(key, i), lr_i,
-                                       w, s, t + i + 1, *arrs)
-                    return (nw, ns, loss.astype(jnp.float32))
+        def loop(key, lr, weights, state, t, *arrs):
+            def body(i, carry):
+                w, s, _ = carry
+                # t is the count BEFORE this window; iteration i runs
+                # update number t+i+1 (step() uses 1-based counts —
+                # Adam's bias correction divides by 1-beta^t, so a
+                # 0-based counter would produce 0/0 on step one)
+                # scheduled lr is evaluated device-side per iteration
+                lr_i = (sched_traced(t + i + 1).astype(jnp.float32)
+                        if sched_traced is not None else lr)
+                nw, ns, loss = raw(jax.random.fold_in(key, i), lr_i,
+                                   w, s, t + i + 1, *arrs)
+                return (nw, ns, loss.astype(jnp.float32))
 
-                return lax.fori_loop(
-                    0, n, body,
-                    (weights, state, jnp.float32(0.0)))
+            return lax.fori_loop(
+                0, n, body,
+                (weights, state, jnp.float32(0.0)))
 
-            jit_kwargs = {}
-            if self._mesh is not None:
-                jit_kwargs["out_shardings"] = self._out_shardings()
-            fn = jax.jit(loop, donate_argnums=(2, 3), **jit_kwargs)
-            self._step_n_cache[sched_key] = fn
-        self._opt.num_update = self._t + n
-        key, lr, t = self._scalar_args(
-            _random.next_key(),
-            jnp.asarray(self._opt.learning_rate, jnp.float32),
-            jnp.asarray(self._t, jnp.int32))
-        with self._mesh_scope():
-            self._weights, self._opt_state, loss = fn(
-                key, lr, self._weights, self._opt_state, t, *arrays)
-        self._tag_weights()
-        self._t += n
-        self._last_loss = loss
-        return loss
+        jit_kwargs = {}
+        if self._mesh is not None:
+            jit_kwargs["out_shardings"] = self._out_shardings()
+        fn = jax.jit(loop, donate_argnums=(2, 3), **jit_kwargs)
+        self._step_n_cache[sched_key] = fn
+        return fn
 
     def _checkpoint_entries(self):
         """Yield ``(name, global host array, spec)`` for every weight and

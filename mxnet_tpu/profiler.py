@@ -20,6 +20,8 @@ import json
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 _lock = threading.Lock()
 _config = {
     "filename": "profile.json",
@@ -100,8 +102,6 @@ def add_span(name, t_start_us, t_end_us, cat="operator", tid=None,
     if not _recording():
         return
     if tid is None:
-        import threading
-
         tid = threading.get_ident() & 0xFFFF
     ev = {
         "name": name, "ph": "X", "cat": cat,
@@ -277,33 +277,64 @@ class Domain:
         return Marker(self, name)
 
 
+class _Annotation(TraceAnnotation):
+    """The one span primitive.  Entering and leaving it is entering and
+    leaving a ``jax.profiler.TraceAnnotation``: inside any ``jax.profiler``
+    trace the span lies on the host plane of the same file, and the same
+    clock, as the device's operations.  Where ``mx.profiler`` itself is
+    running, leaving it also records the chrome event (``add_span``), so
+    ``profile.json`` shows the same spans.  With neither running nothing
+    is stored."""
+
+    def __init__(self, name, event, cat, tid=None):
+        super().__init__(name)
+        self._event, self._cat, self._tid = event, cat, tid
+        self._start = None
+
+    def __enter__(self):
+        self._start = _now_us() if _state["running"] else None
+        super().__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        start, self._start = self._start, None
+        if start is not None:
+            add_span(self._event, start, _now_us(), cat=self._cat,
+                     tid=self._tid)
+
+
+def span(name):
+    """Context manager for one named stretch of the program's own work,
+    ``mx:<name>`` in a device trace (``set_config(xla_trace_dir=...)``, or
+    any ``jax.profiler`` trace around the program) and in ``dump()``'s
+    chrome trace.  It takes a name and nothing else: what would be a
+    keyword goes into the name, and order in time says which step."""
+    name = "mx:" + name
+    return _Annotation(name, name, "span")
+
+
 class _Span:
     _tid = 1
 
     def __init__(self, domain, name):
         self.domain = domain
         self.name = name
-        self._start = None
+        self._open = None
         cls = _Span
         self._tid_id = cls._tid
         cls._tid = cls._tid + 1
 
     def start(self):
-        self._start = _now_us()
+        self._open = _Annotation("mx:" + self.name, self.name,
+                                 str(self.domain), self._tid_id)
+        self._open.__enter__()
 
     def stop(self):
-        if self._start is None:
+        if self._open is None:
             return
-        start, self._start = self._start, None
-        if not _recording():  # same gate as add_span
-            return
-        with _lock:
-            _events.append({
-                "name": self.name, "ph": "X",
-                "cat": str(self.domain), "ts": start,
-                "dur": _now_us() - start,
-                "pid": 0, "tid": self._tid_id,
-            })
+        ann, self._open = self._open, None
+        ann.__exit__(None, None, None)
 
     def __str__(self):
         return self.name

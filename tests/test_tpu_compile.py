@@ -78,8 +78,12 @@ def test_flash_forward_t512(one_chip):
 def test_flash_backward_t512(one_chip):
     c = _compile(jax.grad(_flash_loss, argnums=(0, 1, 2)), one_chip,
                  *_flash_shapes(512, 4))
-    # forward kernel + dq kernel + dk/dv kernel
-    assert c.as_text().count("tpu_custom_call") >= 3
+    # forward kernel + dq kernel + dk/dv kernel, each an instruction under
+    # the name the program gave it (what a device trace shows)
+    text = c.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    for name in ("mx_flash_fwd", "mx_flash_bwd_dq", "mx_flash_bwd_dkv"):
+        assert "%%%s." % name in text
 
 
 @pytest.mark.xfail(strict=True, raises=Exception,
@@ -135,3 +139,4 @@ def test_paged_attention(one_chip, geometry, k1, kv_dtype):
     # use_kernel unset: on a TPU the op must pick the kernel by itself
     c = _compile(paged_attention, one_chip, *shapes)
     assert "tpu_custom_call" in c.as_text()
+    assert "%mx_paged_attention" in c.as_text()
