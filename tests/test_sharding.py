@@ -463,6 +463,72 @@ def test_megatron_tp_bitwise_parity_wrapper_vs_raw_mesh(eight_devices):
     assert rule("blk_attn_o_weight", (16, 32)) == P(None, "model")
 
 
+def test_mesh_step_compiler_options_follow_the_devices(eight_devices,
+                                                        monkeypatch):
+    """The overlap options (PR 28) are the TPU compiler's: a CPU mesh and
+    the one-device path hand ``jax.jit`` none, and compile what they
+    always compiled (the bitwise parity tests above run through here)."""
+    from mxnet_tpu.parallel import train_step as ts
+
+    cpu_mesh = as_jax_mesh(Mesh({"data": 4, "model": 2}))
+    assert ts.mesh_compiler_options(None) is None
+    assert ts.mesh_compiler_options(cpu_mesh) is None
+    seen = []
+    real_jit = jax.jit
+
+    def spy(fn, **kwargs):
+        seen.append(kwargs)
+        return real_jit(fn, **kwargs)
+
+    monkeypatch.setattr(ts.jax, "jit", spy)
+    _train_losses(cpu_mesh, steps=1)
+    _train_losses(None, steps=1)
+    steps = [kw for kw in seen if kw.get("donate_argnums") == (2, 3)]
+    assert len(steps) == 2
+    assert steps[0]["compiler_options"] is None
+    assert "out_shardings" in steps[0]
+    assert set(steps[1]) == {"donate_argnums"}
+
+
+def test_mesh_trained_parameter_keeps_no_gradient_buffer(eight_devices):
+    """A mesh step has its gradients inside the step program only, so
+    placing a parameter's shards releases gluon's zero-gradient buffer on
+    the context device (PR 28: the room the overlapped all-reduces need).
+    The parameter still answers ``grad()`` with zeros, ``data()`` still
+    reads and ``sync_params()`` still writes; one device keeps it all."""
+    X = np.random.RandomState(5).rand(16, 8).astype(np.float32)
+    Y = np.zeros(16, np.float32)
+
+    def run(mesh):
+        net = _mlp()
+        params = list(net.collect_params().values())
+        before = [p.data().asnumpy() for p in params]
+        step = parallel.JitTrainStep(
+            net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+            {"learning_rate": 0.1}, mesh=mesh)
+        step.step(nd.array(X), nd.array(Y))
+        return step, params, before
+
+    step, params, before = run(Mesh({"data": 8}))
+    for p, was in zip(params, before):
+        assert p._data._grad is None
+        g = p.grad()
+        assert g.shape == p.shape and not g.asnumpy().any()
+        assert np.array_equal(p.data().asnumpy(), was)     # not yet synced
+    # an imperative backward over the same parameters still has where to
+    # put its gradients
+    with autograd.record():
+        loss = step._net(nd.array(X)).sum()
+    loss.backward()
+    assert all(p.grad().asnumpy().any() for p in params)
+    step.sync_params()
+    assert all((p.data().asnumpy() != was).any()
+               for p, was in zip(params, before))
+
+    _, params, _ = run(None)
+    assert all(p._data._grad is not None for p in params)
+
+
 # ---------------------------------------------------------------------------
 # MXNET_SHARDING_VERIFY
 # ---------------------------------------------------------------------------

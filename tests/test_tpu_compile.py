@@ -14,6 +14,7 @@ described inside a fixture, never at import.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
 
 from mxnet_tpu.ops.paged_attention import paged_attention
 from mxnet_tpu.ops.pallas_kernels import _flash_bwd_pallas, flash_attention
+from mxnet_tpu.parallel.train_step import mesh_compiler_options
 
 
 @pytest.fixture(scope="module")
@@ -116,6 +118,79 @@ def test_flash_on_a_mesh_runs_per_shard(topo):
     assert text.count("tpu_custom_call") >= 3
     # each device runs the kernel on its own batch rows and heads
     assert "bf16[32,512,128]" in text and "all-gather" not in text
+
+
+def _mlp_train_step(ws, ms, x):
+    # three residual MLP blocks as JitTrainStep runs a decoder's: float32
+    # master weights, bfloat16 products, a momentum update of every weight
+    def loss(ws):
+        h = x
+        for up, down in ws:
+            a = jax.nn.silu(jnp.dot(h, up.astype(jnp.bfloat16)))
+            h = h + jnp.dot(a, down.astype(jnp.bfloat16))
+        return jnp.mean(jnp.square(h.astype(jnp.float32)))
+
+    g = jax.grad(loss)(ws)
+    ms = jax.tree_util.tree_map(lambda m, g: 0.9 * m + 0.1 * g, ms, g)
+    ws = jax.tree_util.tree_map(lambda w, m: w - 1e-3 * m, ws, ms)
+    return ws, ms
+
+
+def _entry(compiled):
+    """The entry computation in schedule order: ``[(instruction name, the
+    shapes it yields, its whole text)]``."""
+    body = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", compiled.as_text(),
+                     re.S | re.M).group(1)
+    return [(name, re.split(r" (?:fusion|all-reduce)\(", text, 1)[0], text)
+            for name, text in re.findall(
+                r"^\s*(?:ROOT )?%([\w.\-]+) = (.*)$", body, re.M)]
+
+
+@pytest.mark.parametrize("with_options", [False, True],
+                         ids=["default", "mesh_options"])
+def test_mesh_step_gradient_all_reduces_overlap(topo, with_options):
+    # Megatron shardings on data=2 x model=2: the weight gradients are
+    # all-reduced over `data`, each shard 16 MiB in bfloat16.  By default
+    # they are synchronous, combined into tuples; with the options a mesh
+    # step is compiled with (PR 28) each travels alone in a pair of
+    # async-collective-start/-done fusions, matmuls of the backward pass
+    # between the two.
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    options = mesh_compiler_options(mesh)
+    assert options, "a mesh of TPUs gets the overlap options"
+    d, f, layers, tokens = 2048, 8192, 3, 2048
+
+    def aval(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    ws = [(aval((d, f), jnp.float32, None, "model"),
+           aval((f, d), jnp.float32, "model", None))] * layers
+    c = jax.jit(_mlp_train_step, donate_argnums=(0, 1),
+                compiler_options=options if with_options else None).lower(
+        ws, ws, aval((tokens, d), jnp.bfloat16, "data", None)).compile()
+    entry = _entry(c)
+    shard = re.compile(r"bf16\[(%d,%d|%d,%d)\]" % (d, f // 2, f // 2, d))
+    # instructions that yield a weight's shard: its gradient, all-reduced
+    started = [i for i, (name, out, _) in enumerate(entry)
+               if name.startswith("async-collective-start")
+               and shard.search(out)]
+    synchronous = [i for i, (name, out, _) in enumerate(entry)
+                   if name.startswith("all-reduce") and shard.search(out)]
+    names = [name for name, _, _ in entry]
+    if not with_options:
+        assert not any("async-collective" in name for name in names)
+        assert synchronous
+        return
+    # at most the last gradients produced stay synchronous
+    assert len(started) >= 2 * layers - 2, (started, synchronous)
+    assert len(synchronous) <= 2
+    # between a start and its done: fusions of the backward pass or the
+    # update, each carrying a piece of the exchange
+    for i in started:
+        j = names.index(names[i].replace("start", "done"))
+        assert any("calls=%async_collective_fusion" in text
+                   for _, _, text in entry[i + 1:j]), names[i]
 
 
 # (page_size, kv_heads, heads, head_dim): the 160M decoder chip_smoke.py
