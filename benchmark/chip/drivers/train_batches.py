@@ -4,10 +4,11 @@ every step.
 Set-up builds ONE object, the compiled step with its state, exactly as
 ``chip_smoke.py:child_train`` does (``with mx.tpu():``, ``amp.init``, the
 ``LM`` wrapper, AdamW, ``SoftmaxCrossEntropyLoss``; a mesh and the Megatron
-rules where the configuration names them), hands it the benchmark's own
-weights from ``--seed``, drives it through its first steps with the
-window's own call and feed, reads what ``correct`` compares, and hands the
-same object to the window.
+rules where the configuration names them) around the network that the
+configuration's architecture module builds (``archs/<model_type>.py``),
+hands it the benchmark's own weights from ``--seed``, drives it through
+its first steps with the window's own call and feed, reads what
+``correct`` compares, and hands the same object to the window.
 
 Parameters (``workloads/<cell>.json``): ``batch``, ``seq``, ``log_every``
 (a loss is fetched every so many steps, as a trainer's log line does),
@@ -24,6 +25,7 @@ import gc
 import sys
 import time
 
+import archs
 import compare
 import reference
 import traffic
@@ -37,11 +39,9 @@ class Driver:
     # -- set-up -------------------------------------------------------------
     def setup(self):
         import jax
-        import numpy as np
 
         import mxnet_tpu as mx
         from mxnet_tpu import amp, gluon, parallel
-        from mxnet_tpu.gluon.model_zoo import llama
         from mxnet_tpu.telemetry import metrics
 
         run, cfg, wl = self.run, self.run["cfg"], self.run["workload"]
@@ -52,17 +52,7 @@ class Driver:
         ctx = mx.tpu()
         self._stack.enter_context(ctx)
         mesh = parallel.make_mesh(cfg["mesh"]) if cfg.get("mesh") else None
-        net = llama.LlamaModel(
-            vocab, units=cfg["hidden_size"],
-            hidden_size=cfg["intermediate_size"],
-            num_layers=cfg["num_hidden_layers"],
-            num_heads=cfg["num_attention_heads"],
-            num_kv_heads=cfg["num_key_value_heads"],
-            rope_base=cfg["rope_theta"], eps=cfg["rms_norm_eps"],
-            tie_embeddings=bool(cfg.get("tie_word_embeddings")))
-        net.initialize(mx.init.Zero(), ctx=ctx)
-        # one tiny forward resolves the Dense layers' deferred shapes
-        net(mx.nd.array(np.zeros((1, 8), np.int32), ctx=ctx))
+        net = archs.of(cfg).build(cfg, ctx)
         params = list(net.collect_params().values())
         specs = reference.leaf_specs(cfg)
         if [tuple(p.shape) for p in params] != [s for _, s in specs]:

@@ -3,12 +3,17 @@
 The yardstick every utilization and roofline share of this benchmark is
 measured with.  Nothing here reads the program: a configuration is the
 dict of a file under ``configs/`` (the published ``config.json`` keys).
-Recomputed operations never count, and neither does the embedding lookup.
+What is one architecture's own (its parameters, the operations a token
+needs) is stated by ``archs/<model_type>.py`` and looked up here, so that a
+reader calls one name for every configuration.  Recomputed operations never
+count, and neither does the embedding lookup.
 """
 from __future__ import annotations
 
 import json
 import os
+
+import archs
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -28,52 +33,17 @@ def head_dim(cfg):
     return cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
 
 
-def layer_params(cfg):
-    """Parameters of one decoder layer: q, k, v, o, gate, up, down and the
-    two RMSNorm gains (no biases)."""
-    h, d = cfg["hidden_size"], head_dim(cfg)
-    q = h * cfg["num_attention_heads"] * d
-    kv = h * cfg["num_key_value_heads"] * d
-    return 2 * q + 2 * kv + 3 * h * cfg["intermediate_size"] + 2 * h
+# -- the model's own count: the configuration's architecture states it ------
 
-
-def param_count(cfg, num_layers=None):
-    """All parameters: embedding, layers, final norm and, where the
-    embeddings are not tied, the output head."""
-    n = cfg["num_hidden_layers"] if num_layers is None else num_layers
-    emb = cfg["vocab_size"] * cfg["hidden_size"]
-    head = 0 if cfg.get("tie_word_embeddings") else emb
-    return emb + n * layer_params(cfg) + cfg["hidden_size"] + head
-
-
-def matmul_params(cfg):
-    """Parameters that multiply every token: the layers' matrices and the
-    head (tied or not); the embedding lookup and the norm gains do not."""
-    h = cfg["hidden_size"]
-    return (cfg["num_hidden_layers"] * (layer_params(cfg) - 2 * h)
-            + cfg["vocab_size"] * h)
-
-
-def attention_flops_per_token(cfg, seq, causal=True):
-    """QK^T and PV of one forward pass, all layers, per token of a
-    sequence of ``seq`` tokens; a causal mask halves what is needed."""
-    units = cfg["num_attention_heads"] * head_dim(cfg)
-    full = 4 * seq * units * cfg["num_hidden_layers"]
-    return full // 2 if causal else full
+def param_count(cfg):
+    """All parameters of the configuration as it is run."""
+    return archs.of(cfg).param_count(cfg)
 
 
 def train_flops_per_token(cfg, seq):
-    """Forward + backward (2 + 4 operations per multiply-add), no
-    recomputation: ``6 * matmul_params`` plus three times the causal
-    attention of a forward pass."""
-    return 6 * matmul_params(cfg) + 3 * attention_flops_per_token(cfg, seq)
-
-
-def forward_flops_per_token(cfg, context):
-    """One forward pass of one token that attends to ``context`` keys."""
-    units = cfg["num_attention_heads"] * head_dim(cfg)
-    return (2 * matmul_params(cfg)
-            + 4 * context * units * cfg["num_hidden_layers"])
+    """Operations one token of a ``seq``-token sequence needs in a train
+    step: forward + backward, no recomputation."""
+    return archs.of(cfg).train_flops_per_token(cfg, seq)
 
 
 # -- the three flash-attention kernels of one layer ------------------------

@@ -1,7 +1,9 @@
-"""The plain reference: a Llama-shaped decoder (RMSNorm, rotate-half RoPE,
-grouped-query causal attention, SwiGLU, no biases), its token-mean
-cross-entropy, its gradients and AdamW, in straightforward ``jax.numpy``
-and float32 with every matrix product at ``highest`` precision.
+"""The plain reference: any decoder's token-mean cross-entropy, its
+gradients and AdamW, in straightforward ``jax.numpy`` and float32 with
+every matrix product at ``highest`` precision.  The decoder itself (the
+shapes of its weights and its logits) is the configuration's architecture
+module, ``archs/<model_type>.py``, which is handed the matrix product to
+use.
 
 It imports nothing of the program and takes nothing the program has made.
 The weights are the benchmark's own, made from the seed leaf by leaf
@@ -13,10 +15,6 @@ with every matrix product's operands rounded to an 8-bit float (e4m3
 forward, e5m2 for the cotangents; one scale a tensor), the step below the
 bfloat16 the configurations state.  It exists to be put in the program's
 place and to fail.
-
-Departures from the published model code, none of which changes a value:
-Mistral's ``sliding_window`` 4096 is never reached at the sequence lengths
-run here and is not implemented; dropout is 0 in both published configs.
 """
 from __future__ import annotations
 
@@ -27,31 +25,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+import archs
+
 HIGHEST = lax.Precision.HIGHEST
-PER_LAYER = ("attn_norm", "q", "k", "v", "o", "ffn_norm", "gate", "up",
-             "down")
-
-
-def head_dim(cfg):
-    return cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
 
 
 def leaf_specs(cfg):
-    """``[(name, shape)]`` in the order the decoder is written down:
-    embedding, the layers, final norm, head.  Dense weights are
-    ``(out, in)``, applied as ``x @ W.T``."""
-    h, d, f = cfg["hidden_size"], head_dim(cfg), cfg["intermediate_size"]
-    q, kv = cfg["num_attention_heads"] * d, cfg["num_key_value_heads"] * d
-    per = {"attn_norm": (h,), "q": (q, h), "k": (kv, h), "v": (kv, h),
-           "o": (h, q), "ffn_norm": (h,), "gate": (f, h), "up": (f, h),
-           "down": (h, f)}
-    specs = [("embed", (cfg["vocab_size"], h))]
-    for i in range(cfg["num_hidden_layers"]):
-        specs += [("layer%d.%s" % (i, k), per[k]) for k in PER_LAYER]
-    specs.append(("norm", (h,)))
-    if not cfg.get("tie_word_embeddings"):
-        specs.append(("head", (cfg["vocab_size"], h)))
-    return specs
+    """``[(name, shape)]`` of the weights, in the architecture's order."""
+    return archs.of(cfg).leaf_specs(cfg)
 
 
 def seed_key(seed):
@@ -63,11 +44,12 @@ def seed_key(seed):
 
 
 def make_leaf(key, index, shape):
-    """Leaf ``index`` of the weights: Xavier-uniform for a matrix, ones
-    for a norm's gain.  Traceable; float32."""
+    """Leaf ``index`` of the weights: Xavier-uniform for a matrix (over its
+    last two axes: a stack of matrices is so many of them), ones for a
+    norm's gain.  Traceable; float32."""
     if len(shape) == 1:
         return jnp.ones(shape, jnp.float32)
-    a = math.sqrt(6.0 / (shape[0] + shape[1]))
+    a = math.sqrt(6.0 / (shape[-2] + shape[-1]))
     return jax.random.uniform(jax.random.fold_in(key, index), shape,
                               jnp.float32, -a, a)
 
@@ -125,51 +107,9 @@ def _einsum(precision):
 
 # -- the model --------------------------------------------------------------
 
-def _rmsnorm(x, gain, eps):
-    return x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
-
-
-def _rope(x, theta):
-    """Rotate-half rotary embedding on ``(B, T, H, D)``."""
-    t, d = x.shape[1], x.shape[-1]
-    half = d // 2
-    inv = jnp.exp(jnp.arange(half, dtype=jnp.float32)
-                  * (-2.0 / d) * math.log(theta))
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
 def forward(cfg, weights, tokens, precision="float32"):
     """Logits ``(B, T, V)`` of ``tokens`` ``(B, T)``."""
-    ein = _einsum(precision)
-    names = [n for n, _ in leaf_specs(cfg)]
-    w = dict(zip(names, weights))
-    nh, nkv, d = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                  head_dim(cfg))
-    eps, theta = cfg["rms_norm_eps"], cfg["rope_theta"]
-    b, t = tokens.shape
-    mask = jnp.tril(jnp.ones((t, t), bool))
-    x = w["embed"][tokens]
-    for i in range(cfg["num_hidden_layers"]):
-        lw = {k: w["layer%d.%s" % (i, k)] for k in PER_LAYER}
-        h = _rmsnorm(x, lw["attn_norm"], eps)
-        q = _rope(ein("bti,oi->bto", h, lw["q"]).reshape(b, t, nh, d), theta)
-        k = _rope(ein("bti,oi->bto", h, lw["k"]).reshape(b, t, nkv, d), theta)
-        v = ein("bti,oi->bto", h, lw["v"]).reshape(b, t, nkv, d)
-        k = jnp.repeat(k, nh // nkv, axis=2)
-        v = jnp.repeat(v, nh // nkv, axis=2)
-        s = ein("bqhd,bkhd->bhqk", q, k) / math.sqrt(d)
-        p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
-        a = ein("bhqk,bkhd->bqhd", p, v).reshape(b, t, nh * d)
-        x = x + ein("bti,oi->bto", a, lw["o"])
-        h = _rmsnorm(x, lw["ffn_norm"], eps)
-        g = jax.nn.silu(ein("bti,oi->bto", h, lw["gate"]))
-        x = x + ein("bti,oi->bto", g * ein("bti,oi->bto", h, lw["up"]),
-                    lw["down"])
-    x = _rmsnorm(x, w["norm"], eps)
-    return ein("bti,vi->btv", x, w.get("head", w["embed"]))
+    return archs.of(cfg).forward(cfg, weights, tokens, _einsum(precision))
 
 
 def loss_fn(cfg, weights, tokens, labels, precision="float32"):

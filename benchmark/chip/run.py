@@ -13,10 +13,15 @@ cell's own shapes (set-up), the window, the peak memory, the comparison
 with the plain reference that decides ``correct``, the metric readers, and
 the contract's one JSON line last on standard output.
 
-Nothing about a cell, a configuration or a metric is in this file:
+Nothing about a cell, a configuration, an architecture or a metric is in
+this file:
 
 - ``BENCHMARK.json`` (root) names the cells and which metrics each reports;
-- ``configs/<config>.json`` holds a configuration's sizes;
+- ``configs/<config>.json`` holds a configuration's sizes and its
+  ``model_type``;
+- ``archs/<model_type>.py`` holds what is that architecture's own: the
+  plain reference's equations, the count of parameters and operations, and
+  the builder of the program's network (``archs/__init__.py``);
 - ``workloads/<cell>.json`` holds a traffic mix, the name of its driver
   (``drivers/<driver>.py``) and the limits of its comparison;
 - ``metrics/<metric>.py`` holds one reader, ``read(run)``, which returns a

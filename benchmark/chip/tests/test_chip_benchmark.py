@@ -17,6 +17,7 @@ CHIP = os.path.dirname(HERE)
 ROOT = os.path.dirname(os.path.dirname(CHIP))
 sys.path.insert(0, CHIP)
 
+import archs  # noqa: E402
 import compare  # noqa: E402
 import flops  # noqa: E402
 import trace_reduce  # noqa: E402
@@ -30,21 +31,42 @@ def _cfg(name):
     return json.load(open(os.path.join(CHIP, "configs", name + ".json")))
 
 
-# -- flops.py and peaks.json ----------------------------------------------------
+# -- flops.py, archs/ and peaks.json ---------------------------------------------
 
 def test_configurations_reproduce_the_published_totals():
+    """Through the look-up by ``model_type``, as every reader counts."""
     for name in ("mistral_7b_d2", "mistral_7b_d3_mesh4"):
         cfg = _cfg(name)
-        assert flops.layer_params(cfg) == 218_112_000
-        assert flops.param_count(cfg, 32) == 7_241_732_096
+        assert archs.of(cfg) is archs.load("mistral")
+        assert archs.of(cfg).layer_params(cfg) == 218_112_000
+        whole = dict(cfg, num_hidden_layers=cfg["published"][
+            "num_hidden_layers"])
+        assert flops.param_count(whole) == 7_241_732_096
         assert cfg["published"]["parameters"] == 7_241_732_096
+        # the leaves the reference is made of are the parameters counted
+        assert sum(int(np.prod(shape)) for _, shape in
+                   archs.of(cfg).leaf_specs(cfg)) == flops.param_count(cfg)
     assert flops.param_count(_cfg("mistral_7b_d2")) == 698_372_096
     assert flops.param_count(_cfg("mistral_7b_d3_mesh4")) == 916_484_096
-    smol = dict(hidden_size=960, num_hidden_layers=32, num_attention_heads=15,
-                num_key_value_heads=5, intermediate_size=2560,
-                vocab_size=49152, tie_word_embeddings=True)
-    assert flops.layer_params(smol) == 9_832_320
+    smol = dict(model_type="mistral", hidden_size=960, num_hidden_layers=32,
+                num_attention_heads=15, num_key_value_heads=5,
+                intermediate_size=2560, vocab_size=49152,
+                tie_word_embeddings=True)
+    assert archs.of(smol).layer_params(smol) == 9_832_320
     assert flops.param_count(smol) == 361_821_120
+
+
+def test_a_model_type_without_a_module_is_an_error_that_names_the_file():
+    cfg = dict(_cfg("mistral_7b_d2"), model_type="imaginary")
+    looked_for = os.path.join(CHIP, "archs", "imaginary.py")
+    for ask in (flops.param_count, lambda c: flops.train_flops_per_token(
+            c, 512), archs.of):
+        with pytest.raises(LookupError) as err:
+            ask(cfg)
+        assert looked_for in str(err.value)
+    del cfg["model_type"]                    # and never a default
+    with pytest.raises(LookupError):
+        flops.param_count(cfg)
 
 
 def test_flops_and_bytes_follow_from_shapes():
@@ -136,10 +158,17 @@ def _synthetic():
              '%q), custom_call_target="tpu_custom_call"'
     fusion = "%fusion.1 = (f32[4,8]{1,0}, f32[4]{0}) fusion(f32[4] %p), " \
              "kind=kLoop"
+    # an asynchronous all-reduce as the TPU compiler writes it: the start
+    # runs under fusion.6, the done waits with nothing beside it
+    start = "%async-collective-start.7 = (f32[8]{0}, f32[8]{0}) " \
+            "fusion(f32[8]{0} %g), kind=kCustom"
+    done = "%async-collective-done = f32[8]{0} fusion(%get-tuple-element.1" \
+           "), kind=kCustom"
     d0 = dev([(fusion, 10 * ms, 4 * ms), (kernel, 14 * ms, 2 * ms),
               ("%all-reduce.3 = f32[8]{0} all-reduce(f32[8]{0} %x)",
                16 * ms, 3 * ms), ("fusion.4", 17 * ms, 1 * ms),
-              ("while.5", 30 * ms, 6 * ms), ("fusion.6", 31 * ms, 2 * ms)],
+              ("while.5", 30 * ms, 6 * ms), ("fusion.6", 31 * ms, 2 * ms),
+              (start, 31 * ms + ms // 2, ms // 2), (done, 37 * ms, 2 * ms)],
              [("jit_step(1)", 10 * ms, 9 * ms), ("jit_step(1)", 30 * ms, 6 * ms)])
     d0["name"] = "/device:TPU:0"
     d1 = dev([(fusion, 10 * ms, 10 * ms)], [])
@@ -148,7 +177,13 @@ def _synthetic():
         ("bench:window", 10 * ms, 30 * ms),
         ("bench:loss_fetch", 18 * ms, 10 * ms),
         ("bench:step.dispatch", 36 * ms, 1 * ms), ("other", 0, 5 * ms)]}]}
-    return {"planes": [d0, d1, host]}
+    # the paths the operations were traced under; fusion.6 and the
+    # compiler's own operations have none
+    scope = {fusion: "jit(step)/jvp(jit(FullyConnected))/dot_general:",
+             kernel: "jit(step)/transpose(jvp(jit(attn)))/mx_flash_bwd_dq/"
+                     "pallas_call:",
+             "fusion.4": "jit(step)/sub:"}
+    return {"planes": [d0, d1, host], "scope": scope}
 
 
 def _check_reductions(trace):
@@ -156,24 +191,44 @@ def _check_reductions(trace):
     t0, t1 = trace_reduce.window_of(trace)
     assert (t0, t1) == (10_000_000, 40_000_000)
     busy, n = trace_reduce.busy_seconds(trace, t0, t1)
-    assert n == 2 and busy == pytest.approx((15 * ms + 10 * ms) / 2)
+    assert n == 2 and busy == pytest.approx((17 * ms + 10 * ms) / 2)
     top = dict(trace_reduce.top_ops(trace, 10, t0, t1))
     assert top["fusion.1 f32[4,8]"] == pytest.approx((4 + 10) * ms / 2)
     assert top["while.5"] == pytest.approx(4 * ms / 2)      # less its body
     kinds = dict(trace_reduce.top_ops(trace, 10, t0, t1,
                                       by=trace_reduce._family))
-    assert kinds["fusion"] == pytest.approx((4 + 1 + 2 + 10) * ms / 2)
+    assert kinds["fusion"] == pytest.approx((4 + 1 + 1.5 + 10) * ms / 2)
     secs, count = trace_reduce.matching_seconds(
         trace, r'custom_call_target="tpu_custom_call"', t0, t1, detail=True)
     assert count == 1 and secs == pytest.approx(2 * ms / 2)
-    # the all-reduce runs 16..19 ms, a fusion covers 17..18 ms of it
+    # the all-reduce runs 16..19 ms, a fusion covers 17..18 ms of it; the
+    # asynchronous one's start runs under fusion.6 and its done, 37..39 ms,
+    # waits alone
     assert trace_reduce.collective_exposed_seconds(trace, t0, t1) == \
-        pytest.approx(2 * ms / 2)
+        pytest.approx((2 + 2) * ms / 2)
+    # each operation's scope came through the file; its self time by scope
+    assert trace["scope"] == {
+        "fusion.1 f32[4,8]": "jit(step)/jvp(jit(FullyConnected))/"
+                             "dot_general:",
+        "flash.2 bf16[8,512,128]": "jit(step)/transpose(jvp(jit(attn)))/"
+                                   "mx_flash_bwd_dq/pallas_call:",
+        "fusion.4": "jit(step)/sub:"}
+    backward = r"transpose\(jvp\("
+    assert trace_reduce.scope_seconds(trace, backward, t0, t1) == \
+        (pytest.approx(2 * ms / 2), 1)
+    assert trace_reduce.scope_seconds(
+        trace, r"^(?!.*%s).*jvp\(" % backward, t0, t1) == \
+        (pytest.approx((4 + 10) * ms / 2), 1)
+    assert trace_reduce.scope_seconds(trace, r"/sub:$", t0, t1) == \
+        (pytest.approx(1 * ms / 2), 1)
+    everything, _ = trace_reduce.scope_seconds(trace, "", t0, t1)
+    assert everything == pytest.approx(busy)    # no scope: the empty path
     assert trace_reduce.module_durations(trace, "jit_step") == \
         {"jit_step": [pytest.approx(9 * ms), pytest.approx(6 * ms)]}
     gaps = dict(trace_reduce.idle_gaps(trace, 10, t0, t1))
     assert gaps["loss_fetch"] == pytest.approx(11 * ms)       # 19..30 ms
-    assert gaps["step.dispatch"] == pytest.approx(4 * ms)     # 36..40 ms
+    assert gaps["step.dispatch"] == pytest.approx(1 * ms)     # 36..37 ms
+    assert gaps["host (unattributed)"] == pytest.approx(1 * ms)   # 39..40
 
 
 def test_trace_reductions_on_a_written_trace(tmp_path):
@@ -202,6 +257,49 @@ def test_trace_reductions_on_the_recorded_fixture():
     top = trace_reduce.top_ops(trace, 3, t0, t1)
     assert [n for n, _ in top] == want["top3"]
     assert trace_reduce.marks(trace)
+
+
+def test_exposed_collectives_on_the_recorded_mesh_fixture():
+    """One step period cut from a traced chip run of
+    train_mistral7b_d3_mesh4, whose gradient all-reduces are asynchronous
+    since PR 28.  On an ``XLA Ops`` line no two operations overlap, so what
+    a collective exposes is its duration: ``fixtures/recorded_mesh_async.
+    json`` holds the sums by hand, per device and kind, and the step split
+    by the scope the profiler recorded for each operation."""
+    want = json.load(open(os.path.join(
+        CHIP, "fixtures", "recorded_mesh_async.json")))
+    trace = trace_reduce.load(os.path.join(
+        CHIP, "fixtures", "recorded_mesh_async.xplane.pb"))
+    t0, t1 = want["window_ns"]
+    ops = trace_reduce.device_ops(trace)
+    assert sorted(ops) == sorted(want["by_hand"])
+    by_hand = 0
+    for name, hand in want["by_hand"].items():
+        assert hand["events"] == {"async_done": 32, "async_start": 32,
+                                  "sync": 9}
+        by_hand += sum(hand["ns"].values())
+        # the -done halves are where the device waits: 29 of 35 ms
+        assert hand["ns"]["async_done"] > 4 * hand["ns"]["sync"]
+    by_hand /= 1e9 * len(ops)
+    assert by_hand == pytest.approx(want["collective_exposed_s"], rel=1e-9)
+    got = trace_reduce.collective_exposed_seconds(trace, t0, t1)
+    assert got == pytest.approx(by_hand, rel=1e-9)
+    assert 100 * got / ((t1 - t0) / 1e9) == pytest.approx(
+        want["collective_exposed_pct"], rel=1e-9)
+    # what the pattern of before PR 29 saw: the synchronous ones alone
+    assert want["synchronous_only_s"] < 0.2 * got
+    # the scopes came through write_xspace and load; forward, backward
+    # and the rest are the whole of what ran
+    assert len(trace["scope"]) == want["scopes"]
+    backward = r"transpose\(jvp\("
+    split = {
+        "backward": trace_reduce.scope_seconds(trace, backward, t0, t1)[0],
+        "forward": trace_reduce.scope_seconds(
+            trace, r"^(?!.*%s).*jvp\(" % backward, t0, t1)[0]}
+    busy, _ = trace_reduce.busy_seconds(trace, t0, t1)
+    split["rest"] = busy - split["backward"] - split["forward"]
+    assert split == {k: pytest.approx(v, rel=1e-6)
+                     for k, v in want["scope_s"].items()}
 
 
 # -- the harness, rehearsed -------------------------------------------------------------
@@ -244,50 +342,177 @@ def test_no_tpu_and_no_rehearse_fails_and_prints_no_result():
     assert "metrics" not in proc.stdout and "tokens" not in proc.stdout
 
 
+def _copy_of_the_benchmark(tmp_path):
+    """The benchmark as a later PR finds it, copied to where files can be
+    added: ``(its directory, BENCHMARK.json as a dict)``."""
+    chip = tmp_path / "benchmark" / "chip"
+    shutil.copytree(CHIP, chip, ignore=shutil.ignore_patterns(
+        ".cache", "__pycache__", "tests"))
+    return chip, json.loads(json.dumps(BENCH))
+
+
+def _add_cell(chip, bench, name, cfg, workload):
+    """One more configuration ``<name>_cfg`` and cell ``<name>_cell``, as
+    files and entries."""
+    (chip / "configs" / (name + "_cfg.json")).write_text(json.dumps(cfg))
+    workload = dict(workload, config=name + "_cfg")
+    (chip / "workloads" / (name + "_cell.json")).write_text(
+        json.dumps(workload))
+    bench["configs"].append({
+        "name": name + "_cfg", "source": "test", "reduced": [], "why": "test",
+        "file": "benchmark/chip/configs/%s_cfg.json" % name})
+    bench["workloads"].append({
+        "name": name + "_cell", "config": name + "_cfg",
+        "traffic": workload["traffic"], "chips": 1, "why": "test"})
+    (chip.parent.parent / "BENCHMARK.json").write_text(json.dumps(bench))
+
+
+RUN_ARGS = ("--seed", "3", "--seconds", "1", "--trace", "1")
+
+
+def _rehearse(chip, cell, script="run.py", args=RUN_ARGS):
+    """``script`` of the copy on ``cell``, from the copy's root."""
+    return _run(str(chip / script), "--workload", cell, *args, "--rehearse",
+                env={"PYTHONPATH": ROOT}, cwd=str(chip.parent.parent))
+
+
+def _d2_workload(**over):
+    wl = json.load(open(os.path.join(
+        CHIP, "workloads", "train_mistral7b_d2_b4s512.json")))
+    # what these tests show is that the files are found, so their limits
+    # are wide: a 2 x 8-token batch is noisier than any cell's rehearsal
+    wl.update(traffic="fresh_b2s8", tiny={"batch": 2, "seq": 8, "limits": {
+        "grad_norm_gap": 0.05, "delta_norm_gap": 0.05}})
+    wl.update(over)
+    return wl
+
+
 def test_a_cell_a_configuration_and_a_metric_are_added_by_files_alone(
         tmp_path):
     """A later PR adds files and entries and edits none: a copy of the
     benchmark with one more configuration, cell and per-layer metric runs
     through the unchanged harness."""
-    chip = tmp_path / "benchmark" / "chip"
-    shutil.copytree(CHIP, chip, ignore=shutil.ignore_patterns(
-        ".cache", "__pycache__", "tests"))
+    chip, bench = _copy_of_the_benchmark(tmp_path)
     cfg = _cfg("mistral_7b_d2")
     cfg["tiny"]["num_hidden_layers"] = 1
-    (chip / "configs" / "added_cfg.json").write_text(json.dumps(cfg))
-    wl = json.load(open(os.path.join(
-        CHIP, "workloads", "train_mistral7b_d2_b4s512.json")))
-    # what this test shows is that the files are found, so its limits are
-    # wide: a 2 x 8-token batch is noisier than any cell's rehearsal
-    wl.update(config="added_cfg", traffic="fresh_b2s8",
-              tiny={"batch": 2, "seq": 8, "limits": {
-                  "grad_norm_gap": 0.05, "delta_norm_gap": 0.05}})
-    (chip / "workloads" / "added_cell.json").write_text(json.dumps(wl))
     (chip / "metrics" / "added.steps.py").write_text(
         "def read(run):\n    return run['steps']\n")
-    bench = json.loads(json.dumps(BENCH))
-    bench["configs"].append({
-        "name": "added_cfg", "source": "test", "reduced": [], "why": "test",
-        "file": "benchmark/chip/configs/added_cfg.json"})
-    bench["workloads"].append({"name": "added_cell", "config": "added_cfg",
-                               "traffic": "fresh_b2s8", "chips": 1,
-                               "why": "test"})
     bench["per_layer"].append({
         "name": "added.steps", "unit": "count", "better": "higher",
         "source": "program_counter", "layer": "train step",
         "moves": "train_tokens_per_s", "workloads": ["added_cell"]})
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    doc = _last_json(_run(str(chip / "run.py"), "--workload", "added_cell",
-                          "--seed", "3", "--seconds", "1", "--trace", "1",
-                          "--rehearse", env={"PYTHONPATH": ROOT},
-                          cwd=str(tmp_path)))
+    _add_cell(chip, bench, "added", cfg, _d2_workload())
+    doc = _last_json(_rehearse(chip, "added_cell"))
     assert doc["correct"] is True and "added.steps" in doc["metrics_read"]
     # and a cell that was there does not report the added metric
-    doc = _last_json(_run(str(chip / "run.py"), "--workload", CELLS[0],
-                          "--seed", "3", "--seconds", "1", "--trace", "1",
-                          "--rehearse", env={"PYTHONPATH": ROOT},
-                          cwd=str(tmp_path)))
+    doc = _last_json(_rehearse(chip, CELLS[0]))
     assert "added.steps" not in doc["metrics_read"]
+
+
+# an architecture that is visibly not Llama's: embedding, one relu-squared
+# MLP layer (no gate, no norm, no attention) with a residual, head; its
+# program from gluon.nn.  Everything a later PR writes for a new model_type.
+ADDED_ARCH = '''
+import jax
+import jax.numpy as jnp
+
+
+def _sizes(cfg):
+    return cfg["hidden_size"], cfg["ffn_size"], cfg["vocab_size"]
+
+
+def leaf_specs(cfg):
+    h, f, v = _sizes(cfg)
+    return [("embed", (v, h)), ("up", (f, h)), ("down", (h, f)),
+            ("head", (v, h))]
+
+
+def forward(cfg, leaves, tokens, ein):
+    embed, up, down, head = leaves
+    x = embed[tokens]
+    a = jnp.square(jax.nn.relu(ein("bti,fi->btf", x, up)))
+    return ein("bti,vi->btv", x + ein("btf,if->bti", a, down), head)
+
+
+def param_count(cfg):
+    h, f, v = _sizes(cfg)
+    return 2 * v * h + 2 * h * f
+
+
+def train_flops_per_token(cfg, seq):
+    h, f, v = _sizes(cfg)
+    return 6 * (2 * h * f + v * h)
+
+
+def build(cfg, ctx):
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import gluon
+    from mxnet_tpu.gluon import nn
+
+    h, f, v = _sizes(cfg)
+
+    class Net(gluon.HybridBlock):
+        def __init__(self):
+            super().__init__()
+            with self.name_scope():
+                self.embed = nn.Embedding(v, h)
+                self.up = nn.Dense(f, flatten=False, use_bias=False)
+                self.down = nn.Dense(h, flatten=False, use_bias=False)
+                self.head = nn.Dense(v, flatten=False, use_bias=False)
+
+        def hybrid_forward(self, F, tokens):
+            x = self.embed(tokens)
+            return self.head(x + self.down(F.square(F.relu(self.up(x)))))
+
+    net = Net()
+    net.initialize(mx.init.Zero(), ctx=ctx)
+    net(mx.nd.array(np.zeros((1, 8), np.int32), ctx=ctx))
+    return net
+'''
+
+
+def test_an_architecture_is_added_by_files_alone(tmp_path):
+    """A later ``model_config`` PR brings ``archs/<model_type>.py`` beside
+    its configuration and cell and edits nothing: the copy rehearses
+    ``correct`` against the added reference, counts the added module's
+    operations and not Llama's, and runs the fp8 control and the unchanged
+    state on the added cell; a ``model_type`` with no module fails with the
+    name of the file looked for."""
+    chip, bench = _copy_of_the_benchmark(tmp_path)
+    (chip / "archs" / "added.py").write_text(ADDED_ARCH)
+    d2 = _cfg("mistral_7b_d2")
+    cfg = {"model_type": "added", "hidden_size": 64, "ffn_size": 96,
+           "vocab_size": 256, "dtype": d2["dtype"],
+           "optimizer": d2["optimizer"]}
+    _add_cell(chip, bench, "arch", cfg, _d2_workload())
+    _add_cell(chip, bench, "nowhere", dict(cfg, model_type="nowhere"),
+              _d2_workload(traffic="fresh_b2s8_nowhere"))
+    doc = _last_json(_rehearse(chip, "arch_cell"))
+    assert doc["correct"] is True and doc["attempted"] > 0
+    ours = doc["check"]["grad_norm_gap"][0]
+    # the count every reader gets is the added module's, from the copy's
+    # own flops.py
+    count = _run("-c", "import json, sys; sys.path.insert(0, %r); "
+                 "import flops; cfg = json.load(open(%r)); "
+                 "print(flops.param_count(cfg), "
+                 "flops.train_flops_per_token(cfg, 8))"
+                 % (str(chip), str(chip / "configs" / "arch_cfg.json")))
+    assert count.returncode == 0, count.stderr[-2000:]
+    assert count.stdout.split() == [
+        str(2 * 256 * 64 + 2 * 64 * 96), str(6 * (2 * 64 * 96 + 256 * 64))]
+    out = str(tmp_path / "probe.jsonl")
+    proc = _rehearse(chip, "arch_cell", "probe.py", (
+        "--seeds", "3", "--what", "control,unchanged", "--out", out))
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    kinds = {d["kind"]: d["numbers"] for d in map(json.loads, open(out))}
+    assert kinds["unchanged"]["delta_norm_gap"] == pytest.approx(1.0)
+    # fp8 in the program's place reads wider than the program's bfloat16
+    assert ours < kinds["control"]["grad_norm_gap"] < compare.NEVER
+    proc = _rehearse(chip, "nowhere_cell")
+    assert proc.returncode != 0 and "rehearsal" not in proc.stdout
+    assert str(chip / "archs" / "nowhere.py") in proc.stderr
 
 
 # -- correct has been shown to fail ---------------------------------------------------------

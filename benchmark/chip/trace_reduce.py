@@ -1,17 +1,29 @@
 """From a profiler trace (``.xplane.pb``) to numbers.
 
-``load`` reads the file with ``jax.profiler.ProfileData`` and nothing else
-and returns plain data::
+``load`` reads the file with ``jax.profiler.ProfileData`` and returns plain
+data::
 
     {"planes": [{"name": str, "lines": [{"name": str,
                  "events": [(name, start_ns, duration_ns), ...]}]}],
-     "detail": {device event name: full text}}
+     "detail": {device event name: full text},
+     "scope": {device event name: the path of scopes it was traced under}}
 
 The TPU profiler names a device operation by its whole HLO instruction
 (``%fusion.12 = (f32[4,512]...) fusion(...), kind=kLoop, ...``).  ``load``
 shortens that to ``fusion.12 f32[4,512]`` and keeps the text under
 ``detail``, where a reader looks for what the short name does not say
 (``custom_call_target="tpu_custom_call"`` marks a Pallas kernel).
+
+The path of ``jit`` and ``jax.named_scope`` names an operation was traced
+under (``jit(step)/transpose(jvp(jit(FullyConnected)))/dot_general:``: the
+HLO ``op_name``) is not in that text.  The profiler keeps it as the
+statistic ``tf_op`` of the event's metadata, which ``ProfileData`` does not
+hand out (its ``stats`` are the event's own: offsets and durations; TPU v5e,
+jax 0.9.0, PR 29), so ``load`` takes it from the file's wire format itself
+(``_metadata_stat``) and keeps it under ``scope``.  A fusion carries one
+path: a weight-gradient product that the compiler fused with its AdamW
+update carries the product's.  Some of what the compiler adds itself
+(copies, slices) carries none.
 
 Every reduction below works on that, so a test can hand them a trace it
 wrote itself (``write_xspace`` writes the same wire format, and is how the
@@ -33,9 +45,13 @@ OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
 MARK = "bench:"
+SCOPE_STAT = "tf_op"      # the statistic that carries an operation's scope
+# a collective by its short name: the synchronous ones, and the two halves
+# of one the compiler made asynchronous (``async-collective-start.N`` begins
+# it, ``async-collective-done.N`` waits for it)
 COLLECTIVE = re.compile(
     r"^(all-reduce|all-gather|reduce-scatter|all-to-all|"
-    r"collective-permute)(-start|-done)?(\.|$)")
+    r"collective-permute|async-collective)(-start|-done)?(\.|\s|$)")
 _HLO = re.compile(r"^%([\w.\-]+) = \(?(\w+\[[\d,]*\])?")
 
 
@@ -59,7 +75,9 @@ def find_xplane(log_dir):
 def load(path):
     from jax.profiler import ProfileData
 
-    data = ProfileData.from_file(path)
+    with open(path, "rb") as f:
+        raw = f.read()
+    data = ProfileData.from_serialized_xspace(raw)
     planes, detail, short = [], {}, {}
     for plane in data.planes:
         device = bool(DEVICE_PLANE.match(plane.name))
@@ -76,10 +94,79 @@ def load(path):
                 events.append((name, int(ev.start_ns), int(ev.duration_ns)))
             lines.append({"name": line.name, "events": events})
         planes.append({"name": plane.name, "lines": lines})
-    return {"planes": planes, "detail": detail}
+    scope = {}
+    for name, value in _metadata_stat(raw, SCOPE_STAT).items():
+        if name in short:
+            scope.setdefault(short[name], value)
+    return {"planes": planes, "detail": detail, "scope": scope}
 
 
-# -- the wire format, written by hand (no protobuf module here) -------------
+# -- the wire format, by hand (no protobuf module here) ---------------------
+# XSpace.planes=1; XPlane id=1 name=2 lines=3 event_metadata=4 (map of id to
+# XEventMetadata id=1 name=2 stats=5) stat_metadata=5 (map of id to
+# XStatMetadata id=1 name=2); XLine id=1 name=2 timestamp_ns=3 events=4;
+# XEvent metadata_id=1 offset_ps=2 duration_ps=3; XStat metadata_id=1
+# str_value=5
+
+def _read_varint(buf, i):
+    n, shift = 0, 0
+    while True:
+        b = buf[i]
+        i += 1
+        n |= (b & 0x7F) << shift
+        shift += 7
+        if not b & 0x80:
+            return n, i
+
+
+def _fields(buf):
+    """``(number, value)`` of each field of a message: an int for a varint,
+    the bytes of any other."""
+    i = 0
+    while i < len(buf):
+        key, i = _read_varint(buf, i)
+        wire = key & 7
+        if wire == 0:
+            value, i = _read_varint(buf, i)
+        else:
+            if wire == 2:
+                size, i = _read_varint(buf, i)
+            elif wire in (1, 5):
+                size = 8 if wire == 1 else 4
+            else:
+                raise ValueError("wire type %d in an XSpace" % wire)
+            value = buf[i:i + size]
+            i += size
+        yield key >> 3, value
+
+
+def _metadata_stat(raw, stat):
+    """``{event name: value}`` of the string statistic ``stat`` that the
+    device planes' event metadata carry; ``{}`` where none does."""
+    out = {}
+    for num, plane in _fields(raw):
+        if num != 1:
+            continue
+        name, events, ids = "", [], set()
+        for n, v in _fields(plane):
+            if n == 2:
+                name = v.decode()
+            elif n == 4:        # a map entry: key=1, value=2
+                events.append(list(_fields(dict(_fields(v)).get(2, b""))))
+            elif n == 5:
+                meta = dict(_fields(dict(_fields(v)).get(2, b"")))
+                if meta.get(2, b"").decode() == stat:
+                    ids.add(meta.get(1))
+        if not ids or not DEVICE_PLANE.match(name):
+            continue
+        for meta in events:
+            event = dict(meta).get(2, b"").decode()
+            for n, v in meta:
+                st = dict(_fields(v)) if n == 5 else {}
+                if st.get(1) in ids and 5 in st:
+                    out.setdefault(event, st[5].decode())
+    return out
+
 
 def _varint(n):
     out = bytearray()
@@ -98,28 +185,36 @@ def _field(num, wire, payload):
 
 
 def write_xspace(trace, path):
-    """Write ``trace`` (the ``planes`` of ``load``'s form) as an XSpace:
-    XSpace.planes=1; XPlane id=1 name=2 lines=3 event_metadata=4 (map of
-    id to XEventMetadata id=1 name=2); XLine id=1 name=2 timestamp_ns=3
-    events=4; XEvent metadata_id=1 offset_ps=2 duration_ps=3."""
+    """Write ``trace`` (``load``'s form: the ``planes``, and ``detail`` and
+    ``scope`` where it has them) as an XSpace that ``load`` reads back."""
     space = b""
+    scopes = trace.get("scope", {})
     for pi, plane in enumerate(trace["planes"], 1):
-        ids, body = {}, _field(1, 0, pi) + _field(2, 2,
-                                                  plane["name"].encode())
+        ids, paths = {}, {}
+        body = _field(1, 0, pi) + _field(2, 2, plane["name"].encode())
         for li, line in enumerate(plane["lines"], 1):
             t0 = min((s for _, s, _ in line["events"]), default=0)
             lb = _field(1, 0, li) + _field(2, 2, line["name"].encode()) \
                 + _field(3, 0, t0)
             for name, start, dur in line["events"]:
+                scope = scopes.get(name)
                 name = trace.get("detail", {}).get(name, name)
                 mid = ids.setdefault(name, len(ids) + 1)
+                if scope is not None:
+                    paths[mid] = scope
                 lb += _field(4, 2, _field(1, 0, mid)
                              + _field(2, 0, (start - t0) * 1000)
                              + _field(3, 0, dur * 1000))
             body += _field(3, 2, lb)
         for name, mid in ids.items():
             meta = _field(1, 0, mid) + _field(2, 2, name.encode())
+            if mid in paths:
+                meta += _field(5, 2, _field(1, 0, 1)
+                               + _field(5, 2, paths[mid].encode()))
             body += _field(4, 2, _field(1, 0, mid) + _field(2, 2, meta))
+        if paths:       # stat_metadata 1 is the scope statistic's name
+            body += _field(5, 2, _field(1, 0, 1) + _field(2, 2, _field(
+                1, 0, 1) + _field(2, 2, SCOPE_STAT.encode())))
         space += _field(1, 2, body)
     with open(path, "wb") as f:
         f.write(space)
@@ -219,12 +314,8 @@ def top_ops(trace, n=10, t0=None, t1=None, by=None):
     return [[name, t / 1e9 / k] for name, t in ranked]
 
 
-def matching_seconds(trace, pattern, t0=None, t1=None, detail=False):
-    """Summed self time, per device, of the operations whose short name
-    (with ``detail`` their whole text) matches ``pattern``; and how many
-    such events there were on the busiest device."""
+def _matching(trace, pattern, text_of, t0, t1):
     rx = re.compile(pattern)
-    texts = trace.get("detail", {})
     ops = device_ops(trace)
     total, count = 0, 0
     for events in ops.values():
@@ -232,11 +323,27 @@ def matching_seconds(trace, pattern, t0=None, t1=None, detail=False):
             events = clip(events, t0, t1)
         n = 0
         for name, t in self_times(events):
-            if rx.search(texts.get(name, name) if detail else name):
+            if rx.search(text_of(name)):
                 total += t
                 n += 1
         count = max(count, n)
     return total / 1e9 / max(1, len(ops)), count
+
+
+def matching_seconds(trace, pattern, t0=None, t1=None, detail=False):
+    """Summed self time, per device, of the operations whose short name
+    (with ``detail`` their whole text) matches ``pattern``; and how many
+    such events there were on the busiest device."""
+    texts = trace.get("detail", {}) if detail else {}
+    return _matching(trace, pattern, lambda n: texts.get(n, n), t0, t1)
+
+
+def scope_seconds(trace, pattern, t0=None, t1=None):
+    """The same for the operations whose scope (the path of ``jit`` and
+    ``jax.named_scope`` names they were traced under) matches ``pattern``;
+    an operation without one has the empty path."""
+    scopes = trace.get("scope", {})
+    return _matching(trace, pattern, lambda n: scopes.get(n, ""), t0, t1)
 
 
 def collective_exposed_seconds(trace, t0=None, t1=None):
@@ -349,4 +456,5 @@ def cut(trace, t0, t1, keep_planes=None):
                  for ln in plane["lines"]]
         planes.append({"name": plane["name"],
                        "lines": [ln for ln in lines if ln["events"]]})
-    return {"planes": planes, "detail": trace.get("detail", {})}
+    return {"planes": planes, "detail": trace.get("detail", {}),
+            "scope": trace.get("scope", {})}
