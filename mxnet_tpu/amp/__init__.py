@@ -28,6 +28,7 @@ _state = {
     "target_ops": frozenset(),
     "fp32_ops": frozenset(),
     "widest_ops": frozenset(),
+    "target_keep": {},
 }
 
 _LOW = (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float16))
@@ -54,6 +55,7 @@ def init(target_dtype="bfloat16", target_precision_ops=None,
         target_ops=frozenset(target),
         fp32_ops=frozenset(fp32),
         widest_ops=frozenset(lists.WIDEST_TYPE_CASTS),
+        target_keep=dict(lists.TARGET_DTYPE_KEEP),
     )
 
 
@@ -71,11 +73,12 @@ def transform_inputs(op_name, datas):
         return datas
     if op_name in _state["target_ops"]:
         tgt = _state["target_dtype"]
+        keep = _state["target_keep"].get(op_name, ())
         return tuple(
             d.astype(tgt)
             if hasattr(d, "dtype") and d.dtype in (jnp.float32,) + _LOW
-            and d.dtype != tgt else d
-            for d in datas)
+            and d.dtype != tgt and i not in keep else d
+            for i, d in enumerate(datas))
     if op_name in _state["fp32_ops"]:
         return tuple(
             d.astype(jnp.float32)

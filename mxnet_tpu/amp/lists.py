@@ -25,7 +25,17 @@ TARGET_DTYPE_OPS = [
     # inputs would double attention HBM traffic and halve MXU rate
     # (xplane r5: f32[96,512,64] custom-calls before this entry)
     "_contrib_flash_attention",
+    # the experts' grouped products; the routing weights stay as they
+    # came (TARGET_DTYPE_KEEP below)
+    "_contrib_moe_grouped_ffn",
 ]
+
+# inputs of a target-dtype op, by position, that keep the dtype they came
+# in: ``_contrib_moe_grouped_ffn``'s ``topk_weight`` (input 2) scales whole
+# rows of the experts' result
+TARGET_DTYPE_KEEP = {
+    "_contrib_moe_grouped_ffn": (2,),
+}
 
 # numerically-sensitive ops forced to float32
 FP32_OPS = [
@@ -71,6 +81,12 @@ FP32_OPS = [
     # norms (131.7k vs 122.7k tok/s) — XLA fuses the f32 norm chain into
     # the adjacent matmuls and skips a convert round trip
     "RMSNorm",
+    # discrete or recurrent: a rounded router score flips an expert, and a
+    # state-space layer's decays, dt and carried state compound over the
+    # sequence (ops/moe.py, ops/ssm.py)
+    "_contrib_moe_router_topk",
+    "_contrib_ssd_scan",
+    "_contrib_causal_conv1d",
 ]
 
 # multi-input ops whose inputs are cast to the widest participating dtype

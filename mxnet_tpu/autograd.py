@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import threading
 
+import jax
 import jax.numpy as jnp
 import numpy as _np
 
@@ -144,7 +145,11 @@ class TapeNode:
         outs = []
         for ct, (shape, dtype) in zip(self.cotangents, self.out_avals):
             if ct is None:
-                ct = jnp.zeros(shape, dtype)
+                # an integer output (a router's indices) has no tangent
+                # space: jax.vjp takes a float0 cotangent for it
+                ct = jnp.zeros(shape, dtype) \
+                    if jnp.issubdtype(dtype, jnp.inexact) \
+                    else _np.zeros(shape, jax.dtypes.float0)
             outs.append(ct)
         return tuple(outs)
 
@@ -299,8 +304,8 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
             node.prim = None
         skip = node.skip_grad_inputs
         for inp, ct in zip(node.inputs, in_cts[skip:] if skip else in_cts):
-            if ct is None:
-                continue
+            if ct is None or getattr(ct, "dtype", None) == jax.dtypes.float0:
+                continue        # float0: the cotangent of an integer input
             child = inp._tape_node
             if child is not None:
                 if hasattr(ct, "tostype"):  # sparse ct into an interior node

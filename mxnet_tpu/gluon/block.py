@@ -75,6 +75,7 @@ def _trace_st():
         _trace_state.param_map = None   # id(Parameter) -> NDArray(tracer)
         _trace_state.aux_updates = None  # list of (Parameter, jax array)
         _trace_state.active = False
+        _trace_state.step_stats = None   # name -> jax array, in a train step
     return _trace_state
 
 
@@ -98,6 +99,20 @@ def record_aux_update(param, value):
         st.aux_updates.append((param, data))
     else:
         param.set_data(data)
+
+
+def record_step_stat(name, value):
+    """Add ``value`` to the statistic ``name`` of the train step being
+    traced.  A block that counts something a step (a routed layer's
+    assignments) declares it in ``step_stat_specs() -> {name: (shape,
+    dtype)}``; ``parallel.JitTrainStep`` then carries one accumulator a name
+    through its step program and sums what each step records into it on the
+    device (``JitTrainStep.step_stats()`` fetches them).  Outside such a
+    trace (imperative, ``hybridize()``) the value is dropped."""
+    stats = _trace_st().step_stats
+    if stats is not None:
+        data = value.data() if isinstance(value, NDArray) else value
+        stats[name] = stats[name] + data if name in stats else data
 
 
 class _BlockScope:

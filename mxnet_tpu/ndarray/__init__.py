@@ -13,6 +13,8 @@ from ..ops import optimizer_ops as _o  # noqa: F401
 from ..ops import contrib as _c  # noqa: F401
 from ..ops import pallas_kernels as _p  # noqa: F401
 from ..ops import paged_attention as _pa  # noqa: F401
+from ..ops import ssm as _ssm  # noqa: F401
+from ..ops import moe as _moe  # noqa: F401
 from ..ops import misc as _m  # noqa: F401
 from ..ops import vision as _v  # noqa: F401
 from ..ops import quantized_ops as _q  # noqa: F401

@@ -14,6 +14,20 @@ Because routing is a straight-through top-1 (gate value scales the
 expert output), the whole layer is differentiable; dropped tokens
 (capacity overflow) contribute zero output and zero gradient, exactly
 like Switch Transformer.
+
+Beside it, the layer of today's sparse models: ``router_topk`` (sigmoid
+scores, a correction bias that moves the choice and not the weight, k of E,
+normalised and scaled) and ``grouped_ffn``, which has **no capacity and
+drops nothing**: assignments are sorted by expert and each projection is
+one grouped product (``lax.ragged_dot``, a Mosaic kernel on TPUs) over the
+rows that landed.  ``first`` and the leading axis of the stacked weights
+tell the layer which experts it holds: it routes over all of them and
+computes its own experts' part of the result, which is what expert
+parallelism asks of each chip; on one chip there is no exchange, and
+nothing stands in for the absent chips.  Both are registered operators
+(``F.contrib.moe_router_topk``, ``F.contrib.moe_grouped_ffn``), so their
+code lives with the operators in ``ops/moe.py`` and is handed out here;
+``docs/moe_ssm.md`` has shapes and dtypes.
 """
 from __future__ import annotations
 
@@ -22,8 +36,11 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..base import MXNetError
+from ..ops.moe import grouped_ffn, router_topk  # noqa: F401
+from ..telemetry import metrics
 
-__all__ = ["moe_ffn", "moe_ffn_sharded", "router_top1"]
+__all__ = ["moe_ffn", "moe_ffn_sharded", "router_top1", "router_topk",
+           "grouped_ffn"]
 
 
 def router_top1(x, router_w, n_experts, capacity):
@@ -124,3 +141,48 @@ def moe_ffn_sharded(x, router_w, w_in, w_out, mesh, axis_name="expert",
         out_specs=(P(), P()),
         check_vma=False,
     )(x, router_w, w_in, w_out)
+
+
+
+# -- counters, fed from the train steps' accumulated counts when read -------
+
+STAT_PREFIX = "moe/"      # a layer's statistic: "moe/<layer>/<first expert>"
+_seen = {}                # (step, statistic) -> the counts last read
+
+
+def _telemetry_collector():
+    """``grouped_ffn``'s counts leave a ``JitTrainStep`` program as a
+    statistic it accumulates on the device (``gluon.block.
+    record_step_stat``); a snapshot fetches them and adds what is new
+    (modulo the accumulators' 32 bits) to the three counter families."""
+    from .train_step import read_step_stats
+
+    total = dropped = 0
+    every = read_step_stats(STAT_PREFIX)
+    if not every:       # no step of this process routes: no family either
+        return
+    for owner, stats in every:
+        for name, counts in stats.items():
+            counts = [int(c) for c in counts]
+            last = _seen.get((owner, name), [0] * len(counts))
+            _seen[(owner, name)] = counts
+            new = [(c - p) % (1 << 32) for c, p in zip(counts, last)]
+            _, layer, first = name.split("/")
+            for j, n in enumerate(new[:-2]):
+                if n:
+                    metrics.counter(
+                        "mxnet_moe_assignments_held_total",
+                        help="routed assignments that landed on an expert "
+                             "held here", layer=layer,
+                        expert=str(int(first) + j)).inc(n)
+            total += new[-2]
+            dropped += new[-1]
+    metrics.counter("mxnet_moe_assignments_total",
+                    help="routed assignments (tokens x experts a token), "
+                         "every routed layer").inc(total)
+    metrics.counter("mxnet_moe_dropped_total",
+                    help="assignments to a held expert that were not "
+                         "computed; has to stay 0").inc(dropped)
+
+
+metrics.register_collector(_telemetry_collector)

@@ -54,6 +54,25 @@ _TPU_MESH_COMPILER_OPTIONS = {
 }
 
 
+# The statistics' accumulators of every step program of this process, by the
+# order the steps were made: a reference a step (a few small device arrays,
+# kept after the step is gone: what it counted still counts), and nothing is
+# fetched until somebody reads.
+_step_stats = {}
+
+
+def read_step_stats(prefix=""):
+    """``[(owner, {name: numpy array})]`` for every step program of this
+    process that carries statistics whose names start with ``prefix``,
+    fetched now.  ``owner`` tells the steps apart."""
+    out = []
+    for owner, stats in list(_step_stats.items()):
+        picked = {k: v for k, v in stats.items() if k.startswith(prefix)}
+        if picked:
+            out.append((owner, jax.device_get(picked)))
+    return out
+
+
 def mesh_compiler_options(mesh):
     """``compiler_options`` for a step program compiled over ``mesh`` (a
     jax mesh, or None): the overlap options above where every device is a
@@ -124,6 +143,7 @@ class JitTrainStep:
         self._step_fn = None
         self._n_outputs = 1
         self._last_loss = None
+        self._stats = {}        # step statistics' accumulators, by name
 
     def _ensure_init(self, batch_nd):
         """Snapshot parameters; resolves deferred shapes with one forward."""
@@ -183,7 +203,49 @@ class JitTrainStep:
                 # shards, is the room the program's gradients in flight
                 # need.  p.grad() answers with fresh zeros without it.
                 p._data._grad = None
+        self._stats = self._new_step_stats()
         self._tag_weights()
+
+    # -- step statistics -----------------------------------------------------
+    def _new_step_stats(self):
+        """One zeroed accumulator for every statistic a block of the net
+        declares (``step_stat_specs``; ``gluon.block.record_step_stat``):
+        ``{name: array}``, on the training device or replicated over the
+        mesh.  ``{}`` for a net that declares none: its step program is
+        then the one it always was."""
+        specs = {}
+        self._net.apply(lambda b: specs.update(b.step_stat_specs())
+                        if hasattr(b, "step_stat_specs") else None)
+        place = self._device if self._mesh is None \
+            else NamedSharding(self._mesh, P())
+        put = self._put_global if self._multiprocess else jax.device_put
+        stats = {name: put(jnp.zeros(shape, dtype), place)
+                 for name, (shape, dtype) in sorted(specs.items())}
+        if stats:
+            self._stats_owner = len(_step_stats)
+            _step_stats[self._stats_owner] = stats
+        return stats
+
+    def _state_arg(self):
+        """What the step program carries beside the weights: the
+        optimizer's state, and the statistics' accumulators where the net
+        declares any."""
+        return (self._opt_state, self._stats) if self._stats \
+            else self._opt_state
+
+    def _take_state(self, state):
+        if self._stats:
+            self._opt_state, self._stats = state
+            _step_stats[self._stats_owner] = self._stats
+        else:
+            self._opt_state = state
+
+    def step_stats(self):
+        """The accumulated statistics as numpy arrays, fetched now:
+        ``{name: array}``, summed over every step since the first.  Integer
+        accumulators wrap at their width; read them more often than that
+        (``telemetry`` readers take differences modulo the width)."""
+        return jax.device_get(self._stats)
 
     def _tag_weights(self):
         """Attribute the live weight buffers to memdump (per-device param
@@ -323,12 +385,13 @@ class JitTrainStep:
 
     def _out_shardings(self):
         """(weights, opt_state, loss) shardings for any step executable."""
-        return (
-            self._param_shardings,
-            [None if st is None else jax.tree_util.tree_map(
-                lambda _, s=sh: s, st)
-             for st, sh in zip(self._opt_state, self._param_shardings)],
-            NamedSharding(self._mesh, P()))
+        rep = NamedSharding(self._mesh, P())
+        state = [None if st is None else jax.tree_util.tree_map(
+            lambda _, s=sh: s, st)
+            for st, sh in zip(self._opt_state, self._param_shardings)]
+        if self._stats:
+            state = (state, {k: rep for k in self._stats})
+        return self._param_shardings, state, rep
 
     def _jit(self, fn):
         """``fn`` (a step or a loop of steps) as the step executable:
@@ -360,6 +423,7 @@ class JitTrainStep:
                 id(p): NDArray(w) for p, w in zip(params, ws)}
             st.aux_updates = []
             st.active = True
+            st.step_stats = {} if has_stats else None
             try:
                 data_nd = [NDArray(b) for b in batch[:n_data]]
                 # train mode (not recording): BN/dropout use batch stats;
@@ -376,16 +440,19 @@ class JitTrainStep:
                 idx_of = {id(p): i for i, p in enumerate(params)}
                 aux = [(idx_of[id(p)], v) for p, v in st.aux_updates]
                 meta['n_outputs'] = len(outs)
-                return loss_val, aux
+                return loss_val, (aux, st.step_stats)
             finally:
                 st.param_map, st.aux_updates, st.active = prev
+                st.step_stats = None
 
         clip_norm = self._clip_global_norm
+        has_stats = bool(self._stats)
 
-        def step(key, lr, weights, opt_state, t, *batch):
+        def step(key, lr, weights, state, t, *batch):
+            opt_state, stats = state if has_stats else (state, None)
             with _random.trace_key_scope(key):
                 train_ws = [weights[i] for i in train_idx]
-                (loss_val, aux), grads = jax.value_and_grad(
+                (loss_val, (aux, seen)), grads = jax.value_and_grad(
                     forward_loss, has_aux=True)(train_ws, weights, batch)
             if clip_norm is not None:
                 from ..gluon.utils import global_norm_scale
@@ -410,6 +477,11 @@ class JitTrainStep:
                     lambda a, b: a.astype(b.dtype), ns, st_i)
             for i, v in aux:
                 new_weights[i] = v.astype(weights[i].dtype)
+            if has_stats:
+                # a declared statistic that no block recorded stays as it is
+                new_state = (new_state, {
+                    k: a + seen[k].astype(a.dtype) if k in seen else a
+                    for k, a in stats.items()})
             return new_weights, new_state, loss_val
 
         self._raw_step = step
@@ -473,8 +545,10 @@ class JitTrainStep:
                 if self._step_fn is None:
                     self._step_fn = self._build(arrays)
                 with self._mesh_scope():
-                    self._weights, self._opt_state, loss = self._step_fn(
-                        key, lr, self._weights, self._opt_state, t, *arrays)
+                    self._weights, state, loss = self._step_fn(
+                        key, lr, self._weights, self._state_arg(), t,
+                        *arrays)
+                self._take_state(state)
             with _span("train_step.tag"):
                 self._tag_weights()
             self._last_loss = loss
@@ -526,8 +600,10 @@ class JitTrainStep:
             with _span("train_step.call"):
                 fn = self._step_n_fn(n, sched, sched_traced, arrays)
                 with self._mesh_scope():
-                    self._weights, self._opt_state, loss = fn(
-                        key, lr, self._weights, self._opt_state, t, *arrays)
+                    self._weights, state, loss = fn(
+                        key, lr, self._weights, self._state_arg(), t,
+                        *arrays)
+                self._take_state(state)
             with _span("train_step.tag"):
                 self._tag_weights()
             self._t += n
@@ -753,8 +829,7 @@ class JitTrainStep:
             return jax.ShapeDtypeStruct(a.shape, a.dtype)
 
         w_avals = [aval(w) for w in self._weights]
-        s_avals = [None if s is None else jax.tree_util.tree_map(aval, s)
-                   for s in self._opt_state]
+        s_avals = jax.tree_util.tree_map(aval, self._state_arg())
         compiled = self._step_fn.lower(
             aval(_random.next_key()),
             jax.ShapeDtypeStruct((), jnp.float32),

@@ -18,6 +18,8 @@ Two kinds of checks:
 The enumeration test at the bottom fails when a new custom_vjp/defvjp
 site appears without being added to a coverage list here.
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -257,6 +259,37 @@ def test_quantize_dequantize_ste_round_trip_grad():
 
 
 # ---------------------------------------------------------------------------
+# routed experts: tokens go to their sorted rows and back by gathers whose
+# hand-written transposes are each other (ops/moe.py)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("argnum", [0, 2, 3, 4])
+def test_grouped_ffn_grads_match_dense_experts(argnum):
+    rs = np.random.RandomState(3)
+    x = rs.randn(10, 6).astype(np.float32)
+    idx = rs.randint(0, 8, (10, 2)).astype(np.float32)
+    w = rs.uniform(0.2, 1.0, (10, 2)).astype(np.float32)
+    up = (rs.randn(3, 5, 6) * 0.5).astype(np.float32)
+    down = (rs.randn(3, 6, 5) * 0.5).astype(np.float32)
+    ct = rs.randn(10, 6).astype(np.float32)
+    args = [x, idx, w, up, down]
+
+    def dense(x, idx, w, up, down):
+        """Experts 2..4 of 8 on every token, weighted where chosen."""
+        out = 0.0
+        for e in range(3):
+            gate = jnp.sum(jnp.where(idx == e + 2, w, 0.0), axis=1)
+            hid = jnp.square(jax.nn.relu(x @ up[e].T))
+            out = out + gate[:, None] * (hid @ down[e].T)
+        return jnp.sum(out * ct)
+    want = jax.grad(dense, argnums=argnum)(*[jnp.asarray(a) for a in args])
+    got = _grad_of(lambda *a: nd.contrib.moe_grouped_ffn(*a, first=2), args,
+                   argnum=argnum, cotangent=ct)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
 # enumeration guard: every hand-written backward is on a coverage list
 # ---------------------------------------------------------------------------
 
@@ -275,6 +308,10 @@ COVERED_CUSTOM_VJP = {
     "SoftmaxOutput", "SoftmaxActivation", "IdentityAttachKLSparseReg",
     # ops/pallas_kernels.py — tests/test_flash_backward.py
     "_contrib_flash_attention",
+    # ops/moe.py — test_grouped_ffn_grads_match_dense_experts above and
+    # tests/test_nemotron_h.py; the router's registration precedes the
+    # gathers' definitions in the file and has no backward of its own
+    "_contrib_moe_grouped_ffn", "_contrib_moe_router_topk",
     # library.py plugin backward — tests/test_library_plugin.py
 }
 

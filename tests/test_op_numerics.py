@@ -672,6 +672,73 @@ SPECS["_contrib_flash_attention"] = S(
     ref=_flash_ref, rtol=1e-3, atol=1e-4)
 
 
+def _causal_conv_ref(x, w, b):
+    k = w.shape[1]
+    xp = np.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(xp[:, j:j + x.shape[1]] * w[:, j] for j in range(k)) + b
+
+
+SPECS["_contrib_causal_conv1d"] = S(
+    [randn((2, 7, 3), 140), randn((3, 4), 141), randn((3,), 142)],
+    ref=_causal_conv_ref, grad=True)
+
+
+def _ssd_ref(x, dt, a_log, bm, cm, d, dt_bias):
+    """Mamba-2's recurrence, one token at a time."""
+    b, t, h, p = x.shape
+    rep = h // bm.shape[2]
+    dt = np.log1p(np.exp(dt + dt_bias.reshape(h)))
+    a = -np.exp(a_log.reshape(h))
+    state = np.zeros((b, h, p, bm.shape[3]))
+    y = np.zeros(x.shape)
+    for i in range(t):
+        bi, ci = np.repeat(bm[:, i], rep, 1), np.repeat(cm[:, i], rep, 1)
+        state = state * np.exp(dt[:, i] * a)[..., None, None] \
+            + (dt[:, i, :, None] * x[:, i])[..., None] * bi[:, :, None, :]
+        y[:, i] = np.einsum("bhpn,bhn->bhp", state, ci) \
+            + x[:, i] * d[:, None]
+    return y
+
+
+SPECS["_contrib_ssd_scan"] = S(
+    [randn((1, 10, 4, 3), 143), randn((1, 10, 4), 144),
+     randn((1, 4), 145, 0.3), randn((1, 10, 2, 5), 146),
+     randn((1, 10, 2, 5), 147), randn((4,), 148), randn((1, 4), 149, 0.3)],
+    {"chunk": 4}, ref=_ssd_ref, rtol=1e-3, atol=1e-4)
+
+
+def _router_ref(x, w, b):
+    s = 1.0 / (1.0 + np.exp(-(x.astype(np.float64) @ w.T)))
+    idx = np.argsort(-(s + b), axis=1, kind="stable")[:, :2]
+    picked = np.take_along_axis(s, idx, 1)
+    return idx, 2.5 * picked / picked.sum(1, keepdims=True)
+
+
+SPECS["_contrib_moe_router_topk"] = S(
+    [randn((6, 5), 150), randn((8, 5), 151), randn((8,), 152, 0.1)],
+    {"k": 2, "scale": 2.5}, ref=_router_ref)
+
+_MOE_IDX = np.array([[2, 5], [3, 0], [7, 4], [2, 3], [4, 5], [1, 2]],
+                    np.float32)
+
+
+def _grouped_ffn_ref(x, idx, w, up, down):
+    """Experts 2..4 of 8 are held: each one densely, weighted where
+    chosen; the counts are rows landed, assignments, dropped."""
+    out = np.zeros(x.shape)
+    for e in range(3):
+        gate = (w * (idx == e + 2)).sum(1)
+        hid = np.maximum(x @ up[e].T, 0.0) ** 2
+        out += gate[:, None] * (hid @ down[e].T)
+    return out, np.array([3, 2, 2, 12, 0])
+
+
+SPECS["_contrib_moe_grouped_ffn"] = S(
+    [randn((6, 5), 153), _MOE_IDX, pos((6, 2), 154),
+     randn((3, 4, 5), 155), randn((3, 5, 4), 156)],
+    {"first": 2}, ref=_grouped_ffn_ref, rtol=1e-3, atol=1e-4)
+
+
 def _paged_attn_ref(q, kp, vp, tbl, pos):
     b, k1, h, d = q.shape
     kv, s_page = kp.shape[1], kp.shape[2]           # pages (P, KV, S, D)

@@ -23,8 +23,10 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
+from mxnet_tpu.ops.moe import grouped_ffn
 from mxnet_tpu.ops.paged_attention import paged_attention
 from mxnet_tpu.ops.pallas_kernels import _flash_bwd_pallas, flash_attention
+from mxnet_tpu.ops.ssm import ssd_scan
 from mxnet_tpu.parallel.train_step import mesh_compiler_options
 
 
@@ -215,3 +217,43 @@ def test_paged_attention(one_chip, geometry, k1, kv_dtype):
     c = _compile(paged_attention, one_chip, *shapes)
     assert "tpu_custom_call" in c.as_text()
     assert "%mx_paged_attention" in c.as_text()
+
+
+# -- the Nemotron-H cell's operators at its own sizes (PR 30) -----------------
+
+def test_grouped_ffn_is_ragged_kernels_forward_and_backward(one_chip):
+    # 4096 tokens x 6 assignments over 8 held experts of 2688 x 1856: each
+    # of the six products (and the two made again in the backward pass) is
+    # the compiler's grouped Mosaic kernel,
+    # whose work follows the group sizes; a projection in another layout
+    # falls back to a dense product over every expert and the whole bound
+    def loss(x, idx, w, up, down):
+        out, counts = grouped_ffn(x, idx, w, up.astype(jnp.bfloat16),
+                                  down.astype(jnp.bfloat16))
+        return jnp.sum(out * out), counts
+    c = _compile(jax.grad(loss, argnums=(0, 2, 3, 4), has_aux=True),
+                 one_chip, ((4096, 2688), jnp.bfloat16),
+                 ((4096, 6), jnp.int32), ((4096, 6), jnp.float32),
+                 ((8, 1856, 2688), jnp.float32),
+                 ((8, 2688, 1856), jnp.float32))
+    text = c.as_text()
+    kernels = re.findall(r"%ragged-dot-none[.\d]* = \S+ custom-call\(",
+                         text)
+    assert len(kernels) == 8, kernels
+    assert "tpu_custom_call" in text
+    assert not re.search(r"bf16\[8,24576,", text)   # no dense fallback
+
+
+@pytest.mark.parametrize("seq", [2048, 2000])
+def test_ssd_scan_forward_and_backward_fit(one_chip, seq):
+    # 2 x 2048 tokens, 64 heads x 64, 8 groups x 128 states, chunks of
+    # 128 (and a length that is no multiple of the chunk): what the
+    # backward keeps is the inputs, under half a gigabyte of temporaries
+    def loss(*args):
+        return jnp.sum(jnp.square(ssd_scan(*args)))
+    f32 = jnp.float32
+    c = _compile(jax.grad(loss, argnums=tuple(range(7))), one_chip,
+                 ((2, seq, 64, 64), f32), ((2, seq, 64), f32),
+                 ((1, 64), f32), ((2, seq, 8, 128), f32),
+                 ((2, seq, 8, 128), f32), ((64,), f32), ((1, 64), f32))
+    assert c.memory_analysis().temp_size_in_bytes < 512 << 20
