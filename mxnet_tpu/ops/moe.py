@@ -12,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .pallas_kernels import grouped_matmul
 from .registry import register
 
 
@@ -116,12 +117,15 @@ def grouped_ffn(data, topk_idx, topk_weight, up, down, first=0):
     ``_computed`` lets through: it has to stay 0).
 
     The ``S * k`` assignments are sorted by expert, absent experts last;
-    the sorted rows go through one grouped product a projection whose
-    group sizes are the rows that landed, so rows of absent experts cost
-    no product.  Shapes are static at the bound ``S * k`` (every
+    the sorted rows go through one grouped product a projection
+    (``pallas_kernels.grouped_matmul``: the kernels ``mx_gmm`` and, for
+    the weights' gradient, ``mx_gmm_dw``) whose group sizes are the rows
+    that landed, so rows of absent experts cost no product and a call
+    costs what landed.  Shapes are static at the bound ``S * k`` (every
     assignment may land here): there is no capacity and no smaller bound
-    to overflow.  The matrix products run in ``data``'s dtype; the
-    weighted sum over a token's assignments is float32.  Nothing of the
+    to overflow.  The matrix products run in ``data``'s dtype, accumulated
+    in float32, on the weights as they are stored (nothing is transposed);
+    the weighted sum over a token's assignments is float32.  Nothing of the
     sorted layout is kept for the backward pass (``jax.checkpoint``): it
     is made again from the inputs."""
     with jax.named_scope("moe_experts"):
@@ -149,16 +153,14 @@ def _grouped_ffn(data, topk_idx, topk_weight, up, down, first):
                     dtype=jnp.int32)
     computed = _computed(key, order, held)
     mask = computed[:, None]
-    up_t = jnp.swapaxes(up, 1, 2).astype(data.dtype)
-    down_t = jnp.swapaxes(down, 1, 2).astype(data.dtype)
     # a grouped product leaves the rows past the last group undefined: they
     # are masked on the way in and on the way out, each time by a select
     # that stands next to the product, so that no undefined value meets
     # arithmetic in either direction (0 * nan is nan)
     rows = jnp.where(mask, _to_sorted(data, order, inverse, k), 0)
-    hid = jax.lax.ragged_dot(rows, up_t, sizes)
+    hid = grouped_matmul(rows, up.astype(data.dtype), sizes)
     hid = _relu2(jnp.where(mask, hid, 0))
-    res = jax.lax.ragged_dot(hid, down_t, sizes)
+    res = grouped_matmul(hid, down.astype(data.dtype), sizes)
     w = topk_weight.reshape(-1)[order].astype(jnp.float32)
     res = jnp.where(mask, res, 0).astype(jnp.float32) * w[:, None]
     out = _from_sorted(res, order, inverse, k)

@@ -1,4 +1,5 @@
-"""Pallas TPU kernels: fused flash attention (forward AND backward).
+"""Pallas TPU kernels: fused flash attention (forward AND backward), and
+the grouped matmul of the routed experts (``grouped_matmul``, at the end).
 
 The reference's fused-attention story is two CUDA kernels
 (``_contrib_interleaved_matmul_selfatt_qk``/``_valatt``,
@@ -449,3 +450,224 @@ def flash_attention(query, key, value, scale=None, causal=False,
 def _finish(out3, b, h, t_q, d, squeeze):
     out = out3.reshape(b, h, t_q, d)
     return out[:, 0] if squeeze else out
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul: rows sorted by group, one matrix a group
+# ---------------------------------------------------------------------------
+
+# the row tile: what the MXU of a v5e takes at once.  A group of 195 rows
+# pays for two or three of them, one of 3072 for 24, and a group's matrix
+# stays in VMEM across its consecutive tiles either way
+_GMM_ROWS = 128
+# what a call's blocks may take of VMEM (a v5e has 128 MiB; the compiler's
+# own limit of 16 MiB holds no whole 2688 x 1856 matrix twice)
+_GMM_VMEM = 96 << 20
+
+
+def _gmm_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    # the blocks, and some room for what the compiler keeps beside them
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=_GMM_VMEM + (8 << 20))
+
+
+def _gmm_split(whole, need, room):
+    """``(block, blocks)`` along a dimension that no product contracts:
+    ``whole`` if ``need(block)`` bytes fit ``room``, else the fewest equal
+    blocks of a multiple of 128 that do (the last one may be partial: what
+    it reads past the edge lands in columns that are cut off again)."""
+    blocks = 1
+    while True:
+        block = whole if blocks == 1 \
+            else -(-whole // (blocks * _LANES)) * _LANES
+        if need(block) <= room or block <= _LANES:
+            return block, -(-whole // block)
+        blocks += 1
+
+
+def _gmm_visits(sizes, m, tm, empty):
+    """The (row tile, group) pairs a grouped kernel walks, in order:
+    ``(offsets (G + 1,), group (V,), tile (V,), count)``, all int32, of
+    which the first ``count`` entries are pairs that hold rows (and, where
+    ``empty``, one pair for each group without rows, so that its result is
+    written).  ``V = tiles + G - 1`` bounds the count; the kernel's grid is
+    ``count`` long, not ``V``."""
+    groups = sizes.shape[0]
+    tiles = -(-m // tm)
+    ends = jnp.cumsum(sizes, dtype=jnp.int32)
+    first = (ends - sizes) // tm
+    span = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, int(empty))
+    visit_ends = jnp.cumsum(span, dtype=jnp.int32)
+    visit = jnp.arange(tiles + groups - 1, dtype=jnp.int32)
+    group = jnp.minimum(
+        jnp.sum(visit[:, None] >= visit_ends[None, :], axis=1,
+                dtype=jnp.int32), groups - 1)
+    # a visit's tile: its group's first, and its place among the group's
+    # visits (a sum over a one-hot row: a gather is a scalar loop on a TPU)
+    mine = group[:, None] == jnp.arange(groups, dtype=jnp.int32)[None, :]
+    tile = visit + jnp.sum(
+        jnp.where(mine, (first - visit_ends + span)[None, :], 0), axis=1,
+        dtype=jnp.int32)
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    return offsets, group, jnp.clip(tile, 0, tiles - 1), visit_ends[-1]
+
+
+def _gmm_rows_of(offsets, groups, tiles, visit, tm, width):
+    """Which rows of this visit's tile belong to this visit's group."""
+    g = groups[visit]
+    row = tiles[visit] * tm + lax.broadcasted_iota(jnp.int32, (tm, width), 0)
+    return (row >= offsets[g]) & (row < offsets[g + 1])
+
+
+def _gmm_kernel(offsets, groups, tiles, lhs_ref, rhs_ref, out_ref, *, tm,
+                transposed):
+    from jax.experimental import pallas as pl
+
+    mine = _gmm_rows_of(offsets, groups, tiles, pl.program_id(1), tm,
+                        out_ref.shape[1])
+    acc = lax.dot_general(
+        lhs_ref[...], rhs_ref[...],
+        (((1,), (1 if transposed else 0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    # a tile that two groups share is visited by each in turn and stays in
+    # VMEM between them: each writes its own rows
+    out_ref[...] = jnp.where(mine, acc.astype(out_ref.dtype), out_ref[...])
+
+
+def _gmm_pallas(lhs, rhs, sizes, transposed, interpret=False):
+    """``out[m] = lhs[m] @ rhs[group(m)]`` (``rhs (G, K, N)``), or ``@
+    rhs[group(m)].T`` where ``transposed`` (``rhs (G, N, K)``).  Rows past
+    the last group are not visited and stay undefined."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, k = lhs.shape
+    n = rhs.shape[1] if transposed else rhs.shape[2]
+    tm = min(_GMM_ROWS, m)
+    size = lhs.dtype.itemsize
+    tn, n_blocks = _gmm_split(
+        n, lambda tn: 2 * size * (tm * k + tn * k + tm * tn) + 4 * tm * tn,
+        _GMM_VMEM)
+    offsets, groups, tiles, count = _gmm_visits(sizes, m, tm, empty=False)
+    if transposed:
+        rhs_spec = pl.BlockSpec((None, tn, k),
+                                lambda j, v, o, g, t: (g[v], j, 0))
+    else:
+        rhs_spec = pl.BlockSpec((None, k, tn),
+                                lambda j, v, o, g, t: (g[v], 0, j))
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, tm=tm, transposed=transposed),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_blocks, count),
+            in_specs=[
+                pl.BlockSpec((tm, k), lambda j, v, o, g, t: (t[v], 0)),
+                rhs_spec,
+            ],
+            out_specs=pl.BlockSpec((tm, tn),
+                                   lambda j, v, o, g, t: (t[v], j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        compiler_params=_gmm_params(),
+        interpret=interpret,
+        name="mx_gmm",
+    )(offsets, groups, tiles, lhs, rhs)
+
+
+def _gmm_dw_kernel(offsets, groups, tiles, lhs_ref, rhs_ref, out_ref, acc_ref,
+                   *, tm):
+    from jax.experimental import pallas as pl
+
+    visit = pl.program_id(1)
+    g = groups[visit]
+
+    @pl.when((visit == 0) | (groups[jnp.maximum(visit - 1, 0)] != g))
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(offsets[g + 1] > offsets[g])
+    def _():
+        # both sides: a neighbour's rows, or what lies past the last group
+        # or the edge, must not meet the sum over rows
+        lhs = jnp.where(
+            _gmm_rows_of(offsets, groups, tiles, visit, tm,
+                         lhs_ref.shape[1]), lhs_ref[...], 0)
+        rhs = jnp.where(
+            _gmm_rows_of(offsets, groups, tiles, visit, tm,
+                         rhs_ref.shape[1]), rhs_ref[...], 0)
+        acc_ref[...] += lax.dot_general(
+            lhs, rhs, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    last = pl.num_programs(1) - 1
+
+    @pl.when((visit == last) | (groups[jnp.minimum(visit + 1, last)] != g))
+    def _():
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _gmm_dw_pallas(lhs, rhs, sizes, interpret=False):
+    """``out[g] = lhs_g.T @ rhs_g`` over the rows of each group: ``lhs (M,
+    N)``, ``rhs (M, K)``, ``out (G, N, K)``; zeros for a group without
+    rows."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, n = lhs.shape
+    k = rhs.shape[1]
+    tm = min(_GMM_ROWS, m)
+    size = lhs.dtype.itemsize
+    tn, n_blocks = _gmm_split(
+        n, lambda tn: 2 * size * (tm * tn + tm * k + tn * k) + 4 * tn * k,
+        _GMM_VMEM)
+    offsets, groups, tiles, count = _gmm_visits(sizes, m, tm, empty=True)
+    return pl.pallas_call(
+        functools.partial(_gmm_dw_kernel, tm=tm),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_blocks, count),
+            in_specs=[
+                pl.BlockSpec((tm, tn), lambda j, v, o, g, t: (t[v], j)),
+                pl.BlockSpec((tm, k), lambda j, v, o, g, t: (t[v], 0)),
+            ],
+            out_specs=pl.BlockSpec((None, tn, k),
+                                   lambda j, v, o, g, t: (g[v], j, 0)),
+            scratch_shapes=[pltpu.VMEM((tn, k), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((sizes.shape[0], n, k), lhs.dtype),
+        compiler_params=_gmm_params(),
+        interpret=interpret,
+        name="mx_gmm_dw",
+    )(offsets, groups, tiles, lhs, rhs)
+
+
+@jax.custom_vjp
+def grouped_matmul(rows, w, sizes):
+    """``out[m] = rows[m] @ w[g].T`` for the group ``g`` that row ``m`` is
+    in: ``rows (M, K)`` sorted by group, ``w (G, N, K)``, ``sizes (G,)``
+    int32 with ``sum(sizes) <= M``; ``out (M, N)`` in ``rows``' dtype,
+    accumulated in float32.  The kernels (``mx_gmm``, ``mx_gmm_dw``) walk
+    the (128-row tile, group) pairs that hold rows and no other, so a call
+    costs what landed and not the bound ``M``; rows past the last group
+    are not visited and stay undefined, here and in the gradient of
+    ``rows``."""
+    run = functools.partial(_gmm_pallas, transposed=True)
+    return _platform_pick(run, rows, w, sizes)
+
+
+def _grouped_matmul_fwd(rows, w, sizes):
+    return grouped_matmul(rows, w, sizes), (rows, w, sizes)
+
+
+def _grouped_matmul_bwd(res, g):
+    rows, w, sizes = res
+    d_rows = _platform_pick(functools.partial(_gmm_pallas, transposed=False),
+                            g, w, sizes)
+    d_w = _platform_pick(functools.partial(_gmm_dw_pallas), g, rows, sizes)
+    return d_rows, d_w, None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)

@@ -19,8 +19,9 @@ Beside it, the layer of today's sparse models: ``router_topk`` (sigmoid
 scores, a correction bias that moves the choice and not the weight, k of E,
 normalised and scaled) and ``grouped_ffn``, which has **no capacity and
 drops nothing**: assignments are sorted by expert and each projection is
-one grouped product (``lax.ragged_dot``, a Mosaic kernel on TPUs) over the
-rows that landed.  ``first`` and the leading axis of the stacked weights
+one grouped product (``ops/pallas_kernels.py:grouped_matmul``: the Pallas
+kernels ``mx_gmm`` / ``mx_gmm_dw``, whose grid follows the group sizes) over
+the rows that landed.  ``first`` and the leading axis of the stacked weights
 tell the layer which experts it holds: it routes over all of them and
 computes its own experts' part of the result, which is what expert
 parallelism asks of each chip; on one chip there is no exchange, and

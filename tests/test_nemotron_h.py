@@ -307,34 +307,35 @@ def test_every_token_on_one_held_expert_loses_nothing():
 
 @pytest.mark.parametrize("load", ["few", "most"])
 def test_rows_past_the_last_group_are_never_read(monkeypatch, load):
-    """On a TPU the grouped product leaves the rows past its last group
-    undefined, in its result and in the gradient of its rows (the CPU
-    writes zeros there).  With NaN planted in both, the layer's result and
-    every gradient still match the dense experts, whether few of the
-    assignments land on the held experts or most of them: no undefined
-    value meets arithmetic in either direction (PR 30: 0 * nan
-    took the router's gradient, and with it every layer below, on the
-    chip)."""
-    real = jax.lax.ragged_dot
+    """The grouped kernels (``mx_gmm``, PR 31) do not visit the rows past
+    the last group: those stay undefined, in a product's result and in the
+    gradient of its rows (on the chip whatever the buffer held; a tile the
+    last group shares keeps what an earlier tile left in VMEM).  With NaN
+    planted in every such row of both, the layer's result and every
+    gradient still match the dense experts, whether few of the assignments
+    land on the held experts or most of them: no undefined value meets
+    arithmetic in either direction (PR 30: 0 * nan took the router's
+    gradient, and with it every layer below, on the chip)."""
+    real = moe_ops.grouped_matmul
 
     def poison(x, sizes):
         rows = jnp.arange(x.shape[0])[:, None]
         return jnp.where(rows < jnp.sum(sizes), x, jnp.nan)
 
     @jax.custom_vjp
-    def undefined_past_the_groups(lhs, rhs, sizes):
-        return poison(real(lhs, rhs, sizes), sizes)
+    def undefined_past_the_groups(rows, w, sizes):
+        return poison(real(rows, w, sizes), sizes)
 
-    def fwd(lhs, rhs, sizes):
-        return undefined_past_the_groups(lhs, rhs, sizes), (lhs, rhs, sizes)
+    def fwd(rows, w, sizes):
+        return undefined_past_the_groups(rows, w, sizes), (rows, w, sizes)
 
     def bwd(res, g):
-        lhs, rhs, sizes = res
-        _, vjp = jax.vjp(lambda a, b: real(a, b, sizes), lhs, rhs)
-        d_lhs, d_rhs = vjp(g)
-        return poison(d_lhs, sizes), d_rhs, None
+        rows, w, sizes = res
+        _, vjp = jax.vjp(lambda a, b: real(a, b, sizes), rows, w)
+        d_rows, d_w = vjp(g)
+        return poison(d_rows, sizes), d_w, None
     undefined_past_the_groups.defvjp(fwd, bwd)
-    monkeypatch.setattr(jax.lax, "ragged_dot", undefined_past_the_groups)
+    monkeypatch.setattr(moe_ops, "grouped_matmul", undefined_past_the_groups)
     x, router, bias, up, down = _moe_inputs()
     # scores that keep most tokens off experts 4..7, or draw them there
     bias = bias.at[4:8].set(-0.25 if load == "few" else 0.25)

@@ -221,27 +221,32 @@ def test_paged_attention(one_chip, geometry, k1, kv_dtype):
 
 # -- the Nemotron-H cell's operators at its own sizes (PR 30) -----------------
 
-def test_grouped_ffn_is_ragged_kernels_forward_and_backward(one_chip):
-    # 4096 tokens x 6 assignments over 8 held experts of 2688 x 1856: each
-    # of the six products (and the two made again in the backward pass) is
-    # the compiler's grouped Mosaic kernel,
-    # whose work follows the group sizes; a projection in another layout
-    # falls back to a dense product over every expert and the whole bound
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_grouped_ffn_is_the_gmm_kernels_forward_and_backward(one_chip, dtype):
+    # 4096 tokens x 6 assignments over 8 held experts of 2688 x 1856 (one
+    # lane tile of 1856 is partial): each of the six products, and the two
+    # made again in the backward pass, is a grouped Pallas kernel whose
+    # grid follows the group sizes (PR 31: ``mx_gmm`` forward and for the
+    # rows' gradient, ``mx_gmm_dw`` for the weights'), with a whole
+    # expert's matrix held in VMEM, in bfloat16 (under amp) and float32;
+    # nothing is left of the compiler's ``ragged-dot-*``, and no product
+    # is dense over every expert and the whole bound
+    dtype = jnp.dtype(dtype)
+
     def loss(x, idx, w, up, down):
-        out, counts = grouped_ffn(x, idx, w, up.astype(jnp.bfloat16),
-                                  down.astype(jnp.bfloat16))
+        out, counts = grouped_ffn(x, idx, w, up.astype(dtype),
+                                  down.astype(dtype))
         return jnp.sum(out * out), counts
     c = _compile(jax.grad(loss, argnums=(0, 2, 3, 4), has_aux=True),
-                 one_chip, ((4096, 2688), jnp.bfloat16),
+                 one_chip, ((4096, 2688), dtype),
                  ((4096, 6), jnp.int32), ((4096, 6), jnp.float32),
                  ((8, 1856, 2688), jnp.float32),
                  ((8, 2688, 1856), jnp.float32))
     text = c.as_text()
-    kernels = re.findall(r"%ragged-dot-none[.\d]* = \S+ custom-call\(",
-                         text)
-    assert len(kernels) == 8, kernels
-    assert "tpu_custom_call" in text
-    assert not re.search(r"bf16\[8,24576,", text)   # no dense fallback
+    kernels = re.findall(r"%(mx_gmm(?:_dw)?)[.\d]* = \S+ custom-call\(", text)
+    assert sorted(kernels) == ["mx_gmm"] * 6 + ["mx_gmm_dw"] * 2, kernels
+    assert "tpu_custom_call" in text and "ragged-dot" not in text
+    assert not re.search(r"\[8,24576,", text)          # no dense fallback
 
 
 @pytest.mark.parametrize("seq", [2048, 2000])
