@@ -49,8 +49,8 @@ SEED = 22
 # llama2_7b's published widths; only the depth is cut
 TRAIN = dict(vocab=32000, units=4096, hidden=11008, heads=32, layers=2,
              batch=4, seq=512, lr=3e-4)
-# the 160M decoder bench.py trains: the largest the bundle design, which
-# bakes the weights into every executable, holds comfortably
+# a 160M decoder: the largest the bundle design, which bakes the
+# weights into every executable, holds comfortably
 SERVE = dict(vocab=32000, units=768, hidden=2048, layers=12, heads=12,
              kv_heads=4, page_size=16, num_pages=512, max_batch=8,
              buckets=(128, 512), prompt_lens=(20, 400), new_tokens=32)

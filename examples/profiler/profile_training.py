@@ -16,9 +16,10 @@ Domain/Task instrumentation).  Produces:
     python examples/profiler/profile_training.py [--steps 20]
 
 On TPU the per-op spans come from the engine's dispatch hook; the XLA
-device timeline itself is captured separately with
-``tools/profile_resnet.py`` (xplane).  This example profiles the
-FRAMEWORK level: op dispatch, custom task spans, counters.
+device timeline itself is a ``jax.profiler`` trace
+(``mx.profiler.set_config(xla_trace_dir=...)``, docs/observability.md).
+This example profiles the FRAMEWORK level: op dispatch, custom task
+spans, counters.
 """
 from __future__ import annotations
 

@@ -76,10 +76,10 @@ FP32_OPS = [
     "InstanceNorm",
     "LayerNorm",
     "GroupNorm",
-    # measured r5 (tools A/B, llama bench geometry, best-of-3 windows):
-    # norms IN this list run 7% faster end-to-end than bf16-in/bf16-out
-    # norms (131.7k vs 122.7k tok/s) — XLA fuses the f32 norm chain into
-    # the adjacent matmuls and skips a convert round trip
+    # norms stay f32: XLA fuses the f32 norm chain into the adjacent
+    # matmuls and skips a convert round trip.  Chosen on an earlier
+    # installation; every cell runs with it and none without (the other
+    # side is not measured)
     "RMSNorm",
     # discrete or recurrent: a rounded router score flips an expert, and a
     # state-space layer's decays, dt and carried state compound over the

@@ -3,9 +3,9 @@
 The engine's design makes every expensive thing a *handle* whose
 release is someone else's job — arena pages freed by the scheduler,
 sockets evicted by the kvstore client, request futures resolved by the
-serve loop, temp dirs removed by the bench harness.  A handle that
-leaks on one early-exit path is invisible in every test that takes the
-happy path, and at fleet scale the leak IS the outage.  This pass
+serve loop, temp dirs removed by the harness that made them.  A handle
+that leaks on one early-exit path is invisible in every test that takes
+the happy path, and at fleet scale the leak IS the outage.  This pass
 tracks acquire/release pairs path-sensitively through each function
 body, over the repo's real handle kinds:
 

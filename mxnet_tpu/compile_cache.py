@@ -31,11 +31,11 @@ two escape hatches, wired under every compile site the framework has
    format, so a fleet restart compiles *nothing*.
 
 Keying notes: bulk segments are structurally keyed by op sequence in
-``engine.py``; the exact O0 taped path compiles through
+``engine.py``; the exact taped path compiles through
 ``lower().compile(compiler_options=...)`` under a *differently named*
-traced callable, so O0 and O2 artifacts can never collide in the disk
-cache (the HLO module name and the compiler options both enter jax's
-cache key).
+traced callable, so exact and fused artifacts can never collide in the
+disk cache (the HLO module name and the compiler options both enter
+jax's cache key).
 """
 from __future__ import annotations
 

@@ -156,18 +156,27 @@ def _build_segment_fn(steps, donate=(), exact=False, example_args=None):
     ``donate`` are ext indices whose buffers are dead at flush time; XLA
     may reuse them for outputs (donated inputs are deleted after the call).
 
-    ``exact=True`` compiles ahead-of-time with XLA optimizations off
-    (``xla_backend_optimization_level=0``) so every op keeps exactly the
-    rounding its standalone eager executable produces.  The default O2
-    pipeline fuses across op boundaries (FMA contraction, output
-    rematerialization) and drifts intermediates off eager by ulps — it
-    even strips ``optimization_barrier`` before fusing, so barriers can't
-    pin the numerics.  Recorded segments need the exact path because the
-    tape re-linearizes against segment intermediates and bulked grads
-    must be BIT-identical to eager; unrecorded segments keep the fast
-    fused path (the forward values the user sees are checked against
-    eager by tier-1 at default opts).  The dispatch win (one push per N
-    ops) is identical either way.
+    ``exact=True`` compiles ahead-of-time with XLA's fusion pass off
+    (``xla_disable_hlo_passes=fusion``), so each op of the segment stays
+    a kernel of its own and keeps the rounding of its standalone eager
+    executable.  The default pipeline fuses across op boundaries (FMA
+    contraction, output rematerialization) and drifts intermediates off
+    eager by ulps — it even strips ``optimization_barrier`` before
+    fusing, so barriers can't pin the numerics.  Backend optimization
+    level 0 is no substitute: it stops the contraction inside an op too,
+    and XLA:CPU emits ``tanh`` as a polynomial whose multiply-adds the
+    op's own executable contracts, so a level-0 segment reads 1-2 ulp
+    off eager wherever it holds one.  Not pinned: an op of several HLO
+    instructions that its own executable fuses (``LayerNorm``'s scale
+    and shift) — compare those with allclose.  Recorded segments need
+    the exact path because the tape re-linearizes against segment
+    intermediates and bulked grads must be BIT-identical to eager;
+    unrecorded segments keep the fast fused path (the forward values the
+    user sees are checked against eager by tier-1 at default opts).  The
+    dispatch win (one push per N ops) is identical either way.  A rule
+    about XLA:CPU: the TPU compiler's program is the same text with or
+    without the option, and bitwise agreement on the chip is not
+    measured.
     """
     steps = tuple(steps)
 
@@ -186,15 +195,15 @@ def _build_segment_fn(steps, donate=(), exact=False, example_args=None):
         return jax.jit(seg_run, donate_argnums=donate)
 
     # distinct traced-function NAME for the exact path: the HLO module name
-    # enters jax's persistent-cache key, so O0 (taped) and O2 (fused)
+    # enters jax's persistent-cache key, so exact (taped) and fused
     # artifacts for the same op sequence can never cross-hit on disk even
     # if a jax version ever drops compiler_options from the key
-    def seg_run_exact_o0(*ext):
+    def seg_run_exact(*ext):
         return _body(ext)
 
-    jitted = jax.jit(seg_run_exact_o0, donate_argnums=donate)
+    jitted = jax.jit(seg_run_exact, donate_argnums=donate)
     return jitted.lower(*example_args).compile(
-        compiler_options={"xla_backend_optimization_level": 0})
+        compiler_options={"xla_disable_hlo_passes": "fusion"})
 
 
 class _BulkRef:

@@ -397,11 +397,13 @@ def flash_attention(query, key, value, scale=None, causal=False,
     Differentiable end-to-end via the blocked flash backward (no (T, T)
     buffer in forward or backward).
 
-    ``block_q``/``block_k`` default to ``min(T, 512)`` — tuned on v5e
-    (tools/llama_ceiling.py block sweep: 512/512 runs the seq-512 llama
-    bench 1.5x faster than 128/128; the VMEM footprint per block at
-    d<=128 stays under ~1MB so large blocks are safe), while 1024+
-    regresses (VMEM pressure starts serializing the pipeline).
+    ``block_q``/``block_k`` default to ``min(T, 512)``: the VMEM
+    footprint per block at d<=128 stays under ~1MB, and 1024+ puts
+    enough pressure on VMEM to serialize the pipeline.  Below T=512
+    (and with no explicit block) the op is XLA's attention, not this
+    kernel.  Both rules date from an earlier installation; today's
+    cells stand on the Pallas side only, T=512 and T=2048 (ROADMAP.md
+    S17, R9), so neither the default nor the switch is measured.
     """
     squeeze = query.ndim == 3
     if squeeze:
@@ -414,10 +416,10 @@ def flash_attention(query, key, value, scale=None, causal=False,
     q3 = query.reshape(b * h, t_q, d)
     k3 = key.reshape(b * h, t_kv, d)
     v3 = value.reshape(b * h, t_kv, d)
-    # short sequences: XLA's fused attention beats the kernel (v5e A/B:
-    # BERT seq-128 994 vs 825 samples/s) and the (T,T) buffer is small;
-    # the Pallas path earns its keep from T>=512 (llama seq-512: 132k vs
-    # 112k tok/s).  Explicit block sizes force the kernel (tests, tuning).
+    # short sequences go to XLA's attention: the (T,T) buffer is small
+    # there.  No cell sits on this side of the switch (ROADMAP.md S17,
+    # R9: a BERT-base seq-128 guard cell would).  Explicit block sizes
+    # force the kernel (tests, tuning).
     if block_q is None and block_k is None and t_q < 512 and t_kv < 512:
         return _finish(_attention_ref(q3, k3, v3, scale, causal),
                        b, h, t_q, d, squeeze)

@@ -54,7 +54,7 @@ Quick start::
 """
 from .arena import PagedKVArena
 from .fleet import (FleetNoHealthyReplica, FleetRouter, HttpReplica,
-                    LocalReplica, fleet_drive_workload)
+                    LocalReplica)
 from .model import (KVGeometry, check_geometry, export_serving_bundle,
                     geometry_from_net, load_serving_executables)
 from .prefix import PrefixCache
@@ -75,7 +75,7 @@ __all__ = [
     "ServeDraining", "ServeInternalError", "ServeQueueFull",
     "ServeSessionBusy", "ServeSessionUnknown",
     "ServeShutdown", "check_geometry", "clamp_retry_after",
-    "drive_workload", "export_serving_bundle", "fleet_drive_workload",
+    "drive_workload", "export_serving_bundle",
     "geometry_from_net", "greedy_sampler",
     "load_serving_executables", "poisson_workload", "propose_ngram",
 ]

@@ -79,8 +79,8 @@ observability") rides on three seams here:
   with ``MXNET_FLEET_SLO_SHED`` the fast-window burn alert sheds
   optional work — hedging turns off until the alert clears.
 
-Replicas can be in-process ``LlamaServer`` objects (the bench and chaos
-matrix run 3 in one process) or ``http://host:port`` bases fronting
+Replicas can be in-process ``LlamaServer`` objects (the chaos matrix
+runs 3 in one process) or ``http://host:port`` bases fronting
 remote servers; both hide behind the same probe/submit/cancel surface.
 """
 from __future__ import annotations
@@ -110,7 +110,6 @@ from .scheduler import (Request, ServeCancelled, ServeDeadlineExceeded,
 
 __all__ = [
     "FleetRouter", "FleetNoHealthyReplica", "LocalReplica", "HttpReplica",
-    "fleet_drive_workload",
 ]
 
 _BACKOFF_CAP_S = 5.0      # same ceiling as the kvstore retry discipline
@@ -1525,24 +1524,3 @@ class FleetRouter:
         threading.Thread(target=self._http.serve_forever,
                          name="mxnet-fleet-http", daemon=True).start()
         return self._http.server_address
-
-
-def fleet_drive_workload(router, workload, timeout=600,
-                         clock=time.monotonic, sleep=time.sleep):
-    """Replay a ``poisson_workload`` against a started router — the
-    fleet twin of ``drive_workload``.  Returns ``(futures, wall_s)``."""
-    t0 = clock()
-    futs = []
-    for arrival, req in workload:
-        lag = arrival - (clock() - t0)
-        if lag > 0:
-            sleep(lag)
-        futs.append(router.submit(req.prompt,
-                                  max_new_tokens=req.max_new_tokens,
-                                  eos_id=req.eos_id, timeout=timeout))
-    for fut in futs:
-        try:
-            fut.result(timeout=timeout)
-        except MXNetError:
-            pass  # failures surface via fut.error
-    return futs, clock() - t0

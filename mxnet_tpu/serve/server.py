@@ -14,10 +14,10 @@ the arena, and hands numpy logits back to the jax-free scheduler.
 Sampling is host-side numpy, so the decode loop's device work is exactly
 one executable call per step.
 
-``static_generate`` is the naive baseline the serving bench compares
+``static_generate`` is the naive baseline the scheduler's tests compare
 against: fixed batches, no mid-flight admission, every batch runs until
-its slowest member finishes — same runner, same arena, so the measured
-gap is pure scheduling.
+its slowest member finishes — same runner, same arena, so the
+difference is pure scheduling.
 """
 from __future__ import annotations
 
@@ -540,7 +540,7 @@ class LlamaServer:
                          {}).get("series", []))),
         }
 
-    # -- naive baseline (bench comparison) --------------------------------
+    # -- naive baseline (the tests' reference) ----------------------------
     def static_generate(self, requests):
         """Static batching: groups of ``max_batch``, no admission between
         steps, each group decodes until its SLOWEST member finishes.
@@ -821,7 +821,7 @@ def drive_workload(server, workload, timeout=600, clock=time.monotonic,
     """Replay a :func:`poisson_workload` against a started server.
 
     Returns ``(requests, wall_seconds)`` — wall time from first submit to
-    last completion.  Used by the serving bench and the serve-smoke CI
+    last completion.  Used by the serve tests and the serve-smoke CI
     job (which passes a null ``sleep`` to hammer the queue).
     """
     t0 = clock()

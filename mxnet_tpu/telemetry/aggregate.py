@@ -21,7 +21,7 @@ Every function here works on :func:`telemetry.snapshot`-shaped dicts —
 objects, so aggregation is pure and scrape-time cheap.
 
 :func:`snapshot_from_stats` synthesizes a snapshot-shaped doc from one
-replica's ``/healthz`` stats: the in-process fleet (bench, chaos matrix,
+replica's ``/healthz`` stats: the in-process fleet (chaos matrix,
 3-replicas-one-process CI jobs) shares a single registry, so scraping it
 per replica would multiply every count by N — the per-server scheduler
 aggregates are the only honestly per-replica numbers in that topology.

@@ -105,9 +105,7 @@ def enable():
 
 
 def disable():
-    """Stop recording (the ring keeps its contents).  ``bench.py``'s
-    ``_notelemetry`` runner toggles this together with the metrics
-    registry to measure the observability overhead."""
+    """Stop recording (the ring keeps its contents)."""
     global _ENABLED
     _ENABLED = False
 

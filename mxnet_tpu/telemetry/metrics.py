@@ -62,7 +62,7 @@ def enable():
 
 def disable():
     """Turn collection off at runtime; handles stay valid but updates
-    become one dead branch (the overhead bench.py tracks)."""
+    become one dead branch."""
     global _ENABLED
     _ENABLED = False
 

@@ -43,8 +43,7 @@ Design constraints:
 
 Enabling mid-process (:func:`install`) affects locks created *after*
 the call; module-level framework singletons created at import keep
-their bare locks.  ``bench.py``'s lockcheck-overhead probe therefore
-constructs a fresh server after ``install()``.
+their bare locks: build the objects to be watched after ``install()``.
 """
 from __future__ import annotations
 

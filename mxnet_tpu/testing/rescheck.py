@@ -43,8 +43,7 @@ Design constraints (same contract as ``lockcheck``):
   the code it watches.
 
 Enabling mid-process (:func:`install`) affects handles acquired
-*after* the call; ``bench.py``'s rescheck-overhead probe therefore
-constructs a fresh server after ``install()``.
+*after* the call: build the objects to be watched after ``install()``.
 """
 from __future__ import annotations
 

@@ -1,8 +1,7 @@
 """Framework-level persistent compile cache (MXNET_COMPILE_CACHE).
 
-Round-4 verdict item 7: the cache must be a framework default, not a
-bench.py special — a second process importing mxnet_tpu gets cache HITS for
-executables a first process compiled.
+The cache is a framework default — a second process importing mxnet_tpu
+gets cache HITS for executables a first process compiled.
 """
 import os
 import subprocess
@@ -216,10 +215,10 @@ print("DONE")
 """
 
 
-def test_o0_and_o2_artifacts_never_cross_hit(tmp_path):
-    """The exact (O0) taped path and the default (O2) segment path must
-    key DIFFERENT disk entries: an O0 request served an O2 artifact
-    would silently change gradient-replay semantics."""
+def test_exact_and_fused_artifacts_never_cross_hit(tmp_path):
+    """The exact taped path (no fusion across ops) and the default fused
+    segment path must key DIFFERENT disk entries: an exact request served
+    a fused artifact would silently change gradient-replay semantics."""
     cache = str(tmp_path / "o_cache")
 
     def run(record):
@@ -233,16 +232,16 @@ def test_o0_and_o2_artifacts_never_cross_hit(tmp_path):
                            text=True, timeout=300)
         assert r.returncode == 0, r.stderr
 
-    run(record=False)                      # O2 segment entries
-    after_o2 = set(os.listdir(cache))
-    assert after_o2
-    run(record=True)                       # recorded chain: O0/backward
-    after_o0 = set(os.listdir(cache))
-    assert after_o0 - after_o2, \
-        "recorded (O0) chain wrote no new entries — it was served the " \
-        "O2 artifact"
+    run(record=False)                      # fused segment entries
+    after_fused = set(os.listdir(cache))
+    assert after_fused
+    run(record=True)                       # recorded chain: exact/backward
+    after_exact = set(os.listdir(cache))
+    assert after_exact - after_fused, \
+        "recorded (exact) chain wrote no new entries — it was served " \
+        "the fused artifact"
     run(record=True)                       # same recorded chain again
-    assert set(os.listdir(cache)) == after_o0, \
+    assert set(os.listdir(cache)) == after_exact, \
         "third process re-wrote entries instead of hitting the cache"
 
 

@@ -1,11 +1,44 @@
-"""Doc-drift guard: tools/check_metric_docs.py keeps the metric catalog
-in docs/observability.md in sync with the registered families."""
+"""Doc-drift guards: tools/check_metric_docs.py keeps the metric catalog
+in docs/observability.md in sync with the registered families, and every
+path a living document names in backticks exists in the tree."""
+import glob
 import importlib.util
 import os
+import re
 import subprocess
 import sys
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# the documents that describe today's tree; dated records (CHANGES.md,
+# VERDICT.md, ADVICE.md, ROADMAP.md, SURVEY.md) may name what is gone
+_LIVING_DOCS = ["README.md", "PERF.md", "COVERAGE.md"] + sorted(
+    os.path.relpath(p, REPO)
+    for p in glob.glob(os.path.join(REPO, "docs", "*.md")))
+_PATH_ROOTS = ("mxnet_tpu/", "tools/", "tests/", "benchmark/", "docs/",
+               "examples/")
+
+
+def _named_paths(text):
+    """Backticked tokens that start with a directory of the repo, cut to
+    the path: `a/b.py:12`, `a/b.py:fn`, `a/b.py::test` and `a/b.py --opt`
+    all name `a/b.py`.  Patterns (`*`, `<x>`, `{a,b}`) are skipped, and so
+    are bare module names (`engine.py`), which the documents use freely."""
+    for token in re.findall(r"`([^`\n]+)`", text):
+        if not token.startswith(_PATH_ROOTS) or re.search(r"[*<{]", token):
+            continue
+        yield token.split()[0].split(":")[0]
+
+
+@pytest.mark.parametrize("doc", _LIVING_DOCS)
+def test_paths_named_in_documents_exist(doc):
+    with open(os.path.join(REPO, doc), encoding="utf-8") as f:
+        named = sorted(set(_named_paths(f.read())))
+    gone = [p for p in named if not os.path.exists(os.path.join(REPO, p))]
+    assert not gone, "%s names paths that are not in the tree: %s" % (
+        doc, gone)
 
 
 def _load():

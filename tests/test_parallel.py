@@ -27,6 +27,23 @@ def test_make_mesh():
     assert mesh2.shape['model'] == 4
 
 
+def test_no_tpu_is_an_error_unless_told_cpu(monkeypatch):
+    """No CPU fallback: where the process was not told JAX_PLATFORMS=cpu,
+    a missing accelerator is an error, in the context and in the step."""
+    from mxnet_tpu import context
+    from mxnet_tpu.base import MXNetError
+
+    # this process was told JAX_PLATFORMS=cpu; take that away (in
+    # process: a child with the variable unset would load libtpu)
+    monkeypatch.setattr(context, "_told_cpu", lambda: False)
+    with pytest.raises(MXNetError, match="no accelerator"):
+        context._best_context()
+    step = parallel.JitTrainStep(_mlp(), gluon.loss.SoftmaxCrossEntropyLoss(),
+                                 'sgd', {'learning_rate': 0.1})
+    with pytest.raises(MXNetError, match="no accelerator"):
+        step.step(np.zeros((4, 8), 'float32'), np.zeros(4, 'float32'))
+
+
 def test_jit_train_step_single_matches_trainer():
     """JitTrainStep must agree numerically with the imperative path."""
     np.random.seed(0)

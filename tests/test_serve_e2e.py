@@ -455,7 +455,7 @@ def test_http_queue_full_returns_503(bundle):
         assert b"max context" in ei.value.read()
 
 
-# -- static baseline (the bench comparator) -----------------------------
+# -- static baseline (the reference continuous batching is held to) ----
 
 def test_static_generate_matches_continuous_tokens(bundle):
     path, net, _ = bundle

@@ -13,7 +13,7 @@ Contracts pinned here:
   (jit specializes per input sharding — an 8-device matmul is ONE
   jitted computation, no per-device loop, no host gather);
 - sharded and single-device executions never share a segment-cache
-  entry, in memory or on disk (subprocess-verified like the O0/O2
+  entry, in memory or on disk (subprocess-verified like the exact/fused
   compile-cache split in test_compile_cache.py);
 - ``MXNET_SHARDING_VERIFY`` turns async placement errors into
   synchronous MXNetErrors at the call site.
@@ -339,7 +339,7 @@ def test_sharded_and_unsharded_artifacts_never_cross_hit(tmp_path):
     """The taped/exact path pins its lowering at build time: an
     unsharded disk artifact served to a sharded run would silently
     compute on the wrong placement.  Subprocess-verified exactly like
-    the O0/O2 split (test_compile_cache.py)."""
+    the exact/fused split (test_compile_cache.py)."""
     cache = str(tmp_path / "sh_cache")
 
     def run(sharded):
