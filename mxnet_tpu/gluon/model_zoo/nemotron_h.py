@@ -146,8 +146,9 @@ class MoEMixer(_Mixer):
             ("shared_down", (units, shared_hidden), None)])
 
     def step_stat_specs(self):
-        """Rows landed on each held expert, assignments in all, dropped."""
-        return {self._stat: ((self._cfg[4] + 2,), jnp.uint32)}
+        """Rows landed on each held expert, assignments in all, dropped,
+        rows of the sorted layout walked."""
+        return {self._stat: ((self._cfg[4] + 3,), jnp.uint32)}
 
     def hybrid_forward(self, F, u, router, router_bias, up, down, shared_up,
                        shared_down):

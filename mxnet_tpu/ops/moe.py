@@ -7,12 +7,14 @@ are for; ``docs/moe_ssm.md`` has shapes and dtypes.
 """
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
 import jax.numpy as jnp
 
-from .pallas_kernels import grouped_matmul
+from .pallas_kernels import grouped_matmul, rows_combine, rows_relu2, \
+    rows_take, rows_walked
 from .registry import register
 
 
@@ -56,50 +58,6 @@ def router_topk(data, weight, bias, k=1, scale=1.0, normalize=True,
         return idx.astype(jnp.int32), w * scale
 
 
-def _relu2(x):
-    return jnp.square(jax.nn.relu(x))
-
-
-# Tokens to their sorted assignments and back.  ``order`` is a permutation
-# of the ``S * k`` assignments (assignment ``i`` is token ``i // k``) and
-# ``inverse`` undoes it.  Each map's transpose is the other, so both
-# directions of both are gathers: jax's own transpose of a gather is a
-# scatter-add, which a TPU serialises.
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _to_sorted(x, order, inverse, k):
-    """``(S, D) -> (S * k, D)``: row ``i`` is the token of assignment
-    ``order[i]``."""
-    return x[order // k]
-
-
-def _to_sorted_fwd(x, order, inverse, k):
-    return _to_sorted(x, order, inverse, k), (order, inverse)
-
-
-def _to_sorted_bwd(k, res, g):
-    return _from_sorted(g, *res, k), None, None
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _from_sorted(rows, order, inverse, k):
-    """``(S * k, D) -> (S, D)``: the sum over each token's ``k``
-    assignments."""
-    return rows[inverse].reshape(-1, k, rows.shape[1]).sum(axis=1)
-
-
-def _from_sorted_fwd(rows, order, inverse, k):
-    return _from_sorted(rows, order, inverse, k), (order, inverse)
-
-
-def _from_sorted_bwd(k, res, g):
-    return _to_sorted(g, *res, k), None, None
-
-
-_to_sorted.defvjp(_to_sorted_fwd, _to_sorted_bwd)
-_from_sorted.defvjp(_from_sorted_fwd, _from_sorted_bwd)
-
-
 @register("_contrib_moe_grouped_ffn", num_outputs=2,
           inputs=("data", "topk_idx", "topk_weight", "up", "down"))
 def grouped_ffn(data, topk_idx, topk_weight, up, down, first=0):
@@ -109,63 +67,125 @@ def grouped_ffn(data, topk_idx, topk_weight, up, down, first=0):
     ``router_topk``; ``up (held, F, D)`` and ``down (held, D, F)``: the
     experts ``first .. first + held - 1`` of the layer, stacked, each
     ``down_e(relu(up_e(u))^2)``.  Returns ``(out (S, D) float32, counts
-    (held + 2,) uint32)``: ``out[s] = sum`` over the assignments of token
+    (held + 3,) uint32)``: ``out[s] = sum`` over the assignments of token
     ``s`` to a held expert of ``weight * expert(data[s])``; ``counts`` are
     the assignments that landed on each held expert, the assignments in
-    all (``S * k``), and those to a held expert whose row did not pass
-    into the result (the assignments the indices send here less the rows
-    ``_computed`` lets through: it has to stay 0).
+    all (``S * k``), those to a held expert whose row did not pass into
+    the result (the assignments the indices send here less the rows
+    ``_computed`` lets through: it has to stay 0), and the rows of the
+    sorted layout that the kernels walked.
 
-    The ``S * k`` assignments are sorted by expert, absent experts last;
-    the sorted rows go through one grouped product a projection
-    (``pallas_kernels.grouped_matmul``: the kernels ``mx_gmm`` and, for
-    the weights' gradient, ``mx_gmm_dw``) whose group sizes are the rows
-    that landed, so rows of absent experts cost no product and a call
-    costs what landed.  Shapes are static at the bound ``S * k`` (every
-    assignment may land here): there is no capacity and no smaller bound
-    to overflow.  The matrix products run in ``data``'s dtype, accumulated
-    in float32, on the weights as they are stored (nothing is transposed);
-    the weighted sum over a token's assignments is float32.  Nothing of the
-    sorted layout is kept for the backward pass (``jax.checkpoint``): it
-    is made again from the inputs."""
+    The ``S * k`` assignments are sorted by expert, absent experts last,
+    and the rows that landed are the first of a layout ``(S * k, .)``
+    whose shapes are static at that bound (every assignment may land
+    here): there is no capacity and no smaller bound to overflow.  Only
+    kernels touch the layout, and each walks the 128-row tiles that hold
+    rows and no other, so a call costs what landed (``pallas_kernels``:
+    ``rows_take`` gathers the tokens' rows, ``grouped_matmul`` is one
+    product a projection with the landed rows as its group sizes,
+    ``rows_relu2`` the activation, ``rows_combine`` the weighted sum over
+    a token's assignments).  What lies past the landed rows is undefined
+    and no one may read it: XLA's arithmetic sees the layout's integers
+    alone (the sorts, the sizes).  The matrix products run in
+    ``data``'s dtype, accumulated in float32, on the weights as they are
+    stored (nothing is transposed); the weighted sum is float32, in the
+    order of a token's experts.  The backward pass is written by hand of
+    the same kernels; it keeps the layout's integers and makes the
+    layout's rows again from the inputs."""
     with jax.named_scope("moe_experts"):
-        return jax.checkpoint(_grouped_ffn, static_argnums=(5,))(
-            data, topk_idx, topk_weight, up, down, int(first))
+        return _grouped_ffn(data, topk_idx, topk_weight, up, down,
+                            int(first))
 
 
 def _computed(key, order, held):
     """Which of the sorted assignments are rows of a held expert (``key``
     is an assignment's expert among the held, ``held`` for an absent one):
-    those, and no other, pass into the products and out of them."""
+    those, and no other, pass into the result."""
     return key[order] < held
 
 
-def _grouped_ffn(data, topk_idx, topk_weight, up, down, first):
-    s, _ = data.shape
-    k = topk_idx.shape[1]
-    held = up.shape[0]
+# Sorted row ``p`` is assignment ``order[p]`` (of token ``token[p]``); the
+# first ``landed[0]`` rows are the held experts', ``sizes`` of them an expert;
+# assignment ``i`` passes into the result where ``passes[i]``, with
+# ``weight[i]`` (0 where not); ``counts`` as ``grouped_ffn`` returns them.
+_Layout = collections.namedtuple(
+    "_Layout", "token order sizes landed passes weight counts")
+
+
+def _by_assignment(order, sorted_values):
+    """``sorted_values`` (one a sorted row) in the assignments' own order:
+    a sort by ``order``.  A TPU sorts the keys in a fraction of the time
+    its gather ``sorted_values[argsort(order)]`` takes them one by one."""
+    return jax.lax.sort_key_val(order, sorted_values)[1]
+
+
+def _layout(topk_idx, topk_weight, held, first):
+    """The sorted layout's integers, and the weights by assignment."""
+    s, k = topk_idx.shape
     local = topk_idx.reshape(-1) - first
     here = (local >= 0) & (local < held)
     key = jnp.where(here, local, held)               # absent experts last
-    order = jnp.argsort(key, stable=True)
-    inverse = jnp.argsort(order)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
     sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
                     dtype=jnp.int32)
+    landed = jnp.sum(sizes, keepdims=True)
     computed = _computed(key, order, held)
-    mask = computed[:, None]
-    # a grouped product leaves the rows past the last group undefined: they
-    # are masked on the way in and on the way out, each time by a select
-    # that stands next to the product, so that no undefined value meets
-    # arithmetic in either direction (0 * nan is nan)
-    rows = jnp.where(mask, _to_sorted(data, order, inverse, k), 0)
-    hid = grouped_matmul(rows, up.astype(data.dtype), sizes)
-    hid = _relu2(jnp.where(mask, hid, 0))
-    res = grouped_matmul(hid, down.astype(data.dtype), sizes)
-    w = topk_weight.reshape(-1)[order].astype(jnp.float32)
-    res = jnp.where(mask, res, 0).astype(jnp.float32) * w[:, None]
-    out = _from_sorted(res, order, inverse, k)
+    passes = _by_assignment(order, computed)
+    weight = jnp.where(passes, topk_weight.reshape(-1).astype(jnp.float32),
+                       0)
     counts = jnp.concatenate([
         sizes, jnp.array([s * k], jnp.int32),
         (jnp.sum(here, dtype=jnp.int32)
-         - jnp.sum(computed, dtype=jnp.int32))[None]])
-    return out, jax.lax.stop_gradient(counts.astype(jnp.uint32))
+         - jnp.sum(computed, dtype=jnp.int32))[None],
+        rows_walked(landed, s * k)[None]])
+    return _Layout(order // k, order, sizes, landed, passes, weight,
+                   counts.astype(jnp.uint32))
+
+
+def _sorted_forward(data, lay, up, down):
+    """Along the sorted layout: the up-projection's result and vjp, the
+    down-projection's result and vjp."""
+    rows = rows_take(data.astype(jnp.float32), lay.token, lay.order,
+                     jnp.ones_like(lay.weight), lay.landed,
+                     lay.order.shape[0], data.dtype)
+
+    def product(rows, w):
+        return grouped_matmul(rows, w, lay.sizes)
+    hid, vjp_up = jax.vjp(product, rows, up.astype(data.dtype))
+    res, vjp_down = jax.vjp(product, rows_relu2(hid, lay.landed),
+                            down.astype(data.dtype))
+    return hid, vjp_up, res, vjp_down
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _grouped_ffn(data, topk_idx, topk_weight, up, down, first):
+    return _grouped_ffn_fwd(data, topk_idx, topk_weight, up, down, first)[0]
+
+
+def _grouped_ffn_fwd(data, topk_idx, topk_weight, up, down, first):
+    lay = _layout(topk_idx, topk_weight, up.shape[0], first)
+    _, _, res, _ = _sorted_forward(data, lay, up, down)
+    out = rows_combine(res, lay.token, lay.order, lay.weight, lay.landed,
+                       data.shape[0])
+    return (out, lay.counts), (data, topk_weight, up, down, lay)
+
+
+def _grouped_ffn_bwd(first, kept, grads):
+    data, topk_weight, up, down, lay = kept
+    hid, vjp_up, res, vjp_down = _sorted_forward(data, lay, up, down)
+    d_res, dots = rows_take(grads[0].astype(jnp.float32), lay.token,
+                            lay.order, lay.weight, lay.landed,
+                            lay.order.shape[0], data.dtype, other=res)
+    d_act, d_down = vjp_down(d_res)
+    d_rows, d_up = vjp_up(rows_relu2(hid, lay.landed, grad=d_act))
+    d_data = rows_combine(d_rows, lay.token, lay.order,
+                          jnp.ones_like(lay.weight), lay.landed,
+                          data.shape[0])
+    # a select: the dots past the tiles that hold rows are undefined
+    d_weight = jnp.where(lay.passes, _by_assignment(lay.order, dots), 0)
+    return (d_data.astype(data.dtype), None,
+            d_weight.reshape(topk_weight.shape).astype(topk_weight.dtype),
+            d_up.astype(up.dtype), d_down.astype(down.dtype))
+
+
+_grouped_ffn.defvjp(_grouped_ffn_fwd, _grouped_ffn_bwd)

@@ -1,5 +1,7 @@
-"""Pallas TPU kernels: fused flash attention (forward AND backward), and
-the grouped matmul of the routed experts (``grouped_matmul``, at the end).
+"""Pallas TPU kernels: fused flash attention (forward AND backward), the
+grouped matmul of the routed experts (``grouped_matmul``), and the kernels
+that move rows into and out of the sorted layout around it (``rows_take``,
+``rows_relu2``, ``rows_combine``, at the end).
 
 The reference's fused-attention story is two CUDA kernels
 (``_contrib_interleaved_matmul_selfatt_qk``/``_valatt``,
@@ -655,7 +657,10 @@ def grouped_matmul(rows, w, sizes):
     the (128-row tile, group) pairs that hold rows and no other, so a call
     costs what landed and not the bound ``M``; rows past the last group
     are not visited and stay undefined, here and in the gradient of
-    ``rows``."""
+    ``rows``.  No one may read them: only a kernel that walks the same
+    rows (another grouped product, ``rows_relu2``, ``rows_combine``, or
+    ``rows_take``'s ``other``) takes such an array, never arithmetic of
+    XLA's (``0 * nan`` is ``nan``)."""
     run = functools.partial(_gmm_pallas, transposed=True)
     return _platform_pick(run, rows, w, sizes)
 
@@ -673,3 +678,228 @@ def _grouped_matmul_bwd(res, g):
 
 
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+# ---------------------------------------------------------------------------
+# rows into a sorted layout, along it, and out of it: the tiles that hold rows
+# ---------------------------------------------------------------------------
+
+# The sorted rows that hold something are the first ``count`` of ``M``.  These
+# kernels walk their 128-row tiles and no other, as the grouped products do,
+# so a call costs what landed.  A single row is no block and no slice of a
+# tiled array (the compiler refuses both), so the array that is indexed by
+# token lies whole in VMEM (all rows, the columns cut by ``_rows_split`` where
+# they do not fit) and a row moves by a load and a store at a dynamic
+# sublane, in float32: bfloat16 packs two rows a sublane.
+
+
+def _rows_tiles(count, tm):
+    """Tiles of ``tm`` sorted rows that hold something."""
+    return (count[0] + tm - 1) // tm
+
+
+def rows_walked(count, m):
+    """Rows of a sorted layout of ``m`` that a ``rows_*`` kernel walks when
+    the first ``count[0]`` hold something: those of the tiles that do."""
+    tm = min(_GMM_ROWS, m)
+    return _rows_tiles(count, tm) * tm
+
+
+def _rows_split(d, rows, tm, tile_bytes):
+    """``(block, blocks)`` along the columns: ``rows`` whole float32 rows of
+    the array indexed by token, a float32 tile, and ``tile_bytes`` a column
+    of the tiles that stream, all double-buffered."""
+    return _gmm_split(
+        d, lambda td: td * (8 * rows + 4 * tm + 2 * tm * tile_bytes),
+        _GMM_VMEM)
+
+
+def _rows_take_kernel(token, order, weight, count, src_ref, *refs, tm,
+                      dotted, d):
+    from jax.experimental import pallas as pl
+
+    if dotted:
+        other_ref, out_ref, dots_ref, buf, scale = refs
+    else:
+        out_ref, buf, scale = refs
+    base = pl.program_id(1) * tm
+    n = jnp.clip(count[0] - base, 0, tm)
+
+    def one(r, carry):
+        buf[pl.ds(r, 1), :] = src_ref[pl.ds(token[base + r], 1), :]
+        scale[pl.ds(r, 1), :] = jnp.full((1, _LANES),
+                                         weight[order[base + r]])
+        return carry
+
+    lax.fori_loop(0, n, one, 0)
+    # selects, not products: the scratch past ``n`` holds anything
+    held = lax.broadcasted_iota(jnp.int32, (tm, 1), 0) < n
+    rows = buf[...]
+    out_ref[...] = jnp.where(held, rows * scale[:, :1], 0) \
+        .astype(out_ref.dtype)
+    if dotted:
+        # nor the columns that a partial last block reads past the edge
+        td = rows.shape[1]
+        col = pl.program_id(0) * td + lax.broadcasted_iota(
+            jnp.int32, (1, td), 1)
+        dots_ref[0] = jnp.sum(jnp.where(
+            held & (col < d), rows * other_ref[...].astype(jnp.float32), 0),
+            axis=1, keepdims=True)
+
+
+def _rows_take_pallas(src, token, order, weight, count, other=None, *, m,
+                      dtype, interpret=False):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s, d = src.shape
+    tm = min(_GMM_ROWS, m)
+    dotted = other is not None
+    td, blocks = _rows_split(d, s, tm, jnp.dtype(dtype).itemsize + (
+        other.dtype.itemsize if dotted else 0))
+    tile = pl.BlockSpec((tm, td), lambda j, v, *_: (v, j))
+    args = [token, order, weight, count, src]
+    in_specs = [pl.BlockSpec((s, td), lambda j, v, *_: (0, j))]
+    out_specs = [tile]
+    out_shape = [jax.ShapeDtypeStruct((m, d), dtype)]
+    if dotted:
+        # a column block's share of the dots: summed below
+        args.append(other)
+        in_specs.append(tile)
+        out_specs.append(pl.BlockSpec((1, tm, 1),
+                                      lambda j, v, *_: (j, v, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((blocks, m, 1), jnp.float32))
+    out = pl.pallas_call(
+        functools.partial(_rows_take_kernel, tm=tm, dotted=dotted, d=d),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(blocks, _rows_tiles(count, tm)),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM((tm, td), jnp.float32),
+                            pltpu.VMEM((tm, _LANES), jnp.float32)],
+        ),
+        out_shape=out_shape,
+        compiler_params=_gmm_params(),
+        interpret=interpret,
+        name="mx_rows_take",
+    )(*args)
+    return (out[0], jnp.sum(out[1], axis=0)[:, 0]) if dotted else out[0]
+
+
+def rows_take(src, token, order, weight, count, m, dtype, other=None):
+    """Rows into a sorted layout: ``out[p] = weight[order[p]] *
+    src[token[p]]`` for the sorted rows ``p < count[0]``, ``(M, D)`` in
+    ``dtype``.  ``src (S, D)`` float32; ``token``, ``order (M,)`` int32 and
+    ``weight (M,)`` float32 are read as scalars (sorted row ``p`` is
+    assignment ``order[p]`` of token ``token[p]``); ``count (1,)`` int32.
+    The kernel ``mx_rows_take`` walks the 128-row tiles that hold rows:
+    the rows of the last one past ``count`` are zeros, the tiles past it
+    are not visited and stay undefined.  With ``other (M, D)``, an array in
+    the sorted layout, also ``dots (M,)`` float32: ``sum(src[token[p]] *
+    other[p])``, zeros and undefined the same way."""
+    run = functools.partial(_rows_take_pallas, m=int(m),
+                            dtype=jnp.dtype(dtype))
+    args = (src, token, order, weight, count)
+    return _platform_pick(run, *args + (() if other is None else (other,)))
+
+
+def _rows_combine_kernel(token, order, weight, count, rows_ref, out_ref, buf,
+                         *, tm):
+    from jax.experimental import pallas as pl
+
+    visit = pl.program_id(1)
+    base = visit * tm
+    n = jnp.clip(count[0] - base, 0, tm)
+
+    @pl.when(visit == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    buf[...] = rows_ref[...].astype(jnp.float32)
+
+    def one(r, carry):
+        at = pl.ds(token[base + r], 1)
+        out_ref[at, :] += weight[order[base + r]] * buf[pl.ds(r, 1), :]
+        return carry
+
+    lax.fori_loop(0, n, one, 0)
+
+
+def _rows_combine_pallas(rows, token, order, weight, count, *, s,
+                         interpret=False):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, d = rows.shape
+    tm = min(_GMM_ROWS, m)
+    td, blocks = _rows_split(d, s, tm, rows.dtype.itemsize)
+    return pl.pallas_call(
+        functools.partial(_rows_combine_kernel, tm=tm),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            # one visit where nothing landed, for the zeros
+            grid=(blocks, jnp.maximum(_rows_tiles(count, tm), 1)),
+            in_specs=[pl.BlockSpec((tm, td), lambda j, v, *_: (v, j))],
+            out_specs=pl.BlockSpec((s, td), lambda j, v, *_: (0, j)),
+            scratch_shapes=[pltpu.VMEM((tm, td), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((s, d), jnp.float32),
+        compiler_params=_gmm_params(),
+        interpret=interpret,
+        name="mx_rows_combine",
+    )(token, order, weight, count, rows)
+
+
+def rows_combine(rows, token, order, weight, count, s):
+    """Rows out of a sorted layout: ``out[t] = sum(weight[order[p]] *
+    rows[p])`` over the sorted rows ``p < count[0]`` with ``token[p] ==
+    t``, ``(S, D)`` float32, zeros for a token without one; the transpose
+    of ``rows_take``.  ``rows (M, D)``; the rest as there.  The kernel
+    ``mx_rows_combine`` walks the tiles that hold rows and reads no other
+    row; the sum is float32, in the order of the sorted rows, into a
+    result that lies in VMEM and is written once."""
+    run = functools.partial(_rows_combine_pallas, s=int(s))
+    return _platform_pick(run, rows, token, order, weight, count)
+
+
+def _rows_relu2_kernel(count, hid_ref, *refs):
+    *grad_ref, out_ref = refs
+    relu = jnp.maximum(hid_ref[...].astype(jnp.float32), 0)
+    if grad_ref:
+        out = 2 * relu * grad_ref[0][...].astype(jnp.float32)
+    else:
+        out = relu * relu
+    out_ref[...] = out.astype(out_ref.dtype)
+
+
+def _rows_relu2_pallas(hid, count, grad=None, *, interpret=False):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, d = hid.shape
+    tm = min(_GMM_ROWS, m)
+    tile = pl.BlockSpec((tm, d), lambda v, c: (v, 0))
+    args = (hid,) if grad is None else (hid, grad)
+    return pl.pallas_call(
+        _rows_relu2_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(_rows_tiles(count, tm),),
+            in_specs=[tile] * len(args),
+            out_specs=tile,
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, d), hid.dtype),
+        interpret=interpret,
+        name="mx_rows_relu2",
+    )(count, *args)
+
+
+def rows_relu2(hid, count, grad=None):
+    """``relu(hid) ** 2`` along a sorted layout, or with ``grad`` its
+    gradient ``grad * 2 * relu(hid)``: ``(M, F)`` in ``hid``'s dtype,
+    computed in float32.  The kernel ``mx_rows_relu2`` walks the tiles that
+    hold rows (``count (1,)`` int32); what a tile holds past ``count`` goes
+    through row by row, the tiles past it stay undefined."""
+    args = (hid, count) if grad is None else (hid, count, grad)
+    return _platform_pick(functools.partial(_rows_relu2_pallas), *args)

@@ -155,10 +155,10 @@ def _telemetry_collector():
     """``grouped_ffn``'s counts leave a ``JitTrainStep`` program as a
     statistic it accumulates on the device (``gluon.block.
     record_step_stat``); a snapshot fetches them and adds what is new
-    (modulo the accumulators' 32 bits) to the three counter families."""
+    (modulo the accumulators' 32 bits) to the four counter families."""
     from .train_step import read_step_stats
 
-    total = dropped = 0
+    total = dropped = walked = 0
     every = read_step_stats(STAT_PREFIX)
     if not every:       # no step of this process routes: no family either
         return
@@ -169,21 +169,27 @@ def _telemetry_collector():
             _seen[(owner, name)] = counts
             new = [(c - p) % (1 << 32) for c, p in zip(counts, last)]
             _, layer, first = name.split("/")
-            for j, n in enumerate(new[:-2]):
+            for j, n in enumerate(new[:-3]):
                 if n:
                     metrics.counter(
                         "mxnet_moe_assignments_held_total",
                         help="routed assignments that landed on an expert "
                              "held here", layer=layer,
                         expert=str(int(first) + j)).inc(n)
-            total += new[-2]
-            dropped += new[-1]
+            total += new[-3]
+            dropped += new[-2]
+            walked += new[-1]
     metrics.counter("mxnet_moe_assignments_total",
                     help="routed assignments (tokens x experts a token), "
                          "every routed layer").inc(total)
     metrics.counter("mxnet_moe_dropped_total",
                     help="assignments to a held expert that were not "
                          "computed; has to stay 0").inc(dropped)
+    metrics.counter("mxnet_moe_sorted_rows_walked_total",
+                    help="rows of the sorted layout (of as many as there "
+                         "are assignments, a layer) that the kernels "
+                         "around the grouped products walked: the tiles "
+                         "that hold a landed row").inc(walked)
 
 
 metrics.register_collector(_telemetry_collector)

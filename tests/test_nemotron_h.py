@@ -166,11 +166,14 @@ def test_three_adamw_steps_match_the_reference_and_count_every_assignment():
     stats = step.step_stats()
     assert sorted(stats) == ["moe/1/4", "moe/4/4"]
     for counts in stats.values():
-        assert counts.dtype == np.uint32 and counts.shape == (4 + 2,)
-        assert counts[-2] == 3 * 32 * 3 and counts[-1] == 0
-        assert 0 < counts[:4].sum() < counts[-2]
+        assert counts.dtype == np.uint32 and counts.shape == (4 + 3,)
+        assert counts[-3] == 3 * 32 * 3 and counts[-2] == 0
+        assert 0 < counts[:4].sum() < counts[-3]
+        # 96 assignments a layer and step are one tile of the sorted layout
+        assert counts[-1] == 3 * 96
     after = _moe_counters()
     assert after["total"] - before["total"] == 2 * 3 * 32 * 3
+    assert after["walked"] - before["walked"] == 2 * 3 * 96
     assert after["dropped"] == before["dropped"] == 0
     held = sum(int(c[:4].sum()) for c in stats.values())
     assert after["held"] - before["held"] == held
@@ -188,6 +191,8 @@ def _moe_counters():
                 "mxnet_moe_assignments_total")),
             "dropped": sum(s["value"] for s in series(
                 "mxnet_moe_dropped_total")),
+            "walked": sum(s["value"] for s in series(
+                "mxnet_moe_sorted_rows_walked_total")),
             "held": sum(s["value"] for s in held),
             "labels": [s["labels"] for s in held]}
 
@@ -296,74 +301,147 @@ def test_every_token_on_one_held_expert_loses_nothing():
     assert (np.asarray(idx) == [5, 6, 7]).all()
     assert np.allclose(np.asarray(w).sum(1), 2.5, rtol=1e-5)
     out, counts = moe_ops.grouped_ffn(x, idx, w, up[4:8], down[4:8], first=4)
-    assert counts.tolist() == [0, 32, 32, 32, 96, 0]      # no capacity
+    assert counts.tolist() == [0, 32, 32, 32, 96, 0, 96]  # no capacity
     _close(out, _dense_experts(x, idx, w, up, down))
     # and one held expert alone takes all of its 32 rows
     out, counts = moe_ops.grouped_ffn(x, idx, w, up[5:6], down[5:6], first=5)
-    assert counts.tolist() == [32, 96, 0]
+    assert counts.tolist() == [32, 96, 0, 96]
     only5 = jnp.where(idx == 5, w, 0.0)
     _close(out, _dense_experts(x, idx, only5, up, down))
 
 
-@pytest.mark.parametrize("load", ["few", "most"])
-def test_rows_past_the_last_group_are_never_read(monkeypatch, load):
-    """The grouped kernels (``mx_gmm``, PR 31) do not visit the rows past
-    the last group: those stay undefined, in a product's result and in the
-    gradient of its rows (on the chip whatever the buffer held; a tile the
-    last group shares keeps what an earlier tile left in VMEM).  With NaN
-    planted in every such row of both, the layer's result and every
-    gradient still match the dense experts, whether few of the assignments
-    land on the held experts or most of them: no undefined value meets
-    arithmetic in either direction (PR 30: 0 * nan took the router's
-    gradient, and with it every layer below, on the chip)."""
+def _poisoned(monkeypatch):
+    """Every array in the sorted layout that a kernel of ``grouped_ffn``
+    writes, forward and backward, with NaN in every row past the landed
+    count: the gathered rows and the result's gradient (``rows_take``, and
+    its dots), both products and their rows' gradients
+    (``grouped_matmul``), the activation and its gradient
+    (``rows_relu2``).  Returns the names of what was poisoned."""
+    seen = []
+
+    def poison(x, landed, name):
+        seen.append(name)
+        rows = jnp.arange(x.shape[0]).reshape((-1,) + (1,) * (x.ndim - 1))
+        return jnp.where(rows < landed, x, jnp.nan)
+
     real = moe_ops.grouped_matmul
 
-    def poison(x, sizes):
-        rows = jnp.arange(x.shape[0])[:, None]
-        return jnp.where(rows < jnp.sum(sizes), x, jnp.nan)
-
     @jax.custom_vjp
-    def undefined_past_the_groups(rows, w, sizes):
-        return poison(real(rows, w, sizes), sizes)
+    def product(rows, w, sizes):
+        return poison(real(rows, w, sizes), jnp.sum(sizes), "product")
 
     def fwd(rows, w, sizes):
-        return undefined_past_the_groups(rows, w, sizes), (rows, w, sizes)
+        return product(rows, w, sizes), (rows, w, sizes)
 
     def bwd(res, g):
         rows, w, sizes = res
         _, vjp = jax.vjp(lambda a, b: real(a, b, sizes), rows, w)
         d_rows, d_w = vjp(g)
-        return poison(d_rows, sizes), d_w, None
-    undefined_past_the_groups.defvjp(fwd, bwd)
-    monkeypatch.setattr(moe_ops, "grouped_matmul", undefined_past_the_groups)
+        return poison(d_rows, jnp.sum(sizes), "product's rows"), d_w, None
+    product.defvjp(fwd, bwd)
+    take, relu2 = moe_ops.rows_take, moe_ops.rows_relu2
+
+    def rows_take(src, token, order, weight, count, m, dtype, other=None):
+        out = take(src, token, order, weight, count, m, dtype, other=other)
+        if other is None:
+            return poison(out, count[0], "take")
+        return tuple(poison(o, count[0], "take with dots") for o in out)
+
+    def rows_relu2(hid, count, grad=None):
+        return poison(relu2(hid, count, grad=grad), count[0],
+                      "relu2" if grad is None else "relu2's gradient")
+    monkeypatch.setattr(moe_ops, "grouped_matmul", product)
+    monkeypatch.setattr(moe_ops, "rows_take", rows_take)
+    monkeypatch.setattr(moe_ops, "rows_relu2", rows_relu2)
+    return seen
+
+
+def _routed(load):
+    """Inputs, and a routing of 32 tokens x 3 over experts 4..7 held:
+    ``(x, w, up, down, idx, mine)``."""
     x, router, bias, up, down = _moe_inputs()
-    # scores that keep most tokens off experts 4..7, or draw them there
-    bias = bias.at[4:8].set(-0.25 if load == "few" else 0.25)
+    if load in ("few", "most"):
+        # scores that keep most tokens off experts 4..7, or draw them there
+        bias = bias.at[4:8].set(-0.25 if load == "few" else 0.25)
     idx, w = moe_ops.router_topk(x, router, bias, k=3, scale=2.5)
+    if load == "none":
+        idx = jnp.where((idx >= 4) & (idx < 8), idx + 4, idx)
+    elif load == "all":                 # every token on three of the held
+        idx = 4 + (jnp.arange(32)[:, None] + jnp.arange(3)[None, :]) % 4
+    elif load == "one_expert":          # one of them takes every token
+        idx = jnp.broadcast_to(jnp.array([6, 0, 12]), (32, 3))
+    elif load == "a_tile_shared":
+        # of the 96 rows (one tile) expert 4 takes 32 and expert 7 takes 32
+        idx = jnp.broadcast_to(jnp.array([4, 1, 7]), (32, 3))
     mine = (idx >= 4) & (idx < 8)
-    landed, quarter = int(mine.sum()), 32 * 3 // 4
-    assert (0 < landed <= quarter) if load == "few" else landed > quarter
+    return x, w, up, down, idx.astype(jnp.int32), mine
+
+
+def _against_the_dense_experts(load, after_forward=lambda: None):
+    """``grouped_ffn``'s result and every gradient against the dense
+    experts at ``_routed(load)``; returns the counts and how many landed."""
+    x, w, up, down, idx, mine = _routed(load)
 
     def ours(x, w, up, down):
-        out, counts = moe_ops._grouped_ffn(x, idx, w, up[4:8], down[4:8], 4)
+        out, counts = moe_ops.grouped_ffn(x, idx, w, up[4:8], down[4:8],
+                                          first=4)
         return jnp.sum(jnp.sin(out)), counts
 
     def dense(x, w, up, down):
         return jnp.sum(jnp.sin(_dense_experts(
             x, idx, jnp.where(mine, w, 0.0), up, down)))
     value, counts = ours(x, w, up, down)
-    assert counts[:4].sum() == landed and counts[-1] == 0
     _close(value, dense(x, w, up, down))
+    after_forward()
     got = jax.grad(ours, argnums=(0, 1, 2, 3), has_aux=True)(
         x, w, up, down)[0]
     want = jax.grad(dense, argnums=(0, 1, 2, 3))(x, w, up, down)
     for a, b in zip(got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype
         assert np.isfinite(np.asarray(a)).all()
         _close(a, b)
+    landed = int(mine.sum())
+    assert counts[:4].sum() == landed and counts[-2] == 0
+    return counts, landed
+
+
+@pytest.mark.parametrize("load", ["none", "few", "most", "all", "one_expert",
+                                  "a_tile_shared"])
+def test_result_and_gradients_match_the_dense_experts_at_every_load(load):
+    counts, landed = _against_the_dense_experts(load)
+    assert {"none": landed == 0, "few": 0 < landed <= 24,
+            "most": 24 < landed < 96, "all": landed == 96}.get(
+                load, landed in (32, 64))
+    assert counts[-1] == (96 if landed else 0)
+
+
+@pytest.mark.parametrize("load", ["few", "most"])
+def test_rows_past_the_last_group_are_never_read(monkeypatch, load):
+    """The kernels of ``grouped_ffn`` do not visit the sorted rows past the
+    last tile that holds a landed row, and the grouped products (``mx_gmm``,
+    PR 31) not even the rest of that tile: those rows stay undefined (on the
+    chip whatever the buffer held).  With NaN planted in every row past the
+    landed count of every array that a kernel writes in the sorted layout
+    (eight of them, forward and backward), the layer's result and every
+    gradient still match the dense experts, whether few of the assignments
+    land on the held experts or most of them: only kernels that walk the
+    landed rows read such an array, and no undefined value meets
+    arithmetic in either direction (PR 30: 0 * nan took the router's
+    gradient, and with it every layer below, on the chip; the selects that
+    stood beside the products since went with PR 33)."""
+    seen = _poisoned(monkeypatch)
+
+    def forward_only():
+        assert sorted(set(seen)) == ["product", "relu2", "take"]
+    _, landed = _against_the_dense_experts(load, forward_only)
+    assert (0 < landed <= 24) if load == "few" else landed > 24
+    assert sorted(set(seen)) == [
+        "product", "product's rows", "relu2", "relu2's gradient", "take",
+        "take with dots"]
 
 
 def test_the_dropped_count_sees_a_row_kept_out_of_the_products(monkeypatch):
-    """``counts[-1]`` is the assignments the indices send here less the
+    """``counts[-2]`` is the assignments the indices send here less the
     rows ``_computed`` lets into the products: with the ``dropped`` fault
     planted there (a capacity of the held experts' mean load) it reads
     what the capacity left out, and the result lacks exactly those."""
@@ -377,7 +455,7 @@ def test_the_dropped_count_sees_a_row_kept_out_of_the_products(monkeypatch):
     capacity = sum(sizes) // 4
     assert sizes[1] == 32 > capacity
     _, counts = moe_ops._grouped_ffn(x, idx, w, up[4:8], down[4:8], 4)
-    assert counts.tolist() == sizes + [96, 0]
+    assert counts.tolist() == sizes + [96, 0, 96]
     monkeypatch.setattr(moe_ops, "_computed", moe_ops._computed)  # put back
     faults_nemotron_h.plant("dropped")
     out, counts = moe_ops._grouped_ffn(x, idx, w, up[4:8], down[4:8], 4)
@@ -385,8 +463,9 @@ def test_the_dropped_count_sees_a_row_kept_out_of_the_products(monkeypatch):
     keep = np.zeros(flat.shape, bool)
     for e in range(4, 8):
         keep[(flat == e).nonzero()[0][:capacity]] = True
-    assert counts.tolist() == sizes + [96, sum(sizes) - int(keep.sum())]
-    assert counts[-1] >= 32 - capacity
+    assert counts.tolist() == sizes + [96, sum(sizes) - int(keep.sum()),
+                                       96]
+    assert counts[-2] >= 32 - capacity
     _close(out, _dense_experts(
         x, idx, jnp.where(keep.reshape(idx.shape), w, 0.0), up, down))
 
@@ -511,7 +590,7 @@ def test_the_share_is_the_models():
         _close(part, ref_part.reshape(24, 32))
         total = total + part
         landed += int(counts[:4].sum())
-        assert counts[-1] == 0
+        assert counts[-2] == 0
     assert landed == 24 * 3            # every assignment landed somewhere
     _close(total, uncut.reshape(24, 32))
 

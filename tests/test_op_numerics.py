@@ -724,13 +724,14 @@ _MOE_IDX = np.array([[2, 5], [3, 0], [7, 4], [2, 3], [4, 5], [1, 2]],
 
 def _grouped_ffn_ref(x, idx, w, up, down):
     """Experts 2..4 of 8 are held: each one densely, weighted where
-    chosen; the counts are rows landed, assignments, dropped."""
+    chosen; the counts are rows landed, assignments, dropped, rows of
+    the sorted layout walked (its one tile of 12)."""
     out = np.zeros(x.shape)
     for e in range(3):
         gate = (w * (idx == e + 2)).sum(1)
         hid = np.maximum(x @ up[e].T, 0.0) ** 2
         out += gate[:, None] * (hid @ down[e].T)
-    return out, np.array([3, 2, 2, 12, 0])
+    return out, np.array([3, 2, 2, 12, 0, 12])
 
 
 SPECS["_contrib_moe_grouped_ffn"] = S(

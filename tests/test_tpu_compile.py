@@ -230,7 +230,13 @@ def test_grouped_ffn_is_the_gmm_kernels_forward_and_backward(one_chip, dtype):
     # rows' gradient, ``mx_gmm_dw`` for the weights'), with a whole
     # expert's matrix held in VMEM, in bfloat16 (under amp) and float32;
     # nothing is left of the compiler's ``ragged-dot-*``, and no product
-    # is dense over every expert and the whole bound
+    # is dense over every expert and the whole bound.  What stands around
+    # them walks the landed rows too (PR 33): three ``mx_rows_take`` (the
+    # tokens' rows, the same again in the backward pass, the result's
+    # gradient with the routing weight), three ``mx_rows_relu2``, two
+    # ``mx_rows_combine``, the 4096 x 2688 float32 array they index by
+    # token whole in VMEM; and no operation of XLA's has a floating-point
+    # result over the bound's 24,576 rows
     dtype = jnp.dtype(dtype)
 
     def loss(x, idx, w, up, down):
@@ -243,10 +249,17 @@ def test_grouped_ffn_is_the_gmm_kernels_forward_and_backward(one_chip, dtype):
                  ((8, 1856, 2688), jnp.float32),
                  ((8, 2688, 1856), jnp.float32))
     text = c.as_text()
-    kernels = re.findall(r"%(mx_gmm(?:_dw)?)[.\d]* = \S+ custom-call\(", text)
-    assert sorted(kernels) == ["mx_gmm"] * 6 + ["mx_gmm_dw"] * 2, kernels
+    kernels = re.findall(r"%(mx_\w+?)(?:\.\d+)* = [^=]+ custom-call\(", text)
+    assert sorted(kernels) == (
+        ["mx_gmm"] * 6 + ["mx_gmm_dw"] * 2 + ["mx_rows_combine"] * 2
+        + ["mx_rows_relu2"] * 3 + ["mx_rows_take"] * 3), kernels
     assert "tpu_custom_call" in text and "ragged-dot" not in text
     assert not re.search(r"\[8,24576,", text)          # no dense fallback
+    over_the_bound = [
+        line.strip() for line in text.splitlines()
+        if re.match(r"\s*(ROOT )?%\S+ = \(?(bf16|f32)\[24576,\d+\]", line)
+        and "custom-call(" not in line and "get-tuple-element(" not in line]
+    assert not over_the_bound, over_the_bound[:3]
 
 
 @pytest.mark.parametrize("seq", [2048, 2000])
