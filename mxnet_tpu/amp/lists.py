@@ -87,6 +87,10 @@ FP32_OPS = [
     "_contrib_moe_router_topk",
     "_contrib_ssd_scan",
     "_contrib_causal_conv1d",
+    # the delta rule: the decay a channel, beta, the triangular solve and
+    # the carried state (ops/kda.py; ``_contrib_kda_attention`` keeps its
+    # inputs as they come and is float32 inside, decay and beta included)
+    "_contrib_kda_scan",
 ]
 
 # multi-input ops whose inputs are cast to the widest participating dtype

@@ -1,0 +1,284 @@
+"""Kimi Linear: a hybrid decoder of Kimi Delta Attention and latent
+attention layers over routed SwiGLU experts (``model_type``
+``kimi_linear``; Kimi Team, "Kimi Linear: An Expressive, Efficient
+Attention Architecture", 2025, and the ``config.json`` of
+``moonshotai/Kimi-Linear-48B-A3B-Instruct``).
+
+Every layer is TWO sub-blocks, each behind its own pre-norm residual: ``x =
+x + mixer(RMSNorm(x)); x = x + ffn(RMSNorm(x))``.  Layers count from 1;
+``kda_layers`` and ``full_attn_layers`` say which mixer a layer has, the
+first ``first_k_dense`` layers have the dense feed-forward and every later
+one the routed.
+
+- ``KDAMixer`` (scope ``kda``): ``q, k, v = silu(causal_conv1d(W u))``
+  (depthwise, no bias); ``q, k`` L2-normalised over a head's channels, ``q``
+  scaled by ``head_dim ** -0.5``; a log-decay a channel ``g = -exp(A_log) *
+  softplus(W_fb W_fa u + dt_bias)``; ``beta = sigmoid(W_b u)``; the gated
+  delta rule; ``o = RMSNorm_head(o) * sigmoid(W_gb W_ga u)``; ``W_o``.
+  Everything between the projections is one operator,
+  ``F.contrib.kda_attention`` (float32 inside, the chunked scan under the
+  scope ``kda_scan``; one checkpoint, so that a layer keeps its
+  projections' results and not sixteen float32 arrays of them).
+- ``MLAMixer`` (scope ``mla``): latent attention materialised for
+  training, **no rotary embedding** (``mla_use_nope``: the ``rope``
+  channels are plain channels): ``q = W_q u`` as heads of ``nope + rope``;
+  ``[c | k_r] = W_kva u``; ``[k_n | v] = W_kvb RMSNorm(c)`` a head; ``k =
+  [k_n | k_r]``, ``k_r`` shared by all heads; causal softmax attention
+  through the flash kernels with keys of ``nope + rope`` channels and
+  values of ``v_head_dim``; ``W_o``.  No absorbed projections, no cache.
+- ``MoEFeedForward`` (scope ``moe``, with ``moe_router``, ``moe_experts``,
+  ``moe_shared`` inside): ``nemotron_h.MoEMixer`` with the ``swiglu``
+  activation: sigmoid top-k routing with a score-correction bias, the held
+  experts' ``down(silu(gate) * up)`` as grouped products without drops, a
+  shared expert every token passes; ``held = (first, count)``,
+  ``force_load_balancing`` and the step statistic as there.
+- ``DenseFeedForward`` (scope ``mlp``): the same SwiGLU, dense.
+
+No projection has a bias.  Weights are their block's own parameters in the
+order the benchmark's plain reference writes them down
+(``benchmark/chip/archs/kimi_linear.py``); gate and up-projection are one
+stacked leaf ``(2F, D)`` (an expert layer's ``(count, 2F, D)``).  A
+``KDAMixer`` declares a step statistic, the chunks its scan ran
+(``kda/<layer>``), which feeds ``mxnet_kda_chunks_total``.
+
+Not built: the latent paged cache and the absorbed decode of the MLA
+layers, a single-token form of the delta rule (serving).
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from ...ops.kda import kda_chunks
+from ...telemetry import metrics
+from .. import nn
+from ..block import HybridBlock, record_step_stat
+from .llama import RMSNorm
+from .nemotron_h import MoEMixer, _dense, _feed_forward, _Mixer
+
+STAT_PREFIX = "kda/"      # a layer's statistic: "kda/<layer>"
+_seen = {}                # (step, statistic) -> the count last read
+
+
+class KDAMixer(_Mixer):
+    def __init__(self, units, num_heads, head_dim, conv_kernel=4,
+                 chunk_size=64, eps=1e-5, layer=0, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        inner = num_heads * head_dim
+        self._cfg = (num_heads, head_dim, int(chunk_size), eps)
+        self._stat = STAT_PREFIX + str(int(layer))
+        self._declare([
+            ("q_proj", (inner, units), None),
+            ("k_proj", (inner, units), None),
+            ("v_proj", (inner, units), None),
+            ("q_conv", (inner, conv_kernel), None),
+            ("k_conv", (inner, conv_kernel), None),
+            ("v_conv", (inner, conv_kernel), None),
+            # 2-D: matrices to the benchmark's seeded initialiser
+            ("A_log", (1, num_heads), "zeros"),
+            ("f_a_proj", (head_dim, units), None),
+            ("f_b_proj", (inner, head_dim), None),
+            ("dt_bias", (num_heads, head_dim), "zeros"),
+            ("b_proj", (num_heads, units), None),
+            ("g_a_proj", (head_dim, units), None),
+            ("g_b_proj", (inner, head_dim), None),
+            ("o_norm", (head_dim,), "ones"),
+            ("o_proj", (units, inner), None)])
+
+    def step_stat_specs(self):
+        """Chunks the scan ran: batch x heads x chunks of the sequence."""
+        return {self._stat: ((1,), jnp.uint32)}
+
+    def hybrid_forward(self, F, u, q_proj, k_proj, v_proj, q_conv, k_conv,
+                       v_conv, A_log, f_a_proj, f_b_proj, dt_bias, b_proj,
+                       g_a_proj, g_b_proj, o_norm, o_proj):
+        heads, hd, chunk, eps = self._cfg
+        b, t, _ = u.shape
+        with jax.named_scope("kda"):
+            def low_rank(a, b):
+                return _dense(F, _dense(F, u, a), b)
+            o = F.contrib.kda_attention(
+                _dense(F, u, q_proj), _dense(F, u, k_proj),
+                _dense(F, u, v_proj), low_rank(f_a_proj, f_b_proj),
+                _dense(F, u, b_proj), low_rank(g_a_proj, g_b_proj), q_conv,
+                k_conv, v_conv, A_log, dt_bias, o_norm, chunk=chunk, eps=eps)
+            record_step_stat(self._stat, jnp.full(
+                (1,), b * heads * kda_chunks(t, chunk), jnp.uint32))
+            return _dense(F, o, o_proj)
+
+
+class MLAMixer(_Mixer):
+    def __init__(self, units, num_heads, kv_lora_rank, qk_nope_head_dim,
+                 qk_rope_head_dim, v_head_dim, eps=1e-5, prefix=None,
+                 params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._cfg = (num_heads, kv_lora_rank, qk_nope_head_dim,
+                     qk_rope_head_dim, v_head_dim, eps)
+        qk = qk_nope_head_dim + qk_rope_head_dim
+        self._declare([
+            ("q_proj", (num_heads * qk, units), None),
+            ("kv_a_proj", (kv_lora_rank + qk_rope_head_dim, units), None),
+            ("kv_a_norm", (kv_lora_rank,), "ones"),
+            ("kv_b_proj", (num_heads * (qk_nope_head_dim + v_head_dim),
+                           kv_lora_rank), None),
+            ("o_proj", (units, num_heads * v_head_dim), None)])
+
+    def hybrid_forward(self, F, u, q_proj, kv_a_proj, kv_a_norm, kv_b_proj,
+                       o_proj):
+        h, rank, nope, rope, vd, eps = self._cfg
+        b, t, _ = u.shape
+        with jax.named_scope("mla"):
+            def heads(x, width):
+                return F.transpose(F.reshape(x, shape=(b, t, -1, width)),
+                                   axes=(0, 2, 1, 3))
+            q = heads(_dense(F, u, q_proj), nope + rope)
+            kv_a = _dense(F, u, kv_a_proj)
+            latent = F.RMSNorm(F.slice_axis(kv_a, axis=2, begin=0, end=rank),
+                               kv_a_norm, axis=-1, eps=eps)
+            k_rope = F.broadcast_axis(
+                heads(F.slice_axis(kv_a, axis=2, begin=rank, end=None),
+                      rope), axis=1, size=h)
+            kv = heads(_dense(F, latent, kv_b_proj), nope + vd)
+            k = F.concat(F.slice_axis(kv, axis=3, begin=0, end=nope),
+                         k_rope, dim=3)
+            v = F.slice_axis(kv, axis=3, begin=nope, end=None)
+            out = F.contrib.flash_attention(
+                q, k, v, scale=(nope + rope) ** -0.5, causal=True)
+            out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
+                            shape=(b, t, h * vd))
+            return _dense(F, out, o_proj)
+
+
+class MoEFeedForward(MoEMixer):
+    """``nemotron_h.MoEMixer`` with SwiGLU experts."""
+
+    def __init__(self, units, n_experts, top_k, moe_hidden, shared_hidden,
+                 **kwargs):
+        super().__init__(units, n_experts, top_k, moe_hidden, shared_hidden,
+                         activation="swiglu", **kwargs)
+
+
+class DenseFeedForward(_Mixer):
+    def __init__(self, units, hidden, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._declare([("gate_up", (2 * hidden, units), None),
+                       ("down", (units, hidden), None)])
+
+    def hybrid_forward(self, F, u, gate_up, down):
+        with jax.named_scope("mlp"):
+            return _feed_forward(F, u, gate_up, down, "swiglu")
+
+
+class KimiLinearBlock(HybridBlock):
+    """``x = x + mixer(RMSNorm(x)); x = x + ffn(RMSNorm(x))``."""
+
+    def __init__(self, units, mixer, ffn, eps=1e-5, prefix=None,
+                 params=None):
+        super().__init__(prefix=prefix, params=params)
+        with self.name_scope():
+            self.mixer_norm = RMSNorm(units, eps, prefix="mixer_norm_")
+            self.mixer = mixer(prefix="mixer_")
+            self.ffn_norm = RMSNorm(units, eps, prefix="ffn_norm_")
+            self.ffn = ffn(prefix="ffn_")
+
+    def hybrid_forward(self, F, x):
+        x = x + self.mixer(self.mixer_norm(x))
+        return x + self.ffn(self.ffn_norm(x))
+
+
+class KimiLinearModel(HybridBlock):
+    """Decoder-only LM.  forward(tokens (B, T)) -> logits (B, T, V).
+    ``kda_layers`` and ``full_attn_layers`` number the layers from 1 and
+    together name every one of ``num_layers``."""
+
+    def __init__(self, vocab_size, units, num_layers, *, kda_layers,
+                 full_attn_layers, kda_num_heads, kda_head_dim,
+                 conv_kernel=4, chunk_size=64, num_heads, kv_lora_rank,
+                 qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                 dense_hidden, first_k_dense=1, n_routed_experts,
+                 num_experts_per_tok, moe_hidden, shared_hidden,
+                 routed_scaling_factor=1.0, norm_topk_prob=True, held=None,
+                 force_load_balancing=False, eps=1e-5, prefix=None,
+                 params=None):
+        super().__init__(prefix=prefix, params=params)
+        kda, full = set(kda_layers), set(full_attn_layers)
+        if kda & full or kda | full != set(range(1, num_layers + 1)):
+            raise ValueError(
+                "kda_layers %r and full_attn_layers %r do not name each of "
+                "the %d layers once" % (sorted(kda), sorted(full),
+                                        num_layers))
+
+        def mixer(i):
+            if i in kda:
+                return lambda prefix: KDAMixer(
+                    units, kda_num_heads, kda_head_dim, conv_kernel,
+                    chunk_size, eps, layer=i, prefix=prefix)
+            return lambda prefix: MLAMixer(
+                units, num_heads, kv_lora_rank, qk_nope_head_dim,
+                qk_rope_head_dim, v_head_dim, eps, prefix=prefix)
+
+        def ffn(i):
+            if i <= first_k_dense:
+                return lambda prefix: DenseFeedForward(units, dense_hidden,
+                                                       prefix=prefix)
+            return lambda prefix: MoEFeedForward(
+                units, n_routed_experts, num_experts_per_tok, moe_hidden,
+                shared_hidden, scale=routed_scaling_factor,
+                normalize=norm_topk_prob, held=held, layer=i,
+                force_load_balancing=force_load_balancing, prefix=prefix)
+
+        with self.name_scope():
+            self.embed = nn.Embedding(vocab_size, units, prefix="embed_")
+            self.blocks = nn.HybridSequential(prefix="blocks_")
+            for i in range(1, num_layers + 1):
+                self.blocks.add(KimiLinearBlock(
+                    units, mixer(i), ffn(i), eps, prefix="block%d_" % i))
+            self.norm = RMSNorm(units, eps, prefix="norm_")
+            self.lm_head = nn.Dense(vocab_size, flatten=False,
+                                    use_bias=False, in_units=units,
+                                    prefix="head_")
+
+    def hybrid_forward(self, F, tokens):
+        return self.lm_head(self.norm(self.blocks(self.embed(tokens))))
+
+
+def kimi_linear_48b_a3b(vocab_size=163840, **kwargs):
+    """Kimi-Linear-48B-A3B (27 layers: 20 KDA, 7 latent attention, a dense
+    feed-forward in layer 1 and 256 routed experts after; 49.12B
+    parameters)."""
+    full = [4, 8, 12, 16, 20, 24, 27]
+    cfg = dict(
+        units=2304, num_layers=27,
+        kda_layers=[i for i in range(1, 28) if i not in full],
+        full_attn_layers=full, kda_num_heads=32, kda_head_dim=128,
+        conv_kernel=4, num_heads=32, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, dense_hidden=9216,
+        first_k_dense=1, n_routed_experts=256, num_experts_per_tok=8,
+        moe_hidden=1024, shared_hidden=1024, routed_scaling_factor=2.446,
+        norm_topk_prob=True)
+    cfg.update(kwargs)
+    return KimiLinearModel(vocab_size, **cfg)
+
+
+def _telemetry_collector():
+    """The scans' chunk counts leave a ``JitTrainStep`` program as a
+    statistic it accumulates on the device; a snapshot fetches them and adds
+    what is new (modulo the accumulators' 32 bits) to the counter."""
+    from ...parallel.train_step import read_step_stats
+
+    every = read_step_stats(STAT_PREFIX)
+    if not every:       # no step of this process scans: no family either
+        return
+    new = 0
+    for owner, stats in every:
+        for name, count in stats.items():
+            count = int(count[0])
+            new += (count - _seen.get((owner, name), 0)) % (1 << 32)
+            _seen[(owner, name)] = count
+    metrics.counter("mxnet_kda_chunks_total",
+                    help="chunks the Kimi Delta Attention scans ran "
+                         "(sequences x heads x chunks, every such layer "
+                         "and train step)").inc(new)
+
+
+metrics.register_collector(_telemetry_collector)

@@ -55,8 +55,19 @@ def _dense(F, x, weight):
                             num_hidden=weight.shape[0])
 
 
-def _relu2(F, x):
-    return F.square(F.relu(x))
+def _feed_forward(F, u, up, down, activation):
+    """``down(act(up(u)))`` over ``u (B, T, D)``: ``relu2``, ``relu(.)^2``
+    of ``up (F, D)``; or ``swiglu``, ``silu(gate) * up`` of ``up (2F, D)``
+    holding the gate's rows and then the up-projection's."""
+    hid = _dense(F, u, up)
+    if activation == "swiglu":
+        f = up.shape[0] // 2
+        hid = F.Activation(F.slice_axis(hid, axis=2, begin=0, end=f),
+                           act_type="silu") \
+            * F.slice_axis(hid, axis=2, begin=f, end=None)
+    else:
+        hid = F.square(F.relu(hid))
+    return _dense(F, hid, down)
 
 
 class _Mixer(HybridBlock):
@@ -125,9 +136,15 @@ class Mamba2Mixer(_Mixer):
 
 
 class MoEMixer(_Mixer):
+    """Routed experts and a shared expert, both of one ``activation``:
+    ``relu2`` (two matrices an expert) or ``swiglu`` (three: the gate's and
+    the up-projection's rows stacked in ``up``, ``(count, 2 * moe_hidden,
+    units)``)."""
+
     def __init__(self, units, n_experts, top_k, moe_hidden, shared_hidden,
                  scale=1.0, normalize=True, held=None, layer=0,
-                 force_load_balancing=False, prefix=None, params=None):
+                 force_load_balancing=False, activation="relu2", prefix=None,
+                 params=None):
         super().__init__(prefix=prefix, params=params)
         first, count = held if held is not None else (0, n_experts)
         if first < 0 or count < 1 or first + count > n_experts:
@@ -137,12 +154,14 @@ class MoEMixer(_Mixer):
                      int(count))
         self._stat = "moe/%d/%d" % (layer, first)
         self._balance_seed = int(layer) if force_load_balancing else None
+        self._activation = activation
+        wide = 2 if activation == "swiglu" else 1
         self._declare([
             ("router", (n_experts, units), None),
             ("router_bias", (n_experts,), "zeros"),
-            ("up", (count, moe_hidden, units), None),
+            ("up", (count, wide * moe_hidden, units), None),
             ("down", (count, units, moe_hidden), None),
-            ("shared_up", (shared_hidden, units), None),
+            ("shared_up", (wide * shared_hidden, units), None),
             ("shared_down", (units, shared_hidden), None)])
 
     def step_stat_specs(self):
@@ -160,11 +179,12 @@ class MoEMixer(_Mixer):
                 flat, router, router_bias, k=k, scale=scale,
                 normalize=normalize, balance_seed=self._balance_seed)
             routed, counts = F.contrib.moe_grouped_ffn(
-                flat, idx, w, up, down, first=first)
+                flat, idx, w, up, down, first=first,
+                activation=self._activation)
             record_step_stat(self._stat, counts)
             with jax.named_scope("moe_shared"):
-                shared = _dense(F, _relu2(F, _dense(F, u, shared_up)),
-                                shared_down)
+                shared = _feed_forward(F, u, shared_up, shared_down,
+                                       self._activation)
             return F.reshape(routed, shape=(b, t, d)) + shared
 
 

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from .pallas_kernels import grouped_matmul, rows_combine, rows_relu2, \
-    rows_take, rows_walked
+    rows_swiglu, rows_take, rows_walked
 from .registry import register
 
 
@@ -60,15 +60,20 @@ def router_topk(data, weight, bias, k=1, scale=1.0, normalize=True,
 
 @register("_contrib_moe_grouped_ffn", num_outputs=2,
           inputs=("data", "topk_idx", "topk_weight", "up", "down"))
-def grouped_ffn(data, topk_idx, topk_weight, up, down, first=0):
+def grouped_ffn(data, topk_idx, topk_weight, up, down, first=0,
+                activation="relu2"):
     """The held experts' part of a routed feed-forward layer, no drops.
 
     ``data (S, D)``; ``topk_idx``/``topk_weight (S, k)`` from
-    ``router_topk``; ``up (held, F, D)`` and ``down (held, D, F)``: the
-    experts ``first .. first + held - 1`` of the layer, stacked, each
-    ``down_e(relu(up_e(u))^2)``.  Returns ``(out (S, D) float32, counts
-    (held + 3,) uint32)``: ``out[s] = sum`` over the assignments of token
-    ``s`` to a held expert of ``weight * expert(data[s])``; ``counts`` are
+    ``router_topk``; ``up`` and ``down (held, D, F)``: the experts ``first
+    .. first + held - 1`` of the layer, stacked.  ``activation`` is the
+    experts' own: ``relu2``, ``up (held, F, D)`` and each expert
+    ``down_e(relu(up_e(u))^2)``; or ``swiglu``, ``up (held, 2F, D)`` holding
+    the gate's rows and then the up-projection's (one grouped product for
+    both) and each expert ``down_e(silu(gate_e(u)) * up_e(u))``.  Returns
+    ``(out (S, D) float32, counts (held + 3,) uint32)``: ``out[s] = sum``
+    over the assignments of token ``s`` to a held expert of ``weight *
+    expert(data[s])``; ``counts`` are
     the assignments that landed on each held expert, the assignments in
     all (``S * k``), those to a held expert whose row did not pass into
     the result (the assignments the indices send here less the rows
@@ -83,18 +88,23 @@ def grouped_ffn(data, topk_idx, topk_weight, up, down, first=0):
     rows and no other, so a call costs what landed (``pallas_kernels``:
     ``rows_take`` gathers the tokens' rows, ``grouped_matmul`` is one
     product a projection with the landed rows as its group sizes,
-    ``rows_relu2`` the activation, ``rows_combine`` the weighted sum over
-    a token's assignments).  What lies past the landed rows is undefined
+    ``rows_relu2`` or ``rows_swiglu`` the activation, ``rows_combine`` the
+    weighted sum over a token's assignments).  What lies past the landed
+    rows is undefined
     and no one may read it: XLA's arithmetic sees the layout's integers
     alone (the sorts, the sizes).  The matrix products run in
     ``data``'s dtype, accumulated in float32, on the weights as they are
     stored (nothing is transposed); the weighted sum is float32, in the
     order of a token's experts.  The backward pass is written by hand of
     the same kernels; it keeps the layout's integers and makes the
-    layout's rows again from the inputs."""
+    layout's rows again from the inputs, once the result's gradient is
+    there."""
+    if activation not in ("relu2", "swiglu"):
+        raise ValueError("grouped_ffn: no activation %r (relu2, swiglu)"
+                         % (activation,))
     with jax.named_scope("moe_experts"):
         return _grouped_ffn(data, topk_idx, topk_weight, up, down,
-                            int(first))
+                            int(first), activation)
 
 
 def _computed(key, order, held):
@@ -142,7 +152,12 @@ def _layout(topk_idx, topk_weight, held, first):
                    counts.astype(jnp.uint32))
 
 
-def _sorted_forward(data, lay, up, down):
+def _rows_act(activation):
+    """The experts' activation along the sorted layout."""
+    return rows_swiglu if activation == "swiglu" else rows_relu2
+
+
+def _sorted_forward(data, lay, up, down, activation):
     """Along the sorted layout: the up-projection's result and vjp, the
     down-projection's result and vjp."""
     rows = rows_take(data.astype(jnp.float32), lay.token, lay.order,
@@ -152,32 +167,40 @@ def _sorted_forward(data, lay, up, down):
     def product(rows, w):
         return grouped_matmul(rows, w, lay.sizes)
     hid, vjp_up = jax.vjp(product, rows, up.astype(data.dtype))
-    res, vjp_down = jax.vjp(product, rows_relu2(hid, lay.landed),
+    res, vjp_down = jax.vjp(product, _rows_act(activation)(hid, lay.landed),
                             down.astype(data.dtype))
     return hid, vjp_up, res, vjp_down
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _grouped_ffn(data, topk_idx, topk_weight, up, down, first):
-    return _grouped_ffn_fwd(data, topk_idx, topk_weight, up, down, first)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _grouped_ffn(data, topk_idx, topk_weight, up, down, first,
+                 activation="relu2"):
+    return _grouped_ffn_fwd(data, topk_idx, topk_weight, up, down, first,
+                            activation)[0]
 
 
-def _grouped_ffn_fwd(data, topk_idx, topk_weight, up, down, first):
+def _grouped_ffn_fwd(data, topk_idx, topk_weight, up, down, first,
+                     activation):
     lay = _layout(topk_idx, topk_weight, up.shape[0], first)
-    _, _, res, _ = _sorted_forward(data, lay, up, down)
+    _, _, res, _ = _sorted_forward(data, lay, up, down, activation)
     out = rows_combine(res, lay.token, lay.order, lay.weight, lay.landed,
                        data.shape[0])
     return (out, lay.counts), (data, topk_weight, up, down, lay)
 
 
-def _grouped_ffn_bwd(first, kept, grads):
+def _grouped_ffn_bwd(first, activation, kept, grads):
     data, topk_weight, up, down, lay = kept
-    hid, vjp_up, res, vjp_down = _sorted_forward(data, lay, up, down)
-    d_res, dots = rows_take(grads[0].astype(jnp.float32), lay.token,
+    # the layout's rows are made again when the result's gradient is there
+    # and not before: left free, the compiler makes every layer's rows
+    # early and holds them all at once (a layer's are 0.5 GB at 32,768 rows)
+    data, d_out = jax.lax.optimization_barrier((data, grads[0]))
+    hid, vjp_up, res, vjp_down = _sorted_forward(data, lay, up, down,
+                                                 activation)
+    d_res, dots = rows_take(d_out.astype(jnp.float32), lay.token,
                             lay.order, lay.weight, lay.landed,
                             lay.order.shape[0], data.dtype, other=res)
     d_act, d_down = vjp_down(d_res)
-    d_rows, d_up = vjp_up(rows_relu2(hid, lay.landed, grad=d_act))
+    d_rows, d_up = vjp_up(_rows_act(activation)(hid, lay.landed, grad=d_act))
     d_data = rows_combine(d_rows, lay.token, lay.order,
                           jnp.ones_like(lay.weight), lay.landed,
                           data.shape[0])

@@ -1,7 +1,7 @@
 """Pallas TPU kernels: fused flash attention (forward AND backward), the
 grouped matmul of the routed experts (``grouped_matmul``), and the kernels
 that move rows into and out of the sorted layout around it (``rows_take``,
-``rows_relu2``, ``rows_combine``, at the end).
+``rows_relu2`` / ``rows_swiglu``, ``rows_combine``, at the end).
 
 The reference's fused-attention story is two CUDA kernels
 (``_contrib_interleaved_matmul_selfatt_qk``/``_valatt``,
@@ -17,6 +17,13 @@ recomputes p blockwise to accumulate dq over k-blocks, and a second
 accumulates dk/dv over q-blocks.  No (T, T) buffer exists in either
 direction, so long-context TRAINING runs at O(T·D) memory; ring attention
 (parallel/ring_attention.py) composes on top to shard T across chips.
+The forward and dq kernels hold a head's whole K and V in VMEM and loop
+over key blocks; the dk/dv kernel streams query blocks over a grid axis
+into float32 accumulators (whole-T q, dO, lse and delta did not fit at
+T=4096).  Under the causal mask no kernel computes a block that the mask
+hides.  Values may be narrower than keys (latent attention: keys of 192
+channels, values of 128): ``q, k (BH, T, D)``, ``v``, the result and
+``dO (BH, T, Dv)``, nothing padded.
 
 On non-TPU backends the kernels run through the Pallas interpreter
 (tests).  Shapes that do not tile take plain jnp attention on every
@@ -74,14 +81,23 @@ def _platform_pick(run, *args, off_tpu=None):
 # ---------------------------------------------------------------------------
 
 
+def _blocks_seen(qi, block_q, block_k, t_kv, causal):
+    """Key blocks that query block ``qi`` reads: all of them, or under the
+    causal mask those that hold a column at or before its last row (a block
+    past them adds exact zeros)."""
+    n_k = t_kv // block_k
+    if not causal or n_k == 1:
+        return n_k          # static: the loop is unrolled as it always was
+    return jnp.minimum(((qi + 1) * block_q + block_k - 1) // block_k, n_k)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
                       block_k, scale, causal):
     from jax.experimental import pallas as pl
 
     q = q_ref[0].astype(jnp.float32) * scale           # (bq, D)
-    t_kv = k_ref.shape[1]
-    n_k = t_kv // block_k
     qi = pl.program_id(1)
+    n_k = _blocks_seen(qi, block_q, block_k, k_ref.shape[1], causal)
     row = qi * block_q + lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
 
@@ -109,7 +125,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
 
     m0 = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
+    acc0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
     m, l, acc = lax.fori_loop(0, n_k, body, (m0, l0, acc0))
     safe_l = jnp.where(l == 0, 1.0, l)
     o_ref[0] = (acc / safe_l).astype(o_ref.dtype)
@@ -127,7 +143,7 @@ def _flash_pallas(q, k, v, scale, causal, block_q, block_k,
     from jax.experimental import pallas as pl
 
     bh, t_q, d = q.shape
-    t_kv = k.shape[1]
+    t_kv, dv = k.shape[1], v.shape[2]
     kernel = functools.partial(
         _flash_fwd_kernel, block_q=block_q, block_k=block_k,
         scale=scale, causal=causal)
@@ -137,14 +153,14 @@ def _flash_pallas(q, k, v, scale, causal, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, t_kv, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, t_kv, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, t_kv, dv), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, _LANES), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t_q, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, t_q, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, t_q, _LANES), jnp.float32),
         ],
         interpret=interpret,
@@ -166,9 +182,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     do = do_ref[0].astype(jnp.float32)                  # (bq, D)
     lse = lse_ref[0][:, :1]                             # (bq, 1) lane 0
     delta = delta_ref[0][:, :1]                         # (bq, 1) lane 0
-    t_kv = k_ref.shape[1]
-    n_k = t_kv // block_k
     qi = pl.program_id(1)
+    n_k = _blocks_seen(qi, block_q, block_k, k_ref.shape[1], causal)
     row = qi * block_q + lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
 
@@ -200,59 +215,85 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
+def _first_block_seen(ki, block_q, block_k, causal):
+    """The first query block that key block ``ki`` is seen by."""
+    return (ki * block_k) // block_q if causal else 0
+
+
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, block_q, block_k, scale,
-                          causal):
+                          dk_ref, dv_ref, dk_acc=None, dv_acc=None, *,
+                          block_q, block_k, scale, causal):
+    """One (key block, query block) pair a grid step: the query blocks
+    stream through VMEM (whole-T operands do not fit at T=4096) and dk, dv
+    accumulate in float32 scratch across the innermost grid axis.  Where T
+    is one query block there is no scratch: the pair's result is written as
+    it is (the kernel of T=512 as it always was)."""
     from jax.experimental import pallas as pl
 
-    k = k_ref[0].astype(jnp.float32)                    # (bk, D)
-    v = v_ref[0].astype(jnp.float32)                    # (bk, D)
-    t_q = q_ref.shape[1]
-    n_q = t_q // block_q
-    ki = pl.program_id(1)
-    col = ki * block_k + lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+    ki, qi = pl.program_id(1), pl.program_id(2)
+    one_block = dk_acc is None          # T is one query block: no sum to keep
 
-    def body(i, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.dslice(i * block_q, block_q), :] \
-            .astype(jnp.float32)
-        do = do_ref[0, pl.dslice(i * block_q, block_q), :] \
-            .astype(jnp.float32)
-        lse = lse_ref[0, pl.dslice(i * block_q, block_q), :1]
-        delta = delta_ref[0, pl.dslice(i * block_q, block_q), :1]
+    def pair():
+        k = k_ref[0].astype(jnp.float32)                # (bk, D)
+        v = v_ref[0].astype(jnp.float32)                # (bk, Dv)
+        q = q_ref[0].astype(jnp.float32)                # (bq, D)
+        do = do_ref[0].astype(jnp.float32)              # (bq, Dv)
+        lse = lse_ref[0][:, :1]
+        delta = delta_ref[0][:, :1]
         s = scale * jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)         # (bq, bk)
         if causal:
-            row = i * block_q + lax.broadcasted_iota(
+            col = ki * block_k + lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            row = qi * block_q + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             s = jnp.where(col <= row, s, -jnp.inf)
         p = jnp.where(jnp.isfinite(lse), jnp.exp(s - lse), 0.0)
-        dv_new = dv + jax.lax.dot_general(
+        dv = jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # (bk, D)
+            preferred_element_type=jnp.float32)         # (bk, Dv)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)         # (bq, bk)
         ds = p * (dp - delta)
-        dk_new = dk + scale * jax.lax.dot_general(
+        dk = scale * jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)         # (bk, D)
-        return dk_new, dv_new
+        return dk, dv
 
-    z = jnp.zeros((k.shape[0], k.shape[1]), jnp.float32)
-    dk, dv = lax.fori_loop(0, n_q, body, (z, z))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    if one_block:
+        dk, dv = pair()
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+        return
+
+    @pl.when(qi == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    # under the causal mask a query block before the key block's first
+    # row adds exact zeros: not computed
+    @pl.when(qi >= _first_block_seen(ki, block_q, block_k, causal))
+    def _():
+        dk, dv = pair()
+        dk_acc[...] += dk
+        dv_acc[...] += dv
+
+    @pl.when(qi == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _flash_bwd_pallas(q, k, v, do, lse, delta, scale, causal, block_q,
                       block_k, interpret=False):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     bh, t_q, d = q.shape
-    t_kv = k.shape[1]
+    t_kv, dv = k.shape[1], v.shape[2]
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_q=block_q,
                           block_k=block_k, scale=scale, causal=causal),
@@ -260,8 +301,8 @@ def _flash_bwd_pallas(q, k, v, do, lse, delta, scale, causal, block_q,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, t_kv, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, t_kv, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, t_kv, dv), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, _LANES), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, _LANES), lambda b, i: (b, i, 0)),
         ],
@@ -270,30 +311,36 @@ def _flash_bwd_pallas(q, k, v, do, lse, delta, scale, causal, block_q,
         interpret=interpret,
         name="mx_flash_bwd_dq",
     )(q, k, v, do, lse, delta)
-    dk, dv = pl.pallas_call(
+
+    def rows(width):
+        # a query block the mask hides is not fetched: the index stays at
+        # the first block that is seen
+        return pl.BlockSpec(
+            (1, block_q, width), lambda b, j, i: (b, jnp.maximum(
+                i, _first_block_seen(j, block_q, block_k, causal)), 0))
+
+    def cols(width):
+        return pl.BlockSpec((1, block_k, width), lambda b, j, i: (b, j, 0))
+    dk, dv_ = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
                           block_k=block_k, scale=scale, causal=causal),
-        grid=(bh, t_kv // block_k),
-        in_specs=[
-            pl.BlockSpec((1, t_q, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, t_q, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, t_q, _LANES), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, t_q, _LANES), lambda b, j: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-        ],
+        grid=(bh, t_kv // block_k, t_q // block_q),
+        in_specs=[rows(d), cols(d), cols(dv), rows(dv), rows(_LANES),
+                  rows(_LANES)],
+        out_specs=[cols(d), cols(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t_kv, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, t_kv, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, t_kv, dv), v.dtype),
         ],
+        scratch_shapes=[] if t_q == block_q else [
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="mx_flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+    return dq, dk, dv_
 
 
 def _attention_ref(q, k, v, scale, causal):
@@ -367,10 +414,10 @@ def _flash_per_shard(mesh, axes, q, k, v, *static):
     spec = P(batch_axis, head_axes or None, None, None)
 
     def body(q, k, v):
-        b, h, t, d = q.shape
-        out = _flash_attention(*(x.reshape(b * h, -1, d) for x in (q, k, v)),
-                               *static)
-        return out.reshape(b, h, t, d)
+        b, h, t, _ = q.shape
+        out = _flash_attention(*(x.reshape(b * h, -1, x.shape[-1])
+                                 for x in (q, k, v)), *static)
+        return out.reshape(b, h, t, -1)
 
     return jax.shard_map(body, in_specs=(spec,) * 3, out_specs=spec,
                          axis_names=set(axes), check_vma=False)(q, k, v)
@@ -393,9 +440,11 @@ def flash_attention(query, key, value, scale=None, causal=False,
                     block_q=None, block_k=None):
     """Fused multi-head attention, one Pallas kernel per (batch·head).
 
-    Inputs (B, H, T, D) [or (BH, T, D)]; returns same shape.  Scores are
-    computed blockwise with an online softmax; ``scale`` defaults to
-    1/sqrt(D).  Falls back to plain XLA attention when T doesn't tile.
+    Inputs (B, H, T, D) [or (BH, T, D)]; ``value`` may have another last
+    dimension than ``query`` and ``key``, and the result has ``value``'s.
+    Scores are computed blockwise with an online softmax; ``scale``
+    defaults to 1/sqrt(D).  Falls back to plain XLA attention when T
+    doesn't tile.
     Differentiable end-to-end via the blocked flash backward (no (T, T)
     buffer in forward or backward).
 
@@ -417,14 +466,14 @@ def flash_attention(query, key, value, scale=None, causal=False,
         scale = 1.0 / (d ** 0.5)
     q3 = query.reshape(b * h, t_q, d)
     k3 = key.reshape(b * h, t_kv, d)
-    v3 = value.reshape(b * h, t_kv, d)
+    v3 = value.reshape(b * h, t_kv, value.shape[-1])
     # short sequences go to XLA's attention: the (T,T) buffer is small
     # there.  No cell sits on this side of the switch (ROADMAP.md S17,
     # R9: a BERT-base seq-128 guard cell would).  Explicit block sizes
     # force the kernel (tests, tuning).
     if block_q is None and block_k is None and t_q < 512 and t_kv < 512:
         return _finish(_attention_ref(q3, k3, v3, scale, causal),
-                       b, h, t_q, d, squeeze)
+                       b, h, t_q, squeeze)
     bq = _tiles(t_q, int(block_q) if block_q else min(t_q, 512))
     bk = _tiles(t_kv, int(block_k) if block_k else min(t_kv, 512))
     if bq is None or bk is None:
@@ -448,11 +497,11 @@ def flash_attention(query, key, value, scale=None, causal=False,
             out3 = _flash_per_shard(mesh, axes, query, key, value, *static)
         else:
             out3 = _flash_attention(q3, k3, v3, *static)
-    return _finish(out3, b, h, t_q, d, squeeze)
+    return _finish(out3, b, h, t_q, squeeze)
 
 
-def _finish(out3, b, h, t_q, d, squeeze):
-    out = out3.reshape(b, h, t_q, d)
+def _finish(out3, b, h, t_q, squeeze):
+    out = out3.reshape(b, h, t_q, out3.shape[-1])
     return out[:, 0] if squeeze else out
 
 
@@ -873,26 +922,56 @@ def _rows_relu2_kernel(count, hid_ref, *refs):
     out_ref[...] = out.astype(out_ref.dtype)
 
 
-def _rows_relu2_pallas(hid, count, grad=None, *, interpret=False):
+def _rows_swiglu_kernel(count, hid_ref, *refs):
+    *grad_ref, out_ref = refs
+    f = hid_ref.shape[1] // 2
+    gate = hid_ref[:, :f].astype(jnp.float32)
+    up = hid_ref[:, f:].astype(jnp.float32)
+    sig = jax.nn.sigmoid(gate)
+    if grad_ref:
+        g = grad_ref[0][...].astype(jnp.float32)
+        # d silu(x) = sig (1 + x (1 - sig))
+        out_ref[:, :f] = (g * up * sig * (1 + gate * (1 - sig))) \
+            .astype(out_ref.dtype)
+        out_ref[:, f:] = (g * gate * sig).astype(out_ref.dtype)
+    else:
+        out_ref[...] = (gate * sig * up).astype(out_ref.dtype)
+
+
+# an activation along the sorted layout: its kernel, the kernel's name, and
+# the hidden columns a column of its result reads
+_ROWS_ACT = {"relu2": (_rows_relu2_kernel, "mx_rows_relu2", 1),
+             "swiglu": (_rows_swiglu_kernel, "mx_rows_swiglu", 2)}
+
+
+def _rows_act_pallas(hid, count, grad=None, *, act, interpret=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    kernel, name, fold = _ROWS_ACT[act]
     m, d = hid.shape
     tm = min(_GMM_ROWS, m)
-    tile = pl.BlockSpec((tm, d), lambda v, c: (v, 0))
-    args = (hid,) if grad is None else (hid, grad)
+
+    def tile(width):
+        return pl.BlockSpec((tm, width), lambda v, c: (v, 0))
+    wide = grad is not None
     return pl.pallas_call(
-        _rows_relu2_kernel,
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(_rows_tiles(count, tm),),
-            in_specs=[tile] * len(args),
-            out_specs=tile,
+            in_specs=[tile(d)] + ([tile(d // fold)] if wide else []),
+            out_specs=tile(d if wide else d // fold),
         ),
-        out_shape=jax.ShapeDtypeStruct((m, d), hid.dtype),
+        out_shape=jax.ShapeDtypeStruct((m, d if wide else d // fold),
+                                       hid.dtype),
         interpret=interpret,
-        name="mx_rows_relu2",
-    )(count, *args)
+        name=name,
+    )(count, *((hid,) if grad is None else (hid, grad)))
+
+
+_rows_relu2_pallas = functools.partial(_rows_act_pallas, act="relu2")
+_rows_swiglu_pallas = functools.partial(_rows_act_pallas, act="swiglu")
 
 
 def rows_relu2(hid, count, grad=None):
@@ -902,4 +981,13 @@ def rows_relu2(hid, count, grad=None):
     hold rows (``count (1,)`` int32); what a tile holds past ``count`` goes
     through row by row, the tiles past it stay undefined."""
     args = (hid, count) if grad is None else (hid, count, grad)
-    return _platform_pick(functools.partial(_rows_relu2_pallas), *args)
+    return _platform_pick(_rows_relu2_pallas, *args)
+
+
+def rows_swiglu(hid, count, grad=None):
+    """``silu(hid[:, :F]) * hid[:, F:]`` along a sorted layout, ``hid (M,
+    2F)`` holding gate and up side by side: ``(M, F)``; or with ``grad (M,
+    F)`` the gradient with respect to ``hid``, ``(M, 2F)``.  The kernel
+    ``mx_rows_swiglu``; the rest as ``rows_relu2``."""
+    args = (hid, count) if grad is None else (hid, count, grad)
+    return _platform_pick(_rows_swiglu_pallas, *args)

@@ -707,6 +707,58 @@ SPECS["_contrib_ssd_scan"] = S(
     {"chunk": 4}, ref=_ssd_ref, rtol=1e-3, atol=1e-4)
 
 
+def _delta_rule_ref(q, k, v, g, beta):
+    """The gated delta rule, one token at a time."""
+    b, t, h, d = q.shape
+    state = np.zeros((b, h, d, v.shape[-1]))
+    o = np.zeros(v.shape)
+    for i in range(t):
+        state = state * np.exp(g[:, i])[..., None]
+        old = np.einsum("bhde,bhd->bhe", state, k[:, i])
+        state = state + (beta[:, i, :, None] * k[:, i])[..., None] \
+            * (v[:, i] - old)[..., None, :]
+        o[:, i] = np.einsum("bhde,bhd->bhe", state, q[:, i])
+    return o
+
+
+SPECS["_contrib_kda_scan"] = S(
+    [randn((1, 21, 2, 4), 160, 0.5), randn((1, 21, 2, 4), 161, 0.5),
+     randn((1, 21, 2, 3), 162), -pos((1, 21, 2, 4), 163),
+     pos((1, 21, 2), 164) / 1.5],
+    {"chunk": 16}, ref=_delta_rule_ref, rtol=1e-3, atol=1e-4)
+
+
+def _kda_attention_ref(q, k, v, decay, beta, gate, qc, kc, vc, a_log,
+                       dt_bias, o_norm):
+    """A KDA mixer between its projections: convolutions, norms, the
+    decay a channel, the delta rule, the head-wise norm and the gate."""
+    b, t, inner = q.shape
+    h = a_log.size
+    d = inner // h
+
+    def mixed(x, w):
+        x = _causal_conv_ref(x, w, 0.0)
+        return (x / (1 + np.exp(-x))).reshape(b, t, h, d)
+
+    def unit(x):
+        return x / np.sqrt((x * x).sum(-1, keepdims=True) + 1e-6)
+    g = -np.exp(a_log.reshape(h, 1)) * np.log1p(np.exp(
+        decay + dt_bias.reshape(-1))).reshape(b, t, h, d)
+    o = _delta_rule_ref(unit(mixed(q, qc)) * d ** -0.5, unit(mixed(k, kc)),
+                        mixed(v, vc), g, 1 / (1 + np.exp(-beta)))
+    o = o / np.sqrt((o * o).mean(-1, keepdims=True) + 1e-5) * o_norm
+    return (o / (1 + np.exp(-gate.reshape(b, t, h, d)))).reshape(b, t, inner)
+
+
+SPECS["_contrib_kda_attention"] = S(
+    [randn((1, 19, 8), 165), randn((1, 19, 8), 166), randn((1, 19, 8), 167),
+     randn((1, 19, 8), 168), randn((1, 19, 2), 169), randn((1, 19, 8), 170),
+     randn((8, 4), 171, 0.5), randn((8, 4), 172, 0.5),
+     randn((8, 4), 173, 0.5), randn((1, 2), 174, 0.3),
+     randn((2, 4), 175, 0.3), pos((4,), 176)],
+    {"chunk": 16}, ref=_kda_attention_ref, rtol=1e-3, atol=1e-4)
+
+
 def _router_ref(x, w, b):
     s = 1.0 / (1.0 + np.exp(-(x.astype(np.float64) @ w.T)))
     idx = np.argsort(-(s + b), axis=1, kind="stable")[:, :2]

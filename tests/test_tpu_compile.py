@@ -23,6 +23,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
+from mxnet_tpu.ops.kda import kda_scan
 from mxnet_tpu.ops.moe import grouped_ffn
 from mxnet_tpu.ops.paged_attention import paged_attention
 from mxnet_tpu.ops.pallas_kernels import _flash_bwd_pallas, flash_attention
@@ -90,20 +91,37 @@ def test_flash_backward_t512(one_chip):
         assert "%%%s." % name in text
 
 
-@pytest.mark.xfail(strict=True, raises=Exception,
-                   reason="RESOURCE_EXHAUSTED: Ran out of memory in memory "
-                          "space vmem while allocating on stack for ... "
-                          "custom_call_target=\"tpu_custom_call\": the dk/dv "
-                          "kernel holds whole-T q, dO, lse and delta per "
-                          "program (ROADMAP Queue 1)")
-def test_flash_backward_t4096(one_chip):
-    # the kernel pair called as flash_attention's vjp calls it.  Inside a
-    # larger program (jax.grad of the op) XLA still fits T=4096 and gives
-    # up at T=8192 with the same message.
-    bwd = functools.partial(_flash_bwd_pallas, scale=128 ** -0.5,
+@pytest.mark.parametrize("d_qk, d_v", [(128, 128), (192, 128)],
+                         ids=["d128", "d192_v128"])
+def test_flash_backward_t4096(one_chip, d_qk, d_v):
+    # the kernel pair called as flash_attention's vjp calls it, at 32 heads
+    # x 4096 tokens: the dk/dv kernel streams its query blocks over a grid
+    # axis (whole-T q, dO, lse and delta did not fit VMEM before PR 34), at
+    # one width for q, k and v and at latent attention's 192 / 128
+    bwd = functools.partial(_flash_bwd_pallas, scale=d_qk ** -0.5,
                             causal=True, block_q=512, block_k=512)
-    _compile(bwd, one_chip, *[((32, 4096, 128), jnp.bfloat16)] * 4,
-             *[((32, 4096, 128), jnp.float32)] * 2)
+    bf16 = jnp.bfloat16
+    c = _compile(bwd, one_chip, *[((32, 4096, d_qk), bf16)] * 2,
+                 *[((32, 4096, d_v), bf16)] * 2,
+                 *[((32, 4096, 128), jnp.float32)] * 2)
+    for name in ("mx_flash_bwd_dq", "mx_flash_bwd_dkv"):
+        assert "%%%s" % name in c.as_text()
+
+
+def test_flash_forward_and_backward_t4096_with_narrower_values(one_chip):
+    # through the operator: q, k of 192 channels, v of 128, no padded copy
+    # of v (no 192-wide value operand reaches a kernel)
+    def loss(q, k, v, w):
+        out = flash_attention(q, k, v, scale=192 ** -0.5, causal=True)
+        return (out * w).astype(jnp.float32).sum()
+    bf16 = jnp.bfloat16
+    c = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                 *[((1, 32, 4096, 192), bf16)] * 2,
+                 *[((1, 32, 4096, 128), bf16)] * 2)
+    text = c.as_text()
+    for name in ("mx_flash_fwd", "mx_flash_bwd_dq", "mx_flash_bwd_dkv"):
+        assert "%%%s" % name in text
+    assert "pad(" not in text
 
 
 def test_flash_on_a_mesh_runs_per_shard(topo):
@@ -275,3 +293,41 @@ def test_ssd_scan_forward_and_backward_fit(one_chip, seq):
                  ((1, 64), f32), ((2, seq, 8, 128), f32),
                  ((2, seq, 8, 128), f32), ((64,), f32), ((1, 64), f32))
     assert c.memory_analysis().temp_size_in_bytes < 512 << 20
+
+
+# -- the Kimi Linear cell's operators at its own sizes (PR 34) ----------------
+
+def test_kda_scan_forward_and_backward_fit(one_chip):
+    # one sequence of 4096 tokens, 32 heads x 128 channels, chunks of 64:
+    # what the backward keeps is the inputs; the chunk states, the solve
+    # and the products within a chunk are temporaries of one layer
+    def loss(*args):
+        return jnp.sum(jnp.square(kda_scan(*args, chunk=64)))
+    f32 = jnp.float32
+    c = _compile(jax.grad(loss, argnums=tuple(range(5))), one_chip,
+                 *[((1, 4096, 32, 128), f32)] * 4, ((1, 4096, 32), f32))
+    assert c.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
+def test_grouped_ffn_with_swiglu_experts(one_chip):
+    # 4096 tokens x 8 assignments over 8 held experts of 2304 x 1024, gate
+    # and up stacked (8, 2048, 2304): the same eight grouped products and
+    # the same kernels around them, with ``mx_rows_swiglu`` where the
+    # Nemotron cell has ``mx_rows_relu2``
+    bf16 = jnp.bfloat16
+
+    def loss(x, idx, w, up, down):
+        out, counts = grouped_ffn(x, idx, w, up.astype(bf16),
+                                  down.astype(bf16), activation="swiglu")
+        return jnp.sum(out * out), counts
+    c = _compile(jax.grad(loss, argnums=(0, 2, 3, 4), has_aux=True),
+                 one_chip, ((4096, 2304), bf16),
+                 ((4096, 8), jnp.int32), ((4096, 8), jnp.float32),
+                 ((8, 2048, 2304), jnp.float32),
+                 ((8, 2304, 1024), jnp.float32))
+    text = c.as_text()
+    kernels = re.findall(r"%(mx_\w+?)(?:\.\d+)* = [^=]+ custom-call\(", text)
+    assert sorted(kernels) == (
+        ["mx_gmm"] * 6 + ["mx_gmm_dw"] * 2 + ["mx_rows_combine"] * 2
+        + ["mx_rows_swiglu"] * 3 + ["mx_rows_take"] * 3), kernels
+    assert "ragged-dot" not in text
