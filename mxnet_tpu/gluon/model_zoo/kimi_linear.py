@@ -38,8 +38,10 @@ No projection has a bias.  Weights are their block's own parameters in the
 order the benchmark's plain reference writes them down
 (``benchmark/chip/archs/kimi_linear.py``); gate and up-projection are one
 stacked leaf ``(2F, D)`` (an expert layer's ``(count, 2F, D)``).  A
-``KDAMixer`` declares a step statistic, the chunks its scan ran
-(``kda/<layer>``), which feeds ``mxnet_kda_chunks_total``.
+``KDAMixer`` declares two step statistics: the chunks its scan ran
+(``kda/<layer>``), which feeds ``mxnet_kda_chunks_total``, and those of them
+whose scan took the Pallas kernels (``kda_kernel/<layer>``: all or none, by
+the shapes), which feeds ``mxnet_kda_kernel_chunks_total``.
 
 Not built: the latent paged cache and the absorbed decode of the MLA
 layers, a single-token form of the delta rule (serving).
@@ -49,7 +51,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ...ops.kda import kda_chunks
+from ...ops.kda import kda_chunks, kda_kernel_chunks
 from ...telemetry import metrics
 from .. import nn
 from ..block import HybridBlock, record_step_stat
@@ -57,6 +59,7 @@ from .llama import RMSNorm
 from .nemotron_h import MoEMixer, _dense, _feed_forward, _Mixer
 
 STAT_PREFIX = "kda/"      # a layer's statistic: "kda/<layer>"
+KERNEL_STAT_PREFIX = "kda_kernel/"
 _seen = {}                # (step, statistic) -> the count last read
 
 
@@ -67,6 +70,7 @@ class KDAMixer(_Mixer):
         inner = num_heads * head_dim
         self._cfg = (num_heads, head_dim, int(chunk_size), eps)
         self._stat = STAT_PREFIX + str(int(layer))
+        self._kernel_stat = KERNEL_STAT_PREFIX + str(int(layer))
         self._declare([
             ("q_proj", (inner, units), None),
             ("k_proj", (inner, units), None),
@@ -86,8 +90,10 @@ class KDAMixer(_Mixer):
             ("o_proj", (units, inner), None)])
 
     def step_stat_specs(self):
-        """Chunks the scan ran: batch x heads x chunks of the sequence."""
-        return {self._stat: ((1,), jnp.uint32)}
+        """Chunks the scan ran: batch x heads x chunks of the sequence; and
+        those whose scan took the Pallas kernels."""
+        return {self._stat: ((1,), jnp.uint32),
+                self._kernel_stat: ((1,), jnp.uint32)}
 
     def hybrid_forward(self, F, u, q_proj, k_proj, v_proj, q_conv, k_conv,
                        v_conv, A_log, f_a_proj, f_b_proj, dt_bias, b_proj,
@@ -104,6 +110,9 @@ class KDAMixer(_Mixer):
                 k_conv, v_conv, A_log, dt_bias, o_norm, chunk=chunk, eps=eps)
             record_step_stat(self._stat, jnp.full(
                 (1,), b * heads * kda_chunks(t, chunk), jnp.uint32))
+            record_step_stat(self._kernel_stat, jnp.full(
+                (1,), b * heads * kda_kernel_chunks(t, hd, hd, chunk),
+                jnp.uint32))
             return _dense(F, o, o_proj)
 
 
@@ -260,25 +269,34 @@ def kimi_linear_48b_a3b(vocab_size=163840, **kwargs):
     return KimiLinearModel(vocab_size, **cfg)
 
 
+_FAMILIES = {      # a statistic's prefix -> the counter it feeds
+    STAT_PREFIX: ("mxnet_kda_chunks_total", "ran"),
+    KERNEL_STAT_PREFIX: ("mxnet_kda_kernel_chunks_total",
+                         "ran in the Pallas kernels mx_kda_*")}
+
+
 def _telemetry_collector():
-    """The scans' chunk counts leave a ``JitTrainStep`` program as a
-    statistic it accumulates on the device; a snapshot fetches them and adds
-    what is new (modulo the accumulators' 32 bits) to the counter."""
+    """The scans' chunk counts leave a ``JitTrainStep`` program as
+    statistics it accumulates on the device; a snapshot fetches them (once,
+    both kinds) and adds what is new (modulo the accumulators' 32 bits) to
+    the counters."""
     from ...parallel.train_step import read_step_stats
 
-    every = read_step_stats(STAT_PREFIX)
-    if not every:       # no step of this process scans: no family either
-        return
-    new = 0
-    for owner, stats in every:
+    new = {}            # no step of this process scans: no family either
+    for owner, stats in read_step_stats("kda"):
         for name, count in stats.items():
-            count = int(count[0])
-            new += (count - _seen.get((owner, name), 0)) % (1 << 32)
-            _seen[(owner, name)] = count
-    metrics.counter("mxnet_kda_chunks_total",
-                    help="chunks the Kimi Delta Attention scans ran "
-                         "(sequences x heads x chunks, every such layer "
-                         "and train step)").inc(new)
+            prefix = name[:name.index("/") + 1]
+            if prefix in _FAMILIES:
+                count = int(count[0])
+                new[prefix] = new.get(prefix, 0) \
+                    + (count - _seen.get((owner, name), 0)) % (1 << 32)
+                _seen[(owner, name)] = count
+    for prefix, count in new.items():
+        family, what = _FAMILIES[prefix]
+        metrics.counter(family,
+                        help="chunks the Kimi Delta Attention scans %s "
+                             "(sequences x heads x chunks, every such layer "
+                             "and train step)" % what).inc(count)
 
 
 metrics.register_collector(_telemetry_collector)

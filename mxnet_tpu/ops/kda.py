@@ -40,19 +40,41 @@ the row's own (``e^24`` at the published range; float32 holds ``e^88``,
 before the exponential is taken.
 
 Everything is float32 whatever the inputs are (the operator is in
-``amp.lists.FP32_OPS``); the products are ``einsum``s at the default
-precision, the solve's own at the highest.  The backward pass is jax's
-through the chunked algebra under a checkpoint (``_made_again``): the
-operator's inputs are kept, the scan over chunks keeps the state each chunk
-starts from and its ``U``, and the rest is made again (the column factors
-of the overflow rule, ``chunk / SUB`` times the size of ``k``, under a
-checkpoint of their own).  No state a token ``(B, T, H, d, e)`` ever
-exists.
+``amp.lists.FP32_OPS``).  No state a token ``(B, T, H, d, e)`` ever exists.
+
+**Where a chunk's factors live: a static test of the shapes.**  Keys and
+values of whole 128-lane rows (``d`` and ``e`` multiples of 128; the chunk
+is whole sub-chunks by construction) take the Pallas kernels of
+``ops/kda_kernels.py``: ``mx_kda_fwd`` makes a chunk's ``cum``, row and
+column factors, ``A``, ``B``, the inverse and ``U`` in VMEM with the state in
+a scratch that lives across the sequential chunk axis, and only the
+operator's operands cross HBM; ``mx_kda_bwd`` walks the chunks in reverse
+with the state's cotangent in VMEM.  One ``custom_vjp`` holds the two: its
+forward pass, when a backward pass will follow, also writes the state each
+chunk starts from, the inverse and ``U`` (112 KB a head and chunk), which
+live from there to the backward kernel and no longer.  Every other shape
+(the tests' 8 and 12 channels) takes the ``jax.numpy`` form below, on every
+platform; there is no option and no fallback: a kernel the TPU compiler
+refuses at a tiling shape is an error.  Both are the same equations, the
+same overflow rule and the same precision: the running sums, exponentials,
+state and accumulations float32; the solve's products (the inverse, its
+application, its gradient) at ``HIGHEST``; every other product one bfloat16
+pass with float32 accumulation on a TPU (the ``einsum``s' default precision
+there; the kernels round their operands themselves).
+
+The ``jax.numpy`` form: the products are ``einsum``s, the inverse forward
+substitution over blocks of ``SUB`` rows and block merges, the chunks a
+``lax.scan``; the backward pass is jax's through the chunked algebra under a
+checkpoint (``_made_again``): the operator's inputs are kept, the scan over
+chunks keeps the state each chunk starts from and its ``U``, and the rest is
+made again (the column factors of the overflow rule, ``chunk / SUB`` times
+the size of ``k``, under a checkpoint of their own).
 
 ``_contrib_kda_attention`` is a whole mixer between its projections (the
 convolutions, the norms, the decay, ``beta``, the scan, the output norm
-and gate) under one such checkpoint, a group of heads at a time: what a
-layer keeps for its backward pass is the projections' results.
+and gate) under one checkpoint (``_made_again``), a group of heads at a
+time: what a layer keeps for its backward pass is the projections' results,
+and the scan inside is either form above.
 
 Shapes: ``q, k (B, T, H, d)``, ``v (B, T, H, e)``, ``g (B, T, H, d)``
 (log-decay, at most 0), ``beta (B, T, H)``; returns ``(B, T, H, e)`` in
@@ -67,13 +89,17 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import kda_kernels
+from .kda_kernels import SUB    # tokens of a sub-chunk: see the overflow rule
 from .registry import register
 from .ssm import causal_conv1d
 
-SUB = 16            # tokens of a sub-chunk: see the overflow rule
-# heads that ``kda_attention`` runs at once: its temporaries (some thirty
-# float32 arrays of a head group's ``(T, d)``) are 1.8 GB for 32 heads x 128
-# at 4096 tokens and a quarter of that for eight, at no cost in operations
+# heads that ``kda_attention`` runs at once: its temporaries (the float32
+# ``(T, d)`` arrays of a head group around the scan, and what the scan's
+# forward leaves for its backward: 7.3 MB a head at 4096 tokens in the
+# kernels, more in the ``jax.numpy`` form) grow with the heads, at no cost in
+# operations: 1.8 GB for 32 heads x 128 in the ``jax.numpy`` form, a quarter
+# of that for eight
 HEADS_AT_ONCE = 8
 HIGHEST = lax.Precision.HIGHEST
 
@@ -209,6 +235,14 @@ def _kda_chunked(q, k, v, g, beta, chunk):
         x = jnp.moveaxis(x, 2, 1)
         return x.reshape((b, h, n, c) + x.shape[3:])
     q, k, v, g = chunks(q), chunks(k), chunks(v), chunks(g)
+    if kda_kernels.tiles(d, e, c):
+        # the same algebra with a chunk's factors made, used and dropped in
+        # VMEM: a static test of the shapes, on every platform
+        out = kda_kernels.chunk_scan(
+            *(x.reshape(b * h, n * c, -1) for x in (q, k, v, g)),
+            chunks(beta).reshape(b * h, n * c), c)
+        out = out.reshape(b, h, t + pad, e)[:, :, :t]
+        return jnp.moveaxis(out, 1, 2).astype(out_dtype)
     beta = chunks(beta)[..., None]                     # (B, H, n, C, 1)
     cum = jnp.cumsum(g, axis=-2)                       # G, inclusive
     a, bm = _pair_products(q, k, cum)
@@ -341,6 +375,13 @@ def kda_attention(q, k, v, decay, beta, gate, q_conv, k_conv, v_conv, A_log,
 def kda_chunks(t, chunk=64):
     """Chunks a head's scan of ``t`` tokens is cut into."""
     return -(-t // _chunk_size(t, chunk))
+
+
+def kda_kernel_chunks(t, d, e, chunk=64):
+    """Those of them whose scan takes the Pallas kernels: all, where keys
+    of ``d`` and values of ``e`` channels tile, else none."""
+    tiles = kda_kernels.tiles(d, e, _chunk_size(t, chunk))
+    return kda_chunks(t, chunk) if tiles else 0
 
 
 def kda_recurrence(q, k, v, g, beta):

@@ -307,6 +307,26 @@ def test_kda_scan_forward_and_backward_fit(one_chip):
     c = _compile(jax.grad(loss, argnums=tuple(range(5))), one_chip,
                  *[((1, 4096, 32, 128), f32)] * 4, ((1, 4096, 32), f32))
     assert c.memory_analysis().temp_size_in_bytes < 3 << 30
+    # the scan is the Pallas kernels (PR 35): the forward, the forward again
+    # with what the backward reads (the chunks' states, inverses and U:
+    # 112 KB a head and chunk, 235 MB for 32 heads), and the backward
+    kernels = re.findall(r"%(mx_\w+?)(?:\.\d+)* = [^=]+ custom-call\(",
+                         c.as_text())
+    assert sorted(kernels) == ["mx_kda_bwd", "mx_kda_fwd", "mx_kda_fwd"]
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("chunk", [16, 32], ids=["chunk16", "chunk32"])
+def test_kda_kernels_at_shorter_chunks(one_chip, chunk):
+    # a chunk of one sub-chunk and of two take the kernels too (the static
+    # test asks for whole sub-chunks): values wider than keys, eight heads
+    def loss(*args):
+        return jnp.sum(jnp.square(kda_scan(*args, chunk=chunk)))
+    f32 = jnp.float32
+    c = _compile(jax.grad(loss, argnums=tuple(range(5))), one_chip,
+                 *[((1, 512, 8, 128), f32)] * 2, ((1, 512, 8, 256), f32),
+                 ((1, 512, 8, 128), f32), ((1, 512, 8), f32))
+    assert "mx_kda_bwd" in c.as_text()
 
 
 def test_grouped_ffn_with_swiglu_experts(one_chip):
