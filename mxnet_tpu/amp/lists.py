@@ -91,6 +91,12 @@ FP32_OPS = [
     # the carried state (ops/kda.py; ``_contrib_kda_attention`` keeps its
     # inputs as they come and is float32 inside, decay and beta included)
     "_contrib_kda_scan",
+    # NOT listed, like ``_contrib_kda_attention``: the rotary embedding of
+    # adjacent pairs (ops/rotary.py, ``_contrib_rotary_embedding``) takes
+    # its data as it comes and hands back the same dtype (a float32 copy of
+    # every query head would double the traffic into the flash kernels);
+    # its angles, sines and cosines are float32 from integer positions and
+    # the rotation is float32 inside whatever the data's dtype
 ]
 
 # multi-input ops whose inputs are cast to the widest participating dtype

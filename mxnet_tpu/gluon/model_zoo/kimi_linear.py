@@ -20,12 +20,14 @@ one the routed.
   scope ``kda_scan``; one checkpoint, so that a layer keeps its
   projections' results and not sixteen float32 arrays of them).
 - ``MLAMixer`` (scope ``mla``): latent attention materialised for
-  training, **no rotary embedding** (``mla_use_nope``: the ``rope``
-  channels are plain channels): ``q = W_q u`` as heads of ``nope + rope``;
-  ``[c | k_r] = W_kva u``; ``[k_n | v] = W_kvb RMSNorm(c)`` a head; ``k =
-  [k_n | k_r]``, ``k_r`` shared by all heads; causal softmax attention
-  through the flash kernels with keys of ``nope + rope`` channels and
-  values of ``v_head_dim``; ``W_o``.  No absorbed projections, no cache.
+  training, here with **no rotary embedding** (``mla_use_nope``: the
+  ``rope`` channels are plain channels) and a full-rank query: ``q = W_q
+  u`` as heads of ``nope + rope``; ``[c | k_r] = W_kva u``; ``[k_n | v] =
+  W_kvb RMSNorm(c)`` a head; ``k = [k_n | k_r]``, ``k_r`` shared by all
+  heads; causal softmax attention through the flash kernels with keys of
+  ``nope + rope`` channels and values of ``v_head_dim``; ``W_o``.  No
+  absorbed projections, no cache.  The same class, given ``q_lora_rank``
+  and ``rope_theta``, is ``joyai_llm_flash``'s mixer.
 - ``MoEFeedForward`` (scope ``moe``, with ``moe_router``, ``moe_experts``,
   ``moe_shared`` inside): ``nemotron_h.MoEMixer`` with the ``swiglu``
   activation: sigmoid top-k routing with a score-correction bias, the held
@@ -117,39 +119,64 @@ class KDAMixer(_Mixer):
 
 
 class MLAMixer(_Mixer):
+    """Latent attention.  ``q_lora_rank`` (None: ``q = W_q u`` at full
+    rank, the leaf ``q_proj``) compresses the query, ``q = W_qb
+    RMSNorm(W_qa u)`` (leaves ``q_a_proj``, ``q_a_norm``, ``q_b_proj`` in
+    ``q_proj``'s place); ``rope_theta`` (None: no rotation, the ``rope``
+    channels are plain channels) is the base of a rotary embedding on the
+    ``rope`` channels of every query head and of the one rope key a token,
+    adjacent channels a pair (``F.contrib.rotary_embedding``).  Under the
+    scope ``mla``; inside it ``mla_rope`` holds the rotation, the repeat of
+    the rope key over the heads and the concatenation that builds the
+    keys."""
+
     def __init__(self, units, num_heads, kv_lora_rank, qk_nope_head_dim,
-                 qk_rope_head_dim, v_head_dim, eps=1e-5, prefix=None,
-                 params=None):
+                 qk_rope_head_dim, v_head_dim, eps=1e-5, q_lora_rank=None,
+                 rope_theta=None, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         self._cfg = (num_heads, kv_lora_rank, qk_nope_head_dim,
-                     qk_rope_head_dim, v_head_dim, eps)
+                     qk_rope_head_dim, v_head_dim, eps, rope_theta)
         qk = qk_nope_head_dim + qk_rope_head_dim
-        self._declare([
-            ("q_proj", (num_heads * qk, units), None),
+        self._declare(
+            ([("q_proj", (num_heads * qk, units), None)]
+             if q_lora_rank is None else
+             [("q_a_proj", (q_lora_rank, units), None),
+              ("q_a_norm", (q_lora_rank,), "ones"),
+              ("q_b_proj", (num_heads * qk, q_lora_rank), None)]) + [
             ("kv_a_proj", (kv_lora_rank + qk_rope_head_dim, units), None),
             ("kv_a_norm", (kv_lora_rank,), "ones"),
             ("kv_b_proj", (num_heads * (qk_nope_head_dim + v_head_dim),
                            kv_lora_rank), None),
             ("o_proj", (units, num_heads * v_head_dim), None)])
 
-    def hybrid_forward(self, F, u, q_proj, kv_a_proj, kv_a_norm, kv_b_proj,
-                       o_proj):
-        h, rank, nope, rope, vd, eps = self._cfg
+    def hybrid_forward(self, F, u, kv_a_proj, kv_a_norm, kv_b_proj, o_proj,
+                       q_proj=None, q_a_proj=None, q_a_norm=None,
+                       q_b_proj=None):
+        h, rank, nope, rope, vd, eps, theta = self._cfg
         b, t, _ = u.shape
         with jax.named_scope("mla"):
             def heads(x, width):
                 return F.transpose(F.reshape(x, shape=(b, t, -1, width)),
                                    axes=(0, 2, 1, 3))
-            q = heads(_dense(F, u, q_proj), nope + rope)
+            if q_proj is not None:
+                q = _dense(F, u, q_proj)
+            else:
+                q = _dense(F, F.RMSNorm(_dense(F, u, q_a_proj), q_a_norm,
+                                        axis=-1, eps=eps), q_b_proj)
+            q = heads(q, nope + rope)
             kv_a = _dense(F, u, kv_a_proj)
             latent = F.RMSNorm(F.slice_axis(kv_a, axis=2, begin=0, end=rank),
                                kv_a_norm, axis=-1, eps=eps)
-            k_rope = F.broadcast_axis(
-                heads(F.slice_axis(kv_a, axis=2, begin=rank, end=None),
-                      rope), axis=1, size=h)
+            k_rope = heads(F.slice_axis(kv_a, axis=2, begin=rank, end=None),
+                           rope)                      # one a token
             kv = heads(_dense(F, latent, kv_b_proj), nope + vd)
-            k = F.concat(F.slice_axis(kv, axis=3, begin=0, end=nope),
-                         k_rope, dim=3)
+            with jax.named_scope("mla_rope"):
+                if theta is not None:
+                    q = F.contrib.rotary_embedding(q, theta=theta,
+                                                   rotary_dim=rope)
+                    k_rope = F.contrib.rotary_embedding(k_rope, theta=theta)
+                k = F.concat(F.slice_axis(kv, axis=3, begin=0, end=nope),
+                             F.broadcast_axis(k_rope, axis=1, size=h), dim=3)
             v = F.slice_axis(kv, axis=3, begin=nope, end=None)
             out = F.contrib.flash_attention(
                 q, k, v, scale=(nope + rope) ** -0.5, causal=True)

@@ -17,8 +17,10 @@ recomputes p blockwise to accumulate dq over k-blocks, and a second
 accumulates dk/dv over q-blocks.  No (T, T) buffer exists in either
 direction, so long-context TRAINING runs at O(T·D) memory; ring attention
 (parallel/ring_attention.py) composes on top to shard T across chips.
-The forward and dq kernels hold a head's whole K and V in VMEM and loop
-over key blocks; the dk/dv kernel streams query blocks over a grid axis
+The forward and dq kernels hold a head's whole K and V in VMEM (read from
+HBM once a head; past the compiler's own 16 MiB they ask for a limit of
+their own, ``_flash_params``: T=8192 at 192 / 128) and loop over key
+blocks; the dk/dv kernel streams query blocks over a grid axis
 into float32 accumulators (whole-T q, dO, lse and delta did not fit at
 T=4096).  Under the causal mask no kernel computes a block that the mask
 hides.  Values may be narrower than keys (latent attention: keys of 192
@@ -138,6 +140,25 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
     lse_ref[0] = lax.broadcast_in_dim(lse, (block_q, _LANES), (0,))
 
 
+def _flash_params(k, v):
+    """What the forward and dq kernels are compiled with: nothing where a
+    head's K and V, double-buffered, leave the compiler's own 16 MiB of
+    scoped VMEM room for the rest (every length to 4096 at 192 / 128); a
+    limit that holds them where they do not (T=8192 at 192 / 128: 12 MiB
+    of the v5e's 128).  Keeping a head's keys in VMEM reads them from HBM
+    once a head; a grid axis over key blocks would read them once a query
+    block, 84 MB a head at T=8192, as long as the products take."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    def lanes(width):
+        return -(-width // _LANES) * _LANES
+    held = 2 * k.shape[1] * (lanes(k.shape[2]) * k.dtype.itemsize
+                             + lanes(v.shape[2]) * v.dtype.itemsize)
+    if held <= 8 << 20:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=held + (16 << 20))
+
+
 def _flash_pallas(q, k, v, scale, causal, block_q, block_k,
                   interpret=False):
     from jax.experimental import pallas as pl
@@ -163,6 +184,7 @@ def _flash_pallas(q, k, v, scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((bh, t_q, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, t_q, _LANES), jnp.float32),
         ],
+        compiler_params=_flash_params(k, v),
         interpret=interpret,
         name="mx_flash_fwd",
     )(q, k, v)
@@ -308,6 +330,7 @@ def _flash_bwd_pallas(q, k, v, do, lse, delta, scale, causal, block_q,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t_q, d), q.dtype),
+        compiler_params=_flash_params(k, v),
         interpret=interpret,
         name="mx_flash_bwd_dq",
     )(q, k, v, do, lse, delta)

@@ -759,6 +759,23 @@ SPECS["_contrib_kda_attention"] = S(
     {"chunk": 16}, ref=_kda_attention_ref, rtol=1e-3, atol=1e-4)
 
 
+def _rotary_ref(x):
+    """Adjacent channel pairs of the last 4 of 6 channels turned at the
+    row's position, theta 100."""
+    t = x.shape[-2]
+    phi = np.arange(t)[:, None] * 100.0 ** (-2.0 * np.arange(2) / 4)
+    out = x.astype(np.float64).copy()
+    a, b = x[..., 2::2], x[..., 3::2]
+    out[..., 2::2] = a * np.cos(phi) - b * np.sin(phi)
+    out[..., 3::2] = a * np.sin(phi) + b * np.cos(phi)
+    return out
+
+
+SPECS["_contrib_rotary_embedding"] = S(
+    [randn((2, 3, 5, 6), 177)], {"theta": 100.0, "rotary_dim": 4},
+    ref=_rotary_ref, grad=True)
+
+
 def _router_ref(x, w, b):
     s = 1.0 / (1.0 + np.exp(-(x.astype(np.float64) @ w.T)))
     idx = np.argsort(-(s + b), axis=1, kind="stable")[:, :2]

@@ -351,3 +351,115 @@ def test_grouped_ffn_with_swiglu_experts(one_chip):
         ["mx_gmm"] * 6 + ["mx_gmm_dw"] * 2 + ["mx_rows_combine"] * 2
         + ["mx_rows_swiglu"] * 3 + ["mx_rows_take"] * 3), kernels
     assert "ragged-dot" not in text
+
+
+# -- the JoyAI-LLM Flash cell's operators at its own sizes (PR 36) ------------
+
+def test_flash_forward_and_backward_t8192_at_latent_attentions_widths(
+        one_chip):
+    # 32 heads x 8192 tokens, keys of 192 channels and values of 128: the
+    # forward and dq kernels hold a head's K and V in VMEM (12 MiB
+    # double-buffered, past the compiler's own 16 MiB with the rest) under
+    # a limit of their own (``_flash_params``)
+    def loss(q, k, v, w):
+        out = flash_attention(q, k, v, scale=192 ** -0.5, causal=True)
+        return (out * w).astype(jnp.float32).sum()
+    bf16 = jnp.bfloat16
+    c = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                 *[((1, 32, 8192, 192), bf16)] * 2,
+                 *[((1, 32, 8192, 128), bf16)] * 2)
+    text = c.as_text()
+    for name in ("mx_flash_fwd", "mx_flash_bwd_dq", "mx_flash_bwd_dkv"):
+        assert "%%%s" % name in text
+    assert "pad(" not in text
+
+
+def _traced(block):
+    """``fn(weights, *inputs)``: ``block`` over raw arrays, its parameters
+    the ``weights`` handed in (what ``JitTrainStep`` does to a network)."""
+    from mxnet_tpu import autograd
+    from mxnet_tpu.gluon import block as block_mod
+    from mxnet_tpu.ndarray import NDArray
+
+    params = list(block.collect_params().values())
+
+    def fn(weights, *inputs):
+        st = block_mod._trace_st()
+        was = (st.param_map, st.aux_updates, st.active)
+        st.param_map = {id(p): NDArray(w) for p, w in zip(params, weights)}
+        st.aux_updates, st.active = [], True
+        try:
+            with autograd.train_mode():
+                return block._forward_imperative(
+                    *[NDArray(x) for x in inputs]).data()
+        finally:
+            st.param_map, st.aux_updates, st.active = was
+    return fn, params
+
+
+def test_the_latent_attention_mixer_fits_at_8192_tokens(one_chip):
+    # JoyAI-LLM Flash's mixer at its published sizes under bfloat16 AMP,
+    # forward and backward over one sequence of 8192 tokens: the three
+    # flash kernels once each, nothing made again.  A layer alone holds
+    # 1.15 GB at once; the first of two keeps 0.69 GB while the second runs
+    # (q, k, v, W_kvb's result and their copies), which is what the step's
+    # five layers are sized on (PERF.md 6, PR 36: 6.8 GB of temporaries
+    # beside 8.3 GB of state)
+    import mxnet_tpu as mx
+    from mxnet_tpu import amp
+    from mxnet_tpu.gluon.model_zoo import kimi_linear
+
+    mixer = kimi_linear.MLAMixer(2048, 32, 512, 128, 64, 128, 1e-6,
+                                 q_lora_rank=1536, rope_theta=32000000)
+    mixer.initialize(mx.init.Zero())
+    fn, params = _traced(mixer)
+
+    def loss(weights, u, w, layers):
+        for _ in range(layers):
+            u = u + fn(weights, u).astype(jnp.float32)
+        return jnp.sum(u * w)
+    amp.init("bfloat16")
+    try:
+        avals = ([jax.ShapeDtypeStruct(p.shape, jnp.float32,
+                                       sharding=one_chip) for p in params],
+                 jax.ShapeDtypeStruct((1, 8192, 2048), jnp.float32,
+                                      sharding=one_chip),
+                 jax.ShapeDtypeStruct((1, 8192, 2048), jnp.float32,
+                                      sharding=one_chip))
+        one, two = (jax.jit(jax.grad(functools.partial(loss, layers=n),
+                                     argnums=(0, 1))).lower(*avals).compile()
+                    for n in (1, 2))
+    finally:
+        amp.turn_off()
+    kernels = re.findall(r"%(mx_\w+?)(?:\.\d+)* = [^=]+ custom-call\(",
+                         one.as_text())
+    assert sorted(kernels) == ["mx_flash_bwd_dkv", "mx_flash_bwd_dq",
+                               "mx_flash_fwd"]
+    temp = one.memory_analysis().temp_size_in_bytes
+    kept = two.memory_analysis().temp_size_in_bytes - temp
+    assert temp < 2 << 30
+    assert kept < 1 << 30
+
+
+def test_grouped_ffn_with_swiglu_experts_of_768_over_8192_tokens(one_chip):
+    # 8192 tokens x 8 assignments over 8 held experts of 2048 x 768, gate
+    # and up stacked (8, 1536, 2048), a layout of 65,536 rows: the same
+    # kernels as the Kimi cell's, and the (8192, 2048) float32 array they
+    # index by token is cut along its columns to fit VMEM
+    bf16 = jnp.bfloat16
+
+    def loss(x, idx, w, up, down):
+        out, counts = grouped_ffn(x, idx, w, up.astype(bf16),
+                                  down.astype(bf16), activation="swiglu")
+        return jnp.sum(out * out), counts
+    c = _compile(jax.grad(loss, argnums=(0, 2, 3, 4), has_aux=True),
+                 one_chip, ((8192, 2048), bf16),
+                 ((8192, 8), jnp.int32), ((8192, 8), jnp.float32),
+                 ((8, 1536, 2048), jnp.float32),
+                 ((8, 2048, 768), jnp.float32))
+    text = c.as_text()
+    kernels = re.findall(r"%(mx_\w+?)(?:\.\d+)* = [^=]+ custom-call\(", text)
+    assert sorted(kernels) == (
+        ["mx_gmm"] * 6 + ["mx_gmm_dw"] * 2 + ["mx_rows_combine"] * 2
+        + ["mx_rows_swiglu"] * 3 + ["mx_rows_take"] * 3), kernels
+    assert "ragged-dot" not in text
