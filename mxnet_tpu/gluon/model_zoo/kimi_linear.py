@@ -58,11 +58,11 @@ from ...telemetry import metrics
 from .. import nn
 from ..block import HybridBlock, record_step_stat
 from .llama import RMSNorm
-from .nemotron_h import MoEMixer, _dense, _feed_forward, _Mixer
+from .nemotron_h import (MoEMixer, _dense, _feed_forward, _Mixer,
+                         chunk_counters)
 
 STAT_PREFIX = "kda/"      # a layer's statistic: "kda/<layer>"
 KERNEL_STAT_PREFIX = "kda_kernel/"
-_seen = {}                # (step, statistic) -> the count last read
 
 
 class KDAMixer(_Mixer):
@@ -296,34 +296,5 @@ def kimi_linear_48b_a3b(vocab_size=163840, **kwargs):
     return KimiLinearModel(vocab_size, **cfg)
 
 
-_FAMILIES = {      # a statistic's prefix -> the counter it feeds
-    STAT_PREFIX: ("mxnet_kda_chunks_total", "ran"),
-    KERNEL_STAT_PREFIX: ("mxnet_kda_kernel_chunks_total",
-                         "ran in the Pallas kernels mx_kda_*")}
-
-
-def _telemetry_collector():
-    """The scans' chunk counts leave a ``JitTrainStep`` program as
-    statistics it accumulates on the device; a snapshot fetches them (once,
-    both kinds) and adds what is new (modulo the accumulators' 32 bits) to
-    the counters."""
-    from ...parallel.train_step import read_step_stats
-
-    new = {}            # no step of this process scans: no family either
-    for owner, stats in read_step_stats("kda"):
-        for name, count in stats.items():
-            prefix = name[:name.index("/") + 1]
-            if prefix in _FAMILIES:
-                count = int(count[0])
-                new[prefix] = new.get(prefix, 0) \
-                    + (count - _seen.get((owner, name), 0)) % (1 << 32)
-                _seen[(owner, name)] = count
-    for prefix, count in new.items():
-        family, what = _FAMILIES[prefix]
-        metrics.counter(family,
-                        help="chunks the Kimi Delta Attention scans %s "
-                             "(sequences x heads x chunks, every such layer "
-                             "and train step)" % what).inc(count)
-
-
-metrics.register_collector(_telemetry_collector)
+metrics.register_collector(
+    chunk_counters("kda", "Kimi Delta Attention scans", "mx_kda_*"))

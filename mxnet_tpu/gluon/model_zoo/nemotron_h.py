@@ -33,7 +33,11 @@ moe_hidden)``.  Blocks open ``jax.named_scope``s (``mamba2``, ``moe`` with
 ``moe_router`` / ``moe_experts`` / ``moe_shared`` inside, ``attention``)
 so that a device trace tells the kinds apart.  An ``E`` block
 declares a step statistic (``step_stat_specs``): the assignments that
-landed on each held expert, accumulated by ``parallel.JitTrainStep``.
+landed on each held expert, accumulated by ``parallel.JitTrainStep``.  An
+``M`` block declares two: the chunks its scan ran (``ssd/<layer>``), which
+feeds ``mxnet_ssd_chunks_total``, and those of them whose scan took the
+Pallas kernels (``ssd_kernel/<layer>``: all or none, by the shapes), which
+feeds ``mxnet_ssd_kernel_chunks_total``.
 
 Not built: the second (denoiser) tower, adaLN and block-diffusion
 generation of the TwoTower release.
@@ -45,6 +49,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ...ops.ssm import ssd_chunks, ssd_kernel_chunks
+from ...telemetry import metrics
 from .. import nn
 from ..block import HybridBlock, record_step_stat
 from .llama import RMSNorm
@@ -80,15 +86,51 @@ class _Mixer(HybridBlock):
                 name, shape=shape, init=init, allow_deferred_init=False))
 
 
+def chunk_counters(stem, scans, kernels):
+    """A telemetry collector for the chunk counts of one kind of scan.  They
+    leave a ``JitTrainStep`` program as the statistics ``<stem>/<layer>``
+    (chunks the scans ran) and ``<stem>_kernel/<layer>`` (those that ran in
+    the Pallas kernels), which it accumulates on the device; a snapshot
+    fetches them (once, both kinds) and adds what is new (modulo the
+    accumulators' 32 bits) to ``mxnet_<stem>_chunks_total`` and
+    ``mxnet_<stem>_kernel_chunks_total``."""
+    families = {stem + "/": ("mxnet_%s_chunks_total" % stem, "ran"),
+                stem + "_kernel/": ("mxnet_%s_kernel_chunks_total" % stem,
+                                    "ran in the Pallas kernels " + kernels)}
+    seen = {}               # (step, statistic) -> the count last read
+
+    def collect():
+        from ...parallel.train_step import read_step_stats
+
+        new = {}        # no step of this process scans: no family either
+        for owner, stats in read_step_stats(stem):
+            for name, count in stats.items():
+                prefix = name[:name.index("/") + 1]
+                if prefix in families:
+                    count = int(count[0])
+                    new[prefix] = new.get(prefix, 0) \
+                        + (count - seen.get((owner, name), 0)) % (1 << 32)
+                    seen[(owner, name)] = count
+        for prefix, count in new.items():
+            family, what = families[prefix]
+            metrics.counter(family,
+                            help="chunks the %s %s (sequences x heads x "
+                                 "chunks, every such layer and train step)"
+                                 % (scans, what)).inc(count)
+    return collect
+
+
 class Mamba2Mixer(_Mixer):
     def __init__(self, units, num_heads, head_dim, n_groups, state_size,
-                 conv_kernel=4, chunk_size=128, eps=1e-5, prefix=None,
-                 params=None):
+                 conv_kernel=4, chunk_size=128, eps=1e-5, layer=0,
+                 prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         inner = num_heads * head_dim
         conv = inner + 2 * n_groups * state_size
         self._cfg = (inner, num_heads, head_dim, n_groups, state_size,
                      int(chunk_size), eps)
+        self._stat = "ssd/%d" % layer
+        self._kernel_stat = "ssd_kernel/%d" % layer
         self._declare([
             ("in_proj", (2 * inner + 2 * n_groups * state_size + num_heads,
                          units), None),
@@ -100,6 +142,12 @@ class Mamba2Mixer(_Mixer):
             ("D", (num_heads,), "ones"),
             ("norm_weight", (inner,), "ones"),
             ("out_proj", (units, inner), None)])
+
+    def step_stat_specs(self):
+        """Chunks the scan ran: batch x heads x chunks of the sequence; and
+        those whose scan took the Pallas kernels."""
+        return {self._stat: ((1,), jnp.uint32),
+                self._kernel_stat: ((1,), jnp.uint32)}
 
     def hybrid_forward(self, F, u, in_proj, conv_weight, conv_bias, dt_bias,
                        A_log, D, norm_weight, out_proj):
@@ -126,6 +174,11 @@ class Mamba2Mixer(_Mixer):
                            shape=(b, t, groups, state))
             y = F.contrib.ssd_scan(x, dt, A_log, bm, cm, D, dt_bias,
                                    chunk=chunk)
+            record_step_stat(self._stat, jnp.full(
+                (1,), b * heads * ssd_chunks(t, chunk), jnp.uint32))
+            record_step_stat(self._kernel_stat, jnp.full(
+                (1,), b * heads * ssd_kernel_chunks(
+                    t, heads, hd, groups, state, chunk), jnp.uint32))
             y = F.reshape(y, shape=(b, t, inner)) \
                 * F.Activation(z, act_type="silu")
             y = F.RMSNorm(
@@ -249,7 +302,7 @@ class NemotronHModel(HybridBlock):
             if kind == "M":
                 return lambda prefix: Mamba2Mixer(
                     units, mamba_num_heads, mamba_head_dim, n_groups,
-                    ssm_state_size, conv_kernel, chunk_size, eps,
+                    ssm_state_size, conv_kernel, chunk_size, eps, layer=i,
                     prefix=prefix)
             if kind == "E":
                 return lambda prefix: MoEMixer(
@@ -293,3 +346,5 @@ def nemotron_h_30b_a3b(vocab_size=131072, **kwargs):
     cfg.update(kwargs)
     return NemotronHModel(vocab_size, **cfg)
 
+
+metrics.register_collector(chunk_counters("ssd", "Mamba-2 scans", "mx_ssd_*"))

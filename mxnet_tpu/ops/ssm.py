@@ -19,17 +19,33 @@ computed in its chunked ("state-space dual") form: the sequence is cut
 into chunks of ``chunk`` tokens; within a chunk the output is a masked
 product of ``C B^T`` with the decays between every pair of its tokens
 (attention-like, quadratic in ``chunk``), between chunks one state a chunk
-is carried (linear in the sequence).  Every chunked product is an
-``einsum`` that XLA puts on the MXU; the decays, ``dt`` and the carried
+is carried (linear in the sequence).  The decays, ``dt`` and the carried
 state are float32 whatever the inputs are (both operators are in
-``amp.lists.FP32_OPS``, so under AMP their inputs arrive as float32, and a
-float32 product at the default precision is the MXU's bfloat16 passes with
-float32 accumulation).
+``amp.lists.FP32_OPS``, so under AMP their inputs arrive as float32); every
+product rounds its operands to bfloat16 once and accumulates in float32 (a
+float32 ``einsum`` at the default precision on a TPU).  Nothing of the size
+"a state a token" ``(B, T, H, P, N)`` ever exists.
 
-The backward pass is jax's own through the chunked algebra, under
-``jax.checkpoint``: what is kept from the forward pass is the operator's
-inputs, and the chunk states and within-chunk products are made again.
-Nothing of the size "a state a token" ``(B, T, H, P, N)`` ever exists.
+**Where a chunk's decays and state live: a static test of the shapes**
+(``ssd_kernels.tiles``).  A group's heads of whole 128-lane rows (``H / G
+* P`` a multiple of 128), states of whole rows and chunks of 128 take the
+Pallas kernels of ``ops/ssd_kernels.py``: ``mx_ssd_fwd`` makes a chunk's
+decays ``(H / G, L, L)``, ``C B^T``, the within-chunk result, the carried
+term and the new state in VMEM, with the state of a group's heads in a
+scratch that lives across the sequential chunk axis, and only the
+operator's operands and result cross HBM; ``mx_ssd_bwd`` walks the chunks
+in reverse with the state's cotangent in VMEM.  One ``custom_vjp`` holds the
+two: what it keeps for the backward pass is the operator's inputs and the
+state each chunk starts from, which the forward writes when a backward pass
+will follow.  What is one number a token and head (the softplus, ``dt * A``,
+its running sum inside a chunk) stays ``jax.numpy`` around the kernels, with
+jax's own gradients.  Every other shape (the tests' 8 channels and 16
+states) takes the ``jax.numpy`` form below, ``_ssd_chunked``, on every
+platform: every chunked product an ``einsum`` that XLA puts on the MXU, the
+backward pass jax's own through the chunked algebra under
+``jax.checkpoint`` (the operator's inputs are kept, the chunk states and
+within-chunk products made again).  It is the definition the kernels are
+held to, with ``ssd_recurrence``.  There is no option and no fallback.
 
 Shapes: ``x (B, T, H, P)``, ``dt (B, T, H)``, ``A_log``/``D``/``dt_bias``
 ``(H,)`` (or any shape of ``H`` elements), ``B``/``C`` ``(B, T, G, N)``
@@ -43,6 +59,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import ssd_kernels
 from .registry import register
 
 
@@ -121,6 +138,47 @@ def _ssd_chunked(x, dt, a_log, bmat, cmat, d_skip, dt_bias, chunk):
     return y.astype(out_dtype)
 
 
+def _ssd_kernels(x, dt, a_log, bmat, cmat, d_skip, dt_bias, chunk):
+    """The same operator with a chunk's decays and state in VMEM
+    (``ops/ssd_kernels.py``).  What is one number a token and head stays
+    here, float32: the softplus, ``dt * A`` and its running sum inside a
+    chunk, and jax's own gradients of them."""
+    f32 = jnp.float32
+    b, t, h, p = x.shape
+    g = bmat.shape[2]
+    dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32).reshape(h))
+    a = -jnp.exp(a_log.astype(f32).reshape(h))
+    x, bmat, cmat = (v.reshape(b, t, -1) for v in (x, bmat, cmat))
+    pad = -t % chunk
+    if pad:
+        # a padded token has dt = 0: it decays nothing and adds nothing
+        x, dt, bmat, cmat = (jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
+                             for v in (x, dt, bmat, cmat))
+    n = (t + pad) // chunk
+    dt = dt.reshape(b, n, chunk, h)
+    cum = jnp.cumsum(dt * a, axis=2)
+
+    def along_lanes(v):
+        # (B, n, L, H) -> (B, H, n, 1, L)
+        return jnp.transpose(v, (0, 3, 1, 2))[:, :, :, None]
+    y = ssd_kernels.chunk_scan(
+        x, along_lanes(dt), along_lanes(cum), bmat, cmat,
+        jnp.repeat(d_skip.astype(f32).reshape(h), p)[None], g)
+    return y[:, :t].reshape(b, t, h, p)
+
+
+def ssd_chunks(t, chunk=128):
+    """Chunks a head's scan of ``t`` tokens is cut into."""
+    return -(-t // int(chunk))
+
+
+def ssd_kernel_chunks(t, heads, head_dim, groups, state, chunk=128):
+    """Those of them whose scan takes the Pallas kernels: all, where a
+    group's heads and the states tile, else none."""
+    takes = ssd_kernels.tiles(heads, head_dim, groups, state, int(chunk))
+    return ssd_chunks(t, chunk) if takes else 0
+
+
 @register("_contrib_ssd_scan",
           inputs=("x", "dt", "A_log", "B", "C", "D", "dt_bias"))
 def ssd_scan(x, dt, A_log, B, C, D, dt_bias, chunk=128):
@@ -129,7 +187,11 @@ def ssd_scan(x, dt, A_log, B, C, D, dt_bias, chunk=128):
     Returns ``y (B, T, H, P)`` in ``x``'s dtype.  ``dt`` is the raw
     projection: the softplus and the bias are applied here, in float32."""
     with jax.named_scope("ssd_scan"):
-        fn = jax.checkpoint(functools.partial(_ssd_chunked, chunk=int(chunk)))
+        if ssd_kernels.tiles(*x.shape[2:], *B.shape[2:], int(chunk)):
+            fn = functools.partial(_ssd_kernels, chunk=int(chunk))
+        else:
+            fn = jax.checkpoint(
+                functools.partial(_ssd_chunked, chunk=int(chunk)))
         return fn(x, dt, A_log, B, C, D, dt_bias)
 
 

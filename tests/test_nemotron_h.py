@@ -164,6 +164,12 @@ def test_three_adamw_steps_match_the_reference_and_count_every_assignment():
     # the routing counts rode out of the step program: two expert layers,
     # 32 tokens x 3 experts a step, three steps; nothing fetched till now
     stats = step.step_stats()
+    # the two Mamba-2 layers' chunk counts ride along (PR 37): 2 sequences
+    # x 4 heads x 2 chunks of 8 a step, none of them in the Pallas kernels
+    # at 8 channels a head
+    scans = {k: stats.pop(k) for k in sorted(stats) if k.startswith("ssd")}
+    assert sorted(scans) == ["ssd/0", "ssd/2", "ssd_kernel/0", "ssd_kernel/2"]
+    assert [int(v[0]) for v in scans.values()] == [3 * 16, 3 * 16, 0, 0]
     assert sorted(stats) == ["moe/1/4", "moe/4/4"]
     for counts in stats.values():
         assert counts.dtype == np.uint32 and counts.shape == (4 + 3,)
