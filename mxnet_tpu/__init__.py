@@ -16,6 +16,10 @@ Usage mirrors MXNet::
 """
 from __future__ import annotations
 
+import time as _time
+
+_T_IMPORT = _time.perf_counter()    # mx:setup.import, closed at the end
+
 __version__ = "0.1.0"
 
 
@@ -127,3 +131,7 @@ from . import library  # noqa: F401
 from . import numpy as np  # noqa: F401
 from . import numpy_extension as npx  # noqa: F401
 from .numpy_extension import set_np, reset_np, is_np_shape, is_np_array  # noqa: F401,E501
+
+# the package's own import (and jax's, where the process had not imported it)
+# as a set-up stage; the profiler is imported by now, so only the total
+profiler._add_setup_seconds("setup.import", _time.perf_counter() - _T_IMPORT)

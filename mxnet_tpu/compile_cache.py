@@ -21,7 +21,10 @@ two escape hatches, wired under every compile site the framework has
    ``MXNET_COMPILE_CACHE_BUDGET_MB`` an LRU size budget enforced here (not
    via jax's own ``jax_compilation_cache_max_size``) so evictions are
    *countable*.  Hit/miss/write/evict counters and a size gauge export
-   through telemetry as ``mxnet_compile_cache_*``.
+   through telemetry as ``mxnet_compile_cache_*``.  The same listener keeps
+   a ledger of every program the process builds, cache on or off
+   (:func:`programs`, :func:`report`): what each cost to trace, to lower and
+   to load or compile, and under which set-up stage it was built.
 
 2. **AOT executable serialization** — ``serialize_compiled`` /
    ``deserialize_compiled`` wrap PJRT executable pickling
@@ -40,9 +43,11 @@ jax's cache key).
 from __future__ import annotations
 
 import atexit
+import collections
 import os
 import pickle
 import threading
+import time
 
 from .base import MXNetError, atomic_path
 from .testing import lockcheck as _lockcheck
@@ -55,6 +60,19 @@ _stats = {"hits": 0, "writes": 0, "requests": 0, "evictions": 0,
           "aot_loads": 0, "aot_saves": 0}
 _state = {"enabled": False, "dir": None, "budget_mb": 0.0,
           "listener": False, "collector": False, "atexit": False}
+
+# The ledger of programs built: one entry a program, closed by jax's backend
+# event.  The oldest entries fall out; ``seq`` counts from 0, so the first
+# kept entry's ``seq`` is the number fallen.  ``_built`` keeps the totals
+# over every entry ever closed, for the collector.
+_PROGRAMS_KEPT = 4096
+_programs = collections.deque(maxlen=_PROGRAMS_KEPT)
+_built = {"closed": 0, "trace": 0.0, "lower": 0.0, "load": 0.0,
+          "compile": 0.0, "hit": 0, "miss": 0, "off": 0}
+_pending = threading.local()        # a thread's build that is still open
+
+_EVENTS = "/jax/core/compile/"
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 def default_cache_dir():
@@ -111,20 +129,123 @@ def cache_size_bytes():
     return total
 
 
+def _open_build():
+    """This thread's pending build: what jax has reported since the thread's
+    last closed entry."""
+    build = _pending.__dict__
+    if not build:
+        build.update(traces=[], lower_s=0.0, cache="off", written=False,
+                     retrieval_s=None)
+    return build
+
+
 def _listener(event, **kwargs):
     # jax emits these from compiler.py/compilation_cache.py:
     #   cache_hits                 -> executable deserialized from disk
     #   cache_misses               -> entry WRITTEN to disk (fired on put)
     #   compile_requests_use_cache -> any compile request with cache on
+    # all three between a build's lowering event and its backend event
     if not event.startswith("/jax/compilation_cache/"):
         return
+    build = _open_build()
     with _lock:
         if event.endswith("/cache_hits"):
             _stats["hits"] += 1
+            build["cache"] = "hit"
         elif event.endswith("/cache_misses"):
             _stats["writes"] += 1
+            build["written"] = True
         elif event.endswith("/compile_requests_use_cache"):
             _stats["requests"] += 1
+            build["cache"] = "miss"         # until a hit says otherwise
+
+
+def _duration_listener(event, duration, **kwargs):
+    """One build on one thread arrives as: trace events (a function traced
+    inside another ends, and so arrives, before it), one lowering event
+    ``jit(<name>)``, the cache's events, and one backend event
+    ``jit(<name>)`` that closes the entry (jax 0.9.0)."""
+    if event == _RETRIEVAL_EVENT:
+        _open_build()["retrieval_s"] = duration
+        return
+    if not event.startswith(_EVENTS):
+        return
+    build = _open_build()
+    if event.endswith("/jaxpr_trace_duration"):
+        # a trace's duration holds those of the functions traced inside it:
+        # keep the outermost, so that every moment counts once
+        start = time.perf_counter() - duration
+        traces = build["traces"]
+        while traces and traces[-1][0] >= start:
+            traces.pop()
+        traces.append((start, duration))
+    elif event.endswith("/jaxpr_to_mlir_module_duration"):
+        build["lower_s"] += duration
+    elif event.endswith("/backend_compile_duration"):
+        from . import profiler
+
+        entry = {"name": kwargs.get("fun_name"),
+                 "trace_s": sum(d for _, d in build["traces"]),
+                 "lower_s": build["lower_s"], "backend_s": duration,
+                 "cache": build["cache"], "written": build["written"],
+                 "retrieval_s": build["retrieval_s"],
+                 "under": profiler.setup_stage(),
+                 "t_end_ns": time.time_ns()}
+        build.clear()
+        backend = "load" if entry["cache"] == "hit" else "compile"
+        with _lock:
+            entry["seq"] = _built["closed"]
+            entry["requests"] = _stats["requests"]
+            _built["closed"] += 1
+            _built["trace"] += entry["trace_s"]
+            _built["lower"] += entry["lower_s"]
+            _built[backend] += duration
+            _built[entry["cache"]] += 1
+            _programs.append(entry)
+
+
+def _total_s(entry):
+    return entry["trace_s"] + entry["lower_s"] + entry["backend_s"]
+
+
+def programs():
+    """The ledger: one dict for every program this process built, oldest
+    first (the last ``_PROGRAMS_KEPT``; the first entry's ``seq`` is the
+    number that fell out).  Keys: ``seq`` (order of closing), ``requests``
+    (``stats()["requests"]`` when the entry closed, so a snapshot of
+    ``stats()`` cuts the ledger at its moment), ``name`` (``jit(step)``),
+    ``trace_s``, ``lower_s``, ``backend_s`` (the load on a hit, XLA's compile
+    otherwise), ``cache`` (``hit``; ``miss``: compiled, and ``written`` says
+    whether the entry went to the disk; ``off``: the build made no request
+    of the cache), ``retrieval_s`` on a hit, ``under`` (the innermost
+    ``profiler.setup_span`` open on the building thread, or None) and
+    ``t_end_ns`` (``time.time_ns()``, the clock of a ``jax.profiler``
+    trace's host plane).  Trace and lowering time that no backend event
+    followed (``jit(f).lower()`` alone) goes to the thread's next entry."""
+    with _lock:
+        return [dict(e) for e in _programs]
+
+
+def report():
+    """The ledger as a text table, the costliest program first, and a line
+    of totals: why a process took as long as it did to start."""
+    rows = sorted(programs(), key=_total_s, reverse=True)
+    with _lock:
+        built = dict(_built)
+    lines = ["%5s  %-36s %-17s %-5s %9s %9s %9s %9s"
+             % ("seq", "name", "under", "cache", "trace_s", "lower_s",
+                "backend_s", "total_s")]
+    for e in rows:
+        lines.append("%5d  %-36s %-17s %-5s %9.3f %9.3f %9.3f %9.3f" % (
+            e["seq"], (e["name"] or "?")[:36], e["under"] or "-", e["cache"],
+            e["trace_s"], e["lower_s"], e["backend_s"], _total_s(e)))
+    lines.append(
+        "total: %d programs (%d hit, %d miss, %d off): trace %.3f s, lower "
+        "%.3f s, load %.3f s, compile %.3f s; %d older entries fell out"
+        % (built["closed"], built["hit"], built["miss"], built["off"],
+           built["trace"], built["lower"], built["load"], built["compile"],
+           built["closed"] - len(rows)))
+    return "\n".join(lines)
 
 
 def _collector():
@@ -153,6 +274,17 @@ def _collector():
         _m.gauge("mxnet_compile_cache_size_bytes",
                  "Total bytes in the persistent compile cache directory"
                  ).set(cache_size_bytes())
+    with _lock:
+        built = dict(_built)
+    for stage in ("trace", "lower", "load", "compile"):
+        _m.counter("mxnet_program_build_seconds_total",
+                   "Seconds spent building programs, by stage (load: the "
+                   "backend on a persistent-cache hit; compile: otherwise)",
+                   stage=stage).set(built[stage])
+    for cache in ("hit", "miss", "off"):
+        _m.counter("mxnet_programs_built_total",
+                   "Programs built, by what the persistent cache did",
+                   cache=cache).set(built[cache])
 
 
 def _ensure_observability():
@@ -161,6 +293,8 @@ def _ensure_observability():
             from jax._src import monitoring
 
             monitoring.register_event_listener(_listener)
+            monitoring.register_event_duration_secs_listener(
+                _duration_listener)
             _state["listener"] = True
         except Exception:
             pass
@@ -254,10 +388,13 @@ def configure(env=None):
 
     Called once at ``import mxnet_tpu`` (before any compile can happen).
     Never raises: a cache is an optimization and must not break import.
-    Returns True when the persistent cache ended up enabled.
+    Returns True when the persistent cache ended up enabled.  The listeners
+    are attached whatever the answer: the ledger of programs built
+    (:func:`programs`) is kept with the cache off too.
     """
     if env is None:
         env = os.environ
+    _ensure_observability()
     raw = env.get("MXNET_COMPILE_CACHE", "auto")
     mode = raw.lower()
     jax_dir = env.get("JAX_COMPILATION_CACHE_DIR") or None
@@ -299,7 +436,6 @@ def configure(env=None):
                 env.get("MXNET_COMPILE_CACHE_BUDGET_MB", "0") or "0")
         except ValueError:
             _state["budget_mb"] = 0.0
-        _ensure_observability()
         enforce_budget()
         if not _state["atexit"]:
             atexit.register(enforce_budget)

@@ -30,7 +30,7 @@ from .. import random as _random
 from .. import autograd as _autograd
 from .. import optimizer as _opt_mod
 from ..gluon import block as _block_mod
-from ..profiler import span as _span
+from ..profiler import setup_span as _setup_span, span as _span
 
 # What a step program over a mesh of TPUs is compiled with; PERF.md section
 # 6, PR 28, has the chip's reading for each.  GSPMD's gradient all-reduces
@@ -146,9 +146,13 @@ class JitTrainStep:
         self._stats = {}        # step statistics' accumulators, by name
 
     def _ensure_init(self, batch_nd):
-        """Snapshot parameters; resolves deferred shapes with one forward."""
         if self._params is not None:
             return
+        with _setup_span("train_step.init"):
+            self._init(batch_nd)
+
+    def _init(self, batch_nd):
+        """Snapshot parameters; resolves deferred shapes with one forward."""
         n_label = 1 if self._loss is not None else 0
         n_data = len(batch_nd) - n_label
         weights_ok = all(
@@ -530,8 +534,10 @@ class JitTrainStep:
         """Run one train step; returns the (device, async) scalar loss.
 
         Four spans (``profiler.span``) partition the call, under
-        ``mx:train_step``: ``.place_batch``, ``.scalars``, ``.call`` (the
-        first call's trace and compile fall here) and ``.tag``."""
+        ``mx:train_step``: ``.place_batch``, ``.scalars``, ``.call`` and
+        ``.tag``.  The first call's own work is two set-up stages inside
+        them (``profiler.setup_span``): ``mx:train_step.init`` in
+        ``.place_batch`` and ``mx:train_step.build`` in ``.call``."""
         with _span("train_step"):
             with _span("train_step.place_batch"):
                 arrays = self._placed(batch)
@@ -543,16 +549,32 @@ class JitTrainStep:
                 key, lr, t = self._scalars()
             with _span("train_step.call"):
                 if self._step_fn is None:
-                    self._step_fn = self._build(arrays)
-                with self._mesh_scope():
-                    self._weights, state, loss = self._step_fn(
-                        key, lr, self._weights, self._state_arg(), t,
-                        *arrays)
-                self._take_state(state)
+                    with _setup_span("train_step.build"):
+                        self._step_fn = self._build(arrays)
+                        loss = self._first_call(self._step_fn, key, lr, t,
+                                                arrays)
+                else:
+                    with self._mesh_scope():
+                        self._weights, state, loss = self._step_fn(
+                            key, lr, self._weights, self._state_arg(), t,
+                            *arrays)
+                    self._take_state(state)
             with _span("train_step.tag"):
                 self._tag_weights()
             self._last_loss = loss
             return loss
+
+    def _first_call(self, fn, key, lr, t, arrays):
+        """The first call of a jitted step or loop, inside the stage
+        ``mx:train_step.build`` that its caller opened around building it:
+        jax traces the Python-unrolled layers, lowers, loads or compiles the
+        step program (the ledger's entry ``jit(step)`` lies ``under`` the
+        stage) and dispatches the first step."""
+        with self._mesh_scope():
+            self._weights, state, loss = fn(
+                key, lr, self._weights, self._state_arg(), t, *arrays)
+        self._take_state(state)
+        return loss
 
     def step_n(self, n, *batch):
         """Run ``n`` train steps as ONE device-side loop (single dispatch).
@@ -598,12 +620,18 @@ class JitTrainStep:
             with _span("train_step.scalars"):
                 key, lr, t = self._scalars()
             with _span("train_step.call"):
-                fn = self._step_n_fn(n, sched, sched_traced, arrays)
-                with self._mesh_scope():
-                    self._weights, state, loss = fn(
-                        key, lr, self._weights, self._state_arg(), t,
-                        *arrays)
-                self._take_state(state)
+                if self._step_fn is None:
+                    with _setup_span("train_step.build"):
+                        loss = self._first_call(
+                            self._step_n_fn(n, sched, sched_traced, arrays),
+                            key, lr, t, arrays)
+                else:
+                    fn = self._step_n_fn(n, sched, sched_traced, arrays)
+                    with self._mesh_scope():
+                        self._weights, state, loss = fn(
+                            key, lr, self._weights, self._state_arg(), t,
+                            *arrays)
+                    self._take_state(state)
             with _span("train_step.tag"):
                 self._tag_weights()
             self._t += n

@@ -314,6 +314,53 @@ def span(name):
     return _Annotation(name, name, "span")
 
 
+_stages = threading.local()     # .open: this thread's open set-up stages
+
+
+def setup_stage():
+    """The innermost :func:`setup_span` open on this thread (its name
+    without ``mx:``), or None: what ``compile_cache``'s ledger files a
+    program ``under``."""
+    stack = getattr(_stages, "open", None)
+    return stack[-1] if stack else None
+
+
+def _add_setup_seconds(stage, seconds):
+    from .telemetry import metrics
+
+    metrics.counter("mxnet_setup_seconds_total",
+                    "Seconds in the program's own set-up stages "
+                    "(profiler.setup_span)", stage=stage).inc(seconds)
+
+
+class _SetupSpan(_Annotation):
+    def __init__(self, stage):
+        super().__init__("mx:" + stage, "mx:" + stage, "span")
+        self._stage = stage
+
+    def __enter__(self):
+        if not hasattr(_stages, "open"):
+            _stages.open = []
+        _stages.open.append(self._stage)
+        self._t0 = time.perf_counter()
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        _stages.open.pop()
+        _add_setup_seconds(self._stage, time.perf_counter() - self._t0)
+
+
+def setup_span(name):
+    """:func:`span` for a stage that runs once a process or once an object,
+    never in a steady step: the same ``mx:<name>`` annotation, and on
+    leaving the stage's seconds are added to
+    ``mxnet_setup_seconds_total{stage=<name>}``, whether or not a trace
+    runs.  Programs built inside it carry ``<name>`` as ``under`` in
+    ``compile_cache.programs()``."""
+    return _SetupSpan(name)
+
+
 class _Span:
     _tid = 1
 
