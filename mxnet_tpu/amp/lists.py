@@ -25,6 +25,10 @@ TARGET_DTYPE_OPS = [
     # inputs would double attention HBM traffic and halve MXU rate
     # (xplane r5: f32[96,512,64] custom-calls before this entry)
     "_contrib_flash_attention",
+    # the same kernels over latent attention's operands in the projections'
+    # own layout (ops/mla_kernels.py): the queries' rotation is float32
+    # inside, from float32 angles
+    "_contrib_mla_flash_attention",
     # the experts' grouped products; the routing weights stay as they
     # came (TARGET_DTYPE_KEEP below)
     "_contrib_moe_grouped_ffn",

@@ -89,11 +89,11 @@ class JoyAIFlashModel(HybridBlock):
             raise ValueError("num_nextn_predict_layers %r: none or one"
                              % (num_nextn_predict_layers,))
 
-        def mixer(prefix):
-            return MLAMixer(
+        def mixer(i):
+            return lambda prefix: MLAMixer(
                 units, num_heads, kv_lora_rank, qk_nope_head_dim,
                 qk_rope_head_dim, v_head_dim, eps, q_lora_rank=q_lora_rank,
-                rope_theta=rope_theta, prefix=prefix)
+                rope_theta=rope_theta, layer=i, prefix=prefix)
 
         def ffn(i, routed):
             if not routed:
@@ -107,7 +107,7 @@ class JoyAIFlashModel(HybridBlock):
 
         def layer(i, routed):
             return lambda prefix: KimiLinearBlock(
-                units, mixer, ffn(i, routed), eps, prefix=prefix)
+                units, mixer(i), ffn(i, routed), eps, prefix=prefix)
 
         with self.name_scope():
             self.embed = nn.Embedding(vocab_size, units, prefix="embed_")

@@ -27,7 +27,9 @@ one the routed.
   heads; causal softmax attention through the flash kernels with keys of
   ``nope + rope`` channels and values of ``v_head_dim``; ``W_o``.  No
   absorbed projections, no cache.  The same class, given ``q_lora_rank``
-  and ``rope_theta``, is ``joyai_llm_flash``'s mixer.
+  and ``rope_theta``, is ``joyai_llm_flash``'s mixer.  Where the shapes
+  tile, the kernels read the projections' results where the products
+  wrote them and ``k`` is never built (``ops/mla_kernels.py``).
 - ``MoEFeedForward`` (scope ``moe``, with ``moe_router``, ``moe_experts``,
   ``moe_shared`` inside): ``nemotron_h.MoEMixer`` with the ``swiglu``
   activation: sigmoid top-k routing with a score-correction bias, the held
@@ -43,7 +45,9 @@ stacked leaf ``(2F, D)`` (an expert layer's ``(count, 2F, D)``).  A
 ``KDAMixer`` declares two step statistics: the chunks its scan ran
 (``kda/<layer>``), which feeds ``mxnet_kda_chunks_total``, and those of them
 whose scan took the Pallas kernels (``kda_kernel/<layer>``: all or none, by
-the shapes), which feeds ``mxnet_kda_kernel_chunks_total``.
+the shapes), which feeds ``mxnet_kda_kernel_chunks_total``.  An ``MLAMixer``
+declares two of the same kind: the layer (``mla/<layer>``) and the layer
+where its kernels read in place (``mla_kernel/<layer>``).
 
 Not built: the latent paged cache and the absorbed decode of the MLA
 layers, a single-token form of the delta rule (serving).
@@ -53,6 +57,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ...ops import mla_kernels
 from ...ops.kda import kda_chunks, kda_kernel_chunks
 from ...telemetry import metrics
 from .. import nn
@@ -63,6 +68,8 @@ from .nemotron_h import (MoEMixer, _dense, _feed_forward, _Mixer,
 
 STAT_PREFIX = "kda/"      # a layer's statistic: "kda/<layer>"
 KERNEL_STAT_PREFIX = "kda_kernel/"
+MLA_STAT_PREFIX = "mla/"
+MLA_KERNEL_STAT_PREFIX = "mla_kernel/"
 
 
 class KDAMixer(_Mixer):
@@ -125,17 +132,36 @@ class MLAMixer(_Mixer):
     ``q_proj``'s place); ``rope_theta`` (None: no rotation, the ``rope``
     channels are plain channels) is the base of a rotary embedding on the
     ``rope`` channels of every query head and of the one rope key a token,
-    adjacent channels a pair (``F.contrib.rotary_embedding``).  Under the
-    scope ``mla``; inside it ``mla_rope`` holds the rotation, the repeat of
-    the rope key over the heads and the concatenation that builds the
-    keys."""
+    adjacent channels a pair.  Under the scope ``mla``.
+
+    Where the shapes tile (``ops/mla_kernels.py:tiles``, a static test:
+    ``nope`` and ``v_head_dim`` whole 128-lane tiles, ``rope`` 64 or a
+    multiple, 512 tokens or more, no mesh) nothing between the projections
+    and the flash kernels is rewritten in HBM:
+    ``F.contrib.mla_flash_attention`` reads ``W_kvb``'s result and the one
+    rope key as they lie and writes where ``W_o`` reads.  The query's weight
+    is split by rows, not its activation (a head's ``nope + rope`` lanes
+    are no whole lane tiles): the leaf stays as published, and its ``nope``
+    rows and ``rope`` rows give ``q_nope`` and ``q_rope`` as two products.
+    The kernels turn the queries' rope channels; the scope ``mla_rope``
+    (inside the operator) holds what stays XLA's, the rotation of the rope
+    key.  Every other shape (the tier-1 models' widths, any mesh) takes the
+    composition: heads transposed to ``(B, H, T, .)``, and under ``mla_rope``
+    the rotation of q and the rope key, its repeat over the heads and the
+    concatenation that builds the keys, then ``F.contrib.flash_attention``.
+
+    Two step statistics (``layer`` names them): ``mla/<layer>`` counts the
+    layer, ``mla_kernel/<layer>`` counts it where its operands were read in
+    place (``mxnet_mla_layers_total``, ``mxnet_mla_kernel_layers_total``)."""
 
     def __init__(self, units, num_heads, kv_lora_rank, qk_nope_head_dim,
                  qk_rope_head_dim, v_head_dim, eps=1e-5, q_lora_rank=None,
-                 rope_theta=None, prefix=None, params=None):
+                 rope_theta=None, layer=0, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         self._cfg = (num_heads, kv_lora_rank, qk_nope_head_dim,
                      qk_rope_head_dim, v_head_dim, eps, rope_theta)
+        self._stat = MLA_STAT_PREFIX + str(int(layer))
+        self._kernel_stat = MLA_KERNEL_STAT_PREFIX + str(int(layer))
         qk = qk_nope_head_dim + qk_rope_head_dim
         self._declare(
             ([("q_proj", (num_heads * qk, units), None)]
@@ -149,27 +175,51 @@ class MLAMixer(_Mixer):
                            kv_lora_rank), None),
             ("o_proj", (units, num_heads * v_head_dim), None)])
 
+    def step_stat_specs(self):
+        """The layer, and the layer where the kernels read in place."""
+        return {self._stat: ((1,), jnp.uint32),
+                self._kernel_stat: ((1,), jnp.uint32)}
+
     def hybrid_forward(self, F, u, kv_a_proj, kv_a_norm, kv_b_proj, o_proj,
                        q_proj=None, q_a_proj=None, q_a_norm=None,
                        q_b_proj=None):
         h, rank, nope, rope, vd, eps, theta = self._cfg
         b, t, _ = u.shape
+        in_place = mla_kernels.tiles(h, nope, rope, vd, t) is not None
+        record_step_stat(self._stat, jnp.ones((1,), jnp.uint32))
+        record_step_stat(self._kernel_stat,
+                         jnp.full((1,), int(in_place), jnp.uint32))
         with jax.named_scope("mla"):
-            def heads(x, width):
-                return F.transpose(F.reshape(x, shape=(b, t, -1, width)),
-                                   axes=(0, 2, 1, 3))
             if q_proj is not None:
-                q = _dense(F, u, q_proj)
+                q_in, q_w = u, q_proj
             else:
-                q = _dense(F, F.RMSNorm(_dense(F, u, q_a_proj), q_a_norm,
-                                        axis=-1, eps=eps), q_b_proj)
-            q = heads(q, nope + rope)
+                q_in, q_w = F.RMSNorm(_dense(F, u, q_a_proj), q_a_norm,
+                                      axis=-1, eps=eps), q_b_proj
             kv_a = _dense(F, u, kv_a_proj)
             latent = F.RMSNorm(F.slice_axis(kv_a, axis=2, begin=0, end=rank),
                                kv_a_norm, axis=-1, eps=eps)
-            k_rope = heads(F.slice_axis(kv_a, axis=2, begin=rank, end=None),
-                           rope)                      # one a token
-            kv = heads(_dense(F, latent, kv_b_proj), nope + vd)
+            k_rope = F.slice_axis(kv_a, axis=2, begin=rank, end=None)
+            kv = _dense(F, latent, kv_b_proj)       # [k_nope | v] a head
+            if in_place:
+                def rows(begin, end):
+                    # these rows of every head's nope + rope, as a weight
+                    per_head = F.reshape(q_w, shape=(h, nope + rope, -1))
+                    return F.reshape(F.slice_axis(per_head, axis=1,
+                                                  begin=begin, end=end),
+                                     shape=(h * (end - begin), -1))
+                out = F.contrib.mla_flash_attention(
+                    _dense(F, q_in, rows(0, nope)),
+                    _dense(F, q_in, rows(nope, nope + rope)), kv, k_rope,
+                    num_heads=h, scale=(nope + rope) ** -0.5,
+                    rope_theta=theta)
+                return _dense(F, out, o_proj)
+
+            def heads(x, width):
+                return F.transpose(F.reshape(x, shape=(b, t, -1, width)),
+                                   axes=(0, 2, 1, 3))
+            q = heads(_dense(F, q_in, q_w), nope + rope)
+            k_rope = heads(k_rope, rope)              # one a token
+            kv = heads(kv, nope + vd)
             with jax.named_scope("mla_rope"):
                 if theta is not None:
                     q = F.contrib.rotary_embedding(q, theta=theta,
@@ -251,7 +301,7 @@ class KimiLinearModel(HybridBlock):
                     chunk_size, eps, layer=i, prefix=prefix)
             return lambda prefix: MLAMixer(
                 units, num_heads, kv_lora_rank, qk_nope_head_dim,
-                qk_rope_head_dim, v_head_dim, eps, prefix=prefix)
+                qk_rope_head_dim, v_head_dim, eps, layer=i, prefix=prefix)
 
         def ffn(i):
             if i <= first_k_dense:
@@ -298,3 +348,7 @@ def kimi_linear_48b_a3b(vocab_size=163840, **kwargs):
 
 metrics.register_collector(
     chunk_counters("kda", "Kimi Delta Attention scans", "mx_kda_*"))
+metrics.register_collector(chunk_counters(
+    "mla", "latent attention mixers",
+    "mx_flash_*_mla, their operands read where the projections wrote them",
+    unit="layers", each="every such layer and train step"))

@@ -86,16 +86,19 @@ class _Mixer(HybridBlock):
                 name, shape=shape, init=init, allow_deferred_init=False))
 
 
-def chunk_counters(stem, scans, kernels):
-    """A telemetry collector for the chunk counts of one kind of scan.  They
-    leave a ``JitTrainStep`` program as the statistics ``<stem>/<layer>``
-    (chunks the scans ran) and ``<stem>_kernel/<layer>`` (those that ran in
-    the Pallas kernels), which it accumulates on the device; a snapshot
-    fetches them (once, both kinds) and adds what is new (modulo the
-    accumulators' 32 bits) to ``mxnet_<stem>_chunks_total`` and
-    ``mxnet_<stem>_kernel_chunks_total``."""
-    families = {stem + "/": ("mxnet_%s_chunks_total" % stem, "ran"),
-                stem + "_kernel/": ("mxnet_%s_kernel_chunks_total" % stem,
+def chunk_counters(stem, scans, kernels, unit="chunks",
+                   each="sequences x heads x chunks, every such layer and "
+                        "train step"):
+    """A telemetry collector for the counts of one kind of scan, or of
+    whatever else a layer counts in ``unit`` (latent attention: layers).
+    They leave a ``JitTrainStep`` program as the statistics
+    ``<stem>/<layer>`` (chunks the scans ran) and ``<stem>_kernel/<layer>``
+    (those that ran in the Pallas kernels), which it accumulates on the
+    device; a snapshot fetches them (once, both kinds) and adds what is new
+    (modulo the accumulators' 32 bits) to ``mxnet_<stem>_<unit>_total`` and
+    ``mxnet_<stem>_kernel_<unit>_total``."""
+    families = {stem + "/": ("mxnet_%s_%s_total" % (stem, unit), "ran"),
+                stem + "_kernel/": ("mxnet_%s_kernel_%s_total" % (stem, unit),
                                     "ran in the Pallas kernels " + kernels)}
     seen = {}               # (step, statistic) -> the count last read
 
@@ -114,9 +117,8 @@ def chunk_counters(stem, scans, kernels):
         for prefix, count in new.items():
             family, what = families[prefix]
             metrics.counter(family,
-                            help="chunks the %s %s (sequences x heads x "
-                                 "chunks, every such layer and train step)"
-                                 % (scans, what)).inc(count)
+                            help="%s the %s %s (%s)"
+                                 % (unit, scans, what, each)).inc(count)
     return collect
 
 

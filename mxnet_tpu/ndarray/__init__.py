@@ -16,6 +16,7 @@ from ..ops import paged_attention as _pa  # noqa: F401
 from ..ops import ssm as _ssm  # noqa: F401
 from ..ops import kda as _kda  # noqa: F401
 from ..ops import rotary as _rotary  # noqa: F401
+from ..ops import mla_kernels as _mla  # noqa: F401
 from ..ops import moe as _moe  # noqa: F401
 from ..ops import misc as _m  # noqa: F401
 from ..ops import vision as _v  # noqa: F401

@@ -25,7 +25,12 @@ into float32 accumulators (whole-T q, dO, lse and delta did not fit at
 T=4096).  Under the causal mask no kernel computes a block that the mask
 hides.  Values may be narrower than keys (latent attention: keys of 192
 channels, values of 128): ``q, k (BH, T, D)``, ``v``, the result and
-``dO (BH, T, Dv)``, nothing padded.
+``dO (BH, T, Dv)``, nothing padded.  What a kernel does with one head
+(``_flash_fwd_head``, ``_flash_dq_head``, ``_flash_dkv_pair``) takes the
+channels in one part or several, a product each into one float32 score:
+``ops/mla_kernels.py`` runs them over latent attention's operands where
+the projections wrote them, keys of a head's ``nope`` channels and one
+rope key that the heads share.
 
 On non-TPU backends the kernels run through the Pallas interpreter
 (tests).  Shapes that do not tile take plain jnp attention on every
@@ -93,25 +98,34 @@ def _blocks_seen(qi, block_q, block_k, t_kv, causal):
     return jnp.minimum(((qi + 1) * block_q + block_k - 1) // block_k, n_k)
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
-                      block_k, scale, causal):
-    from jax.experimental import pallas as pl
+def _scores(qs, ks):
+    """``q k^T`` (bq, bk) in float32, the channels in one part or in several
+    (latent attention: ``nope`` channels a head and ``rope`` channels shared
+    by the heads), each part a product into the same sum."""
+    s = None
+    for q, k in zip(qs, ks):
+        part = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)         # (bq, bk)
+        s = part if s is None else s + part
+    return s
 
-    q = q_ref[0].astype(jnp.float32) * scale           # (bq, D)
-    qi = pl.program_id(1)
-    n_k = _blocks_seen(qi, block_q, block_k, k_ref.shape[1], causal)
+
+def _flash_fwd_head(qs, keys, value, t_kv, dv, qi, *, block_q, block_k,
+                    causal):
+    """One head's query block against the key blocks it sees, the online
+    softmax: ``qs`` the scaled float32 query block, a tuple of its channel
+    parts; ``keys(i)`` key block ``i`` as the same parts and ``value(i)``
+    its ``dv`` values, float32.  The running maximum ``m`` and sum ``l``
+    ``(bq, 1)`` and the result not yet divided by ``l`` ``(bq, dv)``."""
+    n_k = _blocks_seen(qi, block_q, block_k, t_kv, causal)
     row = qi * block_q + lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
 
     def body(i, carry):
         m, l, acc = carry
-        k = k_ref[0, pl.dslice(i * block_k, block_k), :] \
-            .astype(jnp.float32)                        # (bk, D)
-        v = v_ref[0, pl.dslice(i * block_k, block_k), :] \
-            .astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)         # (bq, bk)
+        ks, v = keys(i), value(i)                       # (bk, D), (bk, Dv)
+        s = _scores(qs, ks)
         if causal:
             col = i * block_k + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
@@ -127,17 +141,37 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
 
     m0 = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
-    m, l, acc = lax.fori_loop(0, n_k, body, (m0, l0, acc0))
+    acc0 = jnp.zeros((block_q, dv), jnp.float32)
+    return lax.fori_loop(0, n_k, body, (m0, l0, acc0))
+
+
+def _flash_lse(m, l, safe_l):
+    """logsumexp per row; -inf rows (fully masked) stored as -inf."""
+    return jnp.where(l[:, 0] == 0, -jnp.inf, m[:, 0] + jnp.log(safe_l[:, 0]))
+
+
+def _lane_rows(x, block_q):
+    """A row scalar broadcast across a 128-lane minor dimension — TPU
+    Mosaic requires block minor dims divisible by 128 (or full), so a
+    bare (block_q,) output cannot tile; jax's own TPU flash kernels
+    store l/m the same way (flash_attention.py MIN_BLOCK_SIZE)."""
+    return lax.broadcast_in_dim(x, (block_q, _LANES), (0,))
+
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
+                      block_k, scale, causal):
+    from jax.experimental import pallas as pl
+
+    def block(ref, i):
+        return ref[0, pl.dslice(i * block_k, block_k), :].astype(jnp.float32)
+    q = q_ref[0].astype(jnp.float32) * scale           # (bq, D)
+    m, l, acc = _flash_fwd_head(
+        (q,), lambda i: (block(k_ref, i),), lambda i: block(v_ref, i),
+        k_ref.shape[1], v_ref.shape[-1], pl.program_id(1), block_q=block_q,
+        block_k=block_k, causal=causal)
     safe_l = jnp.where(l == 0, 1.0, l)
     o_ref[0] = (acc / safe_l).astype(o_ref.dtype)
-    # logsumexp per row; -inf rows (fully masked) stored as -inf.  The
-    # row scalar is broadcast across a 128-lane minor dimension — TPU
-    # Mosaic requires block minor dims divisible by 128 (or full), so a
-    # bare (block_q,) output cannot tile; jax's own TPU flash kernels
-    # store l/m the same way (flash_attention.py MIN_BLOCK_SIZE).
-    lse = jnp.where(l[:, 0] == 0, -jnp.inf, m[:, 0] + jnp.log(safe_l[:, 0]))
-    lse_ref[0] = lax.broadcast_in_dim(lse, (block_q, _LANES), (0,))
+    lse_ref[0] = _lane_rows(_flash_lse(m, l, safe_l), block_q)
 
 
 def _flash_params(k, v):
@@ -196,27 +230,18 @@ def _flash_pallas(q, k, v, scale, causal, block_q, block_k,
 # ---------------------------------------------------------------------------
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, *, block_q, block_k, scale, causal):
-    from jax.experimental import pallas as pl
-
-    q = q_ref[0].astype(jnp.float32)                    # (bq, D)
-    do = do_ref[0].astype(jnp.float32)                  # (bq, D)
-    lse = lse_ref[0][:, :1]                             # (bq, 1) lane 0
-    delta = delta_ref[0][:, :1]                         # (bq, 1) lane 0
-    qi = pl.program_id(1)
-    n_k = _blocks_seen(qi, block_q, block_k, k_ref.shape[1], causal)
+def _flash_dq_head(qs, do, lse, delta, keys, value, t_kv, qi, *, block_q,
+                   block_k, scale, causal):
+    """One head's query block against the key blocks it sees: ``dq`` as the
+    parts ``qs`` came in (float32, not scaled).  ``lse`` and ``delta``
+    ``(bq, 1)``; ``keys`` and ``value`` as ``_flash_fwd_head``'s."""
+    n_k = _blocks_seen(qi, block_q, block_k, t_kv, causal)
     row = qi * block_q + lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
 
-    def body(i, dq):
-        k = k_ref[0, pl.dslice(i * block_k, block_k), :] \
-            .astype(jnp.float32)
-        v = v_ref[0, pl.dslice(i * block_k, block_k), :] \
-            .astype(jnp.float32)
-        s = scale * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def body(i, dqs):
+        ks, v = keys(i), value(i)
+        s = scale * _scores(qs, ks)
         if causal:
             col = i * block_k + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
@@ -228,18 +253,58 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)         # (bq, bk)
         ds = p * (dp - delta)
-        return dq + scale * jax.lax.dot_general(
+        return tuple(dq + scale * jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32) for dq, k in zip(dqs, ks))
 
-    dq0 = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
-    dq = lax.fori_loop(0, n_k, body, dq0)
+    return lax.fori_loop(0, n_k, body, tuple(
+        jnp.zeros((block_q, q.shape[-1]), jnp.float32) for q in qs))
+
+
+def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                         dq_ref, *, block_q, block_k, scale, causal):
+    from jax.experimental import pallas as pl
+
+    def block(ref, i):
+        return ref[0, pl.dslice(i * block_k, block_k), :].astype(jnp.float32)
+    dq, = _flash_dq_head(
+        (q_ref[0].astype(jnp.float32),),                # (bq, D)
+        do_ref[0].astype(jnp.float32),                  # (bq, Dv)
+        lse_ref[0][:, :1], delta_ref[0][:, :1],         # (bq, 1) lane 0
+        lambda i: (block(k_ref, i),), lambda i: block(v_ref, i),
+        k_ref.shape[1], pl.program_id(1), block_q=block_q, block_k=block_k,
+        scale=scale, causal=causal)
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _first_block_seen(ki, block_q, block_k, causal):
     """The first query block that key block ``ki`` is seen by."""
     return (ki * block_k) // block_q if causal else 0
+
+
+def _flash_dkv_pair(qs, ks, v, do, lse, delta, ki, qi, *, block_q, block_k,
+                    scale, causal):
+    """One (key block, query block) pair of one head: ``(dks, dv)``, the
+    pair's part of dk (the parts ``ks`` came in) and of dv, float32.  ``qs``
+    and ``ks`` as ``_scores`` takes them, not scaled."""
+    s = scale * _scores(qs, ks)                         # (bq, bk)
+    if causal:
+        col = ki * block_k + lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        row = qi * block_q + lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        s = jnp.where(col <= row, s, -jnp.inf)
+    p = jnp.where(jnp.isfinite(lse), jnp.exp(s - lse), 0.0)
+    dv = jax.lax.dot_general(
+        p, do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)             # (bk, Dv)
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)             # (bq, bk)
+    ds = p * (dp - delta)
+    return tuple(scale * jax.lax.dot_general(
+        ds, q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) for q in qs), dv    # (bk, D)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -260,28 +325,9 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         v = v_ref[0].astype(jnp.float32)                # (bk, Dv)
         q = q_ref[0].astype(jnp.float32)                # (bq, D)
         do = do_ref[0].astype(jnp.float32)              # (bq, Dv)
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
-        s = scale * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)         # (bq, bk)
-        if causal:
-            col = ki * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            row = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            s = jnp.where(col <= row, s, -jnp.inf)
-        p = jnp.where(jnp.isfinite(lse), jnp.exp(s - lse), 0.0)
-        dv = jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # (bk, Dv)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)         # (bq, bk)
-        ds = p * (dp - delta)
-        dk = scale * jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # (bk, D)
+        (dk,), dv = _flash_dkv_pair(
+            (q,), (k,), v, do, lse_ref[0][:, :1], delta_ref[0][:, :1], ki,
+            qi, block_q=block_q, block_k=block_k, scale=scale, causal=causal)
         return dk, dv
 
     if one_block:
@@ -476,8 +522,11 @@ def flash_attention(query, key, value, scale=None, causal=False,
     enough pressure on VMEM to serialize the pipeline.  Below T=512
     (and with no explicit block) the op is XLA's attention, not this
     kernel.  Both rules date from an earlier installation; today's
-    cells stand on the Pallas side only, T=512 and T=2048 (ROADMAP.md
-    S17, R9), so neither the default nor the switch is measured.
+    cells stand on the Pallas side only, at T=512, 2048, 4096 (PR 34) and
+    8192 (PR 36) (ROADMAP.md S17, R9), so neither the default nor the
+    switch is measured.  Latent attention at shapes that tile does not
+    come here: ``ops/mla_kernels.py`` runs the same bodies over the
+    projections' own layout (PR 39).
     """
     squeeze = query.ndim == 3
     if squeeze:

@@ -112,9 +112,18 @@ def test_three_adamw_steps_match_the_reference_and_count_the_rows(forced):
     _changes_close(step._weights, ref, weights)
     stats = step.step_stats()
     # two routed layers (1 and 2), experts 4..7 held, 40 tokens x 3 a step
-    assert sorted(stats) == ["moe/1/4", "moe/2/4"]
-    for counts in stats.values():
-        assert counts[-3] == 3 * 40 * 3 and counts[-2] == 0
+    assert sorted(k for k in stats if k.startswith("moe/")) == \
+        ["moe/1/4", "moe/2/4"]
+    for k, counts in stats.items():
+        if k.startswith("moe/"):
+            assert counts[-3] == 3 * 40 * 3 and counts[-2] == 0
+    # three latent attention layers counted three steps each; at the tiny
+    # widths none reads its operands in place
+    assert sorted(k for k in stats if k.startswith("mla")) == \
+        ["mla/0", "mla/1", "mla/2", "mla_kernel/0", "mla_kernel/1",
+         "mla_kernel/2"]
+    assert all(int(stats["mla/%d" % i][0]) == 3
+               and int(stats["mla_kernel/%d" % i][0]) == 0 for i in range(3))
 
 
 @pytest.mark.parametrize("forced", [False, True], ids=["free", "forced"])
@@ -239,5 +248,10 @@ def test_three_steps_with_the_module_through_the_step_that_has_no_loss(forced):
             cfg, ws, toks, lab.reshape(toks.shape), EIN))
     _close(losses, ref_losses)
     _changes_close(step._weights, ref, weights)
-    # the module's routed layer is numbered after the last: 3
-    assert sorted(step.step_stats()) == ["moe/1/4", "moe/2/4", "moe/3/4"]
+    # the module's layer is numbered after the last, 3: its routed block
+    # and its latent attention mixer
+    stats = sorted(step.step_stats())
+    assert [k for k in stats if k.startswith("moe/")] == \
+        ["moe/1/4", "moe/2/4", "moe/3/4"]
+    assert [k for k in stats if k.startswith("mla/")] == \
+        ["mla/%d" % i for i in range(4)]

@@ -776,6 +776,39 @@ SPECS["_contrib_rotary_embedding"] = S(
     ref=_rotary_ref, grad=True)
 
 
+def _mla_flash_ref(qn, qr, kv, kr):
+    """Two heads of 128 + 64 / 128 channels over 128 tokens: the rope
+    channels of every query head and of the one rope key turned as
+    ``_rotary_ref`` turns them, causal softmax attention a head."""
+    t, h, nope, rope = qn.shape[1], 2, 128, 64
+
+    def turn(x):
+        phi = np.arange(t)[:, None] * 100.0 ** (-2.0 * np.arange(rope // 2)
+                                                / rope)
+        a, b = x[..., 0::2], x[..., 1::2]
+        out = np.empty(x.shape)
+        out[..., 0::2] = a * np.cos(phi) - b * np.sin(phi)
+        out[..., 1::2] = a * np.sin(phi) + b * np.cos(phi)
+        return out
+    qn, qr, kv = (x.astype(np.float64).reshape(t, h, -1)
+                  for x in (qn, qr, kv))
+    kr = turn(kr[0].astype(np.float64))
+    mask = np.tril(np.ones((t, t), bool))
+    out = np.empty((t, h, 128))
+    for j in range(h):
+        s = (qn[:, j] @ kv[:, j, :nope].T + turn(qr[:, j]) @ kr.T) \
+            * (nope + rope) ** -0.5
+        out[:, j] = _softmax_ref(np.where(mask, s, -np.inf)) @ kv[:, j, nope:]
+    return out.reshape(1, t, h * 128)
+
+
+SPECS["_contrib_mla_flash_attention"] = S(
+    [randn((1, 128, 256), 178), randn((1, 128, 128), 179),
+     randn((1, 128, 512), 180), randn((1, 128, 64), 181)],
+    {"num_heads": 2, "rope_theta": 100.0, "block_q": 64, "block_k": 64},
+    ref=_mla_flash_ref, rtol=1e-3, atol=1e-4)
+
+
 def _router_ref(x, w, b):
     s = 1.0 / (1.0 + np.exp(-(x.astype(np.float64) @ w.T)))
     idx = np.argsort(-(s + b), axis=1, kind="stable")[:, :2]

@@ -405,22 +405,16 @@ def _traced(block):
     return fn, params
 
 
-def test_the_latent_attention_mixer_fits_at_8192_tokens(one_chip):
-    # JoyAI-LLM Flash's mixer at its published sizes under bfloat16 AMP,
-    # forward and backward over one sequence of 8192 tokens: the three
-    # flash kernels once each, nothing made again.  A layer alone holds
-    # 1.15 GB at once; the first of two keeps 0.69 GB while the second runs
-    # (q, k, v, W_kvb's result and their copies), which is what the step's
-    # five layers are sized on (PERF.md 6, PR 36: 6.8 GB of temporaries
-    # beside 8.3 GB of state)
+def _mixer_grads(mixer, one_chip, tokens, layers=(1,)):
+    """``mixer`` (an ``MLAMixer`` at 2048 or 2304 units) under bfloat16 AMP,
+    forward and backward over one sequence, compiled for the described
+    chip: one executable for each count of ``layers``."""
     import mxnet_tpu as mx
     from mxnet_tpu import amp
-    from mxnet_tpu.gluon.model_zoo import kimi_linear
 
-    mixer = kimi_linear.MLAMixer(2048, 32, 512, 128, 64, 128, 1e-6,
-                                 q_lora_rank=1536, rope_theta=32000000)
     mixer.initialize(mx.init.Zero())
     fn, params = _traced(mixer)
+    units = params[-1].shape[0]             # o_proj (units, H * vd)
 
     def loss(weights, u, w, layers):
         for _ in range(layers):
@@ -430,23 +424,70 @@ def test_the_latent_attention_mixer_fits_at_8192_tokens(one_chip):
     try:
         avals = ([jax.ShapeDtypeStruct(p.shape, jnp.float32,
                                        sharding=one_chip) for p in params],
-                 jax.ShapeDtypeStruct((1, 8192, 2048), jnp.float32,
+                 jax.ShapeDtypeStruct((1, tokens, units), jnp.float32,
                                       sharding=one_chip),
-                 jax.ShapeDtypeStruct((1, 8192, 2048), jnp.float32,
+                 jax.ShapeDtypeStruct((1, tokens, units), jnp.float32,
                                       sharding=one_chip))
-        one, two = (jax.jit(jax.grad(functools.partial(loss, layers=n),
-                                     argnums=(0, 1))).lower(*avals).compile()
-                    for n in (1, 2))
+        return [jax.jit(jax.grad(functools.partial(loss, layers=n),
+                                 argnums=(0, 1))).lower(*avals).compile()
+                for n in layers]
     finally:
         amp.turn_off()
-    kernels = re.findall(r"%(mx_\w+?)(?:\.\d+)* = [^=]+ custom-call\(",
-                         one.as_text())
-    assert sorted(kernels) == ["mx_flash_bwd_dkv", "mx_flash_bwd_dq",
-                               "mx_flash_fwd"]
+
+
+def _kernels(compiled):
+    return sorted(re.findall(r"%(mx_\w+?)(?:\.\d+)* = [^=]+ custom-call\(",
+                             compiled.as_text()))
+
+
+def test_the_latent_attention_mixer_fits_at_8192_tokens(one_chip):
+    # JoyAI-LLM Flash's mixer at its published sizes under bfloat16 AMP,
+    # forward and backward over one sequence of 8192 tokens: the three
+    # flash kernels once each, nothing made again.  What the first of two
+    # layers keeps while the second runs is what the step's five layers are
+    # sized on: W_kvb's result as it lies, the two query parts, the rope
+    # key, the result and its logsumexp (PR 39; q, k, v and W_kvb's result,
+    # 0.69 GB, before it)
+    from mxnet_tpu.gluon.model_zoo import kimi_linear
+
+    mixer = kimi_linear.MLAMixer(2048, 32, 512, 128, 64, 128, 1e-6,
+                                 q_lora_rank=1536, rope_theta=32000000)
+    one, two = _mixer_grads(mixer, one_chip, 8192, layers=(1, 2))
+    assert _kernels(one) == ["mx_flash_bwd_dkv_mla", "mx_flash_bwd_dq_mla",
+                             "mx_flash_fwd_mla"]
     temp = one.memory_analysis().temp_size_in_bytes
     kept = two.memory_analysis().temp_size_in_bytes - temp
     assert temp < 2 << 30
-    assert kept < 1 << 30
+    assert kept < 640 << 20
+
+
+@pytest.mark.parametrize("model", ["joyai", "kimi"])
+def test_the_latent_attention_mixer_rewrites_no_array_over_the_heads(
+        one_chip, model):
+    # the mixer's path by its shapes (JoyAI-LLM Flash at (1, 8192), a
+    # low-rank query and a rotation; Kimi Linear at (1, 4096), neither):
+    # the kernels read W_kvb's and W_qb's results where the products wrote
+    # them, so under the scope ``mla`` the compiled step holds no transpose
+    # and no copy of a bfloat16 array over (heads, tokens) or (tokens,
+    # heads)
+    from mxnet_tpu.gluon.model_zoo import kimi_linear
+
+    if model == "joyai":
+        tokens, mixer = 8192, kimi_linear.MLAMixer(
+            2048, 32, 512, 128, 64, 128, 1e-6, q_lora_rank=1536,
+            rope_theta=32000000)
+    else:
+        tokens, mixer = 4096, kimi_linear.MLAMixer(2304, 32, 512, 128, 64,
+                                                   128, 1e-5)
+    compiled, = _mixer_grads(mixer, one_chip, tokens)
+    assert _kernels(compiled) == ["mx_flash_bwd_dkv_mla",
+                                  "mx_flash_bwd_dq_mla", "mx_flash_fwd_mla"]
+    over_heads = re.compile(r"bf16\[(?:1,)?(?:32,%d|%d,32),\d+\]"
+                            % (tokens, tokens))
+    moved = [line.strip()[:200] for line in compiled.as_text().splitlines()
+             if re.search(r" (transpose|copy)\(", line)
+             and "mla" in line and over_heads.search(line)]
+    assert not moved, moved
 
 
 def test_grouped_ffn_with_swiglu_experts_of_768_over_8192_tokens(one_chip):
