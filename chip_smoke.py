@@ -9,10 +9,12 @@ paths once, through the entry points a user would call:
   ``parallel.JitTrainStep`` with AdamW, six steps on one fixed batch of
   4 x 512 tokens; then the same child once more for two steps, which must
   find the first one's executables in the persistent compile cache;
-- **serve**: the 160M Llama-shaped decoder in bf16, exported with
-  ``serve.export_serving_bundle``, served by ``python -m mxnet_tpu.serve``
-  over HTTP (8 requests, 4 of them concurrent), then checked against one
-  teacher-forced forward of the gluon model.
+- **serve**: a SmolLM2-360M-shaped decoder in bf16 over 8192 pages of 16
+  tokens, batch 32, exported with ``serve.export_serving_bundle`` (twice:
+  the second export must find every program in the compile cache), served
+  by ``python -m mxnet_tpu.serve`` over HTTP (8 requests, 4 of them
+  concurrent), then every answer checked against a teacher-forced forward
+  of the gluon model.
 
 ``--chips 4`` runs instead, and only, the same training model for three
 steps on a ``{"data": 2, "model": 2}`` mesh under the shipped Megatron
@@ -49,15 +51,18 @@ SEED = 22
 # llama2_7b's published widths; only the depth is cut
 TRAIN = dict(vocab=32000, units=4096, hidden=11008, heads=32, layers=2,
              batch=4, seq=512, lr=3e-4)
-# a 160M decoder: the largest the bundle design, which bakes the
-# weights into every executable, holds comfortably
-SERVE = dict(vocab=32000, units=768, hidden=2048, layers=12, heads=12,
-             kv_heads=4, page_size=16, num_pages=512, max_batch=8,
-             buckets=(128, 512), prompt_lens=(20, 400), new_tokens=32)
+# SmolLM2-360M's shape (HuggingFaceTB/SmolLM2-360M, config.json) at the
+# geometry whose decode did not fit the chip while the programs copied the
+# arena (PERF.md 7.1): the arena is 5.4 GB, 10.7 GB as the chip pads a
+# 64-wide head
+SERVE = dict(vocab=49152, units=960, hidden=2560, layers=32, heads=15,
+             kv_heads=5, tie=True, page_size=16, num_pages=8192,
+             max_batch=32, buckets=(128, 512), prompt_lens=(20, 400),
+             new_tokens=64)
 TINY_TRAIN = dict(vocab=256, units=64, hidden=128, heads=4, layers=2,
                   batch=4, seq=16, lr=3e-3)
 TINY_SERVE = dict(vocab=256, units=64, hidden=128, layers=2, heads=4,
-                  kv_heads=2, page_size=4, num_pages=128, max_batch=8,
+                  kv_heads=2, tie=True, page_size=4, num_pages=128, max_batch=8,
                   buckets=(16, 64), prompt_lens=(3, 40), new_tokens=8)
 
 # bf16 on the chip, random weights: an argmax can flip on a near-tie, so
@@ -251,18 +256,19 @@ def child_train(a):
     _result(doc)
 
 
-def _serve_net(cfg, ctx):
-    """The decoder in bf16 on ``ctx``, weights from SEED."""
+def _serve_net(cfg, ctx, seed=SEED):
+    """The decoder in bf16 on ``ctx``, weights from ``seed``."""
     import numpy as np
 
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.model_zoo import llama
 
-    mx.random.seed(SEED)
+    mx.random.seed(seed)
     net = llama.LlamaModel(cfg["vocab"], units=cfg["units"],
                            hidden_size=cfg["hidden"],
                            num_layers=cfg["layers"], num_heads=cfg["heads"],
-                           num_kv_heads=cfg["kv_heads"])
+                           num_kv_heads=cfg["kv_heads"],
+                           tie_embeddings=cfg["tie"])
     net.initialize(mx.init.Xavier(), ctx=ctx)
     net(mx.nd.array(np.zeros((1, 8), np.int32), ctx=ctx))  # deferred shapes
     net.cast("bfloat16")
@@ -270,39 +276,69 @@ def _serve_net(cfg, ctx):
 
 
 def child_export(a):
-    """Child A: export the serving bundle and exit."""
+    """Child A: export the serving bundle, load it back and exit."""
+    import hashlib
+
+    import jax
+
     import mxnet_tpu as mx
-    from mxnet_tpu import serve
+    from mxnet_tpu import compile_cache, serve
     from mxnet_tpu.serve import model as serve_model
 
     dev, device = _device(a.rehearse)
     cfg = TINY_SERVE if a.rehearse else SERVE
-    t0 = time.perf_counter()
     with mx.tpu() as ctx:
-        net = _serve_net(cfg, ctx)
+        t0 = time.perf_counter()
+        net = _serve_net(cfg, ctx, a.seed)
+        built = time.perf_counter() - t0
         geometry = serve.export_serving_bundle(
             net, a.bundle, page_size=cfg["page_size"],
             num_pages=cfg["num_pages"], max_batch=cfg["max_batch"],
             prefill_buckets=cfg["buckets"],
             # off the chip only the interpreter can stand in for the kernel
             paged_kernel="1" if a.rehearse else None)
-    secs = time.perf_counter() - t0
-    _, exes = serve_model.load_serving_executables(a.bundle)
+        secs = time.perf_counter() - t0 - built
+        del net
+    compiled = [p for p in compile_cache.programs()
+                if p["under"] == "serve.export" and p["cache"] != "hit"]
+    t0 = time.perf_counter()
+    _, exes, weights = serve_model.load_serving_executables(a.bundle)
+    jax.block_until_ready(weights)
+    load = time.perf_counter() - t0
+    check(_on(dev, weights), "the bundle's weights are not on the device")
+    held = sum(w.nbytes for w in jax.tree_util.tree_leaves(weights))
+    size = os.path.getsize(a.bundle)
+    # the programs as text: the serialized bytes of one program differ
+    # from compile to compile
+    programs = hashlib.sha256("".join(
+        exes[name].as_text() for name in sorted(exes)).encode()
+    ).hexdigest()[:16]
     kernels = exes["decode"].as_text().count("tpu_custom_call")
-    say("serve: exported %s in %.1fs (%d bytes): %s"
-        % (sorted(exes), secs, os.path.getsize(a.bundle),
-           geometry.describe()))
+    say("serve: net from seed %d in %.1fs; exported %s in %.1fs (%d of the "
+        "programs compiled, the rest from the cache); loaded in %.1fs"
+        % (a.seed, built, sorted(exes), secs, len(compiled), load))
+    say("serve: bundle %d bytes = weights %d + %d; programs %s; %s"
+        % (size, held, size - held, programs, geometry.describe()))
     say("serve: %d tpu_custom_call in the decode executable" % kernels)
+    # no program holds a weight: one that did would add their bytes again
+    # (what is over them is the compiled code of the unrolled layers)
+    check(size - held < held / 2 or a.rehearse,
+          "the bundle is %d bytes over its weights" % (size - held))
     if not a.rehearse:
         check(kernels >= cfg["layers"],
               "decode holds the attention reference, not the paged kernel")
     _result({"device": device, "executables": sorted(exes),
-             "export_seconds": round(secs, 1), "paged_kernels": kernels})
+             "build_seconds": round(built, 1),
+             "export_seconds": round(secs, 1),
+             "load_seconds": round(load, 1), "compiled": len(compiled),
+             "bundle_bytes": size, "weight_bytes": held,
+             "programs": programs, "paged_kernels": kernels})
 
 
 def child_check(a):
-    """Child C: one teacher-forced gluon forward over prompt + served
-    tokens; each served token's logit against its position's maximum."""
+    """Child C: for every answer one teacher-forced gluon forward over
+    prompt + served tokens; each served token's logit against its
+    position's maximum."""
     import numpy as np
 
     import mxnet_tpu as mx
@@ -310,28 +346,33 @@ def child_check(a):
     dev, device = _device(a.rehearse)
     cfg = TINY_SERVE if a.rehearse else SERVE
     with open(a.served) as f:
-        doc = json.load(f)
-    prompt, served = doc["prompt"], doc["tokens"]
+        answers = json.load(f)
+    worst, exact, of = 0.0, 0, 0
     with mx.tpu() as ctx:
         net = _serve_net(cfg, ctx)
-        out = net(mx.nd.array(np.array([prompt + served], np.int32),
-                              ctx=ctx))
-        check(out.data().devices() == {dev}, "logits left the device")
-        logits = out.asnumpy()[0].astype(np.float32)
-    check(np.isfinite(logits).all(), "non-finite logits")
-    # position len(prompt)-1+i predicts served token i
-    rows = logits[len(prompt) - 1:len(prompt) - 1 + len(served)]
-    gaps = rows.max(axis=-1) - rows[np.arange(len(served)), served]
-    exact = int((rows.argmax(axis=-1) == np.array(served)).sum())
-    say("serve: teacher-forced over %d prompt + %d served tokens: worst "
-        "logit gap %.4f (tolerance %.2f), %d/%d the exact argmax, logit "
-        "std %.3f"
-        % (len(prompt), len(served), gaps.max(), LOGIT_GAP_TOL, exact,
-           len(served), rows.std()))
-    check(gaps.max() <= LOGIT_GAP_TOL,
-          "served tokens disagree with the gluon model: gaps %s" % gaps)
-    _result({"device": device, "worst_gap": float(gaps.max()),
-             "exact": exact, "of": len(served)})
+        for doc in answers:
+            prompt, served = doc["prompt"], doc["tokens"]
+            out = net(mx.nd.array(np.array([prompt + served], np.int32),
+                                  ctx=ctx))
+            check(out.data().devices() == {dev}, "logits left the device")
+            logits = out.asnumpy()[0].astype(np.float32)
+            check(np.isfinite(logits).all(), "non-finite logits")
+            # position len(prompt)-1+i predicts served token i
+            rows = logits[len(prompt) - 1:len(prompt) - 1 + len(served)]
+            gaps = rows.max(axis=-1) - rows[np.arange(len(served)), served]
+            same = int((rows.argmax(axis=-1) == np.array(served)).sum())
+            say("serve: teacher-forced over %d prompt + %d served tokens: "
+                "worst logit gap %.4f (tolerance %.2f), %d/%d the exact "
+                "argmax, logit std %.3f"
+                % (len(prompt), len(served), gaps.max(), LOGIT_GAP_TOL,
+                   same, len(served), rows.std()))
+            check(gaps.max() <= LOGIT_GAP_TOL,
+                  "served tokens disagree with the gluon model: gaps %s"
+                  % gaps)
+            worst = max(worst, float(gaps.max()))
+            exact, of = exact + same, of + len(served)
+    _result({"device": device, "worst_gap": worst, "exact": exact,
+             "of": of, "answers": len(answers)})
 
 
 CHILDREN = {"train": child_train, "export": child_export,
@@ -423,6 +464,21 @@ def phase_serve(a, env, work):
     bundle = os.path.join(work, "decoder.mxaot")
     exported = run_child(a, "serve export", "export", ["--bundle", bundle],
                          env, 900)
+    # another net of the same shape: the programs are the same text, and
+    # every one of them comes from the compile cache
+    other = bundle + ".other"
+    again = run_child(a, "serve export again", "export",
+                      ["--bundle", other, "--seed", str(SEED + 1)], env, 900)
+    os.remove(other)
+    if again["programs"] != exported["programs"]:
+        raise PhaseFailed("serve export again: another seed's programs "
+                          "differ (%s, %s): they hold something of the "
+                          "weights" % (again["programs"],
+                                       exported["programs"]))
+    if again["compiled"]:
+        raise PhaseFailed("serve export again: %d programs compiled, the "
+                          "cache held the first export's"
+                          % again["compiled"])
 
     port = _free_port()
     base = "http://127.0.0.1:%d" % port
@@ -490,13 +546,11 @@ def phase_serve(a, env, work):
         raise PhaseFailed("serve: server exited %d after SIGTERM" % rc)
     say("serve: server exited 0 after SIGTERM")
 
-    # the longest prompt among the concurrent ones
-    i = max(range(4, 8), key=lambda j: len(prompts[j]))
     served = os.path.join(work, "served.json")
     with open(served, "w") as f:
-        json.dump({"prompt": prompts[i], "tokens": answers[i][1]["tokens"]},
-                  f)
-    run_child(a, "serve check", "check", ["--served", served], env, 600)
+        json.dump([{"prompt": p, "tokens": body["tokens"]}
+                   for p, (_, body, _) in zip(prompts, answers)], f)
+    run_child(a, "serve check", "check", ["--served", served], env, 900)
     return exported["device"]
 
 
@@ -570,6 +624,7 @@ def main():
     ap.add_argument("--steps", type=int, default=6, help=argparse.SUPPRESS)
     ap.add_argument("--mesh", action="store_true", help=argparse.SUPPRESS)
     ap.add_argument("--bundle", help=argparse.SUPPRESS)
+    ap.add_argument("--seed", type=int, default=SEED, help=argparse.SUPPRESS)
     ap.add_argument("--served", help=argparse.SUPPRESS)
     a = ap.parse_args()
     if a.child:

@@ -50,11 +50,13 @@ def export_serving_bundle(net, path, **kwargs):
 
 
 def load_serving_bundle(path, expect_geometry=None):
-    """Load + validate a serving bundle: ``(KVGeometry, executables)``.
+    """Load + validate a serving bundle: ``(KVGeometry, executables,
+    weights)``, the weight tree on the device, passed to every
+    executable after the cache state.
 
     All checks run at load time — bundle kind, complete KV-page
     geometry (page size, num pages, dtype, …), presence of every
-    executable the geometry names, and agreement with
+    executable the geometry names and of the weights, and agreement with
     ``expect_geometry`` when given — so a mismatched bundle fails here
     with a field-by-field error instead of inside XLA on the first
     decode."""

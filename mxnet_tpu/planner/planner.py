@@ -255,11 +255,11 @@ def plan_serving(net, geometry, mesh, data_axis="data", **kwargs):
     """
     pl = plan_for_net(net, mesh, data_axis=data_axis, **kwargs)
     axes = pl.mesh_axes
-    kv_spec = [None, None, None, None, None]
+    kv_spec = [None, None, None, None]
     for axis, size in axes.items():
         if axis != data_axis and size > 1 \
                 and geometry.num_kv_heads % size == 0:
-            kv_spec[2] = axis        # (L, P, KV-heads, page, head-dim)
+            kv_spec[1] = axis        # a layer's (P, KV-heads, page, head-dim)
             break
     doc = pl.as_dict()
     doc["kv_spec"] = kv_spec

@@ -6,10 +6,13 @@ Three coupled pieces (ISSUE 8 tentpole):
   queue with backpressure, prefill/decode split over bucketed sequence
   lengths, slot recycling on EOS;
 - :mod:`.arena` — paged KV-cache arena: block tables over fixed-size
-  KV pages held as NDArrays, reuse gated on the engine's
-  var-dependency tracking (``Engine.pending_reads``);
-- :mod:`.model` — AOT-compiled paged prefill/decode executables in a
-  PR 7 ``MXAOT1`` bundle, so a serving process performs zero live jits.
+  KV pages, one device buffer a layer and side, reuse gated on the
+  engine's var-dependency tracking (``Engine.pending_reads``);
+- :mod:`.model` — paged prefill/decode programs AOT-compiled from the
+  geometry's shapes alone, with the weights as an argument: a PR 7
+  ``MXAOT1`` bundle holds each program once and the weights once, so a
+  serving process performs zero live jits and a reload swaps programs,
+  weights and arena together.
 
 ISSUE 13 stacked two decode multipliers on top: n-gram self-speculative
 decoding (:mod:`.spec` proposes drafts, the bundle's compiled ``verify``

@@ -1,8 +1,11 @@
 """Paged KV-cache arena: fixed-size pages, block tables, liveness-safe
 reuse.
 
-The whole cache is two NDArrays shaped ``(L, P, KV, page, D)`` (one for
-K, one for V).  Sequences own pages through host-side block tables —
+The cache is one ``(P, KV, page, D)`` device buffer a layer and side
+(K, V), each with a ``(P,)`` float32 scale row beside it when the arena
+is int8 (``model.state_avals``): every buffer is a donated argument of
+its own, so a program appends to a layer's pages where they lie.
+Sequences own pages through host-side block tables —
 int32 rows mapping ``token_position // page_size`` to a page index — so
 admission never copies or reshapes cache memory: allocating a sequence
 is popping page ids off a free list, finishing one is pushing them back.
@@ -40,50 +43,26 @@ from ..testing import rescheck as _rescheck
 
 
 class PagedKVArena:
-    """Block-table allocator over two arena NDArrays (K and V)."""
+    """Block-table allocator over the per-layer K and V page buffers."""
 
     def __init__(self, geometry, mesh=None, kv_spec=None):
-        import jax
-
-        from ..ndarray.ndarray import NDArray
-
         self.geometry = geometry
-        shape = geometry.kv_shape()
-        # int8 geometries store quantized pages plus one float32 scale
-        # per (layer, page) for each of K and V — the scales live on
-        # device too, as executable state alongside the kv buffers
         self.quantized = geometry.quantized
-        dtype = np.dtype(geometry.kv_dtype)
-        # device_put, NOT nd.zeros: a serving process must not push ops
-        # (zero live compiles — the tentpole claim of the AOT warm start)
-        # With mesh=/kv_spec= the arena buffers live sharded on the mesh
-        # — KV heads (dim 2) on the tp axis is the canonical spec; the
-        # serving executables' kv arguments then inherit the placement.
-        placement = None
+        # With mesh=/kv_spec= the page buffers live sharded on the mesh
+        # — KV heads (dim 1 of a layer's buffer) on the tp axis is the
+        # canonical spec; the serving executables' kv arguments then
+        # inherit the placement.  Scales are tiny and stay on one device.
+        self._placement = None
         if mesh is not None or kv_spec is not None:
             from .. import sharding as _sharding
 
-            placement = _sharding.named_sharding(mesh, kv_spec)
-            _sharding.maybe_verify(placement.mesh, placement.spec,
-                                   shape=shape, what="kv_arena")
-        self.kv_k = NDArray(jax.device_put(np.zeros(shape, dtype),
-                                           placement))
-        self.kv_v = NDArray(jax.device_put(np.zeros(shape, dtype),
-                                           placement))
-        _memdump.tag(self.kv_k.data(), origin="kv_page", label="arena.k")
-        _memdump.tag(self.kv_v.data(), origin="kv_page", label="arena.v")
-        self.k_scale = self.v_scale = None
-        if self.quantized:
-            # scales are tiny and replicated — never sharded
-            sshape = geometry.scale_shape()
-            self.k_scale = NDArray(jax.device_put(
-                np.zeros(sshape, np.float32)))
-            self.v_scale = NDArray(jax.device_put(
-                np.zeros(sshape, np.float32)))
-            _memdump.tag(self.k_scale.data(), origin="kv_page",
-                         label="arena.k_scale")
-            _memdump.tag(self.v_scale.data(), origin="kv_page",
-                         label="arena.v_scale")
+            self._placement = _sharding.named_sharding(mesh, kv_spec)
+            _sharding.maybe_verify(self._placement.mesh,
+                                   self._placement.spec,
+                                   shape=geometry.kv_shape(),
+                                   what="kv_arena")
+        self._state = self._zeros()
+        self.tag()
         # page 0 is the null page — never allocated
         self._free = collections.deque(range(1, geometry.num_pages))
         # page id -> LIST of owner tags.  One entry per reference: the
@@ -238,8 +217,6 @@ class PagedKVArena:
         zero live compiles holds even through a crash).  Only legal once
         every request was failed (``Scheduler.fail_all``): resetting
         under a live sequence would be silent KV corruption."""
-        import jax
-
         if self._owner:
             raise MXNetError(
                 "arena reset with %d live page(s) — fail the in-flight "
@@ -248,20 +225,8 @@ class PagedKVArena:
         for tok in self._res.values():
             _rescheck.release(tok)
         self._res.clear()
-        dtype = np.dtype(self.geometry.kv_dtype)
-        zeros = np.zeros(self.geometry.kv_shape(), dtype)
-        self.kv_k._set_data(jax.device_put(zeros))
-        self.kv_v._set_data(jax.device_put(zeros))
-        _memdump.tag(self.kv_k.data(), origin="kv_page", label="arena.k")
-        _memdump.tag(self.kv_v.data(), origin="kv_page", label="arena.v")
-        if self.quantized:
-            szeros = np.zeros(self.geometry.scale_shape(), np.float32)
-            self.k_scale._set_data(jax.device_put(szeros))
-            self.v_scale._set_data(jax.device_put(szeros))
-            _memdump.tag(self.k_scale.data(), origin="kv_page",
-                         label="arena.k_scale")
-            _memdump.tag(self.v_scale.data(), origin="kv_page",
-                         label="arena.v_scale")
+        self._state = self._zeros()
+        self.tag()
         self._gauges()
 
     def block_row(self, pages):
@@ -272,14 +237,44 @@ class PagedKVArena:
         return row
 
     # -- engine liveness --------------------------------------------------
+    def _zeros(self):
+        """A zeroed cache state placed with plain ``device_put`` — NOT
+        nd.zeros: a serving process must not push ops (zero live
+        compiles, the AOT warm start's claim, even through a crash)."""
+        import jax
+
+        from .model import state_avals
+
+        def zero(aval, where=None):
+            return aval and jax.device_put(
+                np.zeros(aval.shape, aval.dtype), where)
+
+        # the pages where the caller placed them, the scales where the
+        # programs run
+        return tuple(
+            ((zero(k, self._placement), zero(k_sc)),
+             (zero(v, self._placement), zero(v_sc)))
+            for (k, k_sc), (v, v_sc) in state_avals(self.geometry))
+
+    def tag(self):
+        """Attribute every buffer of the current state to ``kv_page``
+        (an untagged buffer sweeps as "temp").  A program hands back new
+        array objects every step, two to four a layer, so the tags are
+        renewed where the accounting is read (``LlamaServer.healthz``,
+        ``/metrics``) and not in :meth:`adopt`.  Any thread may call it:
+        it reads one reference, and a buffer donated meanwhile is no
+        longer live and counts nowhere."""
+        for (k, k_sc), (v, v_sc) in self._state:
+            _memdump.tag(k, origin="kv_page", label="arena.k")
+            _memdump.tag(v, origin="kv_page", label="arena.v")
+            _memdump.tag(k_sc, origin="kv_page", label="arena.k_scale")
+            _memdump.tag(v_sc, origin="kv_page", label="arena.v_scale")
+
     def buffers(self):
-        """The concrete arena state buffers in executable argument order
-        (for liveness queries/donation): ``(k, v)``, or ``(k, v,
-        k_scale, v_scale)`` when the arena is quantized."""
-        if self.quantized:
-            return (self.kv_k.data(), self.kv_v.data(),
-                    self.k_scale.data(), self.v_scale.data())
-        return (self.kv_k.data(), self.kv_v.data())
+        """The cache state as the programs take it (argument 0): one
+        ``((k_pages, k_scale), (v_pages, v_scale))`` a layer, the scales
+        None unless the arena is quantized."""
+        return self._state
 
     def drain_pending_readers(self, origin):
         """Flush this thread's bulk segment if it still reads the arena.
@@ -290,8 +285,10 @@ class PagedKVArena:
         overwritten under a deferred read.  Cheap no-op when nothing
         pends (the steady-state serving case — no imperative ops at all).
         """
+        import jax
+
         eng = Engine.get()
-        bufs = self.buffers()
+        bufs = jax.tree_util.tree_leaves(self._state)
         if eng.pending_reads(bufs):
             eng.flush_if_referencing(bufs, origin)
             self.liveness_flushes += 1
@@ -301,27 +298,11 @@ class PagedKVArena:
                     help="bulk-segment flushes forced because a pending "
                          "segment still read the KV arena").inc()
 
-    def adopt(self, new_k, new_v, new_k_scale=None, new_v_scale=None):
-        """Swap in the post-call arena buffers (the donating executables
-        deleted the old ones, so this is the only live reference
-        handoff).  Quantized arenas must hand the two scale arrays back
-        too — they are executable state."""
-        self.kv_k._set_data(new_k)
-        self.kv_v._set_data(new_v)
-        # re-attribute: the swap is the only place fresh arena storage
-        # appears, and an untagged buffer would sweep as "temp"
-        _memdump.tag(new_k, origin="kv_page", label="arena.k")
-        _memdump.tag(new_v, origin="kv_page", label="arena.v")
-        if self.quantized:
-            if new_k_scale is None or new_v_scale is None:
-                raise MXNetError("quantized arena adopt needs the scale "
-                                 "arrays back from the executable")
-            self.k_scale._set_data(new_k_scale)
-            self.v_scale._set_data(new_v_scale)
-            _memdump.tag(new_k_scale, origin="kv_page",
-                         label="arena.k_scale")
-            _memdump.tag(new_v_scale, origin="kv_page",
-                         label="arena.v_scale")
+    def adopt(self, state):
+        """Swap in the post-call cache state (the donating executables
+        deleted the old buffers, so this is the only live reference
+        handoff)."""
+        self._state = state
 
     def _gauges(self):
         if _metrics.enabled():

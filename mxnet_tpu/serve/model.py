@@ -1,8 +1,11 @@
 """Paged-attention prefill/decode graphs + AOT serving bundles.
 
-The serving tier never runs the gluon model: at export time the Llama
-weights are pulled out of the block tree and baked as XLA constants into
-two purpose-built graphs —
+The serving tier never runs the gluon model.  Its programs are built
+from a :class:`KVGeometry` alone — every one takes the cache state
+(donated), then the weight tree ``(embed, layers, norm, head)`` (an
+ordinary argument), then the step's inputs — so no program holds a weight;
+at export the Llama weights are pulled out of the block tree and written
+once, beside the programs.  The graphs —
 
 - ``prefill_<T>`` (one per sequence-length bucket): runs the whole
   prompt through full causal attention, scatters every K/V row into the
@@ -34,11 +37,15 @@ token-for-token identical at int8.  Page reuse is safe for free: a new
 owner's first write to a page is always that page's slot 0 (positions
 are written in order), which resets the scale.
 
-All of them donate the KV arena buffers (argnums 0/1), so the
-steady-state decode loop updates the cache in place with zero copies.
-The compiled executables ship in a PR 7 ``MXAOT1`` bundle whose meta carries the
-KV-page geometry; a serving process deserializes them at startup and
-performs **zero live jits** (asserted by the serve-smoke CI job).
+All of them donate the cache state — one ``(P, KV, page, D)`` buffer a
+layer and side (:func:`state_avals`; on a TPU ``D`` is padded to whole
+128-lane rows) — so a layer's append is written where its pages lie and
+the paged kernel reads them there: no program makes an array the size of
+a layer's pages (tests/test_tpu_compile.py).  Each compiled program ships
+once, and the weights once, in one PR 7 ``MXAOT1`` bundle whose meta
+carries the KV-page geometry; a serving process deserializes the
+programs, places the weights on the device once and performs **zero live
+jits** (asserted by the serve-smoke CI job).
 
 Numerics match ``gluon.model_zoo.llama`` exactly: RMSNorm in f32
 (``lax.rsqrt``), rotate-half RoPE with the same inv-freq table, GQA via
@@ -55,12 +62,14 @@ import numpy as np
 from ..base import MXNetError
 
 BUNDLE_KIND = "serving"
+# the bundle entry that holds the weight tree; every other entry is a
+# serialized program
+WEIGHTS_ENTRY = "weights"
 
 # geometry fields a serving bundle must carry; the load-time validator
 # refuses a bundle missing any of them (satellite: fail at load, not
-# inside XLA on the first mismatched decode).  kv_dtype/spec_k are NOT
-# in this list: pre-PR-13 bundles lack them and must keep loading
-# (defaulting to an fp32 arena with speculation off).
+# inside XLA on the first mismatched decode).  The others default: the
+# arena in the model dtype, speculation and chunked prefill off.
 _GEOM_INT_FIELDS = ("num_layers", "num_heads", "num_kv_heads", "head_dim",
                     "units", "hidden_size", "vocab_size", "page_size",
                     "num_pages", "max_pages_per_seq", "max_batch")
@@ -106,23 +115,20 @@ class KVGeometry:
         self.rope_base = float(rope_base)
         self.eps = float(eps)
         self.tie_embeddings = bool(tie_embeddings)
-        # PR 13 fields with pre-PR-13 defaults: an old bundle dict that
-        # carries neither loads as an fp32 arena with speculation off
+        # default: the arena in the model dtype, speculation off
         self.kv_dtype = str(kv_dtype) if kv_dtype else self.dtype
         self.spec_k = int(spec_k)
         # ISSUE 19: chunked-prefill width.  > 0 additionally compiles a
         # batched mid-sequence ``chunk`` executable (the step graph at
         # k1=prefill_chunk) so long / over-bucket prompts prefill in
         # ladder-sized chunks interleaved with decode steps, and cached
-        # prefix splices resume mid-sequence.  0 = off; old bundle
-        # dicts lack the field and load with it off.
+        # prefix splices resume mid-sequence.  0 = off.
         self.prefill_chunk = int(prefill_chunk)
         # PR 14: which decode/verify attention the executables were
         # BUILT with — "auto" (Pallas kernel on TPU, XLA reference
         # elsewhere), "1" (kernel forced; interpreter off-TPU), "0"
         # (reference forced).  Baked at export: a bundle records the
-        # choice in its meta, a loaded server inherits it.  Old bundle
-        # dicts lack the field and load as "auto".
+        # choice in its meta, a loaded server inherits it.
         if paged_kernel is None or paged_kernel == "":
             paged_kernel = "auto"
         if isinstance(paged_kernel, bool) or isinstance(paged_kernel, int):
@@ -201,23 +207,24 @@ class KVGeometry:
         return cls(**d)
 
     def kv_shape(self):
-        """Arena buffer shape: (L, P, KV-heads, page, head-dim).
+        """One layer's K (or V) buffer: (P, KV-heads, page, head-dim) —
+        the arena is ``2 x num_layers`` of them (:func:`state_avals`).
 
         A page of one kv-head is the contiguous ``(page, head-dim)`` tile
         the paged-attention kernel DMAs: the TPU lowering takes a block
         only if its last two dims are the array's own or multiples of
         (8, 128), which a block of 1 on a kv-head axis in second-minor
         position is not."""
-        return (self.num_layers, self.num_pages, self.num_kv_heads,
-                self.page_size, self.head_dim)
+        return (self.num_pages, self.num_kv_heads, self.page_size,
+                self.head_dim)
 
     # the fields a replacement bundle must agree on for an in-place
     # hot-swap (``LlamaServer.reload``): everything the scheduler and
     # the queued requests already depend on — paging layout, batch
     # width, bucket ladder, vocabulary, arena dtype and verify width.
     # Model internals (layers, heads, weights) are free to change: the
-    # arena is rebuilt from the new geometry and the executables are
-    # self-contained.
+    # arena is rebuilt from the new geometry, and programs, weights and
+    # arena swap together.
     HOT_SWAP_FIELDS = ("page_size", "num_pages", "max_pages_per_seq",
                        "max_batch", "prefill_buckets", "vocab_size",
                        "kv_dtype", "spec_k", "prefill_chunk")
@@ -235,19 +242,34 @@ class KVGeometry:
         return self.kv_dtype == "int8"
 
     def scale_shape(self):
-        """Per-page quantization scale shape: (L, pages); one float32
-        scale per (layer, page) for each of K and V."""
-        return (self.num_layers, self.num_pages)
+        """One layer's quantization scales for K (or V): (pages,), one
+        float32 scale a page."""
+        return (self.num_pages,)
+
+    def arena_bytes(self, padded=False):
+        """What the cache takes: ``2 x num_layers`` page buffers, and
+        their scale rows when quantized.  ``padded``: as a TPU holds
+        them, in (8, 128) tiles and with the head dim in whole 128-lane
+        rows (:func:`state_avals`) — 2x at a 64-wide head."""
+        rows, lanes = self.page_size, self.head_dim
+        if padded:
+            rows, lanes = -(-rows // 8) * 8, -(-lanes // 128) * 128
+        side = self.num_pages * (
+            self.num_kv_heads * rows * lanes
+            * np.dtype(self.kv_dtype).itemsize + 4 * self.quantized)
+        return 2 * self.num_layers * side
 
     def describe(self):
         return ("layers=%d heads=%d/%d head_dim=%d pages=%dx%d "
                 "max_batch=%d buckets=%s dtype=%s kv_dtype=%s spec_k=%d "
-                "paged_kernel=%s prefill_chunk=%d"
+                "paged_kernel=%s prefill_chunk=%d arena=%.3fGB "
+                "(%.3fGB in a TPU's tiles)"
                 % (self.num_layers, self.num_heads, self.num_kv_heads,
                    self.head_dim, self.num_pages, self.page_size,
                    self.max_batch, list(self.prefill_buckets), self.dtype,
                    self.kv_dtype, self.spec_k, self.paged_kernel,
-                   self.prefill_chunk))
+                   self.prefill_chunk, self.arena_bytes() / 1e9,
+                   self.arena_bytes(padded=True) / 1e9))
 
 
 def _env_int(name, default):
@@ -379,18 +401,84 @@ def _rotate(x, cos, sin):
                            axis=-1)
 
 
-def build_step_fn(weights, geometry, k1):
+def weight_avals(geometry):
+    """The weight tree ``(embed, layers, norm, head)`` as
+    ``jax.ShapeDtypeStruct``s — the geometry alone says every shape, so
+    the programs compile with no weight anywhere (``head`` is None for
+    tied embeddings; dense weights keep the gluon (out, in) layout)."""
+    import jax
+
+    g = geometry
+    dt = np.dtype(g.dtype)
+
+    def aval(*shape):
+        return jax.ShapeDtypeStruct(shape, dt)
+
+    u, f = g.units, g.hidden_size
+    hd, kvd = g.num_heads * g.head_dim, g.num_kv_heads * g.head_dim
+    layer = {"attn_norm": aval(u), "q": aval(hd, u), "k": aval(kvd, u),
+             "v": aval(kvd, u), "o": aval(u, hd), "ffn_norm": aval(u),
+             "gate": aval(f, u), "up": aval(f, u), "down": aval(u, f)}
+    return (aval(g.vocab_size, u),
+            [dict(layer) for _ in range(g.num_layers)], aval(u),
+            None if g.tie_embeddings else aval(g.vocab_size, u))
+
+
+def state_avals(geometry, device=None):
+    """The cache state as ``jax.ShapeDtypeStruct``s: one entry a layer,
+    each ``((k_pages, k_scale), (v_pages, v_scale))`` — the scale a
+    ``scale_shape()`` float32 row for int8 and None otherwise.  Every
+    buffer is a donated argument of its own, so a layer's append aliases
+    that layer's buffer and the paged kernel reads it where it lies.
+
+    Pages are ``kv_shape()`` in the arena dtype, except that for a TPU
+    (``device``, default the first one) the head dim is padded to whole
+    128-lane rows.  The chip holds a row-major ``(page, D)`` tile in 128
+    lanes whatever ``D`` is, so the padding costs no byte
+    (``KVGeometry.arena_bytes(padded=True)``); but left an array whose
+    rows are not whole lanes, it lays it out pages-minor instead, and
+    every step then turns every layer's buffer over, twice, to feed the
+    kernel (PERF.md 7.1)."""
+    import jax
+
+    g = geometry
+    lanes = g.head_dim
+    if (device or jax.devices()[0]).platform == "tpu":
+        lanes = -(-lanes // 128) * 128
+    side = (jax.ShapeDtypeStruct(g.kv_shape()[:-1] + (lanes,),
+                                 np.dtype(g.kv_dtype)),
+            jax.ShapeDtypeStruct(g.scale_shape(), np.dtype(np.float32))
+            if g.quantized else None)
+    return tuple((side, side) for _ in range(g.num_layers))
+
+
+def _to_lanes(x, lanes):
+    """``x`` with zeros after its last axis up to ``lanes`` wide."""
+    import jax.numpy as jnp
+
+    pad = lanes - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
+
+
+def _absmax(rows):
+    """Per-token absmax over (KV, D) in float32 (int8 scale input)."""
+    import jax.numpy as jnp
+
+    return jnp.max(jnp.abs(rows.astype(jnp.float32)), axis=(-2, -1))
+
+
+def build_step_fn(geometry, k1):
     """``k1`` tokens per lane through the paged arena in one call.
 
-    This is the shared body of ``decode`` (``k1=1``) and ``verify``
-    (``k1=spec_k+1``).  Signature (all positional; kv buffers — and the
-    scale arrays for int8 — donated by the AOT compile):
+    This is the shared body of ``decode`` (``k1=1``), ``verify``
+    (``k1=spec_k+1``) and ``chunk`` (``k1=prefill_chunk``):
 
-    - fp32: ``(kv_k, kv_v, tokens (B, k1) i32, positions (B,) i32,
-      block_table (B, maxp) i32) -> (kv_k, kv_v, logits (B, k1, V))``
-    - int8: ``(kv_k, kv_v, k_scale (L, P) f32, v_scale (L, P) f32,
-      tokens, positions, block_table) -> (kv_k, kv_v, k_scale, v_scale,
-      logits)``
+    ``(state, weights, tokens (B, k1) i32, positions (B,) i32,
+    block_table (B, maxp) i32) -> (state, logits (B, k1, V) f32)``
+
+    ``state`` is the cache (:func:`state_avals`), donated by the AOT
+    compile; ``weights`` the tree of :func:`weight_avals`, an ordinary
+    argument — no program holds a weight.
 
     Lane ``b``'s token ``j`` sits at position ``positions[b] + j``;
     query ``j`` attends context ``<= positions[b] + j`` only, so the
@@ -405,23 +493,20 @@ def build_step_fn(weights, geometry, k1):
     Int8 append: the row landing on a page's slot 0 fixes the page
     scale (own absmax x headroom / 127); rows landing further into a
     page quantize against the page's current scale — the scale of its
-    slot-0 write, whether that write happened in this call (the
-    ``start >= 0`` branch below) or in an earlier one.  Nothing already
-    stored is ever requantized, so arena bytes after token t are
-    independent of call grouping.
+    slot-0 write, whether that write happened in this call or in an
+    earlier one.  Nothing already stored is ever requantized, so arena
+    bytes after token t are independent of call grouping.
     """
     import jax
     import jax.numpy as jnp
 
     from ..ops.paged_attention import paged_attention as _paged_attn
 
-    embed, layers, norm, head = weights
     g = geometry
     H, KV, D, S = g.num_heads, g.num_kv_heads, g.head_dim, g.page_size
     scale = 1.0 / math.sqrt(D)
     ctx = g.max_pages_per_seq * S
-    int8 = g.quantized
-    jidx = jnp.arange(k1)
+    jidx = np.arange(k1)
     # attention path, resolved at BUILD time (the executable is AOT-
     # compiled for the default backend, so there is nothing to defer):
     # "1" forces the Pallas kernel (interpreter off-TPU — it traces to
@@ -431,44 +516,17 @@ def build_step_fn(weights, geometry, k1):
     kernel = g.paged_kernel == "1" or (
         g.paged_kernel == "auto" and jax.default_backend() == "tpu")
 
-    def append(kv, sc, li, pid, slot, rows):
-        """Scatter ``rows`` (B, k1, KV, D) at (li, pid, slot); quantize
-        against per-page scales when the arena is int8."""
-        if not int8:
-            return kv.at[li, pid, :, slot].set(rows.astype(kv.dtype)), sc
-        r32 = rows.astype(jnp.float32)
-        amax = jnp.max(jnp.abs(r32), axis=(2, 3))            # (B, k1)
-        # in-call page starts: token j's page began at call offset
-        # j - slot[j]; negative means the page's slot 0 was written by
-        # an earlier call and its stored scale rules
-        start = jidx[None, :] - slot                         # (B, k1)
-        first = jnp.take_along_axis(amax, jnp.clip(start, 0, k1 - 1),
-                                    axis=1)
-        news = jnp.where(start >= 0,
-                         first * (_INT8_SCALE_HEADROOM / _INT8_QMAX),
-                         sc[li, pid])
-        news = jnp.maximum(news, _INT8_MIN_SCALE)
-        q = jnp.clip(jnp.round(r32 / news[..., None, None]),
-                     -_INT8_QMAX, _INT8_QMAX).astype(jnp.int8)
-        # rows of one page all write the page's resolved scale — equal
-        # values, so duplicate scatter order cannot matter
-        return kv.at[li, pid, :, slot].set(q), sc.at[li, pid].set(news)
-
-    def gather(kv, sc, li, block_table, b, dt):
+    def gather(side, block_table, b, dt):
         """This lane's pages as (B, C, KV, D) in the model dtype."""
-        pages = kv[li, block_table]            # (B, maxp, KV, S, D)
-        if int8:
-            ps = sc[li, block_table]           # (B, maxp)
-            pages = (pages.astype(jnp.float32)
-                     * ps[..., None, None, None]).astype(dt)
-        return pages.transpose(0, 1, 3, 2, 4).reshape(b, ctx, KV, D)
+        pages, sc = side
+        got = pages[block_table][..., :D]      # (B, maxp, KV, S, D)
+        if sc is not None:
+            got = (got.astype(jnp.float32)
+                   * sc[block_table][..., None, None, None]).astype(dt)
+        return got.transpose(0, 1, 3, 2, 4).reshape(b, ctx, KV, D)
 
-    def step(kv_k, kv_v, *rest):
-        if int8:
-            k_sc, v_sc, tokens, positions, block_table = rest
-        else:
-            tokens, positions, block_table = rest
-            k_sc = v_sc = None
+    def step(state, weights, tokens, positions, block_table):
+        embed, layers, norm, head = weights
         b = tokens.shape[0]
         x = embed[tokens]                                    # (B, k1, U)
         pos = positions[:, None] + jidx[None, :]             # (B, k1)
@@ -479,30 +537,66 @@ def build_step_fn(weights, geometry, k1):
         pid = block_table[rows_b[:, None], pos // S]         # (B, k1)
         slot = pos % S
         valid = jnp.arange(ctx)[None, None, :] <= pos[..., None]
-        for li, lw in enumerate(layers):
+
+        def append(side, rows):
+            """Write ``rows`` (B, k1, KV, D) at ``[pid, :, slot]`` of one
+            layer's ``(pages, scale)``; quantize against per-page scales
+            when the arena is int8.  The scatter names page, kv-head and
+            slot, so each update is one contiguous row (``D`` wide, zeros
+            up to the buffer's lanes) of the layout the paged kernel
+            reads, and the buffer is updated where it lies."""
+            pages, sc = side
+            at = (pid[..., None], jnp.arange(KV), slot[..., None])
+            if sc is not None:
+                # in-call page starts: token j's page began at call
+                # offset j - slot[j]; negative means the page's slot 0
+                # was written by an earlier call and its stored scale
+                # rules
+                start = jidx[None, :] - slot                 # (B, k1)
+                first = jnp.take_along_axis(
+                    _absmax(rows), jnp.clip(start, 0, k1 - 1), axis=1)
+                news = jnp.maximum(jnp.where(
+                    start >= 0, first * (_INT8_SCALE_HEADROOM / _INT8_QMAX),
+                    sc[pid]), _INT8_MIN_SCALE)
+                rows = jnp.clip(
+                    jnp.round(rows.astype(jnp.float32)
+                              / news[..., None, None]),
+                    -_INT8_QMAX, _INT8_QMAX)
+                # rows of one page all write the page's resolved scale —
+                # equal values, so duplicate scatter order cannot matter
+                sc = sc.at[pid].set(news)
+            rows = _to_lanes(rows.astype(pages.dtype), pages.shape[-1])
+            return pages.at[at].set(rows), sc
+
+        new_state = []
+        for lw, (k_side, v_side) in zip(layers, state):
             h = _rmsnorm(x, lw["attn_norm"], g.eps)
             q = _rotate((h @ lw["q"].T).reshape(b, k1, H, D), cos, sin)
             k = _rotate((h @ lw["k"].T).reshape(b, k1, KV, D), cos, sin)
             v = (h @ lw["v"].T).reshape(b, k1, KV, D)
-            kv_k, k_sc = append(kv_k, k_sc, li, pid, slot, k)
-            kv_v, v_sc = append(kv_v, v_sc, li, pid, slot, v)
+            k_side, v_side = append(k_side, k), append(v_side, v)
+            new_state.append((k_side, v_side))
             if kernel:
                 # fused gather + dequant + online-softmax attention
-                # straight off the arena's pages — no (B, ctx, KV, D)
+                # straight off this layer's pages — no (B, ctx, KV, D)
                 # HBM materialization, no fp32 dequant copy, no GQA
                 # replication (ops/paged_attention.py)
-                sc_args = (k_sc[li], v_sc[li]) if int8 else ()
-                att = _paged_attn(q, kv_k[li], kv_v[li], block_table,
-                                  positions, *sc_args, scale=scale,
-                                  use_kernel=1)
+                # (a buffer wider than D holds zeros there: the padded
+                # query's scores are the query's, the result's lanes
+                # past D are zero)
+                att = _paged_attn(_to_lanes(q, k_side[0].shape[-1]),
+                                  k_side[0], v_side[0], block_table,
+                                  positions, k_scale=k_side[1],
+                                  v_scale=v_side[1], scale=scale,
+                                  use_kernel=1)[..., :D]
             else:
                 # XLA reference: still gathers the context, but attends
                 # grouped heads (B, k1, KV, G, ctx) directly — K/V are
                 # never replicated H/KV-fold (equal to the jnp.repeat
                 # form to float rounding, tests/test_paged_attention
                 # .py::test_grouped_einsum_matches_repeat)
-                keys = gather(kv_k, k_sc, li, block_table, b, x.dtype)
-                vals = gather(kv_v, v_sc, li, block_table, b, x.dtype)
+                keys = gather(k_side, block_table, b, x.dtype)
+                vals = gather(v_side, block_table, b, x.dtype)
                 qg = q.reshape(b, k1, KV, H // KV, D)
                 scores = jnp.einsum("bkvgd,bcvd->bkvgc", qg, keys) * scale
                 scores = jnp.where(valid[:, :, None, None, :],
@@ -517,53 +611,47 @@ def build_step_fn(weights, geometry, k1):
         xh = _rmsnorm(x, norm, g.eps)
         hw = embed if head is None else head
         logits = (xh @ hw.T).astype(jnp.float32)             # (B, k1, V)
-        if int8:
-            return kv_k, kv_v, k_sc, v_sc, logits
-        return kv_k, kv_v, logits
+        return tuple(new_state), logits
 
     return step
 
 
-def build_decode_fn(weights, geometry):
+def build_decode_fn(geometry):
     """One batched single-token decode step: the ``k1=1`` slice of
-    :func:`build_step_fn` with the historical external signature
-    (tokens ``(B,)``, logits ``(B, V)``); int8 geometries insert the
-    two scale arrays after the kv buffers."""
-    step = build_step_fn(weights, geometry, 1)
-    int8 = geometry.quantized
+    :func:`build_step_fn` with tokens ``(B,)`` and logits ``(B, V)``."""
+    step = build_step_fn(geometry, 1)
 
-    def decode(kv_k, kv_v, *rest):
-        scales, (tokens, positions, block_table) = \
-            (rest[:2], rest[2:]) if int8 else ((), rest)
-        outs = step(kv_k, kv_v, *scales, tokens[:, None], positions,
-                    block_table)
-        return outs[:-1] + (outs[-1][:, 0],)
+    def decode(state, weights, tokens, positions, block_table):
+        state, logits = step(state, weights, tokens[:, None], positions,
+                             block_table)
+        return state, logits[:, 0]
 
     return decode
 
 
-def build_verify_fn(weights, geometry):
+def build_verify_fn(geometry):
     """The speculative-decoding signature: ``spec_k + 1`` tokens per
     lane — ``tokens[:, 0]`` is the last accepted token, ``tokens[:,
     1:]`` the drafts — returning logits at every position so the
     scheduler accepts the longest draft prefix the model reproduces."""
     if geometry.spec_k <= 0:
         raise MXNetError("verify needs a geometry with spec_k > 0")
-    return build_step_fn(weights, geometry, geometry.spec_k + 1)
+    return build_step_fn(geometry, geometry.spec_k + 1)
 
 
-def build_prefill_fn(weights, geometry, bucket):
+def build_prefill_fn(geometry, bucket):
     """Whole-prompt pass for one padded bucket length ``T``.
 
-    ``(kv_k, kv_v, tokens (T,) i32, length () i32,
-    block_table (maxp,) i32) -> (kv_k, kv_v, logits (V,) f32)``; int8
-    geometries insert ``k_scale``/``v_scale`` after the kv buffers in
-    both tuples, exactly as in :func:`build_step_fn`.
+    ``(state, weights, tokens (T,) i32, length () i32,
+    block_table (maxp,) i32) -> (state, logits (V,) f32)``, state and
+    weights as in :func:`build_step_fn`.
 
-    Every position's K/V is scattered into the arena (pad positions land
-    on the null page or on this sequence's own not-yet-read slots, both
-    harmless: a pad-set page scale is reset by the sequence's own later
-    slot-0 write before any masked-in read); the returned logits are the
+    Every position's K/V is written into the arena a whole page at a
+    time (pad positions land on the null page or on this sequence's own
+    not-yet-read slots, both harmless: a pad-set page scale is reset by
+    the sequence's own later slot-0 write before any masked-in read; the
+    table's unallocated entries all name the null page, whose content
+    nothing reads); the returned logits are the
     last REAL token's — the first generated token comes straight out of
     prefill.  Attention here runs over the in-call full-precision K/V,
     not the arena, so prefill logits are identical between fp32 and int8
@@ -572,51 +660,47 @@ def build_prefill_fn(weights, geometry, bucket):
     import jax
     import jax.numpy as jnp
 
-    embed, layers, norm, head = weights
     g = geometry
     H, KV, D, S = g.num_heads, g.num_kv_heads, g.head_dim, g.page_size
     scale = 1.0 / math.sqrt(D)
     t = int(bucket)
-    int8 = g.quantized
 
-    def prefill(kv_k, kv_v, *rest):
-        if int8:
-            k_sc, v_sc, tokens, length, block_table = rest
-        else:
-            tokens, length, block_table = rest
-            k_sc = v_sc = None
+    def prefill(state, weights, tokens, length, block_table):
+        embed, layers, norm, head = weights
         x = embed[tokens]                                    # (T, U)
         pos = jnp.arange(t)
         cos, sin = _rope_tables(pos.astype(jnp.float32), D, g.rope_base)
         cos, sin = cos[:, None, :], sin[:, None, :]          # (T, 1, half)
-        pid = block_table[pos // S]                          # (T,)
-        slot = pos % S
         causal = (pos[None, :] <= pos[:, None]) \
             & (pos[None, :] < length)                        # (T, T)
+        n_pages = -(-t // S)
 
-        def append(kv, sc, li, rows):
-            if not int8:
-                return kv.at[li, pid, :, slot].set(
-                    rows.astype(kv.dtype)), sc
-            r32 = rows.astype(jnp.float32)
-            amax = jnp.max(jnp.abs(r32), axis=(1, 2))        # (T,)
-            # every page start is in-call during prefill: row (p//S)*S
-            # fixes page p//S's scale, all rows of a page scatter the
-            # same value so duplicate null-page writes stay harmless
-            first = amax[(pos // S) * S]
-            news = jnp.maximum(first * (_INT8_SCALE_HEADROOM / _INT8_QMAX),
-                               _INT8_MIN_SCALE)
-            q = jnp.clip(jnp.round(r32 / news[:, None, None]),
-                         -_INT8_QMAX, _INT8_QMAX).astype(jnp.int8)
-            return kv.at[li, pid, :, slot].set(q), sc.at[li, pid].set(news)
+        def append(side, rows):
+            """``rows`` (T, KV, D) as whole pages at the table's first
+            entries: one contiguous ``(KV, S, lanes)`` update a page.
+            Every page start is in-call during prefill: a page's slot-0
+            row fixes its scale."""
+            pages, sc = side
+            tiles = jnp.pad(rows, ((0, n_pages * S - t), (0, 0), (0, 0))) \
+                .reshape(n_pages, S, KV, D).transpose(0, 2, 1, 3)
+            if sc is not None:
+                news = jnp.maximum(
+                    _absmax(tiles[:, :, 0].reshape(n_pages, KV, D))
+                    * (_INT8_SCALE_HEADROOM / _INT8_QMAX), _INT8_MIN_SCALE)
+                tiles = jnp.clip(jnp.round(
+                    tiles.astype(jnp.float32) / news[:, None, None, None]),
+                    -_INT8_QMAX, _INT8_QMAX)
+                sc = sc.at[block_table[:n_pages]].set(news)
+            tiles = _to_lanes(tiles.astype(pages.dtype), pages.shape[-1])
+            return pages.at[block_table[:n_pages]].set(tiles), sc
 
-        for li, lw in enumerate(layers):
+        new_state = []
+        for lw, (k_side, v_side) in zip(layers, state):
             h = _rmsnorm(x, lw["attn_norm"], g.eps)
             q = _rotate((h @ lw["q"].T).reshape(t, H, D), cos, sin)
             k = _rotate((h @ lw["k"].T).reshape(t, KV, D), cos, sin)
             v = (h @ lw["v"].T).reshape(t, KV, D)
-            kv_k, k_sc = append(kv_k, k_sc, li, k)
-            kv_v, v_sc = append(kv_v, v_sc, li, v)
+            new_state.append((append(k_side, k), append(v_side, v)))
             # grouped-head attention: queries fold to (T, KV, G, D) so
             # K/V are never replicated H/KV-fold (equal to the
             # jnp.repeat form to float rounding; head h = kv*G + g)
@@ -634,81 +718,66 @@ def build_prefill_fn(weights, geometry, bucket):
         last = jnp.take(xh, length - 1, axis=0)              # (U,)
         hw = embed if head is None else head
         logits = (last @ hw.T).astype(jnp.float32)
-        if int8:
-            return kv_k, kv_v, k_sc, v_sc, logits
-        return kv_k, kv_v, logits
+        return tuple(new_state), logits
 
     return prefill
 
 
-def _aot_compile(fn, avals, n_state=2):
-    """jit → lower → compile; the first ``n_state`` args (KV buffers,
-    plus the two scale arrays for int8) are donated, so the decode loop
-    updates the cache in place.  Aliasing survives executable
-    serialization on every backend under jax 0.9.0 (the CPU exception
-    made for 0.4.37 was re-tested and dropped)."""
-    import jax
-
-    return jax.jit(fn, donate_argnums=tuple(range(n_state))) \
-        .lower(*avals).compile()
-
-
-def compile_serving_executables(net, geometry):
-    """Build + AOT-compile the decode, verify (when ``spec_k > 0``) and
-    per-bucket prefill graphs.
-
-    Returns ``{name: jax.stages.Compiled}`` with weights baked in as
-    constants — the bundle is self-contained, no .params sidecar.
-    """
+def serving_programs(geometry, device=None):
+    """``{name: (fn, avals)}`` for every program the geometry names:
+    ``decode``, ``verify`` (``spec_k > 0``), ``chunk`` (``prefill_chunk
+    > 0``: the step graph at that width — scatters a chunk of prompt
+    tokens and attends causally over arena context, so a prompt resumes
+    at any position) and one ``prefill_<T>`` a bucket.  ``avals`` are
+    shapes alone, cache state first, weights second, for ``device``
+    (default: the first; a described one, which the avals then name,
+    compiles with no chip)."""
     import jax
 
     g = geometry
-    raw = extract_weights(net)
-    from ..telemetry import memdump as _memdump
-
-    def dev(a):
-        buf = jax.device_put(np.asarray(a, dtype=g.dtype))
-        _memdump.tag(buf, origin="param", label="serving_weight")
-        return buf
-    weights = (dev(raw[0]), [{k: dev(v) for k, v in lw.items()}
-                             for lw in raw[1]], dev(raw[2]),
-               None if raw[3] is None else dev(raw[3]))
-    kv = jax.ShapeDtypeStruct(g.kv_shape(), np.dtype(g.kv_dtype))
     i32 = np.dtype(np.int32)
-    sc = jax.ShapeDtypeStruct(g.scale_shape(), np.dtype(np.float32))
-    state = (kv, kv, sc, sc) if g.quantized else (kv, kv)
-    exes = {}
+    head = (state_avals(g, device), weight_avals(g))
 
-    def lane_avals(tok_shape):
-        return state + (
+    def lanes(*tok_shape):
+        return head + (
             jax.ShapeDtypeStruct(tok_shape, i32),
             jax.ShapeDtypeStruct((g.max_batch,), i32),
             jax.ShapeDtypeStruct((g.max_batch, g.max_pages_per_seq), i32))
 
-    exes["decode"] = _aot_compile(build_decode_fn(weights, g),
-                                  lane_avals((g.max_batch,)),
-                                  n_state=len(state))
+    programs = {"decode": (build_decode_fn(g), lanes(g.max_batch))}
     if g.spec_k > 0:
-        exes["verify"] = _aot_compile(
-            build_verify_fn(weights, g),
-            lane_avals((g.max_batch, g.spec_k + 1)), n_state=len(state))
+        programs["verify"] = (build_verify_fn(g),
+                              lanes(g.max_batch, g.spec_k + 1))
     if g.prefill_chunk > 0:
-        # mid-sequence chunked prefill: the step graph at
-        # k1=prefill_chunk — scatters a chunk of prompt tokens into the
-        # arena and attends causally over arena context, so a prompt
-        # resumes at any position (cached-prefix splice, chunk N of M)
-        exes["chunk"] = _aot_compile(
-            build_step_fn(weights, g, g.prefill_chunk),
-            lane_avals((g.max_batch, g.prefill_chunk)),
-            n_state=len(state))
+        programs["chunk"] = (build_step_fn(g, g.prefill_chunk),
+                             lanes(g.max_batch, g.prefill_chunk))
     for b in g.prefill_buckets:
-        pf_avals = state + (jax.ShapeDtypeStruct((b,), i32),
-                            jax.ShapeDtypeStruct((), i32),
-                            jax.ShapeDtypeStruct((g.max_pages_per_seq,),
-                                                 i32))
-        exes["prefill_%d" % b] = _aot_compile(
-            build_prefill_fn(weights, g, b), pf_avals, n_state=len(state))
-    return exes
+        programs["prefill_%d" % b] = (build_prefill_fn(g, b), head + (
+            jax.ShapeDtypeStruct((b,), i32), jax.ShapeDtypeStruct((), i32),
+            jax.ShapeDtypeStruct((g.max_pages_per_seq,), i32)))
+    if device is not None:
+        where = jax.sharding.SingleDeviceSharding(device)
+        programs = {name: (fn, jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=where),
+            avals)) for name, (fn, avals) in programs.items()}
+    return programs
+
+
+def compile_serving_executables(geometry, device=None):
+    """AOT-compile :func:`serving_programs` from shapes alone: ``{name:
+    jax.stages.Compiled}``.  The cache state (argument 0) is donated,
+    so the decode loop updates the pages in place — aliasing survives
+    executable serialization on every backend under jax 0.9.0.  The same
+    geometry gives the same programs whatever the weights, so jax's
+    compile cache serves every export after the first."""
+    import jax
+
+    from .. import profiler as _profiler
+
+    with _profiler.setup_span("serve.export"):
+        return {name: jax.jit(fn, donate_argnums=(0,)).lower(*avals)
+                .compile() for name, (fn, avals)
+                in serving_programs(geometry, device).items()}
 
 
 def export_serving_bundle(net, path, page_size=None, num_pages=None,
@@ -718,9 +787,11 @@ def export_serving_bundle(net, path, page_size=None, num_pages=None,
                           prefill_chunk=None):
     """Export ``net`` as a self-contained MXAOT1 serving bundle.
 
-    The bundle carries the AOT-compiled decode + per-bucket prefill
-    executables (weights baked in) and the :class:`KVGeometry` in its
-    meta, so ``serve.LlamaServer(path)`` starts with zero live compiles.
+    One file: each AOT-compiled program once (decode, per-bucket
+    prefill, verify / chunk where the geometry asks), the weights once
+    (the ``weights`` entry, host arrays in the model dtype) and the
+    :class:`KVGeometry` in the meta, so ``serve.LlamaServer(path)``
+    starts with zero live compiles.
     Paging knobs default from ``MXNET_SERVE_*`` (docs/env_vars.md);
     ``kv_dtype="int8"`` quantizes the arena pages, ``spec_k=K`` adds the
     compiled ``verify`` executable for n-gram speculative decoding, and
@@ -737,8 +808,6 @@ def export_serving_bundle(net, path, page_size=None, num_pages=None,
     (``planner.plan_serving``).  The executables themselves stay
     single-device; the planner meta is advisory placement data.
     """
-    from .. import compile_cache as _ccache
-
     g = geometry_from_net(net, page_size=page_size, num_pages=num_pages,
                           max_batch=max_batch,
                           prefill_buckets=prefill_buckets,
@@ -746,22 +815,37 @@ def export_serving_bundle(net, path, page_size=None, num_pages=None,
                           kv_dtype=kv_dtype, spec_k=spec_k,
                           paged_kernel=paged_kernel,
                           prefill_chunk=prefill_chunk)
-    meta = {"kind": BUNDLE_KIND, "geometry": g.to_dict()}
+    meta = {}
     if mesh is not None:
         from .. import planner as _planner
 
         meta["planner"] = _planner.plan_serving(net, g, mesh)
-    exes = compile_serving_executables(net, g)
-    entries = {name: _ccache.serialize_compiled(c)
-               for name, c in exes.items()}
-    _ccache.save_bundle(path, entries, meta=meta)
+    save_serving_bundle(path, g, extract_weights(net), meta)
     return g
+
+
+def save_serving_bundle(path, geometry, weights, meta=None):
+    """Compile ``geometry``'s programs and write them, with the host
+    weight tree ``(embed, layers, norm, head)`` (:func:`weight_avals`'s
+    shapes; cast to the model dtype), as one MXAOT1 file — the half of
+    :func:`export_serving_bundle` that needs no gluon net."""
+    import jax
+
+    from .. import compile_cache as _ccache
+
+    g = geometry
+    entries = {name: _ccache.serialize_compiled(c)
+               for name, c in compile_serving_executables(g).items()}
+    entries[WEIGHTS_ENTRY] = jax.tree_util.tree_map(
+        lambda a: np.asarray(a, dtype=g.dtype), weights)
+    _ccache.save_bundle(path, entries, meta=dict(
+        meta or {}, kind=BUNDLE_KIND, geometry=g.to_dict()))
 
 
 def read_bundle_geometry(path):
     """Parse + validate a serving bundle's KV geometry WITHOUT
-    deserializing any executable (cheap inspection: Predictor's
-    redirect error, doctor tools).  Returns ``(KVGeometry, doc)``."""
+    deserializing any executable (Predictor's redirect error, doctor
+    tools).  Returns ``(KVGeometry, doc)``."""
     from .. import compile_cache as _ccache
 
     doc = _ccache.load_bundle(path)
@@ -775,33 +859,54 @@ def read_bundle_geometry(path):
 
 
 def load_serving_executables(path, expect=None):
-    """Load a serving bundle: ``(KVGeometry, {name: Compiled})``.
+    """Load a serving bundle: ``(KVGeometry, {name: Compiled},
+    weights)`` — the weight tree placed on the device once, to be
+    passed to every program after the cache state.
 
     Validation happens HERE, not on the first decode: the bundle must be
     a serving bundle, its meta must carry a complete geometry, every
-    executable named by the geometry must be present, and — when the
-    caller passes ``expect`` (a KVGeometry or partial dict) — the
-    KV-page geometry must agree field by field, each mismatch named in
-    the error.
+    executable named by the geometry and the weights must be present,
+    and — when the caller passes ``expect`` (a KVGeometry or partial
+    dict) — the KV-page geometry must agree field by field, each
+    mismatch named in the error.
     """
+    import jax
+
     from .. import compile_cache as _ccache
+    from .. import profiler as _profiler
+    from ..telemetry import memdump as _memdump
 
     g, doc = read_bundle_geometry(path)
     if expect is not None:
         check_geometry(g, expect, origin=path)
-    want = ["decode"] + ["prefill_%d" % b for b in g.prefill_buckets]
-    if g.spec_k > 0:
-        want.append("verify")
-    if g.prefill_chunk > 0:
-        want.append("chunk")
+    want = list(serving_programs(g))
     entries = doc.get("entries", {})
+    if WEIGHTS_ENTRY not in entries:
+        raise MXNetError(
+            "%s: serving bundle carries no weights (its programs held "
+            "them as constants) — re-export with "
+            "serve.export_serving_bundle" % path)
     missing = [n for n in want if n not in entries]
     if missing:
         raise MXNetError("%s: serving bundle is missing executables %s "
                          "for geometry [%s]"
                          % (path, missing, g.describe()))
-    exes = {n: _ccache.deserialize_compiled(entries[n]) for n in want}
-    return g, exes
+    with _profiler.setup_span("serve.load.programs"):
+        exes = {n: _ccache.deserialize_compiled(entries[n]) for n in want}
+    def sig(tree):
+        return jax.tree_util.tree_map(
+            lambda a: (tuple(a.shape), str(a.dtype)), tree)
+
+    if sig(entries[WEIGHTS_ENTRY]) != sig(weight_avals(g)):
+        raise MXNetError("%s: the bundle's weights are not the shapes its "
+                         "geometry names [%s]" % (path, g.describe()))
+    with _profiler.setup_span("serve.load.weights"):
+        # to the end of the transfer, so that the stage is the weights'
+        weights = jax.block_until_ready(
+            jax.device_put(entries[WEIGHTS_ENTRY]))
+        for leaf in jax.tree_util.tree_leaves(weights):
+            _memdump.tag(leaf, origin="param", label="serving_weight")
+    return g, exes, weights
 
 
 def check_geometry(got, expect, origin="bundle"):

@@ -1,16 +1,17 @@
 """LlamaServer: AOT warm-start serving over the paged arena.
 
 Startup deserializes the bundle's decode + prefill executables (PR 7
-``MXAOT1`` path), builds the arena with plain ``device_put`` zeros, and
+``MXAOT1`` path), places the bundle's weights on the device once, builds
+the arena with plain ``device_put`` zeros, and
 spins one scheduler thread — **no jit anywhere on the serving path**, so
 ``mxnet_compiles_total`` stays empty for the process lifetime (the
 serve-smoke CI job asserts exactly this from the telemetry dump).
 
 The runner is the only jax-touching layer: it drains pending bulk
 segments that still read the arena (the executables donate the KV
-buffers), calls the
-deserialized executable, adopts the new buffers into
-the arena, and hands numpy logits back to the jax-free scheduler.
+buffers), calls the deserialized executable on the cache state, the
+weights and the step's inputs, adopts the new state into the arena, and
+hands numpy logits back to the jax-free scheduler.
 Sampling is host-side numpy, so the decode loop's device work is exactly
 one executable call per step.
 
@@ -76,23 +77,23 @@ def _retry_after_header(retry_after_s):
 
 
 class AOTRunner:
-    """Executes the bundle's compiled graphs against one arena."""
+    """Executes the bundle's compiled graphs, over the bundle's weights,
+    against one arena."""
 
-    def __init__(self, executables, arena):
+    def __init__(self, executables, weights, arena):
         self._exes = executables
+        self._weights = weights
         self.arena = arena
-        g = arena.geometry
         self._pad = {b: np.zeros(b, dtype=np.int32)
-                     for b in g.prefill_buckets}
+                     for b in arena.geometry.prefill_buckets}
 
     def _call(self, exe, origin, *args):
-        """Drain pending readers, run ``exe`` over the arena state (kv
-        buffers, plus scale arrays for int8), adopt the returned state,
-        hand back the trailing logits output."""
+        """Drain pending readers, run ``exe`` over the cache state and
+        the weights, adopt the returned state, hand back the logits."""
         self.arena.drain_pending_readers(origin)
-        outs = exe(*self.arena.buffers(), *args)
-        self.arena.adopt(*outs[:-1])
-        return outs[-1]
+        state, logits = exe(self.arena.buffers(), self._weights, *args)
+        self.arena.adopt(state)
+        return logits
 
     def prefill(self, bucket, tokens, length, block_row):
         exe = self._exes.get("prefill_%d" % bucket)
@@ -106,46 +107,38 @@ class AOTRunner:
         _memdump.tag(logits, origin="activation", label="prefill_logits")
         return np.asarray(logits)  # mxlint: allow-host-sync
 
-    def decode(self, tokens, positions, block_tables):
-        logits = self._call(self._exes["decode"], "serve_decode",
-                            tokens.astype(np.int32),
+    def _step(self, name, needs, tokens, positions, block_tables):
+        """One call of a lane program (``decode``, ``verify``, ``chunk``:
+        the same signature at three token widths)."""
+        exe = self._exes.get(name)
+        if exe is None:
+            raise MXNetError("bundle has no %s executable — re-export "
+                             "with %s" % (name, needs))
+        logits = self._call(exe, "serve_" + name, tokens.astype(np.int32),
                             positions.astype(np.int32),
                             block_tables.astype(np.int32))
-        _memdump.tag(logits, origin="activation", label="decode_logits")
+        _memdump.tag(logits, origin="activation", label=name + "_logits")
         return np.asarray(logits)  # mxlint: allow-host-sync
+
+    def decode(self, tokens, positions, block_tables):
+        """tokens (B,) -> logits (B, V)."""
+        return self._step("decode", "serve.export_serving_bundle", tokens,
+                          positions, block_tables)
 
     def verify(self, tokens, positions, block_tables):
         """Speculative verify: tokens (B, spec_k+1) -> logits
         (B, spec_k+1, V), from the bundle's compiled ``verify``
         executable — still zero live jits."""
-        exe = self._exes.get("verify")
-        if exe is None:
-            raise MXNetError(
-                "bundle has no verify executable — re-export with "
-                "spec_k > 0 to enable speculative decoding")
-        logits = self._call(exe, "serve_verify",
-                            tokens.astype(np.int32),
-                            positions.astype(np.int32),
-                            block_tables.astype(np.int32))
-        _memdump.tag(logits, origin="activation", label="verify_logits")
-        return np.asarray(logits)  # mxlint: allow-host-sync
+        return self._step("verify", "spec_k > 0 to enable speculative "
+                          "decoding", tokens, positions, block_tables)
 
     def chunk(self, tokens, positions, block_tables):
         """Chunked prefill: tokens (B, prefill_chunk) -> logits
         (B, prefill_chunk, V) from the bundle's ``chunk`` executable —
         the same multi-token shape as verify, compiled at the chunk
         width instead of spec_k+1."""
-        exe = self._exes.get("chunk")
-        if exe is None:
-            raise MXNetError(
-                "bundle has no chunk executable — re-export with "
-                "prefill_chunk > 0 to enable chunked prefill")
-        logits = self._call(exe, "serve_chunk",
-                            tokens.astype(np.int32),
-                            positions.astype(np.int32),
-                            block_tables.astype(np.int32))
-        _memdump.tag(logits, origin="activation", label="chunk_logits")
-        return np.asarray(logits)  # mxlint: allow-host-sync
+        return self._step("chunk", "prefill_chunk > 0 to enable chunked "
+                          "prefill", tokens, positions, block_tables)
 
 
 class LlamaServer:
@@ -175,13 +168,13 @@ class LlamaServer:
                  sampler=None, spec_k=None, kv_dtype=None):
         from .model import check_geometry, load_serving_executables
 
-        geometry, exes = load_serving_executables(
+        geometry, exes, weights = load_serving_executables(
             bundle_path, expect=expect_geometry)
         if kv_dtype is not None:
             check_geometry(geometry, {"kv_dtype": str(kv_dtype)},
                            origin=bundle_path)
         arena = PagedKVArena(geometry)
-        self._init_core(AOTRunner(exes, arena), arena,
+        self._init_core(AOTRunner(exes, weights, arena), arena,
                         queue_depth=queue_depth, sampler=sampler,
                         spec_k=spec_k)
         self.bundle_path = bundle_path
@@ -380,20 +373,21 @@ class LlamaServer:
     # -- bundle hot-swap --------------------------------------------------
     def reload(self, bundle_path, timeout=60):
         """Hot-swap to a new serving bundle with zero dropped requests
-        and zero live jits: deserialize the MXAOT1 executables on the
-        CALLING thread (the loop keeps serving), pin the geometry fields
-        live traffic depends on (``KVGeometry.hot_swap_pins``), then
-        hand runner + fresh arena to the loop, which swaps them at the
-        first step boundary with no active lanes — in-flight requests
+        and zero live jits: deserialize the MXAOT1 executables and place
+        the new weights on the CALLING thread (the loop keeps serving;
+        both models' weights are on the device until the old runner
+        goes), pin the geometry fields live traffic depends on
+        (``KVGeometry.hot_swap_pins``), then hand the runner — programs
+        and weights — and its fresh arena to the loop, which swaps them
+        together at the first step boundary with no active lanes — in-flight requests
         finish on the old executables, queued requests wait (admission
         held, never dropped) and prefill into the new arena."""
-        from .model import check_geometry, load_serving_executables
+        from .model import load_serving_executables
 
-        g2, exes2 = load_serving_executables(bundle_path)
-        check_geometry(g2, self.geometry.hot_swap_pins(),
-                       origin=bundle_path)
+        g2, exes2, weights2 = load_serving_executables(
+            bundle_path, expect=self.geometry.hot_swap_pins())
         arena2 = PagedKVArena(g2)
-        runner2 = AOTRunner(exes2, arena2)
+        runner2 = AOTRunner(exes2, weights2, arena2)
         done = threading.Event()
         with self._swap_lock:
             if self._pending_swap is not None:
@@ -498,6 +492,7 @@ class LlamaServer:
         queue depth, live device memory, flight-recorder state."""
         st = self.scheduler.stats()
         try:
+            self.arena.tag()
             by_origin, total = _memdump.refresh()
         except Exception:           # health must not 500 on accounting
             by_origin, total = {}, 0
@@ -667,6 +662,8 @@ class LlamaServer:
                 return True
 
             def do_GET(self):
+                if self.path.startswith("/metrics"):
+                    server.arena.tag()      # mxnet_device_bytes{kv_page}
                 if self.path == "/metrics":
                     self._send(200, _metrics.prometheus_text(),
                                ctype="text/plain; version=0.0.4")

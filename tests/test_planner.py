@@ -254,7 +254,7 @@ def test_plan_serving_suggests_kv_spec():
                           prefill_buckets=(4,), max_pages_per_seq=4)
     doc = planner.plan_serving(net, g, AXES)
     # llama_small has 2 KV heads: model=2 divides -> heads dim sharded
-    assert doc["kv_spec"] == [None, None, "model", None, None]
+    assert doc["kv_spec"] == [None, "model", None, None]
     assert doc["candidate"] == "megatron[model]"
     json.dumps(doc)    # bundle-meta JSON-stable
 

@@ -9,6 +9,7 @@ liveness tests exercise the actual ``Engine.pending_reads`` /
 """
 import itertools
 
+import jax
 import numpy as np
 import pytest
 
@@ -19,6 +20,7 @@ from mxnet_tpu.engine import Engine
 from mxnet_tpu.serve import (PagedKVArena, Request, Scheduler,
                              ServeQueueFull)
 from mxnet_tpu.serve.model import KVGeometry
+from mxnet_tpu.telemetry import memdump
 
 
 def tiny_geometry(**over):
@@ -378,10 +380,11 @@ def test_arena_alloc_drains_pending_bulk_readers():
     with engine_mod.bulk(64):
         # deferred imperative read of the K arena (an eviction scorer,
         # a debug checksum, ...) — captured as an ext input, not run
-        probe = nd.NDArray(arena.kv_k.data()).sum()
-        assert eng.pending_reads(arena.buffers()) != ()
+        bufs = jax.tree_util.tree_leaves(arena.buffers())
+        probe = nd.NDArray(bufs[0]).sum()
+        assert eng.pending_reads(bufs) != ()
         reused = arena.alloc(4, owner="c")  # the reuse moment
-        assert eng.pending_reads(arena.buffers()) == ()
+        assert eng.pending_reads(bufs) == ()
         assert set(reused) == set(first), "free list must recycle pages"
     assert arena.liveness_flushes == flushes0 + 1
     assert float(probe.asnumpy()) == 0.0  # read the pre-reuse snapshot
@@ -413,7 +416,7 @@ def test_arena_stress_never_reuses_live_page():
                 key = list(held)[int(rng.integers(0, len(held)))]
                 arena.free(held.pop(key), owner=key)
             elif roll == 1:
-                probes.append(nd.NDArray(arena.kv_k.data()).sum())
+                probes.append(nd.NDArray(arena.buffers()[0][0][0]).sum())
             else:
                 n = int(rng.integers(1, g.max_pages_per_seq + 1))
                 pages = arena.alloc(n, owner=i)
@@ -791,26 +794,125 @@ def test_int8_arena_stores_quantized_pages_and_scales():
     g = tiny_geometry(kv_dtype="int8")
     arena = PagedKVArena(g)
     assert arena.quantized
-    bufs = arena.buffers()
-    assert len(bufs) == 4
-    assert bufs[0].dtype == np.int8 and bufs[1].dtype == np.int8
-    assert bufs[2].shape == g.scale_shape() == (1, 9)
-    assert bufs[2].dtype == np.float32
-    # fp32 arena keeps the historical 2-tuple contract
-    assert len(PagedKVArena(tiny_geometry()).buffers()) == 2
+    state = arena.buffers()
+    assert len(state) == g.num_layers == 1
+    for pages, scale in state[0]:                        # K, then V
+        assert pages.shape == g.kv_shape() and pages.dtype == np.int8
+        assert scale.shape == g.scale_shape() == (9,)
+        assert scale.dtype == np.float32
+    # a float arena has the same tree with no scale in it
+    (k, k_scale), (v, v_scale) = PagedKVArena(tiny_geometry()).buffers()[0]
+    assert k_scale is None and v_scale is None
+    assert k.shape == v.shape == g.kv_shape()
 
 
-def test_int8_arena_adopt_requires_scales():
-    import jax
-
+def test_int8_arena_adopts_the_whole_state():
     g = tiny_geometry(kv_dtype="int8")
     arena = PagedKVArena(g)
-    k, v, ks, vs = arena.buffers()
-    with pytest.raises(MXNetError, match="scale"):
-        arena.adopt(k, v)
-    arena.adopt(k, v, jax.device_put(np.ones(g.scale_shape(), np.float32)),
-                vs)
-    assert float(np.asarray(arena.k_scale.data())[0, 0]) == 1.0
+    ((k, _), v_side), = arena.buffers()
+    ones = jax.device_put(np.ones(g.scale_shape(), np.float32))
+    arena.adopt((((k, ones), v_side),))
+    assert arena.buffers()[0][0][1] is ones
+    # the adopted buffers are attributed like the first ones once asked
+    arena.tag()
+    assert {r["label"] for r in memdump.topk(1 << 20) if r["origin"] == "kv_page"} \
+        >= {"arena.k", "arena.v", "arena.k_scale", "arena.v_scale"}
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+def test_arena_bytes_are_what_the_buffers_hold(kv_dtype):
+    g = tiny_geometry(num_layers=3, kv_dtype=kv_dtype)
+    held = sum(b.nbytes
+               for b in jax.tree_util.tree_leaves(PagedKVArena(g).buffers()))
+    assert g.arena_bytes() == held
+    # a TPU's (8, 128) tiles: 4 slots a page pad to 8 rows, a 4-wide head
+    # to 128 lanes; the scale rows are counted as they are
+    size = np.dtype(kv_dtype).itemsize
+    scales = 2 * 3 * 9 * 4 * g.quantized
+    assert g.arena_bytes() == 2 * 3 * 9 * 1 * 4 * 4 * size + scales
+    assert g.arena_bytes(padded=True) == 2 * 3 * 9 * 1 * 8 * 128 * size \
+        + scales
+    assert "arena=%.3fGB (%.3fGB in a TPU's tiles)" % (
+        g.arena_bytes() / 1e9, g.arena_bytes(padded=True) / 1e9) \
+        in g.describe()
+    # at a 128-wide head and whole tiles nothing pads
+    wide = tiny_geometry(head_dim=128, page_size=8, prefill_buckets=(8,))
+    assert wide.arena_bytes() == wide.arena_bytes(padded=True)
+
+
+def test_state_avals_are_a_buffer_a_layer_and_side_in_whole_lanes_on_a_tpu():
+    import types
+
+    from mxnet_tpu.serve.model import state_avals
+
+    g = tiny_geometry(num_layers=2, kv_dtype="int8")
+    state = state_avals(g)
+    assert len(state) == 2
+    for (k, k_scale), (v, v_scale) in state:
+        for pages, scale in ((k, k_scale), (v, v_scale)):
+            assert pages.shape == g.kv_shape() == (9, 1, 4, 4)
+            assert scale.shape == g.scale_shape() and scale.dtype == np.float32
+    (k, k_scale), _ = state_avals(tiny_geometry())[0]
+    assert k_scale is None and k.dtype == np.float32
+    # for a TPU the head dim fills whole 128-lane rows: what the chip
+    # pads a row-major tile to, and arena_bytes(padded=True) counts it
+    tpu = types.SimpleNamespace(platform="tpu")
+    for head_dim, lanes in ((4, 128), (128, 128), (192, 256)):
+        gt = tiny_geometry(head_dim=head_dim, page_size=8,
+                           prefill_buckets=(8,))
+        ((k, _), (v, _)), = state_avals(gt, tpu)
+        assert k.shape == v.shape == (9, 1, 8, lanes)
+        assert gt.arena_bytes(padded=True) == 2 * k.size * k.dtype.itemsize
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+@pytest.mark.parametrize("paged_kernel", ["0", "1"])
+def test_pages_in_whole_lanes_give_the_same_logits(paged_kernel, kv_dtype):
+    """What a TPU's programs do with a head narrower than 128 lanes, run
+    here: pages 128 wide, zeros past the head dim, through prefill and
+    three decode steps against pages as wide as the head."""
+    import types
+
+    from mxnet_tpu.serve import model as M
+
+    g = tiny_geometry(num_layers=2, num_heads=4, num_kv_heads=2, head_dim=8,
+                      units=32, kv_dtype=kv_dtype, paged_kernel=paged_kernel)
+    rng = np.random.default_rng(0)
+    weights = jax.tree_util.tree_map(
+        lambda a: rng.standard_normal(a.shape).astype(a.dtype) * 0.3,
+        M.weight_avals(g))
+    prompt = np.array([3, 1, 4, 1, 5, 0, 0, 0], np.int32)
+    table = np.array([2, 5, 0, 0], np.int32)
+
+    def run(device):
+        state = jax.tree_util.tree_map(
+            lambda a: np.zeros(a.shape, a.dtype), M.state_avals(g, device))
+        state, logits = jax.jit(M.build_prefill_fn(g, 8))(
+            state, weights, prompt, np.int32(5), table)
+        out = [np.asarray(logits)]
+        decode = jax.jit(M.build_decode_fn(g))
+        tables = np.stack([table, np.zeros(4, np.int32)])
+        for pos in (5, 6, 7):
+            state, logits = decode(state, weights,
+                                   np.array([7, 0], np.int32),
+                                   np.array([pos, 0], np.int32), tables)
+            out.append(np.asarray(logits[0]))
+        return state, out
+
+    narrow, want = run(None)
+    wide, got = run(types.SimpleNamespace(platform="tpu"))
+    assert wide[0][0][0].shape == (9, 2, 4, 128)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+    for (k, k_sc), (k_narrow, k_sc_narrow) in zip(wide[1], narrow[1]):
+        # (the second layer's keys carry the first's attention: rounding)
+        np.testing.assert_allclose(
+            np.asarray(k)[..., :8].astype(np.float32),
+            np.asarray(k_narrow).astype(np.float32),
+            atol=1 if g.quantized else 1e-5)
+        assert not np.asarray(k)[..., 8:].any()
+        if g.quantized:
+            np.testing.assert_allclose(k_sc, k_sc_narrow, rtol=1e-5)
 
 
 def test_geometry_kv_dtype_and_spec_k_validation():

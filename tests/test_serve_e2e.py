@@ -9,11 +9,13 @@ runs), and the stdlib HTTP front speaks the documented endpoints.
 """
 import json
 import os
+import re
 import subprocess
 import sys
 import urllib.error
 import urllib.request
 
+import jax
 import numpy as np
 import pytest
 
@@ -118,6 +120,123 @@ def test_load_rejects_non_serving_bundle(tmp_path):
     compile_cache.save_bundle(path, {"k": b"x"}, meta={"kind": "other"})
     with pytest.raises(MXNetError, match="serving"):
         serve.load_serving_executables(path)
+
+
+# -- weights are arguments (ISSUE 40) -------------------------------------
+
+def test_two_seeds_export_the_same_programs_and_serve_each_others_weights(
+        bundle, tmp_path):
+    """No program holds a weight: another net of the same geometry
+    exports the same programs (compared as text less the call stacks they
+    were traced under: XLA:CPU's serialized bytes differ from compile to
+    compile of one program), and this bundle's programs over the other's
+    weights are the other net."""
+    def text(exe):
+        hlo = exe.as_text().split("\nFileNames\n")[0]
+        return re.sub(r"(, )?(metadata|stack_frame_id)=(\{[^}]*\}|\d+)", "",
+                      hlo)
+
+    path_a, net_a, _ = bundle
+    net_b = micro_llama(seed=6)
+    path_b = str(tmp_path / "other-seed.mxaot")
+    serve.export_serving_bundle(net_b, path_b, **GEOM_KW)
+    g, exes_a, _ = serve.load_serving_executables(path_a)
+    _, exes_b, weights_b = serve.load_serving_executables(path_b)
+    assert sorted(exes_a) == sorted(exes_b) == [
+        "decode", "prefill_16", "prefill_8"]
+    for name in exes_a:
+        assert text(exes_a[name]) == text(exes_b[name]), name
+    arena = serve.PagedKVArena(g)
+    srv = serve.LlamaServer.from_parts(
+        serve.AOTRunner(exes_a, weights_b, arena), arena)
+    prompt = [3, 1, 4, 1, 5]
+    with srv:
+        got = srv.generate(prompt, max_new_tokens=6)
+    assert got == greedy_reference(net_b, prompt, 6)
+    assert got != greedy_reference(net_a, prompt, 6)
+
+
+def test_programs_compile_from_shapes_alone():
+    """A geometry is all ``compile_serving_executables`` takes: no net,
+    no weight on any device; the weights are arguments of what it
+    returns, after the cache state."""
+    from mxnet_tpu.serve import model as serve_model
+
+    g = serve_model.KVGeometry(
+        num_layers=2, num_heads=2, num_kv_heads=1, head_dim=8, units=16,
+        hidden_size=32, vocab_size=64, spec_k=2, prefill_chunk=4,
+        **dict(GEOM_KW, max_pages_per_seq=8))
+    before = {id(a) for a in jax.live_arrays()}
+    exes = serve_model.compile_serving_executables(g)
+    assert not [a.shape for a in jax.live_arrays() if id(a) not in before]
+    assert sorted(exes) == ["chunk", "decode", "prefill_16", "prefill_8",
+                            "verify"]
+    leaves = jax.tree_util.tree_leaves
+    state, weights = serve_model.state_avals(g), serve_model.weight_avals(g)
+    assert len(leaves(weights)) == 1 + 9 * 2 + 1 + 1     # untied head
+    for exe in exes.values():
+        args, _ = exe.args_info
+        assert len(leaves(args[0])) == len(leaves(state)) == 2 * 2
+        assert [a.shape for a in leaves(args[1])] == [
+            w.shape for w in leaves(weights)]
+        assert len(args) == 5       # state, weights and three of the step
+        # the cache is donated, the weights are not
+        assert all(a.donated for a in leaves(args[0]))
+        assert not any(a.donated for a in leaves(args[1]))
+
+
+def test_bundle_is_its_weights_and_little_more(tmp_path):
+    """Where the weights are most of the file, the file is under 1.25 x
+    their bytes: each program once, the weights once.  (Closed over, the
+    weights were constants of every program: two programs, two copies.)"""
+    mx.random.seed(11)
+    net = LlamaModel(vocab_size=8192, units=128, hidden_size=256,
+                     num_layers=1, num_heads=2, num_kv_heads=1)
+    net.initialize()
+    net(nd.array(np.zeros((1, 4), np.int32)))
+    path = str(tmp_path / "wide.mxaot")
+    serve.export_serving_bundle(net, path, page_size=4, num_pages=16,
+                                max_batch=2, prefill_buckets=(8,))
+    _, exes, weights = serve.load_serving_executables(path)
+    held = sum(w.nbytes for w in jax.tree_util.tree_leaves(weights))
+    assert held > 8e6 and sorted(exes) == ["decode", "prefill_8"]
+    assert os.path.getsize(path) < 1.25 * held
+    with serve.LlamaServer(path) as srv:
+        assert srv.generate([5, 6, 7], max_new_tokens=4) \
+            == greedy_reference(net, [5, 6, 7], 4)
+
+
+def test_bundle_without_weights_is_refused_at_load(bundle, tmp_path):
+    """Programs that took no weights cannot be told from programs that
+    held them: a bundle with no weights entry is refused, by name."""
+    from mxnet_tpu import compile_cache
+
+    path, _, _ = bundle
+    doc = compile_cache.load_bundle(path)
+    entries = {k: v for k, v in doc["entries"].items() if k != "weights"}
+    assert len(entries) == len(doc["entries"]) - 1
+    old = str(tmp_path / "programs-only.mxaot")
+    compile_cache.save_bundle(old, entries, meta=doc["meta"])
+    for load in (serve.load_serving_executables, serve.LlamaServer):
+        with pytest.raises(
+                MXNetError,
+                match="re-export with serve.export_serving_bundle"):
+            load(old)
+
+
+def test_bundle_weights_of_other_shapes_are_refused_at_load(bundle,
+                                                            tmp_path):
+    from mxnet_tpu import compile_cache
+
+    path, _, _ = bundle
+    doc = compile_cache.load_bundle(path)
+    embed, layers, norm, head = doc["entries"]["weights"]
+    entries = dict(doc["entries"], weights=(embed[:, :-1], layers, norm,
+                                            head))
+    bad = str(tmp_path / "other-shapes.mxaot")
+    compile_cache.save_bundle(bad, entries, meta=doc["meta"])
+    with pytest.raises(MXNetError, match="not the shapes its geometry"):
+        serve.load_serving_executables(bad)
 
 
 def test_predictor_redirects_serving_bundle(bundle):
@@ -314,8 +433,8 @@ def test_memdump_kv_page_bytes_roughly_halve(spec_bundle, int8_bundle):
     _, _, g8 = int8_bundle
     a32 = serve.PagedKVArena(g32)
     a8 = serve.PagedKVArena(g8)
-    bytes32 = sum(b.nbytes for b in a32.buffers())
-    bytes8 = sum(b.nbytes for b in a8.buffers())
+    bytes32 = sum(b.nbytes for b in jax.tree_util.tree_leaves(a32.buffers()))
+    bytes8 = sum(b.nbytes for b in jax.tree_util.tree_leaves(a8.buffers()))
     assert bytes8 <= 0.55 * bytes32, (bytes8, bytes32)
 
 

@@ -618,15 +618,16 @@ def test_kv_arena_shards_on_mesh(eight_devices, monkeypatch):
         return KVGeometry(**kw)
 
     mesh = Mesh({"model": 2})
-    spec = P(None, None, "model", None, None)   # KV heads on tp axis
+    spec = P(None, "model", None, None)   # a layer's KV heads on tp axis
     arena = PagedKVArena(geom(), mesh=mesh, kv_spec=spec)
-    for buf in (arena.kv_k, arena.kv_v):
-        assert isinstance(buf.sharding, NamedSharding)
-        assert buf.sharding.spec == spec
-        assert len(buf.sharding.device_set) == 2
+    for (k, _), (v, _) in arena.buffers():
+        for buf in (k, v):
+            assert isinstance(buf.sharding, NamedSharding)
+            assert buf.sharding.spec == spec
+            assert len(buf.sharding.device_set) == 2
     # default stays single-device (the AOT executables expect it)
     plain = PagedKVArena(geom())
-    assert not isinstance(plain.kv_k.sharding, NamedSharding)
+    assert not isinstance(plain.buffers()[0][0][0].sharding, NamedSharding)
     # MXNET_SHARDING_VERIFY covers the arena too
     monkeypatch.setenv("MXNET_SHARDING_VERIFY", "1")
     with pytest.raises(MXNetError, match="not divisible"):

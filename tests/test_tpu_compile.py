@@ -213,8 +213,9 @@ def test_mesh_step_gradient_all_reduces_overlap(topo, with_options):
                    for _, _, text in entry[i + 1:j]), names[i]
 
 
-# (page_size, kv_heads, heads, head_dim): the 160M decoder chip_smoke.py
-# serves, and llama2_7b-like heads at D=128
+# (page_size, kv_heads, heads, head_dim): a 160M decoder's heads at D=64
+# (the kernel alone; the serving programs below pad such a head to whole
+# lanes), and llama2_7b-like heads at D=128
 _GEOMETRIES = {"serve160m": (16, 4, 12, 64), "d128": (16, 8, 32, 128)}
 
 
@@ -235,6 +236,61 @@ def test_paged_attention(one_chip, geometry, k1, kv_dtype):
     c = _compile(paged_attention, one_chip, *shapes)
     assert "tpu_custom_call" in c.as_text()
     assert "%mx_paged_attention" in c.as_text()
+
+
+# -- the serving programs at a size whose decode once did not fit (PR 40) -----
+
+def _smol360m(kv_dtype):
+    # SmolLM2-360M's shape at the geometry PERF.md 7.1 names: while the
+    # arena was one (L, P, KV, S, D) array a side the decode wanted 19.40
+    # of 15.75 GB (two copies of it, turned over for the scatter and back)
+    from mxnet_tpu.serve.model import KVGeometry
+
+    return KVGeometry(
+        num_layers=32, num_heads=15, num_kv_heads=5, head_dim=64, units=960,
+        hidden_size=2560, vocab_size=49152, page_size=16, num_pages=8192,
+        max_pages_per_seq=256, max_batch=32, prefill_buckets=(512,),
+        dtype="bfloat16", tie_embeddings=True, kv_dtype=kv_dtype, spec_k=4,
+        prefill_chunk=64, paged_kernel="1")
+
+
+@pytest.fixture(scope="module", params=["bfloat16", "int8"])
+def serving(request, topo):
+    """Geometry and programs; the weights are shapes, so nothing but the
+    compiler is needed."""
+    from mxnet_tpu.serve.model import serving_programs
+
+    g = _smol360m(request.param)
+    return g, serving_programs(g, topo.devices[0])
+
+
+@pytest.mark.parametrize("name", ["decode", "verify", "chunk", "prefill_512"])
+def test_a_serving_program_updates_the_arena_where_it_lies(serving, name):
+    g, programs = serving
+    fn, avals = programs[name]
+    c = jax.jit(fn, donate_argnums=(0,)).lower(*avals).compile()
+    m = c.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert held < 15.75e9, held
+    # every layer's pages come back in the buffer they came in; a 64-wide
+    # head takes 128 lanes there, and the geometry says so
+    (k, _), _ = avals[0][0]
+    assert k.shape == (8192, 5, 16, 128)
+    pages = 2 * g.num_layers * k.size * k.dtype.itemsize
+    assert g.arena_bytes(padded=True) >= pages > g.arena_bytes()
+    assert m.alias_size_in_bytes >= pages
+    # and nothing the size of a layer's pages is made beside them: the
+    # temporaries are activations
+    assert m.temp_size_in_bytes < 1.25 * pages / g.num_layers, \
+        m.temp_size_in_bytes
+    text = c.as_text()
+    if name != "prefill_512":
+        assert text.count("%mx_paged_attention") >= g.num_layers
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= \w+\[%d,%d,%d,%d\]\S* (copy|transpose)\("
+                          % k.shape, line)]
+    assert not moved, moved[:3]
 
 
 # -- the Nemotron-H cell's operators at its own sizes (PR 30) -----------------
