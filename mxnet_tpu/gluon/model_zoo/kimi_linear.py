@@ -18,7 +18,9 @@ one the routed.
   Everything between the projections is one operator,
   ``F.contrib.kda_attention`` (float32 inside, the chunked scan under the
   scope ``kda_scan``; one checkpoint, so that a layer keeps its
-  projections' results and not sixteen float32 arrays of them).
+  projections' results and not sixteen float32 arrays of them); where the
+  shapes tile, its kernels take it whole and read the projections'
+  results where the products wrote them (``ops/kda_kernels.py:mixer``).
 - ``MLAMixer`` (scope ``mla``): latent attention materialised for
   training, here with **no rotary embedding** (``mla_use_nope``: the
   ``rope`` channels are plain channels) and a full-rank query: ``q = W_q
@@ -45,7 +47,10 @@ stacked leaf ``(2F, D)`` (an expert layer's ``(count, 2F, D)``).  A
 ``KDAMixer`` declares two step statistics: the chunks its scan ran
 (``kda/<layer>``), which feeds ``mxnet_kda_chunks_total``, and those of them
 whose scan took the Pallas kernels (``kda_kernel/<layer>``: all or none, by
-the shapes), which feeds ``mxnet_kda_kernel_chunks_total``.  An ``MLAMixer``
+the shapes), which feeds ``mxnet_kda_kernel_chunks_total``; and the layer
+(``kda_layer/<layer>``, ``mxnet_kda_layers_total``) and the layer where the
+kernels took the mixer whole (``kda_layer_fused/<layer>``,
+``mxnet_kda_fused_layers_total``).  An ``MLAMixer``
 declares two of the same kind: the layer (``mla/<layer>``) and the layer
 where its kernels read in place (``mla_kernel/<layer>``).
 
@@ -58,7 +63,7 @@ import jax
 import jax.numpy as jnp
 
 from ...ops import mla_kernels
-from ...ops.kda import kda_chunks, kda_kernel_chunks
+from ...ops.kda import kda_chunks, kda_fused, kda_kernel_chunks
 from ...telemetry import metrics
 from .. import nn
 from ..block import HybridBlock, record_step_stat
@@ -68,6 +73,8 @@ from .nemotron_h import (MoEMixer, _dense, _feed_forward, _Mixer,
 
 STAT_PREFIX = "kda/"      # a layer's statistic: "kda/<layer>"
 KERNEL_STAT_PREFIX = "kda_kernel/"
+LAYER_STAT_PREFIX = "kda_layer/"
+FUSED_STAT_PREFIX = "kda_layer_fused/"
 MLA_STAT_PREFIX = "mla/"
 MLA_KERNEL_STAT_PREFIX = "mla_kernel/"
 
@@ -77,9 +84,11 @@ class KDAMixer(_Mixer):
                  chunk_size=64, eps=1e-5, layer=0, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         inner = num_heads * head_dim
-        self._cfg = (num_heads, head_dim, int(chunk_size), eps)
+        self._cfg = (num_heads, head_dim, int(chunk_size), eps, conv_kernel)
         self._stat = STAT_PREFIX + str(int(layer))
         self._kernel_stat = KERNEL_STAT_PREFIX + str(int(layer))
+        self._layer_stat = LAYER_STAT_PREFIX + str(int(layer))
+        self._fused_stat = FUSED_STAT_PREFIX + str(int(layer))
         self._declare([
             ("q_proj", (inner, units), None),
             ("k_proj", (inner, units), None),
@@ -100,14 +109,16 @@ class KDAMixer(_Mixer):
 
     def step_stat_specs(self):
         """Chunks the scan ran: batch x heads x chunks of the sequence; and
-        those whose scan took the Pallas kernels."""
-        return {self._stat: ((1,), jnp.uint32),
-                self._kernel_stat: ((1,), jnp.uint32)}
+        those whose scan took the Pallas kernels.  The mixer run, and run
+        whole in the kernels (one each)."""
+        return {name: ((1,), jnp.uint32)
+                for name in (self._stat, self._kernel_stat, self._layer_stat,
+                             self._fused_stat)}
 
     def hybrid_forward(self, F, u, q_proj, k_proj, v_proj, q_conv, k_conv,
                        v_conv, A_log, f_a_proj, f_b_proj, dt_bias, b_proj,
                        g_a_proj, g_b_proj, o_norm, o_proj):
-        heads, hd, chunk, eps = self._cfg
+        heads, hd, chunk, eps, taps = self._cfg
         b, t, _ = u.shape
         with jax.named_scope("kda"):
             def low_rank(a, b):
@@ -122,6 +133,9 @@ class KDAMixer(_Mixer):
             record_step_stat(self._kernel_stat, jnp.full(
                 (1,), b * heads * kda_kernel_chunks(t, hd, hd, chunk),
                 jnp.uint32))
+            record_step_stat(self._layer_stat, jnp.ones((1,), jnp.uint32))
+            record_step_stat(self._fused_stat, jnp.full(
+                (1,), kda_fused(t, hd, taps, chunk), jnp.uint32))
             return _dense(F, o, o_proj)
 
 
@@ -348,6 +362,11 @@ def kimi_linear_48b_a3b(vocab_size=163840, **kwargs):
 
 metrics.register_collector(
     chunk_counters("kda", "Kimi Delta Attention scans", "mx_kda_*"))
+metrics.register_collector(chunk_counters(
+    "kda", "Kimi Delta Attention mixers",
+    "mx_kda_fwd / mx_kda_bwd whole, the projections' results read where "
+    "they lie", unit="layers", each="every such layer and train step",
+    kernel="fused", stat="kda_layer"))
 metrics.register_collector(chunk_counters(
     "mla", "latent attention mixers",
     "mx_flash_*_mla, their operands read where the projections wrote them",

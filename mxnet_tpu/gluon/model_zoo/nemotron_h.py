@@ -88,25 +88,28 @@ class _Mixer(HybridBlock):
 
 def chunk_counters(stem, scans, kernels, unit="chunks",
                    each="sequences x heads x chunks, every such layer and "
-                        "train step"):
+                        "train step", kernel="kernel", stat=None):
     """A telemetry collector for the counts of one kind of scan, or of
     whatever else a layer counts in ``unit`` (latent attention: layers).
     They leave a ``JitTrainStep`` program as the statistics
-    ``<stem>/<layer>`` (chunks the scans ran) and ``<stem>_kernel/<layer>``
-    (those that ran in the Pallas kernels), which it accumulates on the
-    device; a snapshot fetches them (once, both kinds) and adds what is new
-    (modulo the accumulators' 32 bits) to ``mxnet_<stem>_<unit>_total`` and
-    ``mxnet_<stem>_kernel_<unit>_total``."""
-    families = {stem + "/": ("mxnet_%s_%s_total" % (stem, unit), "ran"),
-                stem + "_kernel/": ("mxnet_%s_kernel_%s_total" % (stem, unit),
-                                    "ran in the Pallas kernels " + kernels)}
+    ``<stat>/<layer>`` (chunks the scans ran) and
+    ``<stat>_<kernel>/<layer>`` (those that ran in the Pallas kernels;
+    ``stat`` is ``stem`` unless given), which it accumulates on the device;
+    a snapshot fetches them (once, both kinds) and adds what is new (modulo
+    the accumulators' 32 bits) to ``mxnet_<stem>_<unit>_total`` and
+    ``mxnet_<stem>_<kernel>_<unit>_total``."""
+    stat = stat or stem
+    families = {stat + "/": ("mxnet_%s_%s_total" % (stem, unit), "ran"),
+                "%s_%s/" % (stat, kernel): (
+                    "mxnet_%s_%s_%s_total" % (stem, kernel, unit),
+                    "ran in the Pallas kernels " + kernels)}
     seen = {}               # (step, statistic) -> the count last read
 
     def collect():
         from ...parallel.train_step import read_step_stats
 
         new = {}        # no step of this process scans: no family either
-        for owner, stats in read_step_stats(stem):
+        for owner, stats in read_step_stats(stat):
             for name, count in stats.items():
                 prefix = name[:name.index("/") + 1]
                 if prefix in families:

@@ -36,8 +36,11 @@ sub-chunks of ``SUB = 16`` tokens and the rows of sub-chunk ``I`` take ``r
 = G`` at its first token: the row factor is at most 1, the column factor
 at most 1 for every earlier sub-chunk and at most ``e^{15 |g|}`` inside
 the row's own (``e^24`` at the published range; float32 holds ``e^88``,
-``g`` down to -5.8 a token).  Columns of later sub-chunks are masked
-before the exponential is taken.
+``g`` down to -5.8 a token in range, but the products of such factors
+lose their digits sooner: against the recurrence, at float32 products, the
+chunked form holds 1e-4 of the largest value to -5.0 a token and reads
+0.12 at -5.5, PR 41).  Columns of later sub-chunks are masked before the
+exponential is taken.
 
 Everything is float32 whatever the inputs are (the operator is in
 ``amp.lists.FP32_OPS``).  No state a token ``(B, T, H, d, e)`` ever exists.
@@ -48,7 +51,9 @@ is whole sub-chunks by construction) take the Pallas kernels of
 ``ops/kda_kernels.py``: ``mx_kda_fwd`` makes a chunk's ``cum``, row and
 column factors, ``A``, ``B``, the inverse and ``U`` in VMEM with the state in
 a scratch that lives across the sequential chunk axis, and only the
-operator's operands cross HBM; ``mx_kda_bwd`` walks the chunks in reverse
+operator's operands cross HBM, read as the heads' lanes of ``(B, T, H * d)``
+(one launch for every head: the grid walks batch, chunks and, inside a
+chunk, groups of eight heads); ``mx_kda_bwd`` walks the chunks in reverse
 with the state's cotangent in VMEM.  One ``custom_vjp`` holds the two: its
 forward pass, when a backward pass will follow, also writes the state each
 chunk starts from, the inverse and ``U`` (112 KB a head and chunk), which
@@ -72,14 +77,28 @@ the size of ``k``, under a checkpoint of their own).
 
 ``_contrib_kda_attention`` is a whole mixer between its projections (the
 convolutions, the norms, the decay, ``beta``, the scan, the output norm
-and gate) under one checkpoint (``_made_again``), a group of heads at a
-time: what a layer keeps for its backward pass is the projections' results,
-and the scan inside is either form above.
+and gate) under one checkpoint (``_made_again``): what a layer keeps for its
+backward pass is the six projections' results in their own dtype, and the
+rest is made again when the cotangent arrives.  Where the shapes tile
+(``kda_fused``: heads of whole 128-lane rows, convolutions of at most 17
+taps) the kernels take it whole (``kda_kernels.mixer``): they read the
+projections' results where the products wrote them, make the convolutions
+and SiLU, the unit norms, the decay gate, ``sigmoid(beta)`` and, after the
+scan, the output's RMSNorm times ``sigmoid(gate)`` in VMEM from the blocks
+they already hold, write the result where ``o_proj`` reads it, and write
+the projections' cotangents, the taps', and a chunk's token sums for
+``A_log``, ``dt_bias`` and ``o_norm`` (which XLA adds up, a few KB); no
+array of the mixer's per-token work exists in HBM.  Every other shape keeps
+the composition: the same steps in XLA around ``_kda_chunked``, a group of
+``HEADS_AT_ONCE`` heads at a time.
 
 Shapes: ``q, k (B, T, H, d)``, ``v (B, T, H, e)``, ``g (B, T, H, d)``
 (log-decay, at most 0), ``beta (B, T, H)``; returns ``(B, T, H, e)`` in
 ``v``'s dtype.  ``T`` is padded to whole chunks with tokens that neither
-decay nor write.
+decay nor write: ``g = 0`` and ``beta = 0`` where the gates were applied
+before the padding; the fused mixer pads the projections' results, whose
+gates would make ``softplus(dt_bias)`` and 1/2 of a padded zero, so its
+kernels mask a token past ``T`` by its position.
 """
 from __future__ import annotations
 
@@ -94,12 +113,11 @@ from .kda_kernels import SUB    # tokens of a sub-chunk: see the overflow rule
 from .registry import register
 from .ssm import causal_conv1d
 
-# heads that ``kda_attention`` runs at once: its temporaries (the float32
-# ``(T, d)`` arrays of a head group around the scan, and what the scan's
-# forward leaves for its backward: 7.3 MB a head at 4096 tokens in the
-# kernels, more in the ``jax.numpy`` form) grow with the heads, at no cost in
-# operations: 1.8 GB for 32 heads x 128 in the ``jax.numpy`` form, a quarter
-# of that for eight
+# heads that ``kda_attention``'s composition runs at once (the shapes the
+# kernels do not take whole): its temporaries (the float32 ``(T, d)`` arrays
+# of a head group around the scan, and what the scan's forward leaves for its
+# backward) grow with the heads, at no cost in operations: 1.8 GB for 32
+# heads x 128 in the ``jax.numpy`` form, a quarter of that for eight
 HEADS_AT_ONCE = 8
 HIGHEST = lax.Precision.HIGHEST
 
@@ -234,15 +252,16 @@ def _kda_chunked(q, k, v, g, beta, chunk):
             x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
         x = jnp.moveaxis(x, 2, 1)
         return x.reshape((b, h, n, c) + x.shape[3:])
-    q, k, v, g = chunks(q), chunks(k), chunks(v), chunks(g)
     if kda_kernels.tiles(d, e, c):
         # the same algebra with a chunk's factors made, used and dropped in
-        # VMEM: a static test of the shapes, on every platform
-        out = kda_kernels.chunk_scan(
-            *(x.reshape(b * h, n * c, -1) for x in (q, k, v, g)),
-            chunks(beta).reshape(b * h, n * c), c)
-        out = out.reshape(b, h, t + pad, e)[:, :, :t]
-        return jnp.moveaxis(out, 1, 2).astype(out_dtype)
+        # VMEM: a static test of the shapes, on every platform.  The
+        # kernels read the heads as lanes, (B, T, H * d): no copy
+        def lanes(x):
+            x = x.astype(f32).reshape(b, t, -1)
+            return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
+        out = kda_kernels.chunk_scan(*map(lanes, (q, k, v, g, beta)), c)
+        return out[:, :t].reshape(b, t, h, e).astype(out_dtype)
+    q, k, v, g = chunks(q), chunks(k), chunks(v), chunks(g)
     beta = chunks(beta)[..., None]                     # (B, H, n, C, 1)
     cum = jnp.cumsum(g, axis=-2)                       # G, inclusive
     a, bm = _pair_products(q, k, cum)
@@ -298,6 +317,30 @@ def kda_gate(data, A_log, dt_bias):
         * rate.reshape(b, t, h, -1)
 
 
+def _kda_fused(q, k, v, decay, beta, gate, q_conv, k_conv, v_conv, A_log,
+               dt_bias, o_norm, chunk, eps):
+    """``kda_attention`` where the shapes tile: the kernels read the
+    projections' results where they lie and do the rest."""
+    f32 = jnp.float32
+    b, t, inner = q.shape
+    heads = A_log.size
+    c = _chunk_size(t, chunk)
+    pad = -t % c
+
+    def whole(x):
+        # whole chunks; the kernels mask the tokens past ``t`` by position
+        return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
+    with jax.named_scope("kda_scan"):
+        o = kda_kernels.mixer(
+            *map(whole, (q, k, v, decay, beta, gate)),
+            jnp.repeat(A_log.astype(f32).reshape(-1), inner // heads)[None],
+            dt_bias.astype(f32).reshape(1, -1),
+            jnp.tile(o_norm.astype(f32).reshape(-1), heads)[None],
+            jnp.stack([q_conv, k_conv, v_conv]).astype(f32).transpose(0, 2, 1),
+            c, t if pad else None, eps)
+    return o[:, :t] if pad else o
+
+
 def _kda_attention(q, k, v, decay, beta, gate, q_conv, k_conv, v_conv, A_log,
                    dt_bias, o_norm, chunk, eps):
     """``kda_attention`` for the heads it is given (``A_log`` has one
@@ -344,15 +387,24 @@ def kda_attention(q, k, v, decay, beta, gate, q_conv, k_conv, v_conv, A_log,
     Returns ``(B, T, H * d)`` float32.  Everything between the casts is
     float32.  A layer made of separate operators keeps some sixteen ``(B,
     T, H * d)`` float32 arrays for its backward pass (1.1 GB at 4096 tokens
-    of 32 x 128); this keeps five in the inputs' dtype and makes the rest
-    again, the chunked scan among them (once: it is not checkpointed a
-    second time inside), ``HEADS_AT_ONCE`` heads after ``HEADS_AT_ONCE``
-    heads (``lax.map``: every step of the mixer is a head's own)."""
+    of 32 x 128); this keeps the projections' results and makes the rest
+    again when the cotangent arrives.  Where the shapes tile
+    (``kda_fused``) every step above is the kernels' (``_kda_fused``): the
+    per-token work is done in VMEM on the blocks the scan reads, one launch
+    a pass for all heads, and a padded token is masked by its position in
+    the kernels.  Every other shape is the composition, ``HEADS_AT_ONCE``
+    heads after ``HEADS_AT_ONCE`` heads (``lax.map``: every step of the
+    mixer is a head's own), its scan padded after the gates."""
     with jax.named_scope("kda_attention"):
-        fn = _made_again(functools.partial(
-            _kda_attention, chunk=int(chunk), eps=float(eps)))
         b, t, inner = q.shape
         heads = A_log.size
+        if kda_fused(t, inner // heads, q_conv.shape[1], chunk):
+            return _made_again(functools.partial(
+                _kda_fused, chunk=int(chunk), eps=float(eps)))(
+                q, k, v, decay, beta, gate, q_conv, k_conv, v_conv, A_log,
+                dt_bias, o_norm)
+        fn = _made_again(functools.partial(
+            _kda_attention, chunk=int(chunk), eps=float(eps)))
         groups = max(1, heads // HEADS_AT_ONCE)
         if heads % groups:
             groups = 1
@@ -382,6 +434,14 @@ def kda_kernel_chunks(t, d, e, chunk=64):
     of ``d`` and values of ``e`` channels tile, else none."""
     tiles = kda_kernels.tiles(d, e, _chunk_size(t, chunk))
     return kda_chunks(t, chunk) if tiles else 0
+
+
+def kda_fused(t, d, taps, chunk=64):
+    """Whether a mixer of ``t`` tokens, heads of ``d`` channels and
+    convolutions of ``taps`` takes the kernels whole (``kda_attention``'s
+    static test of the shapes)."""
+    return kda_kernels.tiles(d, d, _chunk_size(t, chunk)) \
+        and taps <= kda_kernels.SPAN + 1
 
 
 def kda_recurrence(q, k, v, g, beta):

@@ -1,29 +1,46 @@
 """Pallas TPU kernels of the chunked gated delta rule (``ops/kda.py`` has
 the equations and the overflow rule): ``mx_kda_fwd`` and ``mx_kda_bwd``.
 
-A grid step is one chunk of ``C`` tokens of up to ``HEADS_A_STEP`` heads, held
-as arrays with the heads leading and every product batched over them; the
-chunk axis is sequential and the float32 state of a head lives in a VMEM
-scratch across it, transposed ``(e, d)`` so that every product with it is a
-plain or a transposed-operand matrix product.  A step reads the chunk's ``q,
-k, g (C, d)``, ``v (C, e)`` and ``beta (1, C)``, makes the running sum of
-``g``, the row and column factors of the overflow rule, ``A``, ``B``, the
-inverse, ``U`` and the new state in VMEM, and writes ``out (C, e)``: nothing
-but the operator's operands crosses HBM.  When the backward pass asks, the
-forward also writes what a chunk's backward cannot make again from the
-operands: the state the chunk starts from ``(e, d)``, the inverse ``(C, C)``
-and ``U (C, e)``, 112 KB a head and chunk, which live until the backward
-kernel has read them.  The backward kernel walks the chunks in reverse with
-the state's cotangent in VMEM and writes ``dq, dk, dv, dg`` and ``dbeta`` a
-chunk.
+The operands are read where their producers wrote them, a head's channels
+as whole lane tiles of ``(B, T, H * d)`` and ``beta`` as ``(B, T, H)``: the
+grid is ``(batch, chunk, group)``, a step one chunk of ``C`` tokens of a
+group of up to ``HEADS_A_STEP`` heads, whose blocks it splits into arrays
+with the heads leading (every product batched over them) and writes back as
+lanes.  The chunk axis and the group axis inside it are sequential: the
+float32 state of every head lives in a VMEM scratch across the chunks,
+transposed ``(e, d)`` so that every product with it is a plain or a
+transposed-operand matrix product, and a chunk's ``(C, H)`` block of
+``beta``'s cotangent stays in VMEM while the groups fill their lanes.  A
+step makes the running sum of ``g``, the row and column factors of the
+overflow rule, ``A``, ``B``, the inverse, ``U`` and the new state in VMEM,
+and writes ``out``: nothing but the operator's operands crosses HBM.  When
+the backward pass asks, the forward also writes what a chunk's backward
+cannot make again from the operands: the state the chunk starts from ``(e,
+d)``, the inverse ``(C, C)`` and ``U (C, e)``, 112 KB a head and chunk,
+which live until the backward kernel has read them.  The backward kernel
+walks the chunks in reverse with the state's cotangent in VMEM.
 
-Precision: the running sums, the exponentials, the state and every
-accumulation are float32.  The products of the solve (the inverse's, ``U = T
-R``, the two products of its gradient) run at float32 precision
-(``HIGHEST``), and so do the two running sums (three bfloat16 pieces that
-sum to the float32 operand, against ones); every other product rounds its
-operands to bfloat16 and accumulates in float32, which is what the
-``jax.numpy`` form's default-precision ``einsum`` does on a TPU.
+Two operators share the kernels.  ``chunk_scan`` is the scan alone (``q, k,
+v, g, beta`` as the algebra reads them).  ``mixer`` is a KDA mixer between
+its projections (``ops/kda.py:kda_attention`` where the shapes tile): from
+the projections' results a step makes, on the blocks it holds, the causal
+convolutions and SiLU (the chunk before read as a block of its own), the
+unit norms of ``q`` and ``k``, the decay gate, ``sigmoid(beta)``, and after
+the scan the output's RMSNorm times its weight times ``sigmoid(gate)``; the
+backward kernel makes the same again, and writes the projections'
+cotangents in their own dtype, each chunk's token sums for ``A_log``,
+``dt_bias``, the norm's weight and the taps (added up outside, a few KB),
+and carries the convolutions' cotangent from a chunk's first tokens to the
+chunk before in VMEM.  A token past the sequence (padded to whole chunks)
+is masked by its position.
+
+Precision: the running sums, the exponentials, the state, the mixer's
+prologue and epilogue and every accumulation are float32.  The products of
+the solve (the inverse's, ``U = T R``, the two products of its gradient) run
+at float32 precision (``HIGHEST``), and so do the two running sums (three
+bfloat16 pieces that sum to the float32 operand, against ones); every other
+product rounds its operands to bfloat16 and accumulates in float32, which
+is what the ``jax.numpy`` form's default-precision ``einsum`` does on a TPU.
 
 The inverse of the unit lower-triangular ``I + N (C, C)`` is exact: the
 diagonal blocks of ``SUB`` rows by elimination, a column after another (the
@@ -51,6 +68,13 @@ SUB = 16            # tokens of a sub-chunk: the overflow rule of ``ops/kda.py``
 # them (bundles a head and chunk as compiled for a v5e, forward / backward:
 # 1499 / 1285 for one head, 1072 / 750 for eight, PR 35)
 HEADS_A_STEP = 8
+# tokens after a chunk whose cotangent the mixer's backward kernel carries
+# to the chunk before for the convolutions: a convolution reaches that many
+# tokens back at most
+SPAN = 16
+# the mixer's kernel operands: q, k, v, decay, beta, gate, A_log, dt_bias,
+# the output norm, the taps, and q, k, v again for the tokens before a chunk
+MIXER_OPERANDS = 13
 HIGHEST = lax.Precision.HIGHEST
 
 
@@ -209,55 +233,191 @@ def _inverse(n, row, col):
     return out
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, out_ref, *rest, keep):
+def _heads(x, width):
+    """``(., G * width)`` -> ``(G, ., width)``: a head is whole lane tiles, so
+    nothing moves."""
+    return jnp.stack([x[:, h * width:(h + 1) * width]
+                      for h in range(x.shape[-1] // width)])
+
+
+def _store(ref, x, *lead):
+    """``x (G, ., w)`` into ``ref[lead] (., G * w)`` (``lead`` indexes every
+    axis but the lanes; all of a 2-D ``ref``), a head's lanes at a time."""
+    w = x.shape[-1]
+    for h in range(x.shape[0]):
+        ref[(lead or (slice(None),)) + (slice(h * w, (h + 1) * w),)] = \
+            x[h].astype(ref.dtype)
+
+
+def _head_lanes(shape, grp, heads):
+    """Of a ``(C, H)`` block, the lane of each head of group ``grp``."""
+    lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+    return [lane == grp * heads + h for h in range(heads)]
+
+
+def _sigmoid(x):
+    """As ``0.5 + 0.5 tanh(x / 2)``: one transcendental and two vector
+    operations where ``1 / (1 + exp(-x))`` takes a division (a thousand
+    bundles a grid step in either kernel); float32, within 1e-7 of it."""
+    return 0.5 + 0.5 * jnp.tanh(0.5 * x)
+
+
+def _before(x, prev, s):
+    """``x[t - s]`` along a chunk's tokens ``x (G, C, .)``, the first ``s``
+    from the chunk before, ``prev (G, C, .)``: one select and one roll."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if s == 0:
+        return x
+    c = x.shape[1]
+    row = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return pltpu.roll(jnp.where(row >= c - s, prev, x), s, 1)
+
+
+def _after(x, nxt, s):
+    """``x[t + s]``, the last ``s`` from the chunk after, ``nxt (G, C,
+    .)`` (of which the first ``SPAN`` tokens are read)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    c = x.shape[1]
+    row = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return pltpu.roll(jnp.where(row < s, nxt, x), c - s, 1)
+
+
+def _operands(refs, grp, at, heads, tokens, mixer):
+    """What a grid step's chunk is made of, from the group's blocks: ``q, k,
+    g (G, C, d)``, ``v (G, C, e)`` and ``beta (G, C, 1)`` as the algebra
+    reads them.  In the mixer's form they are made here from the
+    projections' results (the convolutions and SiLU, the unit norms, the
+    decay gate, the sigmoid), with what their gradients read beside them.
+    ``tokens`` (when the sequence was padded to whole chunks) masks the
+    tokens past it by position: they neither decay nor write."""
+    f32 = jnp.float32
+    q_ref, k_ref, v_ref, g_ref, beta_ref = refs[:5]
+    d, e = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
+    rows = beta_ref[...].astype(f32)                    # (C, H)
+    x = dict(lanes=_head_lanes(rows.shape, grp, heads))
+    beta = jnp.stack([jnp.sum(jnp.where(lane, rows, 0.0), axis=-1,
+                              keepdims=True) for lane in x["lanes"]])
+    q, k, v, g = (_heads(r[...].astype(f32), w)
+                  for r, w in zip(refs[:4], (d, d, e, d)))
+    if mixer:
+        a_ref, dt_ref, _, taps_ref = refs[6:10]
+        taps, x["conv"] = taps_ref[...], []
+        for i, (raw, prev_ref) in enumerate(zip((q, k, v), refs[10:])):
+            # the causal convolution over the tokens before the chunk too;
+            # there are none before the first
+            prev = jnp.where(at == 0, 0.0,
+                             _heads(prev_ref[...].astype(f32), d))
+            w = _heads(taps[i], d)                      # (G, K, d)
+            n = w.shape[1]
+            shifted = [_before(raw, prev, s) for s in range(n)]
+            y = sum(xs * w[:, n - 1 - s:n - s] for s, xs in enumerate(shifted))
+            x["conv"].append(dict(shifted=shifted, w=w, y=y,
+                                  sig=_sigmoid(y)))
+        q, k, v = (cv["y"] * cv["sig"] for cv in x["conv"])
+        x["q_len"] = lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6)
+        x["k_len"] = lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
+        x["q_unit"], x["k_unit"] = q * x["q_len"], k * x["k_len"]
+        q, k = x["q_unit"] * d ** -0.5, x["k_unit"]
+        z = g + _heads(dt_ref[...], d)                  # decay + dt_bias
+        x["z"], x["rate"] = z, -jnp.exp(_heads(a_ref[...], d))
+        # softplus(z), as jax.nn.softplus writes it
+        g = x["rate"] * (jnp.maximum(z, 0.0)
+                         + jnp.log1p(jnp.exp(-jnp.abs(z))))
+        beta = _sigmoid(beta)
+    if tokens is not None:
+        c = rows.shape[0]
+        token = at * c + lax.broadcasted_iota(jnp.int32, (1, c, 1), 1)
+        x["valid"] = token < tokens
+        q, k, v, g, beta = (jnp.where(x["valid"], y, 0.0)
+                            for y in (q, k, v, g, beta))
+    x.update(q=q, k=k, v=v, g=g, beta=beta)
+    return x
+
+
+def _epilogue(o, gate, norm, eps):
+    """The mixer's output from the scan's ``o (G, C, e)``: a head's RMSNorm
+    times ``norm``, times ``sigmoid(gate)``; and what its gradient reads."""
+    scale = lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+    unit, s = o * scale, _sigmoid(gate)
+    return dict(scale=scale, unit=unit, s=s, y=unit * norm * s)
+
+
+def _fwd_kernel(*refs, heads, mixer, keep, tokens, eps):
     from jax.experimental import pallas as pl
 
-    state = rest[-1]
+    n_in = MIXER_OPERANDS if mixer else 5
+    ins, outs, state = refs[:n_in], refs[n_in:-1], refs[-1]
+    j, grp = pl.program_id(1), pl.program_id(2)
 
-    @pl.when(pl.program_id(1) == 0)
+    @pl.when(j == 0)
     def _():
-        state[...] = jnp.zeros_like(state)
+        state[grp] = jnp.zeros(state.shape[1:], state.dtype)
 
-    row, col = _iota(q_ref.shape[1])
-    q, k, v = q_ref[...], k_ref[...], v_ref[...]        # (heads, C, .)
-    f = _factors(q, k, g_ref[...])
-    beta = _column(beta_ref[:, 0], row == col)
-    st = state[...]                                     # (heads, e, d)
+    x = _operands(ins, grp, j, heads, tokens, mixer)
+    q, k, v, beta = x["q"], x["k"], x["v"], x["beta"]
+    row, col = _iota(q.shape[1])
+    f = _factors(q, k, x["g"])
+    st = state[grp]                                     # (G, e, d)
     a, bm = _pairs(f)
     t = _inverse(jnp.where(col < row, beta * a, 0.0), row, col)
     u = _dot32(t, beta * (v - _dot(k * f["grow"], st, "nt")))
-    out_ref[...] = _dot(q * f["grow"], st, "nt") \
+    o = _dot(q * f["grow"], st, "nt") \
         + _dot(jnp.where(col <= row, bm, 0.0), u)
-    state[...] = st * f["total"] + _dot(u, k * f["ef"], "tn")
+    state[grp] = st * f["total"] + _dot(u, k * f["ef"], "tn")
+    if mixer:
+        e = v.shape[-1]
+        o = _epilogue(o, _heads(ins[5][...].astype(jnp.float32), e),
+                      _heads(ins[8][...], e), eps)["y"]
+    _store(outs[0], o)
     if keep:
-        st_ref, t_ref, u_ref = rest[:3]
-        st_ref[:, 0] = st
-        t_ref[:, 0] = t
-        u_ref[:, 0] = u
+        st_ref, t_ref, u_ref = outs[1:]
+        st_ref[...] = st
+        t_ref[...] = t
+        u_ref[...] = u
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, t_ref, u_ref,
-                do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate):
+def _bwd_kernel(*refs, heads, mixer, tokens, eps):
     from jax.experimental import pallas as pl
 
-    @pl.when(pl.program_id(1) == 0)
-    def _():
-        dstate[...] = jnp.zeros_like(dstate)
+    f32 = jnp.float32
+    n_in = MIXER_OPERANDS if mixer else 5
+    ins = refs[:n_in]
+    st_ref, t_ref, u_ref, dout_ref = refs[n_in:n_in + 4]
+    scratch = refs[-2:] if mixer else refs[-1:]
+    outs, dstate = refs[n_in + 4:-len(scratch)], scratch[0]
+    j, grp = pl.program_id(1), pl.program_id(2)
 
-    c = q_ref.shape[1]
+    @pl.when(j == 0)
+    def _():
+        for ref in scratch:
+            ref[grp] = jnp.zeros(ref.shape[1:], ref.dtype)
+
+    x = _operands(ins, grp, pl.num_programs(1) - 1 - j, heads, tokens, mixer)
+    q, k, v, beta = x["q"], x["k"], x["v"], x["beta"]
+    c, e = q.shape[1], v.shape[-1]
     row, col = _iota(c)
-    eye = row == col
-    q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
-    st, t, u = st_ref[:, 0], t_ref[:, 0], u_ref[:, 0]
-    dst = dstate[...]                                   # (heads, e, d)
-    f = _factors(q, k, g_ref[...])
-    beta = _column(beta_ref[:, 0], eye)
+    st, t, u = st_ref[...], t_ref[...], u_ref[...]
+    dst = dstate[grp]                                   # (G, e, d)
+    f = _factors(q, k, x["g"])
     a, bm = _pairs(f)
+    bm = jnp.where(col <= row, bm, 0.0)
     kg, qg, kend = k * f["grow"], q * f["grow"], k * f["ef"]
+    do = _heads(dout_ref[...].astype(f32), e)
+    if mixer:
+        # the epilogue made again from the scan's result, and its gradient
+        norm = _heads(ins[8][...], e)
+        y = _epilogue(_dot(qg, st, "nt") + _dot(bm, u),
+                      _heads(ins[5][...].astype(f32), e), norm, eps)
+        dunit = do * norm * y["s"]
+        dgate = do * y["unit"] * norm * y["s"] * (1.0 - y["s"])
+        dnorm = jnp.sum(do * y["unit"] * y["s"], axis=1, keepdims=True)
+        do = y["scale"] * (dunit - y["unit"] * jnp.mean(
+            dunit * y["unit"], axis=-1, keepdims=True))
     p = v - _dot(kg, st, "nt")
     # out = qg S + B u,  S' = total S + kend^T u,  u = T (beta p)
-    du = _dot(jnp.where(col <= row, bm, 0.0), do, "tn") \
-        + _dot(kend, dst, "nt")
+    du = _dot(bm, do, "tn") + _dot(kend, dst, "nt")
     dbm = jnp.where(col <= row, _dot(do, u, "nt"), 0.0)
     dqg = _dot(do, st)
     dkend = _dot(u, dst)
@@ -268,7 +428,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, t_ref, u_ref,
     dp = beta * dr
     da = beta * dn
     dkg = -_dot(dp, st)
-    dstate[...] = dst * f["total"] + _dot(do, qg, "tn") - _dot(dp, kg, "tn")
+    dstate[grp] = dst * f["total"] + _dot(do, qg, "tn") - _dot(dp, kg, "tn")
     dk_row, dq_row, dk_col, dfirsts = _pairs_bwd(f, da, dbm)
     dq = dq_row + dqg * f["grow"]
     dk_end = dkend * f["ef"]
@@ -283,112 +443,265 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, t_ref, u_ref,
     dcum = dcum + jnp.where(token == c - 1, dlast, 0.0)
     for i, dfirst in enumerate(dfirsts):
         dcum = dcum + jnp.where(token == i * SUB, dfirst, 0.0)
-    dq_ref[...] = dq
-    dk_ref[...] = dk
-    dv_ref[...] = dp
-    dg_ref[...] = _running_sum(col >= row, dcum)
-    dbeta_ref[:, 0] = jnp.sum(jnp.where(eye, dbeta, 0.0), axis=-2,
-                              keepdims=True)
+    dg = _running_sum(col >= row, dcum)
+    if tokens is not None:
+        dq, dk, dp, dg, dbeta = (jnp.where(x["valid"], y, 0.0)
+                                 for y in (dq, dk, dp, dg, dbeta))
+    if mixer:
+        # back through the prologue: the unit norms, the gate, the sigmoid,
+        # SiLU and the convolutions (whose cotangent from the next chunk's
+        # first tokens the reversed walk carries in ``dnext``)
+        d, dnext = q.shape[-1], scratch[1]
+        dq = d ** -0.5 * x["q_len"] * (dq - x["q_unit"] * jnp.sum(
+            dq * x["q_unit"], axis=-1, keepdims=True))
+        dk = x["k_len"] * (dk - x["k_unit"] * jnp.sum(
+            dk * x["k_unit"], axis=-1, keepdims=True))
+        sums = [jnp.sum(dg * x["g"], axis=1, keepdims=True)]   # A_log's
+        dg = dg * x["rate"] * _sigmoid(x["z"])
+        sums += [jnp.sum(dg, axis=1, keepdims=True), dnorm]    # dt_bias's
+        dbeta = dbeta * beta * (1.0 - beta)
+        raw, taps = [], []
+        for i, (dm, cv) in enumerate(zip((dq, dk, dp), x["conv"])):
+            dy = dm * cv["sig"] * (1.0 + cv["y"] * (1.0 - cv["sig"]))
+            nxt = dnext[grp, i]
+            dnext[grp, i, :, :SPAN] = dy[:, :SPAN]
+            n = cv["w"].shape[1]
+            dx = dy * cv["w"][:, n - 1:]
+            for s in range(1, n):
+                dx = dx + _after(dy, nxt, s) * cv["w"][:, n - 1 - s:n - s]
+            raw.append(dx)
+            # tap n - 1 - s: the sum over the tokens of dy times x[t - s]
+            taps.append([jnp.sum(dy * xs, axis=1, keepdims=True)
+                         for xs in cv["shifted"]])
+        dq, dk, dp = raw
+    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref = outs[:5]
+    _store(dq_ref, dq)
+    _store(dk_ref, dk)
+    _store(dv_ref, dp)
+    _store(dg_ref, dg)
+    # the group's lanes of (C, H); the block stays in VMEM while the group
+    # axis, the innermost, walks the heads of the chunk
+    merged = dbeta_ref[...].astype(f32)
+    for h, lane in enumerate(x["lanes"]):
+        merged = jnp.where(lane, dbeta[h], merged)
+    dbeta_ref[...] = merged.astype(dbeta_ref.dtype)
+    if mixer:
+        _store(outs[5], dgate)
+        for ref, total in zip(outs[6:9], sums):
+            _store(ref, total)
+        for i, by_shift in enumerate(taps):
+            n = len(by_shift)
+            for s, total in enumerate(by_shift):
+                tap = n - 1 - s
+                _store(outs[9], total, i, slice(tap, tap + 1))
 
 
 def _specs(n, c, heads, reverse):
+    """Block specs over the grid ``(batch, chunk, group)``: ``cols(w)``, a
+    chunk's rows and a group's lanes of ``(B, T, H * w)``; ``rows(h)``, a
+    chunk's rows of ``(B, T, H)``, whole; ``lanes(w)``, a group's lanes of a
+    ``(1, H * w)`` vector; ``kept(...)``, a chunk's block of the group's
+    heads of ``(B, n, H, ...)``; ``sums(*lead, w)``, a chunk's ``(B, n,
+    *lead, H * w)`` for the group; ``before(w)``, the chunk before of ``(B,
+    T, H * w)`` (the first chunk's own: masked)."""
     from jax.experimental import pallas as pl
 
     def at(j):
         return n - 1 - j if reverse else j
 
-    def tokens(width):
-        return pl.BlockSpec((heads, c, width), lambda i, j: (i, at(j), 0))
+    def cols(width):
+        return pl.BlockSpec((None, c, heads * width),
+                            lambda i, j, g: (i, at(j), g))
 
-    def a_chunk(rows, width):
-        return pl.BlockSpec((heads, 1, rows, width),
-                            lambda i, j: (i, at(j), 0, 0))
-    return tokens, a_chunk
+    def rows(h):
+        return pl.BlockSpec((None, c, h), lambda i, j, g: (i, at(j), 0))
+
+    def lanes(width):
+        return pl.BlockSpec((1, heads * width), lambda i, j, g: (0, g))
+
+    def kept(*shape):
+        return pl.BlockSpec((None, None, heads) + shape,
+                            lambda i, j, g: (i, at(j), g) + (0,) * len(shape))
+
+    def sums(*shape):
+        lead, width = shape[:-1], shape[-1]
+        return pl.BlockSpec(
+            (None, None) + lead + (heads * width,),
+            lambda i, j, g: (i, at(j)) + (0,) * len(lead) + (g,))
+
+    def before(width):
+        return pl.BlockSpec((None, c, heads * width),
+                            lambda i, j, g: (i, jnp.maximum(at(j) - 1, 0), g))
+    return dict(cols=cols, rows=rows, lanes=lanes, kept=kept, sums=sums,
+                before=before)
 
 
 def _params():
+    """The chunk axis and the group axis inside it are sequential: the
+    state of every group is in VMEM (2 MB at 32 heads of 128 x 128, which
+    takes the backward kernel past the compiler's own 16 MiB of scoped VMEM;
+    a v5e has 128 MiB)."""
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"))
+        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=32 << 20)
 
 
-def _heads_a_step(bh):
-    return max(h for h in range(1, HEADS_A_STEP + 1) if bh % h == 0)
+def _heads_a_step(h):
+    return max(g for g in range(1, HEADS_A_STEP + 1) if h % g == 0)
 
 
-def _fwd_pallas(q, k, v, g, beta, c, keep, interpret=False):
+def _sizes(ops):
+    """``(B, T, H, d, e, G)``: a head's channels from the lanes."""
+    b, t, hd = ops[0].shape
+    h = ops[4].shape[-1]
+    return b, t, h, hd // h, ops[2].shape[-1] // h, _heads_a_step(h)
+
+
+def _in_specs(specs, ops, d, e, h, heads):
+    cols = specs["cols"]
+    out = [cols(d), cols(d), cols(e), cols(d), specs["rows"](h)]
+    if len(ops) > 5:
+        from jax.experimental import pallas as pl
+
+        taps = pl.BlockSpec((3, ops[9].shape[1], heads * d),
+                            lambda i, j, g: (0, 0, g))
+        out += [cols(e), specs["lanes"](d), specs["lanes"](d),
+                specs["lanes"](e), taps, specs["before"](d),
+                specs["before"](d), specs["before"](e)]
+    return out
+
+
+def _fwd_pallas(*ops, c, tokens, eps, keep, interpret=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, t, d = q.shape
-    e = v.shape[2]
+    b, t, h, d, e, heads = _sizes(ops)
     n = t // c
-    heads = _heads_a_step(bh)
-    tokens, a_chunk = _specs(n, c, heads, False)
+    specs = _specs(n, c, heads, False)
     f32 = jnp.float32
-    out_specs = [tokens(e)]
-    out_shape = [jax.ShapeDtypeStruct((bh, t, e), f32)]
+    out_specs = [specs["cols"](e)]
+    out_shape = [jax.ShapeDtypeStruct((b, t, h * e), f32)]
     if keep:
-        out_specs += [a_chunk(e, d), a_chunk(c, c), a_chunk(c, e)]
-        out_shape += [jax.ShapeDtypeStruct((bh, n, e, d), f32),
-                      jax.ShapeDtypeStruct((bh, n, c, c), f32),
-                      jax.ShapeDtypeStruct((bh, n, c, e), f32)]
+        kept = specs["kept"]
+        out_specs += [kept(e, d), kept(c, c), kept(c, e)]
+        out_shape += [jax.ShapeDtypeStruct((b, n, h, e, d), f32),
+                      jax.ShapeDtypeStruct((b, n, h, c, c), f32),
+                      jax.ShapeDtypeStruct((b, n, h, c, e), f32)]
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, keep=keep),
-        grid=(bh // heads, n),
-        in_specs=[tokens(d), tokens(d), tokens(e), tokens(d),
-                  a_chunk(1, c)],
+        functools.partial(_fwd_kernel, heads=heads, mixer=len(ops) > 5,
+                          keep=keep, tokens=tokens, eps=eps),
+        grid=(b, n, h // heads),
+        in_specs=_in_specs(specs, ops, d, e, h, heads),
         out_specs=out_specs, out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((heads, e, d), f32)],
+        scratch_shapes=[pltpu.VMEM((h // heads, heads, e, d), f32)],
         compiler_params=_params(), interpret=interpret, name="mx_kda_fwd",
-    )(q, k, v, g, beta.reshape(bh, n, 1, c))
+    )(*ops)
 
 
-def _bwd_pallas(q, k, v, g, beta, st, tinv, u, do, c, interpret=False):
+def _bwd_pallas(*ops, c, tokens, eps, mixer, interpret=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, t, d = q.shape
-    e = v.shape[2]
+    ins = ops[:MIXER_OPERANDS if mixer else 5]
+    b, t, h, d, e, heads = _sizes(ins)
     n = t // c
-    heads = _heads_a_step(bh)
-    tokens, a_chunk = _specs(n, c, heads, True)
+    specs = _specs(n, c, heads, True)
+    cols, kept, sums = specs["cols"], specs["kept"], specs["sums"]
     f32 = jnp.float32
-    dq, dk, dv, dg, dbeta = pl.pallas_call(
-        _bwd_kernel,
-        grid=(bh // heads, n),
-        in_specs=[tokens(d), tokens(d), tokens(e), tokens(d), a_chunk(1, c),
-                  a_chunk(e, d), a_chunk(c, c), a_chunk(c, e), tokens(e)],
-        out_specs=[tokens(d), tokens(d), tokens(e), tokens(d),
-                   a_chunk(1, c)],
-        out_shape=[jax.ShapeDtypeStruct((bh, t, d), f32),
-                   jax.ShapeDtypeStruct((bh, t, d), f32),
-                   jax.ShapeDtypeStruct((bh, t, e), f32),
-                   jax.ShapeDtypeStruct((bh, t, d), f32),
-                   jax.ShapeDtypeStruct((bh, n, 1, c), f32)],
-        scratch_shapes=[pltpu.VMEM((heads, e, d), f32)],
+
+    def like(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype)
+    out_specs = [cols(d), cols(d), cols(e), cols(d), specs["rows"](h)]
+    out_shape = [like(x) for x in ins[:5]]
+    scratch = [pltpu.VMEM((h // heads, heads, e, d), f32)]
+    if mixer:
+        k = ins[9].shape[1]
+        out_specs += [cols(e), sums(1, d), sums(1, d), sums(1, e),
+                      sums(3, k, d)]
+        out_shape += [like(ins[5])] + [
+            jax.ShapeDtypeStruct((b, n, 1, h * w), f32) for w in (d, d, e)] \
+            + [jax.ShapeDtypeStruct((b, n, 3, k, h * d), f32)]
+        scratch += [pltpu.VMEM((h // heads, 3, heads, c, d), f32)]
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, heads=heads, mixer=mixer,
+                          tokens=tokens, eps=eps),
+        grid=(b, n, h // heads),
+        in_specs=_in_specs(specs, ins, d, e, h, heads)
+        + [kept(e, d), kept(c, c), kept(c, e), cols(e)],
+        out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
         compiler_params=_params(), interpret=interpret, name="mx_kda_bwd",
-    )(q, k, v, g, beta.reshape(bh, n, 1, c), st, tinv, u, do)
-    return dq, dk, dv, dg, dbeta.reshape(bh, t)
+    )(*ops)
+
+
+def _forward(ops, c, tokens, eps, keep):
+    if len(ops) > 5:
+        ops = ops + ops[:3]     # the tokens before a chunk: the same arrays
+    return _platform_pick(functools.partial(
+        _fwd_pallas, c=c, tokens=tokens, eps=eps, keep=keep), *ops)
+
+
+def _backward(ops, kept, dout, c, tokens, eps):
+    mixer = len(ops) > 5
+    grads = _platform_pick(functools.partial(
+        _bwd_pallas, c=c, tokens=tokens, eps=eps, mixer=mixer),
+        *ops, *(ops[:3] if mixer else ()), *kept, dout)
+    # the vectors' and the taps' gradients: a chunk's token sums, added
+    return tuple(grads[:6]) + tuple(x.sum(axis=(0, 1)) for x in grads[6:])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def chunk_scan(q, k, v, g, beta, c):
     """The gated delta rule over whole chunks of ``c`` tokens: ``q, k, g
-    (BH, T, d)``, ``v (BH, T, e)``, ``beta (BH, T)``, all float32, ``T`` a
-    multiple of ``c``; returns ``(BH, T, e)``."""
-    return _platform_pick(functools.partial(_fwd_pallas, c=c, keep=False),
-                          q, k, v, g, beta)[0]
+    (B, T, H * d)``, ``v (B, T, H * e)``, ``beta (B, T, H)``, all float32,
+    ``T`` a multiple of ``c``, a padded token ``g = 0`` and ``beta = 0``;
+    returns ``(B, T, H * e)``."""
+    return _forward((q, k, v, g, beta), c, None, 0.0, False)[0]
 
 
 def _chunk_scan_fwd(q, k, v, g, beta, c):
-    out, st, tinv, u = _platform_pick(
-        functools.partial(_fwd_pallas, c=c, keep=True), q, k, v, g, beta)
-    return out, (q, k, v, g, beta, st, tinv, u)
+    ops = (q, k, v, g, beta)
+    out, *kept = _forward(ops, c, None, 0.0, True)
+    return out, (ops, kept)
 
 
-def _chunk_scan_bwd(c, res, do):
-    return _platform_pick(functools.partial(_bwd_pallas, c=c), *res, do)
+def _chunk_scan_bwd(c, res, dout):
+    return _backward(*res, dout, c, None, 0.0)
 
 
 chunk_scan.defvjp(_chunk_scan_fwd, _chunk_scan_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(10, 11, 12))
+def mixer(q, k, v, decay, beta, gate, a, dt_bias, norm, taps, c, tokens,
+          eps):
+    """A KDA mixer between its projections, over whole chunks of ``c``
+    tokens: the projections' results ``q, k, decay (B, T, H * d)``, ``v,
+    gate (B, T, H * e)`` (``e = d``), ``beta (B, T, H)``, in any float dtype;
+    ``a`` and ``dt_bias (1, H * d)`` (``A_log`` a head, repeated over its
+    channels), ``norm (1, H * e)`` (the output norm's weight, repeated over
+    the heads), ``taps (3, K, H * d)`` (the three convolutions', ``K`` at
+    most ``SPAN + 1``); ``tokens``, where ``T`` was padded to whole chunks,
+    the tokens before the padding.  Returns ``(B, T, H * e)`` float32::
+
+        q, k, v = silu(causal_conv1d(x, taps)), in float32
+        q, k    = x / sqrt(sum x^2 + 1e-6) a head;  q *= d ** -0.5
+        g       = -exp(a) * softplus(decay + dt_bias);  beta = sigmoid(beta)
+        o       = the delta rule
+        out     = o / sqrt(mean o^2 + eps) * norm * sigmoid(gate), a head"""
+    ops = (q, k, v, decay, beta, gate, a, dt_bias, norm, taps)
+    return _forward(ops, c, tokens, eps, False)[0]
+
+
+def _mixer_fwd(q, k, v, decay, beta, gate, a, dt_bias, norm, taps, c, tokens,
+               eps):
+    ops = (q, k, v, decay, beta, gate, a, dt_bias, norm, taps)
+    out, *kept = _forward(ops, c, tokens, eps, True)
+    return out, (ops, kept)
+
+
+def _mixer_bwd(c, tokens, eps, res, dout):
+    return _backward(*res, dout, c, tokens, eps)
+
+
+mixer.defvjp(_mixer_fwd, _mixer_bwd)

@@ -462,7 +462,8 @@ def _traced(block):
 
 
 def _mixer_grads(mixer, one_chip, tokens, layers=(1,)):
-    """``mixer`` (an ``MLAMixer`` at 2048 or 2304 units) under bfloat16 AMP,
+    """``mixer`` (an ``MLAMixer`` at 2048 or 2304 units, or a ``KDAMixer``:
+    the last parameter is ``o_proj``) under bfloat16 AMP,
     forward and backward over one sequence, compiled for the described
     chip: one executable for each count of ``layers``."""
     import mxnet_tpu as mx
@@ -568,3 +569,64 @@ def test_grouped_ffn_with_swiglu_experts_of_768_over_8192_tokens(one_chip):
         ["mx_gmm"] * 6 + ["mx_gmm_dw"] * 2 + ["mx_rows_combine"] * 2
         + ["mx_rows_swiglu"] * 3 + ["mx_rows_take"] * 3), kernels
     assert "ragged-dot" not in text
+
+
+# -- the Kimi Linear cell's KDA mixer whole in its kernels (PR 41) ------------
+
+def _launches(compiled):
+    return sorted(re.findall(r"%(mx_\w+?)(?:\.\d+)* = .* custom-call\(",
+                             compiled.as_text()))
+
+
+def test_the_kda_mixer_is_its_kernels_and_rewrites_no_array_over_the_heads(
+        one_chip):
+    # Kimi Linear's KDA mixer at its published sizes under bfloat16 AMP,
+    # forward and backward over one sequence of 4096 tokens: the kernels
+    # read the projections' results where they lie, (1, 4096, 32 x 128), so
+    # under ``kda_attention`` nothing transposes, copies or concatenates an
+    # array over (heads, tokens) or (head groups, batch, tokens); a layer
+    # launches ``mx_kda_fwd`` once a pass (the forward, and the forward
+    # again when the cotangent arrives) and ``mx_kda_bwd`` once.  A layer's
+    # temporaries were 768 MiB and what it keeps while the next runs 282
+    # before PR 41 (548 and 264 since)
+    from mxnet_tpu.gluon.model_zoo import kimi_linear
+
+    mixer = kimi_linear.KDAMixer(2304, 32, 128, layer=1)
+    one, two = _mixer_grads(mixer, one_chip, 4096, layers=(1, 2))
+    assert _launches(one) == ["mx_kda_bwd", "mx_kda_fwd", "mx_kda_fwd"]
+    assert _launches(two) == ["mx_kda_bwd"] * 2 + ["mx_kda_fwd"] * 4
+    over_heads = re.compile(r"\[(?:\d+,)*(?:32,4096|4096,32),128\]"
+                            r"|\[4,1,4096,\d+\]")
+    moved = [line.strip()[:200] for line in one.as_text().splitlines()
+             if re.search(r" (transpose|copy|concatenate)\(", line)
+             and "kda_attention" in line and over_heads.search(line)]
+    assert not moved, moved
+    temp = one.memory_analysis().temp_size_in_bytes
+    kept = two.memory_analysis().temp_size_in_bytes - temp
+    assert temp < 640 << 20
+    assert kept < 282 << 20
+
+
+def test_the_mamba2_scan_and_convolution_trace_as_they_did():
+    # ``ops/ssd_kernels.py`` takes ``_dot``, ``_column`` and ``_iota`` from
+    # ``kda_kernels``, and the KDA mixer no longer calls ``causal_conv1d``:
+    # the Nemotron cell's scan and convolution, forward and backward, trace
+    # to the jaxpr text they had before PR 41 (sha256 of ``jax.make_jaxpr``
+    # at small shapes, recorded from the parent; nothing compiles here)
+    import hashlib
+
+    from mxnet_tpu.ops.ssm import causal_conv1d
+
+    f32 = jnp.float32
+
+    def digest(fn, *shapes):
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(jnp.square(fn(*a))),
+            argnums=tuple(range(len(shapes)))))(
+            *[jnp.zeros(s, f32) for s in shapes]))
+        return hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert digest(ssd_scan, (1, 256, 2, 64), (1, 256, 2), (1, 2),
+                  (1, 256, 1, 128), (1, 256, 1, 128), (2,), (1, 2)) \
+        == "cc2528a72423d0c9"
+    assert digest(causal_conv1d, (1, 64, 256), (256, 4)) \
+        == "f33e4420777b9092"
