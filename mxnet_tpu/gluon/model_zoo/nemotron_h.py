@@ -45,6 +45,7 @@ generation of the TwoTower release.
 from __future__ import annotations
 
 import math
+import os
 
 import jax
 import jax.numpy as jnp
@@ -88,28 +89,33 @@ class _Mixer(HybridBlock):
 
 def chunk_counters(stem, scans, kernels, unit="chunks",
                    each="sequences x heads x chunks, every such layer and "
-                        "train step", kernel="kernel", stat=None):
+                        "train step", kernel="kernel", stat=None,
+                   kernel_stat=None, kernel_family=None):
     """A telemetry collector for the counts of one kind of scan, or of
-    whatever else a layer counts in ``unit`` (latent attention: layers).
+    whatever else a layer counts in ``unit`` (latent attention: layers;
+    block-diffusion attention: tiles).
     They leave a ``JitTrainStep`` program as the statistics
     ``<stat>/<layer>`` (chunks the scans ran) and
     ``<stat>_<kernel>/<layer>`` (those that ran in the Pallas kernels;
-    ``stat`` is ``stem`` unless given), which it accumulates on the device;
+    ``stat`` is ``stem`` unless given, the second ``<kernel_stat>/<layer>``
+    where that is given), which it accumulates on the device;
     a snapshot fetches them (once, both kinds) and adds what is new (modulo
     the accumulators' 32 bits) to ``mxnet_<stem>_<unit>_total`` and
-    ``mxnet_<stem>_<kernel>_<unit>_total``."""
+    ``mxnet_<stem>_<kernel>_<unit>_total`` (or ``kernel_family``)."""
     stat = stat or stem
+    second = (kernel_stat or "%s_%s" % (stat, kernel)) + "/"
     families = {stat + "/": ("mxnet_%s_%s_total" % (stem, unit), "ran"),
-                "%s_%s/" % (stat, kernel): (
-                    "mxnet_%s_%s_%s_total" % (stem, kernel, unit),
-                    "ran in the Pallas kernels " + kernels)}
+                second: (kernel_family
+                         or "mxnet_%s_%s_%s_total" % (stem, kernel, unit),
+                         "ran in the Pallas kernels " + kernels)}
+    read = os.path.commonprefix(list(families))
     seen = {}               # (step, statistic) -> the count last read
 
     def collect():
         from ...parallel.train_step import read_step_stats
 
         new = {}        # no step of this process scans: no family either
-        for owner, stats in read_step_stats(stat):
+        for owner, stats in read_step_stats(read):
             for name, count in stats.items():
                 prefix = name[:name.index("/") + 1]
                 if prefix in families:

@@ -20,37 +20,53 @@ from .registry import register
 
 @register("_contrib_moe_router_topk", num_outputs=2,
           inputs=("data", "weight", "bias"))
-def router_topk(data, weight, bias, k=1, scale=1.0, normalize=True,
-                balance_seed=None):
-    """Sigmoid top-k routing: ``(index (S, k) int32, weight (S, k) f32)``.
+def router_topk(data, weight, bias=None, k=1, scale=1.0, normalize=True,
+                balance_seed=None, scoring="sigmoid"):
+    """Top-k routing: ``(index (S, k) int32, weight (S, k) f32)``.
 
     ``data (S, D)``, ``weight (E, D)`` (one row an expert, all E of them
-    whichever are held here), ``bias (E,)``.  ``s = sigmoid(data @
-    weight.T)`` in float32 at the highest matmul precision (the choice is
-    discrete: a rounded score flips it); the ``k`` largest of ``s + bias``
-    are chosen, ties to the lower index (``lax.top_k``); the weights are
-    ``s`` at the chosen experts, without the bias, over their sum (+1e-20)
-    where ``normalize``, times ``scale``.  The bias takes no gradient: it
-    moves indices only.
+    whichever are held here).  The scores are float32 at the highest matmul
+    precision (the choice is discrete: a rounded score flips it), by
+    ``scoring``:
+
+    - ``sigmoid`` (DeepSeek's rule): ``s = sigmoid(data @ weight.T)``; the
+      ``k`` largest of ``s + bias`` (``bias (E,)``) are chosen, ties to the
+      lower index (``lax.top_k``); the weights are ``s`` at the chosen
+      experts, without the bias.  The bias takes no gradient: it moves
+      indices only.
+    - ``softmax`` (Qwen3-MoE's rule): ``s = softmax(data @ weight.T)`` over
+      all E experts; the ``k`` largest of ``s`` are chosen; the weights are
+      ``s`` at the chosen experts.  No bias (``bias`` must be None).
+
+    The weights are then divided by their sum (+1e-20) where ``normalize``
+    and multiplied by ``scale``.
 
     ``balance_seed`` (an int; for measuring throughput only) forces the
     load to balance, as Megatron-LM's ``--moe-router-force-load-balancing``
     does: the ``k`` experts of a row are the largest of ``uniform(PRNGKey(
-    balance_seed), (S, E))`` in place of ``s + bias``, so every expert gets
-    the same expected load whatever the weights and the data are; the
-    weights are still ``s`` at the chosen experts.  Random weights route
-    unevenly, each draw in its own way, and a step's cost follows the
+    balance_seed), (S, E))`` in place of the scores (and bias), so every
+    expert gets the same expected load whatever the weights and the data
+    are; the weights are still ``s`` at the chosen experts.  Random weights
+    route unevenly, each draw in its own way, and a step's cost follows the
     routing."""
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError("router_topk: no scoring %r (sigmoid, softmax)"
+                         % (scoring,))
+    if (bias is None) != (scoring == "softmax"):
+        raise ValueError("router_topk: sigmoid scores take a bias, softmax "
+                         "scores none")
     with jax.named_scope("moe_router"):
-        s = jax.nn.sigmoid(jnp.einsum(
+        logits = jnp.einsum(
             "sd,ed->se", data.astype(jnp.float32),
             weight.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
-        if balance_seed is None:
-            choice = s + bias.astype(jnp.float32)
-        else:
+            precision=jax.lax.Precision.HIGHEST)
+        s = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" \
+            else jax.nn.sigmoid(logits)
+        if balance_seed is not None:
             choice = jax.random.uniform(
                 jax.random.PRNGKey(int(balance_seed)), s.shape, jnp.float32)
+        else:
+            choice = s if bias is None else s + bias.astype(jnp.float32)
         _, idx = jax.lax.top_k(choice, int(k))
         w = jnp.take_along_axis(s, idx, axis=1)
         if normalize:

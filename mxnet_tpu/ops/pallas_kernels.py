@@ -38,6 +38,7 @@ platform; a kernel the TPU compiler refuses is an error.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import math
 import warnings
@@ -91,11 +92,111 @@ def _platform_pick(run, *args, off_tpu=None):
 def _blocks_seen(qi, block_q, block_k, t_kv, causal):
     """Key blocks that query block ``qi`` reads: all of them, or under the
     causal mask those that hold a column at or before its last row (a block
-    past them adds exact zeros)."""
+    past them adds exact zeros).  Under the block-diffusion mask the blocks
+    are no range: ``_bd_visits`` walks them."""
     n_k = t_kv // block_k
     if not causal or n_k == 1:
         return n_k          # static: the loop is unrolled as it always was
     return jnp.minimum(((qi + 1) * block_q + block_k - 1) // block_k, n_k)
+
+
+# -- the block-diffusion mask ------------------------------------------------
+# The sequence is ``[x_0 | x_t]``, ``half`` tokens each, cut into blocks of
+# ``block`` tokens.  A clean query ``i < half`` sees the clean keys of its
+# block and the blocks before it; a noised query ``half + p`` sees the clean
+# keys of the blocks strictly before its own and the noised keys of its own
+# block.  With tiles of ``tile`` tokens that ``block`` divides (``n = half /
+# tile`` a half), a clean query tile ``qi`` sees clean key tiles ``0 .. qi``,
+# the last one partly; a noised query tile ``n + p`` sees clean tiles ``0 ..
+# p``, the last one partly, and its own diagonal tile, partly.  Every other
+# tile is dead: ``n (n + 2)`` of the ``4 n^2`` are computed.
+
+# the static description of the block-diffusion mask
+BlockDiffusion = collections.namedtuple("BlockDiffusion", "half block")
+
+
+def _kernel_name(name, mask):
+    """A kernel's name: the mask's kernels carry ``_bd`` after it."""
+    return name if mask is None else name + "_bd"
+
+
+def block_diffusion_mask(half, block):
+    """``(2 half, 2 half)`` bool: which key each query sees (the dense form
+    of the mask, for ``_attention_ref`` and the tests)."""
+    i = jnp.arange(2 * half)
+    noised, blk = i >= half, (i % half) // block
+    rn, cn = noised[:, None], noised[None, :]
+    rb, cb = blk[:, None], blk[None, :]
+    return jnp.where(cn, rn & (cb == rb), jnp.where(rn, cb < rb, cb <= rb))
+
+
+def bd_tiles(half, block, tile=None):
+    """``(tiles of the grid, tiles the kernels compute)`` of
+    ``flash_attention`` under the mask over ``2 half`` positions, at
+    ``tile``-token tiles (the operator's own choice unless given): ``4 n^2``
+    and ``n (n + 2)``; where the kernels do not take the shape (``bd_tile``;
+    below 512 positions, where no tile is given, the operator is XLA's
+    attention) every tile is computed, by the dense fallback."""
+    t = 2 * half
+    kernels = tile is not None or t >= 512
+    tile = _tiles(t, int(tile) if tile else min(t, 512))
+    if tile is None:
+        return 1, 1
+    n = half // tile
+    grid = (t // tile) ** 2
+    if not kernels or bd_tile(half, block, tile) is None:
+        return grid, grid
+    return grid, n * (n + 2)
+
+
+def bd_tile(half, block, tile):
+    """``tile`` where the kernels take the mask at it, else None: the tile
+    divides a half, and ``block``, a power of two, divides the tile."""
+    if tile is None or half % tile or tile % block or block & (block - 1):
+        return None
+    return tile
+
+
+def _bd_visible(qi, ki, mask, tile):
+    """Which pairs of query tile ``qi`` and key tile ``ki`` the mask leaves
+    live, ``(tile, tile)`` bool: the blocks' distance ``d = key block -
+    query block`` within the tiles (tiles start on a block's first token)
+    must lie in ``[lo, hi]``: ``(-inf, 0]`` clean to clean, ``(-inf, -1]``
+    noised to clean, ``[0, 0]`` noised to noised."""
+    n = mask.half // tile
+    shift = mask.block.bit_length() - 1
+
+    def blocks(axis):
+        return jnp.right_shift(
+            lax.broadcasted_iota(jnp.int32, (tile, tile), axis), shift)
+    d = blocks(1) - blocks(0)
+    key_noised = ki >= n
+    hi = jnp.where(key_noised | (qi < n), 0, -1)
+    lo = jnp.where(key_noised, 0, -(tile + 1))
+    return (d <= hi) & (d >= lo)
+
+
+def _bd_visits(step, carry, qi, mask, tile):
+    """``step(i, carry, visible=None)`` over the key tiles query tile ``qi``
+    sees: its own diagonal tile first (every row sees itself there, so no
+    row's running maximum stays -inf), for a noised tile the clean tile at
+    its position (masked), then the clean tiles before that one, whole."""
+    n = mask.half // tile
+    noised = (qi >= n).astype(jnp.int32)
+    own = qi - n * noised
+    carry = step(qi, carry, _bd_visible(qi, qi, mask, tile))
+    carry = lax.fori_loop(0, noised, lambda _, c: step(
+        own, c, _bd_visible(qi, own, mask, tile)), carry)
+    return lax.fori_loop(0, own, step, carry)
+
+
+def _bd_query_tile(ki, i, n):
+    """The ``i``-th query tile that key tile ``ki`` is seen by (the last one
+    where ``i`` is past them): a clean key tile by the clean query tiles from
+    its own on and then the noised ones from its position on, a noised key
+    tile by its own diagonal tile alone."""
+    clean = jnp.where(i < n - ki, ki + i, jnp.minimum(2 * ki + i, 2 * n - 1))
+    return jnp.where(ki >= n, ki, clean)
 
 
 def _scores(qs, ks):
@@ -112,17 +213,20 @@ def _scores(qs, ks):
 
 
 def _flash_fwd_head(qs, keys, value, t_kv, dv, qi, *, block_q, block_k,
-                    causal):
+                    causal, mask=None):
     """One head's query block against the key blocks it sees, the online
     softmax: ``qs`` the scaled float32 query block, a tuple of its channel
     parts; ``keys(i)`` key block ``i`` as the same parts and ``value(i)``
     its ``dv`` values, float32.  The running maximum ``m`` and sum ``l``
-    ``(bq, 1)`` and the result not yet divided by ``l`` ``(bq, dv)``."""
-    n_k = _blocks_seen(qi, block_q, block_k, t_kv, causal)
+    ``(bq, 1)`` and the result not yet divided by ``l`` ``(bq, dv)``.
+    ``mask`` (a ``BlockDiffusion``; ``causal`` False) walks the tiles that
+    mask leaves live (``_bd_visits``)."""
+    if mask is None:
+        n_k = _blocks_seen(qi, block_q, block_k, t_kv, causal)
     row = qi * block_q + lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
 
-    def body(i, carry):
+    def body(i, carry, visible=None):
         m, l, acc = carry
         ks, v = keys(i), value(i)                       # (bk, D), (bk, Dv)
         s = _scores(qs, ks)
@@ -130,6 +234,8 @@ def _flash_fwd_head(qs, keys, value, t_kv, dv, qi, *, block_q, block_k,
             col = i * block_k + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(col <= row, s, -jnp.inf)
+        if visible is not None:
+            s = jnp.where(visible, s, -jnp.inf)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -142,6 +248,8 @@ def _flash_fwd_head(qs, keys, value, t_kv, dv, qi, *, block_q, block_k,
     m0 = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
     acc0 = jnp.zeros((block_q, dv), jnp.float32)
+    if mask is not None:
+        return _bd_visits(body, (m0, l0, acc0), qi, mask, block_q)
     return lax.fori_loop(0, n_k, body, (m0, l0, acc0))
 
 
@@ -159,7 +267,7 @@ def _lane_rows(x, block_q):
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
-                      block_k, scale, causal):
+                      block_k, scale, causal, mask=None):
     from jax.experimental import pallas as pl
 
     def block(ref, i):
@@ -168,7 +276,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
     m, l, acc = _flash_fwd_head(
         (q,), lambda i: (block(k_ref, i),), lambda i: block(v_ref, i),
         k_ref.shape[1], v_ref.shape[-1], pl.program_id(1), block_q=block_q,
-        block_k=block_k, causal=causal)
+        block_k=block_k, causal=causal, mask=mask)
     safe_l = jnp.where(l == 0, 1.0, l)
     o_ref[0] = (acc / safe_l).astype(o_ref.dtype)
     lse_ref[0] = _lane_rows(_flash_lse(m, l, safe_l), block_q)
@@ -194,7 +302,7 @@ def _flash_params(k, v):
 
 
 def _flash_pallas(q, k, v, scale, causal, block_q, block_k,
-                  interpret=False):
+                  interpret=False, mask=None):
     from jax.experimental import pallas as pl
 
     bh, t_q, d = q.shape
@@ -202,6 +310,8 @@ def _flash_pallas(q, k, v, scale, causal, block_q, block_k,
     kernel = functools.partial(
         _flash_fwd_kernel, block_q=block_q, block_k=block_k,
         scale=scale, causal=causal)
+    if mask is not None:
+        kernel = functools.partial(kernel, mask=mask)
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, t_q // block_q),
@@ -220,7 +330,7 @@ def _flash_pallas(q, k, v, scale, causal, block_q, block_k,
         ],
         compiler_params=_flash_params(k, v),
         interpret=interpret,
-        name="mx_flash_fwd",
+        name=_kernel_name("mx_flash_fwd", mask),
     )(q, k, v)
     return out, lse
 
@@ -231,21 +341,24 @@ def _flash_pallas(q, k, v, scale, causal, block_q, block_k,
 
 
 def _flash_dq_head(qs, do, lse, delta, keys, value, t_kv, qi, *, block_q,
-                   block_k, scale, causal):
+                   block_k, scale, causal, mask=None):
     """One head's query block against the key blocks it sees: ``dq`` as the
     parts ``qs`` came in (float32, not scaled).  ``lse`` and ``delta``
-    ``(bq, 1)``; ``keys`` and ``value`` as ``_flash_fwd_head``'s."""
-    n_k = _blocks_seen(qi, block_q, block_k, t_kv, causal)
+    ``(bq, 1)``; ``keys``, ``value`` and ``mask`` as ``_flash_fwd_head``'s."""
+    if mask is None:
+        n_k = _blocks_seen(qi, block_q, block_k, t_kv, causal)
     row = qi * block_q + lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
 
-    def body(i, dqs):
+    def body(i, dqs, visible=None):
         ks, v = keys(i), value(i)
         s = scale * _scores(qs, ks)
         if causal:
             col = i * block_k + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(col <= row, s, -jnp.inf)
+        if visible is not None:
+            s = jnp.where(visible, s, -jnp.inf)
         # p is the NORMALIZED probability (lse folds in the row sum);
         # fully-masked rows have lse=-inf -> exp(-inf - -inf) guarded to 0
         p = jnp.where(jnp.isfinite(lse), jnp.exp(s - lse), 0.0)
@@ -257,12 +370,15 @@ def _flash_dq_head(qs, do, lse, delta, keys, value, t_kv, qi, *, block_q,
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) for dq, k in zip(dqs, ks))
 
-    return lax.fori_loop(0, n_k, body, tuple(
-        jnp.zeros((block_q, q.shape[-1]), jnp.float32) for q in qs))
+    dqs = tuple(jnp.zeros((block_q, q.shape[-1]), jnp.float32) for q in qs)
+    if mask is not None:
+        return _bd_visits(body, dqs, qi, mask, block_q)
+    return lax.fori_loop(0, n_k, body, dqs)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, *, block_q, block_k, scale, causal):
+                         dq_ref, *, block_q, block_k, scale, causal,
+                         mask=None):
     from jax.experimental import pallas as pl
 
     def block(ref, i):
@@ -273,7 +389,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         lse_ref[0][:, :1], delta_ref[0][:, :1],         # (bq, 1) lane 0
         lambda i: (block(k_ref, i),), lambda i: block(v_ref, i),
         k_ref.shape[1], pl.program_id(1), block_q=block_q, block_k=block_k,
-        scale=scale, causal=causal)
+        scale=scale, causal=causal, mask=mask)
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
@@ -283,10 +399,12 @@ def _first_block_seen(ki, block_q, block_k, causal):
 
 
 def _flash_dkv_pair(qs, ks, v, do, lse, delta, ki, qi, *, block_q, block_k,
-                    scale, causal):
+                    scale, causal, visible=None):
     """One (key block, query block) pair of one head: ``(dks, dv)``, the
     pair's part of dk (the parts ``ks`` came in) and of dv, float32.  ``qs``
-    and ``ks`` as ``_scores`` takes them, not scaled."""
+    and ``ks`` as ``_scores`` takes them, not scaled.  ``visible`` (bool
+    ``(bq, bk)``; ``causal`` False) masks a pair the block-diffusion mask
+    leaves partly live."""
     s = scale * _scores(qs, ks)                         # (bq, bk)
     if causal:
         col = ki * block_k + lax.broadcasted_iota(
@@ -294,6 +412,8 @@ def _flash_dkv_pair(qs, ks, v, do, lse, delta, ki, qi, *, block_q, block_k,
         row = qi * block_q + lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)
         s = jnp.where(col <= row, s, -jnp.inf)
+    if visible is not None:
+        s = jnp.where(visible, s, -jnp.inf)
     p = jnp.where(jnp.isfinite(lse), jnp.exp(s - lse), 0.0)
     dv = jax.lax.dot_general(
         p, do, (((0,), (0,)), ((), ())),
@@ -307,9 +427,36 @@ def _flash_dkv_pair(qs, ks, v, do, lse, delta, ki, qi, *, block_q, block_k,
         preferred_element_type=jnp.float32) for q in qs), dv    # (bk, D)
 
 
+def _bd_dkv_visit(pair, dk_acc, dv_acc, ki, i, mask, tile):
+    """Grid step ``i`` of key tile ``ki`` under the block-diffusion mask:
+    query tile ``_bd_query_tile(ki, i)`` where ``i`` is one of the tiles
+    that see it (the first ``2 (n - ki)`` steps of a clean tile, the first
+    of a noised one), masked where the pair is partly live (the query tile
+    at the key tile's position in either half), whole otherwise; past them
+    nothing."""
+    from jax.experimental import pallas as pl
+
+    n = mask.half // tile
+    qt = _bd_query_tile(ki, i, n)
+    live = i < jnp.where(ki >= n, 1, 2 * (n - ki))
+    partial_ = (qt == ki) | (qt == ki + n)
+
+    @pl.when(live & partial_)
+    def _():
+        dk, dv = pair(_bd_visible(qt, ki, mask, tile))
+        dk_acc[...] += dk
+        dv_acc[...] += dv
+
+    @pl.when(live & jnp.logical_not(partial_))
+    def _():
+        dk, dv = pair()
+        dk_acc[...] += dk
+        dv_acc[...] += dv
+
+
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc=None, dv_acc=None, *,
-                          block_q, block_k, scale, causal):
+                          block_q, block_k, scale, causal, mask=None):
     """One (key block, query block) pair a grid step: the query blocks
     stream through VMEM (whole-T operands do not fit at T=4096) and dk, dv
     accumulate in float32 scratch across the innermost grid axis.  Where T
@@ -320,14 +467,15 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     ki, qi = pl.program_id(1), pl.program_id(2)
     one_block = dk_acc is None          # T is one query block: no sum to keep
 
-    def pair():
+    def pair(visible=None):
         k = k_ref[0].astype(jnp.float32)                # (bk, D)
         v = v_ref[0].astype(jnp.float32)                # (bk, Dv)
         q = q_ref[0].astype(jnp.float32)                # (bq, D)
         do = do_ref[0].astype(jnp.float32)              # (bq, Dv)
         (dk,), dv = _flash_dkv_pair(
             (q,), (k,), v, do, lse_ref[0][:, :1], delta_ref[0][:, :1], ki,
-            qi, block_q=block_q, block_k=block_k, scale=scale, causal=causal)
+            qi, block_q=block_q, block_k=block_k, scale=scale, causal=causal,
+            visible=visible)
         return dk, dv
 
     if one_block:
@@ -341,13 +489,16 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    # under the causal mask a query block before the key block's first
-    # row adds exact zeros: not computed
-    @pl.when(qi >= _first_block_seen(ki, block_q, block_k, causal))
-    def _():
-        dk, dv = pair()
-        dk_acc[...] += dk
-        dv_acc[...] += dv
+    if mask is not None:
+        _bd_dkv_visit(pair, dk_acc, dv_acc, ki, qi, mask, block_k)
+    else:
+        # under the causal mask a query block before the key block's first
+        # row adds exact zeros: not computed
+        @pl.when(qi >= _first_block_seen(ki, block_q, block_k, causal))
+        def _():
+            dk, dv = pair()
+            dk_acc[...] += dk
+            dv_acc[...] += dv
 
     @pl.when(qi == pl.num_programs(2) - 1)
     def _():
@@ -356,15 +507,18 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_pallas(q, k, v, do, lse, delta, scale, causal, block_q,
-                      block_k, interpret=False):
+                      block_k, interpret=False, mask=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t_q, d = q.shape
     t_kv, dv = k.shape[1], v.shape[2]
+    static = dict(block_q=block_q, block_k=block_k, scale=scale,
+                  causal=causal)
+    if mask is not None:
+        static["mask"] = mask
     dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, block_q=block_q,
-                          block_k=block_k, scale=scale, causal=causal),
+        functools.partial(_flash_bwd_dq_kernel, **static),
         grid=(bh, t_q // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
@@ -378,12 +532,17 @@ def _flash_bwd_pallas(q, k, v, do, lse, delta, scale, causal, block_q,
         out_shape=jax.ShapeDtypeStruct((bh, t_q, d), q.dtype),
         compiler_params=_flash_params(k, v),
         interpret=interpret,
-        name="mx_flash_bwd_dq",
+        name=_kernel_name("mx_flash_bwd_dq", mask),
     )(q, k, v, do, lse, delta)
 
     def rows(width):
         # a query block the mask hides is not fetched: the index stays at
-        # the first block that is seen
+        # the first block that is seen (the block-diffusion mask: at the
+        # last, its blocks being visited in order, ``_bd_query_tile``)
+        if mask is not None:
+            n = mask.half // block_q
+            return pl.BlockSpec((1, block_q, width), lambda b, j, i: (
+                b, _bd_query_tile(j, i, n), 0))
         return pl.BlockSpec(
             (1, block_q, width), lambda b, j, i: (b, jnp.maximum(
                 i, _first_block_seen(j, block_q, block_k, causal)), 0))
@@ -391,8 +550,7 @@ def _flash_bwd_pallas(q, k, v, do, lse, delta, scale, causal, block_q,
     def cols(width):
         return pl.BlockSpec((1, block_k, width), lambda b, j, i: (b, j, 0))
     dk, dv_ = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
-                          block_k=block_k, scale=scale, causal=causal),
+        functools.partial(_flash_bwd_dkv_kernel, **static),
         grid=(bh, t_kv // block_k, t_q // block_q),
         in_specs=[rows(d), cols(d), cols(dv), rows(dv), rows(_LANES),
                   rows(_LANES)],
@@ -407,13 +565,14 @@ def _flash_bwd_pallas(q, k, v, do, lse, delta, scale, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="mx_flash_bwd_dkv",
+        name=_kernel_name("mx_flash_bwd_dkv", mask),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv_
 
 
-def _attention_ref(q, k, v, scale, causal):
-    """Plain jnp attention (fallback for non-tiling shapes)."""
+def _attention_ref(q, k, v, scale, causal, mask=None):
+    """Plain jnp attention (fallback for non-tiling shapes); ``mask`` a
+    ``BlockDiffusion``, as a dense boolean."""
     s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if causal:
@@ -421,27 +580,33 @@ def _attention_ref(q, k, v, scale, causal):
         row = jnp.arange(t_q)[:, None]
         col = jnp.arange(t_kv)[None, :]
         s = jnp.where(col <= row, s, -jnp.inf)
+    if mask is not None:
+        s = jnp.where(block_diffusion_mask(*mask), s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bqk,bkd->bqd", p, v.astype(jnp.float32)) \
         .astype(q.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_attention(q, k, v, scale, causal, block_q, block_k):
-    run = functools.partial(_flash_pallas, scale=scale, causal=causal,
-                            block_q=block_q, block_k=block_k)
+def _pallas_static(fn, scale, causal, block_q, block_k, mask):
+    run = functools.partial(fn, scale=scale, causal=causal, block_q=block_q,
+                            block_k=block_k)
+    return run if mask is None else functools.partial(run, mask=mask)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attention(q, k, v, scale, causal, block_q, block_k, mask=None):
+    run = _pallas_static(_flash_pallas, scale, causal, block_q, block_k, mask)
     out, _ = _platform_pick(run, q, k, v)
     return out
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
-    run = functools.partial(_flash_pallas, scale=scale, causal=causal,
-                            block_q=block_q, block_k=block_k)
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, mask=None):
+    run = _pallas_static(_flash_pallas, scale, causal, block_q, block_k, mask)
     out, lse = _platform_pick(run, q, k, v)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, res, g):
+def _flash_bwd(scale, causal, block_q, block_k, mask, res, g):
     q, k, v, out, lse = res
     # delta_i = sum_d dO_id * O_id  (rowwise), O(T*D) — the only
     # off-kernel piece of the two-pass flash backward.  Broadcast across
@@ -449,8 +614,8 @@ def _flash_bwd(scale, causal, block_q, block_k, res, g):
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)
     delta = jnp.broadcast_to(delta[..., None], (*delta.shape, _LANES))
-    run = functools.partial(_flash_bwd_pallas, scale=scale, causal=causal,
-                            block_q=block_q, block_k=block_k)
+    run = _pallas_static(_flash_bwd_pallas, scale, causal, block_q, block_k,
+                         mask)
     dq, dk, dv = _platform_pick(run, q, k, v, g, lse, delta)
     return dq, dk, dv
 
@@ -506,7 +671,7 @@ def _tiles(t, preferred):
 
 @register("_contrib_flash_attention", inputs=("query", "key", "value"))
 def flash_attention(query, key, value, scale=None, causal=False,
-                    block_q=None, block_k=None):
+                    block_q=None, block_k=None, mask=None):
     """Fused multi-head attention, one Pallas kernel per (batch·head).
 
     Inputs (B, H, T, D) [or (BH, T, D)]; ``value`` may have another last
@@ -527,7 +692,24 @@ def flash_attention(query, key, value, scale=None, causal=False,
     switch is measured.  Latent attention at shapes that tile does not
     come here: ``ops/mla_kernels.py`` runs the same bodies over the
     projections' own layout (PR 39).
+
+    ``mask=("block_diffusion", T, L)`` (``causal`` False) is the mask of
+    block diffusion over a sequence laid out ``[x_0 | x_t]``, ``2 T`` query
+    and key positions, blocks of ``L`` tokens: a clean position ``i < T``
+    sees the clean keys of its block and the blocks before it, a noised one
+    ``T + p`` the clean keys of the blocks strictly before its own and the
+    noised keys of its own block.  The kernels compute no tile the mask
+    leaves dead (``n (n + 2)`` of ``4 n^2`` tiles, ``n`` a half's) where
+    the tile divides ``T`` and ``L``, a power of two, divides the tile, and
+    the query and key tiles are one size; any other shape takes plain
+    attention under the dense mask.
     """
+    if mask is not None:
+        kind, half, blk = mask
+        if kind != "block_diffusion" or causal:
+            raise ValueError("flash_attention: mask %r: only "
+                             "('block_diffusion', T, L), not causal" % (mask,))
+        mask = BlockDiffusion(half, blk)
     squeeze = query.ndim == 3
     if squeeze:
         query, key, value = (x[:, None] if x.ndim == 3 else x
@@ -544,16 +726,20 @@ def flash_attention(query, key, value, scale=None, causal=False,
     # R9: a BERT-base seq-128 guard cell would).  Explicit block sizes
     # force the kernel (tests, tuning).
     if block_q is None and block_k is None and t_q < 512 and t_kv < 512:
-        return _finish(_attention_ref(q3, k3, v3, scale, causal),
+        return _finish(_attention_ref(q3, k3, v3, scale, causal, mask),
                        b, h, t_q, squeeze)
     bq = _tiles(t_q, int(block_q) if block_q else min(t_q, 512))
     bk = _tiles(t_kv, int(block_k) if block_k else min(t_kv, 512))
+    if mask is not None and (bq != bk or t_q != t_kv or t_q != 2 * mask.half
+                             or bd_tile(mask.half, mask.block, bq) is None):
+        bq = None
     if bq is None or bk is None:
         # a shape rule, on every platform: T has no block that both
         # divides it and satisfies the sublane rule
-        out3 = _attention_ref(q3, k3, v3, scale, causal)
+        out3 = _attention_ref(q3, k3, v3, scale, causal, mask)
     else:
-        static = (float(scale), bool(causal), bq, bk)
+        static = (float(scale), bool(causal), bq, bk) \
+            + (() if mask is None else (mask,))
         # a program traced under a context mesh (JitTrainStep with a
         # mesh) will be partitioned by GSPMD, and the TPU lowering
         # refuses a Mosaic kernel there ("cannot be automatically

@@ -820,6 +820,27 @@ SPECS["_contrib_moe_router_topk"] = S(
     [randn((6, 5), 150), randn((8, 5), 151), randn((8,), 152, 0.1)],
     {"k": 2, "scale": 2.5}, ref=_router_ref)
 
+def _bd_noise_check(outs, inputs):
+    """Blocks of 4: one level a block in [1e-3, 1] from the key
+    ``fold_in(PRNGKey(3), sum of the ids)``, a position masked where its
+    own uniform draw is below its block's level, and then the mask id 9."""
+    import jax
+
+    toks = inputs[0].astype(np.int32)
+    x_t, masked, level = (o.asnumpy() for o in outs)
+    k_level, k_mask = jax.random.split(jax.random.fold_in(
+        jax.random.PRNGKey(3), int(toks.sum())))
+    want = np.repeat(np.asarray(jax.random.uniform(
+        k_level, (2, 3), minval=1e-3, maxval=1.0)), 4, axis=1)
+    draw = np.asarray(jax.random.uniform(k_mask, (2, 12)))
+    return (np.allclose(level, want) and np.array_equal(masked, draw < want)
+            and np.array_equal(x_t, np.where(draw < want, 9, toks)))
+
+
+SPECS["_contrib_block_diffusion_noise"] = S(
+    [np.arange(24, dtype=np.float32).reshape(2, 12) % 9],
+    {"block": 4, "mask_id": 9, "seed": 3}, check=_bd_noise_check)
+
 _MOE_IDX = np.array([[2, 5], [3, 0], [7, 4], [2, 3], [4, 5], [1, 2]],
                     np.float32)
 

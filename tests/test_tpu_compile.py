@@ -630,3 +630,112 @@ def test_the_mamba2_scan_and_convolution_trace_as_they_did():
         == "cc2528a72423d0c9"
     assert digest(causal_conv1d, (1, 64, 256), (256, 4)) \
         == "f33e4420777b9092"
+
+
+# -- the SDAR cell: block diffusion through the flash kernels ----------------
+
+def test_flash_mla_and_the_sigmoid_router_trace_as_they_did():
+    # the block-diffusion mask is a static fact that selects code, never an
+    # operand: the six standing cells' attention (causal flash, latent
+    # attention) and their sigmoid router, free and forced, forward and
+    # backward, trace to the jaxpr text they had before the mask came
+    # (sha256 of ``jax.make_jaxpr`` at small shapes, recorded from the tree
+    # without it; nothing compiles here)
+    import hashlib
+
+    from mxnet_tpu.ops.mla_kernels import mla_flash_attention
+    from mxnet_tpu.ops.moe import router_topk
+
+    f32 = jnp.float32
+
+    def digest(fn, *shapes):
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(jnp.square(fn(*a))),
+            argnums=tuple(range(len(shapes)))))(
+            *[jnp.zeros(s, f32) for s in shapes]))
+        return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+    def router(seed):
+        return lambda x, w: router_topk(x, w, jnp.ones((8,), f32), k=2,
+                                        scale=2.5, balance_seed=seed)[1]
+    heads = [(1, 2, 256, 128)] * 3
+    assert digest(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=128, block_k=128), *heads) \
+        == "e6dd130cf9e4058c"
+    assert digest(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                  *[(1, 2, 1024, 128)] * 3) == "5953ee5bec2eeafa"
+    assert digest(lambda qn, qr, kv, kr: mla_flash_attention(
+        qn, qr, kv, kr, num_heads=2, rope_theta=10000.0),
+        (1, 512, 256), (1, 512, 128), (1, 512, 512), (1, 512, 64)) \
+        == "4b7ea19e8ec2ed71"
+    assert digest(router(None), (16, 32), (8, 32)) == "d4349856736fb737"
+    assert digest(router(3), (16, 32), (8, 32)) == "506bff14e050d278"
+
+
+@pytest.mark.parametrize("block, tile", [(4, 512), (16, 128)])
+def test_flash_under_the_block_diffusion_mask_at_8192_positions(
+        one_chip, block, tile):
+    # the SDAR cell's attention: 32 heads x 2 x 4096 positions x 128, the
+    # three kernels of the mask (no dead tile computed) forward and
+    # backward; the forward and dq kernels hold a head's K and V in VMEM
+    def loss(q, k, v, w):
+        out = flash_attention(q, k, v, block_q=tile, block_k=tile,
+                              mask=("block_diffusion", 4096, block))
+        return (out * w).astype(jnp.float32).sum()
+    c = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                 *[((1, 32, 8192, 128), jnp.bfloat16)] * 4)
+    assert _kernels(c) == ["mx_flash_bwd_dkv_bd", "mx_flash_bwd_dq_bd",
+                           "mx_flash_fwd_bd"]
+
+
+def test_the_sdar_step_fits_one_chip_at_the_cells_shapes(one_chip):
+    # the configuration as the cell runs it (4 layers, 16 held experts, an
+    # eighth of the vocabulary) under bfloat16 AMP: the model's gradient at
+    # one 4096-token sequence (8192 positions through every layer) and an
+    # Adam update, the state donated; beside it the state at 20 B a
+    # parameter (weights, m and v, and gluon's data and zero-gradient
+    # copies) fits 15.75 GB.  At depth 5 it would want 17.20 (PERF.md 4)
+    import json
+    import sys
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import amp
+
+    chip = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "chip")
+    sys.path.insert(0, chip)
+    import archs
+
+    with open(os.path.join(chip, "configs", "sdar_30b_a3b_e16.json")) as f:
+        cfg = json.load(f)
+    net = archs.of(cfg).build(cfg, mx.cpu())
+    fn, params = _traced(net)
+    vocab = cfg["vocab_size"]
+
+    def loss(weights, toks, labels):
+        logp = jax.nn.log_softmax(
+            fn(weights, toks).reshape(-1, vocab).astype(jnp.float32), -1)
+        return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], 1))
+
+    def step(ws, ms, vs, toks, labels):
+        g = jax.grad(loss)(ws, toks, labels)
+        ms = [0.9 * m + 0.1 * d for m, d in zip(ms, g)]
+        vs = [0.999 * v + 0.001 * d * d for v, d in zip(vs, g)]
+        return ([w - 1e-3 * m / (jnp.sqrt(v) + 1e-8)
+                 for w, m, v in zip(ws, ms, vs)], ms, vs)
+    amp.init("bfloat16")
+    try:
+        state = [jax.ShapeDtypeStruct(p.shape, jnp.float32,
+                                      sharding=one_chip) for p in params]
+        c = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+            state, state, state,
+            jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((4096,), jnp.int32, sharding=one_chip)) \
+            .compile()
+    finally:
+        amp.turn_off()
+    n = sum(p.size for p in state)
+    assert n == 456346624
+    assert 20 * n + c.memory_analysis().temp_size_in_bytes < 15.75e9
+    assert {"mx_flash_fwd_bd", "mx_flash_bwd_dq_bd", "mx_flash_bwd_dkv_bd",
+            "mx_gmm", "mx_rows_swiglu"} <= set(_kernels(c))
