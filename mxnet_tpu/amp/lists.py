@@ -29,6 +29,10 @@ TARGET_DTYPE_OPS = [
     # own layout (ops/mla_kernels.py): the queries' rotation is float32
     # inside, from float32 angles
     "_contrib_mla_flash_attention",
+    # the same kernels under the block-diffusion mask over the projections'
+    # own layout (ops/bd_kernels.py): the qk-norm and the rotation are
+    # float32 inside, the gains kept as they come (below)
+    "_contrib_bd_flash_attention",
     # the experts' grouped products; the routing weights stay as they
     # came (TARGET_DTYPE_KEEP below)
     "_contrib_moe_grouped_ffn",
@@ -36,9 +40,11 @@ TARGET_DTYPE_OPS = [
 
 # inputs of a target-dtype op, by position, that keep the dtype they came
 # in: ``_contrib_moe_grouped_ffn``'s ``topk_weight`` (input 2) scales whole
-# rows of the experts' result
+# rows of the experts' result; ``_contrib_bd_flash_attention``'s head norms'
+# gains (inputs 3, 4) scale the float32 norm before the operands are rounded
 TARGET_DTYPE_KEEP = {
     "_contrib_moe_grouped_ffn": (2,),
+    "_contrib_bd_flash_attention": (3, 4),
 }
 
 # numerically-sensitive ops forced to float32

@@ -21,11 +21,29 @@ attn(RMSNorm(x)); x = x + moe(RMSNorm(x))``:
 - ``BDAttention`` (scope ``bd_attention``): GQA, ``num_heads`` query heads
   and ``num_kv_heads`` key and value heads of ``head_dim``; an RMSNorm over
   each head's channels of q and of k (qk-norm), then RoPE (``rope_theta``,
-  the rotation of halves, ``llama._rope``); K and V repeated to the query
-  heads; the flash kernels under the mask at ``head_dim ** -0.5``; ``W_o``.
-  Two step statistics: ``flash_tiles/<layer>`` (the tiles of the kernels'
-  grid) and ``bd_flash_tiles/<layer>`` (those computed), exported as
-  ``mxnet_flash_tiles_total`` and ``mxnet_flash_tiles_computed_total``.
+  the rotation of halves, ``llama._rope``); the flash kernels under the
+  mask at ``head_dim ** -0.5``, query head ``h`` reading key and value head
+  ``h // (num_heads / num_kv_heads)``; ``W_o``.  Where the shapes tile
+  (``ops/bd_kernels.py:tiles``, a static test: a head whole 128-lane
+  tiles, the query heads a whole number of key heads' groups, a tile that
+  takes the mask, no mesh) ``F.contrib.bd_flash_attention`` takes the three
+  projections' results as they lie, ``(B, 2T, heads * head_dim)``: head
+  ``h`` of a query tile is lanes ``h * head_dim ..`` of its rows, and of K
+  and V the lanes of head ``h // (num_heads / num_kv_heads)``, read by the
+  kernels' index maps; the qk-norm is XLA's, float32 in that layout; the
+  key's rotation XLA's too, on its ``num_kv_heads`` heads; the queries'
+  rotation happens in VMEM as the forward kernel loads a block, and the
+  backward kernels read the turned block it writes (the dq kernel turns
+  ``dq`` back); the result is written where ``W_o`` reads it.  Every other
+  shape takes the composition: heads transposed to ``(B, H, 2T, D)``,
+  ``llama._rope``, K and V repeated to the query heads,
+  ``flash_attention(mask=...)``, the result transposed back.  Four step
+  statistics: ``flash_tiles/<layer>`` (the tiles of the kernels' grid) and
+  ``bd_flash_tiles/<layer>`` (those computed), exported as
+  ``mxnet_flash_tiles_total`` and ``mxnet_flash_tiles_computed_total``;
+  ``bd/<layer>`` (the layer) and ``bd_kernel/<layer>`` (the layer where
+  the kernels read in place), exported as ``mxnet_bd_layers_total`` and
+  ``mxnet_bd_kernel_layers_total``.
 - ``SDARMoE`` (scope ``moe``): Qwen3-MoE's routing, a float32 softmax over
   all ``n_experts`` router logits, the ``top_k`` largest, renormalised
   (``norm_topk_prob``; ``router_topk(scoring="softmax")``), no bias, no
@@ -48,6 +66,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ...ops import bd_kernels
 from ...ops.pallas_kernels import bd_tiles
 from ...telemetry import metrics
 from .. import nn
@@ -57,6 +76,8 @@ from .nemotron_h import _dense, _Mixer, chunk_counters
 
 TILES_STAT_PREFIX = "flash_tiles/"        # a layer's statistic
 COMPUTED_STAT_PREFIX = "bd_flash_tiles/"
+LAYER_STAT_PREFIX = "bd/"
+KERNEL_STAT_PREFIX = "bd_kernel/"
 
 
 class BDAttention(_Mixer):
@@ -70,6 +91,8 @@ class BDAttention(_Mixer):
                      int(block_length), eps)
         self._stat = TILES_STAT_PREFIX + str(int(layer))
         self._computed_stat = COMPUTED_STAT_PREFIX + str(int(layer))
+        self._layer_stat = LAYER_STAT_PREFIX + str(int(layer))
+        self._kernel_stat = KERNEL_STAT_PREFIX + str(int(layer))
         self._declare([
             ("q_proj", (num_heads * head_dim, units), None),
             ("k_proj", (num_kv_heads * head_dim, units), None),
@@ -79,9 +102,11 @@ class BDAttention(_Mixer):
             ("o_proj", (units, num_heads * head_dim), None)])
 
     def step_stat_specs(self):
-        """The tiles of the flash kernels' grid, and those computed."""
-        return {self._stat: ((1,), jnp.uint32),
-                self._computed_stat: ((1,), jnp.uint32)}
+        """The tiles of the flash kernels' grid, and those computed; the
+        layer, and the layer where the kernels read in place."""
+        return {name: ((1,), jnp.uint32) for name in (
+            self._stat, self._computed_stat, self._layer_stat,
+            self._kernel_stat)}
 
     def hybrid_forward(self, F, u, q_proj, k_proj, v_proj, q_norm, k_norm,
                        o_proj):
@@ -89,10 +114,21 @@ class BDAttention(_Mixer):
         b, t2, _ = u.shape
         half = t2 // 2
         grid, computed = bd_tiles(half, block)
+        in_place = bd_kernels.tiles(h, kv, d, half, block) is not None
         record_step_stat(self._stat, jnp.full((1,), b * h * grid, jnp.uint32))
         record_step_stat(self._computed_stat,
                          jnp.full((1,), b * h * computed, jnp.uint32))
+        record_step_stat(self._layer_stat, jnp.ones((1,), jnp.uint32))
+        record_step_stat(self._kernel_stat,
+                         jnp.full((1,), int(in_place), jnp.uint32))
         with jax.named_scope("bd_attention"):
+            if in_place:
+                out = F.contrib.bd_flash_attention(
+                    _dense(F, u, q_proj), _dense(F, u, k_proj),
+                    _dense(F, u, v_proj), q_norm, k_norm, num_heads=h,
+                    block_length=block, rope_theta=theta, eps=eps)
+                return _dense(F, out, o_proj)
+
             def heads(w, n, gain=None):
                 x = F.reshape(_dense(F, u, w), shape=(b, t2, n, d))
                 if gain is not None:
@@ -247,3 +283,7 @@ metrics.register_collector(chunk_counters(
     each="batch x heads x tiles, every such layer and train step",
     stat="flash_tiles", kernel_stat="bd_flash_tiles",
     kernel_family="mxnet_flash_tiles_computed_total"))
+metrics.register_collector(chunk_counters(
+    "bd", "block-diffusion attention layers",
+    "mx_flash_*_bd, their operands read where the projections wrote them",
+    unit="layers", each="every such layer and train step"))

@@ -17,6 +17,7 @@ from ..ops import ssm as _ssm  # noqa: F401
 from ..ops import kda as _kda  # noqa: F401
 from ..ops import rotary as _rotary  # noqa: F401
 from ..ops import mla_kernels as _mla  # noqa: F401
+from ..ops import bd_kernels as _bdk  # noqa: F401
 from ..ops import moe as _moe  # noqa: F401
 from ..ops import block_diffusion as _bd  # noqa: F401
 from ..ops import misc as _m  # noqa: F401

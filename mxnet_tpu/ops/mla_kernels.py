@@ -56,7 +56,8 @@ from jax import lax
 
 from .pallas_kernels import (_LANES, _first_block_seen, _flash_dkv_pair,
                              _flash_dq_head, _flash_fwd_head, _flash_lse,
-                             _lane_rows, _platform_pick, _tiles)
+                             _lane_rows, _platform_pick, _sequence_params,
+                             _tiles)
 from .registry import register
 from .rotary import rope_angles, rotate_pairs
 
@@ -240,18 +241,6 @@ def _dkv_kernel(qn_ref, qr_ref, kv_ref, kr_ref, do_ref, lse_ref, delta_ref,
             dkr_ref[0, mine, :] += dkr_acc[...]
 
 
-def _params(t, lanes, itemsize, semantics=None):
-    """A VMEM limit that holds what a step keeps: ``lanes`` lanes of whole
-    sequences of ``t`` tokens, double-buffered, and 32 MiB for the streamed
-    blocks, the accumulators and the bodies' float32 temporaries (a v5e has
-    128 MiB; the compiler's own limit is 16)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.CompilerParams(
-        dimension_semantics=semantics,
-        vmem_limit_bytes=2 * t * lanes * itemsize + (32 << 20))
-
-
 def _specs(dims, block_q, block_k, causal=None):
     """The block specs of the operands by name.  ``causal`` given: the dk/dv
     kernel's grid ``(b, h, j, i)``, whose query blocks follow ``i`` (a block
@@ -311,7 +300,7 @@ def _fwd_pallas(qn, qr, kv, kr, *angles, heads, scale, causal, block_q,
         out_specs=[s["o"], s["stats"]],
         out_shape=[jax.ShapeDtypeStruct((b, t, heads * vd), qn.dtype),
                    jax.ShapeDtypeStruct((b, heads, t, _LANES), jnp.float32)],
-        compiler_params=_params(t, kv.shape[2] // heads * group
+        compiler_params=_sequence_params(t, kv.shape[2] // heads * group
                                 + group * rope, kv.dtype.itemsize),
         interpret=interpret,
         name="mx_flash_fwd_mla",
@@ -344,7 +333,7 @@ def _bwd_pallas(qn, qr, kv, kr, out, do, lse, *angles, heads, scale,
         out_shape=[jax.ShapeDtypeStruct(qn.shape, qn.dtype),
                    jax.ShapeDtypeStruct(qr.shape, qr.dtype),
                    jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
-        compiler_params=_params(t, kept, kv.dtype.itemsize),
+        compiler_params=_sequence_params(t, kept, kv.dtype.itemsize),
         interpret=interpret,
         name="mx_flash_bwd_dq_mla",
     )(qn, qr, kv, kr, out, do, lse, *angles)
@@ -364,7 +353,7 @@ def _bwd_pallas(qn, qr, kv, kr, out, do, lse, *angles, heads, scale,
             pltpu.VMEM((block_k, group * (nope + vd)), jnp.float32),
             pltpu.VMEM((block_k, group * rope), jnp.float32)],
         # the head axis is sequential too: dk_rope sums over it
-        compiler_params=_params(
+        compiler_params=_sequence_params(
             t, group * rope, 4,
             ("parallel", "arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,

@@ -301,6 +301,19 @@ def _flash_params(k, v):
     return pltpu.CompilerParams(vmem_limit_bytes=held + (16 << 20))
 
 
+def _sequence_params(t, lanes, itemsize, semantics=None):
+    """A VMEM limit that holds what a step keeps: ``lanes`` lanes of whole
+    sequences of ``t`` tokens, double-buffered, and 32 MiB for the streamed
+    blocks, the accumulators and the bodies' float32 temporaries (a v5e has
+    128 MiB; the compiler's own limit is 16): the kernels of
+    ``mla_kernels`` and ``bd_kernels``, which hold a head's whole keys."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics,
+        vmem_limit_bytes=2 * t * lanes * itemsize + (32 << 20))
+
+
 def _flash_pallas(q, k, v, scale, causal, block_q, block_k,
                   interpret=False, mask=None):
     from jax.experimental import pallas as pl

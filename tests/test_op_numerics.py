@@ -809,6 +809,44 @@ SPECS["_contrib_mla_flash_attention"] = S(
     ref=_mla_flash_ref, rtol=1e-3, atol=1e-4)
 
 
+def _bd_flash_ref(q, k, v, q_norm, k_norm):
+    """Two query heads over one key head of 128 channels, ``[x_0 | x_t]`` of
+    512 positions each, blocks of 4: every head of q and k normed (eps
+    1e-6) and turned by the rotation of halves at positions ``0 .. 511`` of
+    each half, softmax attention under the block-diffusion mask."""
+    t2, h, d = q.shape[1], 2, 128
+    half = t2 // 2
+
+    def normed_turned(x, gain):
+        x = x.astype(np.float64).reshape(t2, -1, d)
+        x = x / np.sqrt((x * x).mean(-1, keepdims=True) + 1e-6) * gain
+        phi = (np.arange(t2) % half)[:, None, None] \
+            * 100.0 ** (-2.0 * np.arange(d // 2) / d)
+        a, b = x[..., :d // 2], x[..., d // 2:]
+        return np.concatenate([a * np.cos(phi) - b * np.sin(phi),
+                               b * np.cos(phi) + a * np.sin(phi)], -1)
+    qs, ks = normed_turned(q[0], q_norm), normed_turned(k[0], k_norm)[:, 0]
+    i = np.arange(t2)
+    noised, blk = i >= half, (i % half) // 4
+    seen = np.where(noised[None, :], noised[:, None]
+                    & (blk[None, :] == blk[:, None]),
+                    np.where(noised[:, None], blk[None, :] < blk[:, None],
+                             blk[None, :] <= blk[:, None]))
+    out = np.empty((t2, h, d))
+    for j in range(h):
+        s = qs[:, j] @ ks.T * d ** -0.5
+        out[:, j] = _softmax_ref(np.where(seen, s, -np.inf)) \
+            @ v[0].astype(np.float64)
+    return out.reshape(1, t2, h * d)
+
+
+SPECS["_contrib_bd_flash_attention"] = S(
+    [randn((1, 1024, 256), 182), randn((1, 1024, 128), 183),
+     randn((1, 1024, 128), 184), pos((128,), 185), pos((128,), 186)],
+    {"num_heads": 2, "block_length": 4, "rope_theta": 100.0},
+    ref=_bd_flash_ref, rtol=1e-3, atol=1e-4)
+
+
 def _router_ref(x, w, b):
     s = 1.0 / (1.0 + np.exp(-(x.astype(np.float64) @ w.T)))
     idx = np.argsort(-(s + b), axis=1, kind="stable")[:, :2]

@@ -640,7 +640,9 @@ def test_flash_mla_and_the_sigmoid_router_trace_as_they_did():
     # attention) and their sigmoid router, free and forced, forward and
     # backward, trace to the jaxpr text they had before the mask came
     # (sha256 of ``jax.make_jaxpr`` at small shapes, recorded from the tree
-    # without it; nothing compiles here)
+    # without it; nothing compiles here).  The mask's own composition,
+    # which every shape that ``ops/bd_kernels.py`` does not take keeps,
+    # traces as it did before those kernels came
     import hashlib
 
     from mxnet_tpu.ops.mla_kernels import mla_flash_attention
@@ -668,6 +670,9 @@ def test_flash_mla_and_the_sigmoid_router_trace_as_they_did():
         qn, qr, kv, kr, num_heads=2, rope_theta=10000.0),
         (1, 512, 256), (1, 512, 128), (1, 512, 512), (1, 512, 64)) \
         == "4b7ea19e8ec2ed71"
+    assert digest(lambda q, k, v: flash_attention(
+        q, k, v, block_q=128, block_k=128, mask=("block_diffusion", 256, 4)),
+        *[(1, 2, 512, 128)] * 3) == "a94c6797721db69f"
     assert digest(router(None), (16, 32), (8, 32)) == "d4349856736fb737"
     assert digest(router(3), (16, 32), (8, 32)) == "506bff14e050d278"
 
@@ -686,6 +691,45 @@ def test_flash_under_the_block_diffusion_mask_at_8192_positions(
                  *[((1, 32, 8192, 128), jnp.bfloat16)] * 4)
     assert _kernels(c) == ["mx_flash_bwd_dkv_bd", "mx_flash_bwd_dq_bd",
                            "mx_flash_fwd_bd"]
+
+
+def test_the_bd_attention_layer_rewrites_no_array_over_the_heads(one_chip):
+    # the SDAR cell's attention layer (32 / 4 heads of 128, 2 x 4096
+    # positions, width 2048) under bfloat16 AMP, forward and backward: the
+    # kernels read the projections' results where the products wrote them,
+    # so no array over (heads, positions) exists but the kernels' float32
+    # logsumexp and delta rows: no transpose, copy or broadcast of q, of
+    # the result or of their cotangents, and no K or V at 32 heads; K and V
+    # and their cotangents are (1, 8192, 4 x 128)
+    import mxnet_tpu as mx
+    from mxnet_tpu import amp
+    from mxnet_tpu.gluon.model_zoo import sdar_moe
+
+    attn = sdar_moe.BDAttention(2048, 32, 4, 128, 1e6, 4)
+    attn.initialize(mx.init.Zero())
+    fn, params = _traced(attn)
+
+    def loss(weights, u, w):
+        return jnp.sum(fn(weights, u).astype(jnp.float32) * w)
+    amp.init("bfloat16")
+    try:
+        c = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            [jax.ShapeDtypeStruct(p.shape, jnp.float32, sharding=one_chip)
+             for p in params],
+            *[jax.ShapeDtypeStruct((1, 8192, 2048), jnp.float32,
+                                   sharding=one_chip)] * 2).compile()
+    finally:
+        amp.turn_off()
+    text = c.as_text()
+    assert _kernels(c) == ["mx_flash_bwd_dkv_bd", "mx_flash_bwd_dq_bd",
+                           "mx_flash_fwd_bd"]
+    assert not re.search(r"bf16\[(?:1,)?(?:32,8192|8192,32),128\]", text)
+    assert not re.search(r"f32\[(?:(?:1,)?8192,32,128|32,8192,128)\]", text)
+    dkv = [line for line in text.splitlines()
+           if re.search(r"%mx_flash_bwd_dkv_bd\S* = ", line)]
+    assert len(dkv) == 1 and re.search(
+        r"= \(bf16\[1,8192,512\]\S*, bf16\[1,8192,512\]", dkv[0])
+    assert c.memory_analysis().temp_size_in_bytes < 640 << 20
 
 
 def test_the_sdar_step_fits_one_chip_at_the_cells_shapes(one_chip):
