@@ -101,13 +101,18 @@ def chunk_counters(stem, scans, kernels, unit="chunks",
     where that is given), which it accumulates on the device;
     a snapshot fetches them (once, both kinds) and adds what is new (modulo
     the accumulators' 32 bits) to ``mxnet_<stem>_<unit>_total`` and
-    ``mxnet_<stem>_<kernel>_<unit>_total`` (or ``kernel_family``)."""
+    ``mxnet_<stem>_<kernel>_<unit>_total`` (or ``kernel_family``).  With
+    ``kernel`` None there is the first statistic alone, counted in the
+    Pallas kernels ``kernels``."""
     stat = stat or stem
-    second = (kernel_stat or "%s_%s" % (stat, kernel)) + "/"
-    families = {stat + "/": ("mxnet_%s_%s_total" % (stem, unit), "ran"),
-                second: (kernel_family
-                         or "mxnet_%s_%s_%s_total" % (stem, kernel, unit),
-                         "ran in the Pallas kernels " + kernels)}
+    in_kernels = "ran in the Pallas kernels " + kernels
+    families = {stat + "/": ("mxnet_%s_%s_total" % (stem, unit),
+                             in_kernels if kernel is None else "ran")}
+    if kernel is not None:
+        second = (kernel_stat or "%s_%s" % (stat, kernel)) + "/"
+        families[second] = (kernel_family
+                            or "mxnet_%s_%s_%s_total" % (stem, kernel, unit),
+                            in_kernels)
     read = os.path.commonprefix(list(families))
     seen = {}               # (step, statistic) -> the count last read
 

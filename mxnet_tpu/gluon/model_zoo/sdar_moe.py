@@ -37,13 +37,16 @@ attn(RMSNorm(x)); x = x + moe(RMSNorm(x))``:
   ``dq`` back); the result is written where ``W_o`` reads it.  Every other
   shape takes the composition: heads transposed to ``(B, H, 2T, D)``,
   ``llama._rope``, K and V repeated to the query heads,
-  ``flash_attention(mask=...)``, the result transposed back.  Four step
+  ``flash_attention(mask=...)``, the result transposed back.  Five step
   statistics: ``flash_tiles/<layer>`` (the tiles of the kernels' grid) and
   ``bd_flash_tiles/<layer>`` (those computed), exported as
   ``mxnet_flash_tiles_total`` and ``mxnet_flash_tiles_computed_total``;
   ``bd/<layer>`` (the layer) and ``bd_kernel/<layer>`` (the layer where
   the kernels read in place), exported as ``mxnet_bd_layers_total`` and
-  ``mxnet_bd_kernel_layers_total``.
+  ``mxnet_bd_kernel_layers_total``; ``bd_dkv_steps/<layer>`` (the dk/dv
+  kernel's grid steps: its live visits in place, ``bd_kernels.dkv_steps``,
+  every tile of the grid in the composition), exported as
+  ``mxnet_flash_dkv_steps_total``.
 - ``SDARMoE`` (scope ``moe``): Qwen3-MoE's routing, a float32 softmax over
   all ``n_experts`` router logits, the ``top_k`` largest, renormalised
   (``norm_topk_prob``; ``router_topk(scoring="softmax")``), no bias, no
@@ -78,6 +81,7 @@ TILES_STAT_PREFIX = "flash_tiles/"        # a layer's statistic
 COMPUTED_STAT_PREFIX = "bd_flash_tiles/"
 LAYER_STAT_PREFIX = "bd/"
 KERNEL_STAT_PREFIX = "bd_kernel/"
+DKV_STAT_PREFIX = "bd_dkv_steps/"
 
 
 class BDAttention(_Mixer):
@@ -93,6 +97,7 @@ class BDAttention(_Mixer):
         self._computed_stat = COMPUTED_STAT_PREFIX + str(int(layer))
         self._layer_stat = LAYER_STAT_PREFIX + str(int(layer))
         self._kernel_stat = KERNEL_STAT_PREFIX + str(int(layer))
+        self._dkv_stat = DKV_STAT_PREFIX + str(int(layer))
         self._declare([
             ("q_proj", (num_heads * head_dim, units), None),
             ("k_proj", (num_kv_heads * head_dim, units), None),
@@ -103,10 +108,11 @@ class BDAttention(_Mixer):
 
     def step_stat_specs(self):
         """The tiles of the flash kernels' grid, and those computed; the
-        layer, and the layer where the kernels read in place."""
+        layer, and the layer where the kernels read in place; the dk/dv
+        kernel's grid steps."""
         return {name: ((1,), jnp.uint32) for name in (
             self._stat, self._computed_stat, self._layer_stat,
-            self._kernel_stat)}
+            self._kernel_stat, self._dkv_stat)}
 
     def hybrid_forward(self, F, u, q_proj, k_proj, v_proj, q_norm, k_norm,
                        o_proj):
@@ -114,13 +120,18 @@ class BDAttention(_Mixer):
         b, t2, _ = u.shape
         half = t2 // 2
         grid, computed = bd_tiles(half, block)
-        in_place = bd_kernels.tiles(h, kv, d, half, block) is not None
+        tile = bd_kernels.tiles(h, kv, d, half, block)
+        in_place = tile is not None
+        dkv_steps = bd_kernels.dkv_steps(h, kv, half, tile) if in_place \
+            else h * grid
         record_step_stat(self._stat, jnp.full((1,), b * h * grid, jnp.uint32))
         record_step_stat(self._computed_stat,
                          jnp.full((1,), b * h * computed, jnp.uint32))
         record_step_stat(self._layer_stat, jnp.ones((1,), jnp.uint32))
         record_step_stat(self._kernel_stat,
                          jnp.full((1,), int(in_place), jnp.uint32))
+        record_step_stat(self._dkv_stat,
+                         jnp.full((1,), b * dkv_steps, jnp.uint32))
         with jax.named_scope("bd_attention"):
             if in_place:
                 out = F.contrib.bd_flash_attention(
@@ -283,6 +294,12 @@ metrics.register_collector(chunk_counters(
     each="batch x heads x tiles, every such layer and train step",
     stat="flash_tiles", kernel_stat="bd_flash_tiles",
     kernel_family="mxnet_flash_tiles_computed_total"))
+metrics.register_collector(chunk_counters(
+    "flash_dkv", "block-diffusion attention layers' dk/dv grids",
+    "mx_flash_bwd_dkv_bd", unit="steps",
+    each="batch x key heads x live visits in place, batch x heads x tiles "
+         "in the composition, every such layer and train step",
+    stat="bd_dkv_steps", kernel=None))
 metrics.register_collector(chunk_counters(
     "bd", "block-diffusion attention layers",
     "mx_flash_*_bd, their operands read where the projections wrote them",

@@ -27,16 +27,17 @@ array exists:
   bytes at 32 / 4 heads) and rounded once to the queries' dtype.  Query
   head ``h`` reads key head ``h // (H / KV)`` by the index map: the forward
   and dq kernels hold it in VMEM across the group's heads and query tiles,
-  fetched once.  The dk/dv kernel's grid walks the group's query heads on its
-  sequential axis and sums ``dk`` and ``dv`` in float32 scratch, written
-  once into ``(B, 2T, KV * D)``.
+  fetched once.  The dk/dv kernel's grid walks a list of the mask's live
+  (key tile, query head of the group, query tile) visits, prefetched as
+  scalars (``_dkv_visits``), and sums ``dk`` and ``dv`` over a key tile's
+  visits in float32 scratch, written once into ``(B, 2T, KV * D)``.
 - the result is written at ``(b, i, h)`` of ``(B, 2T, H * D)``, where the
   output projection reads it; ``dO`` is read the same way, and ``delta =
   rowsum(dO * O)`` is made in the dq kernel, which holds both blocks.
 
 The kernels run the SAME bodies as ``flash_attention``'s under the mask
 (``_flash_fwd_head``, ``_flash_dq_head``, ``_flash_dkv_pair`` over the live
-tiles that ``_bd_visits`` and ``_bd_query_tile`` walk), one head a grid step,
+tiles that ``_bd_visits`` and ``_dkv_visits`` walk), one head a grid step,
 under the names ``mx_flash_fwd_bd``, ``mx_flash_bwd_dq_bd`` and
 ``mx_flash_bwd_dkv_bd``.  Off a TPU they run in the Pallas interpreter
 (tests).
@@ -48,14 +49,19 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
-from .pallas_kernels import (_LANES, BlockDiffusion, _bd_dkv_visit,
-                             _bd_query_tile, _flash_dkv_pair, _flash_dq_head,
+from .pallas_kernels import (_LANES, BlockDiffusion, _bd_visible,
+                             _flash_dkv_pair, _flash_dq_head,
                              _flash_fwd_head, _flash_lse, _lane_rows,
                              _platform_pick, _sequence_params, _tiles,
                              bd_tile)
 from .registry import register
+
+# a dk/dv visit's flags: the first and the last of its key tile's visits,
+# and a query tile that sees the key tile in part
+_FIRST, _LAST, _PARTIAL = 1, 2, 4
 
 
 def tiles(heads, kv_heads, head_dim, half, block):
@@ -228,33 +234,87 @@ def _dq_kernel(qt_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, cos_ref,
         dq_ref.dtype)
 
 
-def _dkv_kernel(qt_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                dv_ref, dk_acc, dv_acc, *, tile, mask, n_q):
-    """One (key tile, query head and tile) pair a grid step: the last axis
-    walks the group's query heads, each over its query tiles
-    (``_bd_dkv_visit``), and ``dk``, ``dv`` sum in float32 scratch across
-    it."""
+def _visit_bits(n, group):
+    """The bits of a packed visit's query or key tile (``2 n`` of them) and
+    of its query head (``group``)."""
+    return (2 * n - 1).bit_length(), (group - 1).bit_length()
+
+
+def _dkv_visits(n, group):
+    """The dk/dv kernel's visits at ``n`` tiles a half and ``group`` query
+    heads a key head, in the order the grid walks them: each key tile, each
+    of the group's query heads, the query tiles that see the key tile (a
+    clean key tile the clean query tiles from its own on and then the noised
+    ones from its position on, a noised one its own diagonal tile alone).
+    ``group n (n + 2)`` entries, int32, each ``((key tile, query tile),
+    head, flags)`` packed (``_visit`` unpacks it); the flags ``_FIRST`` and
+    ``_LAST`` fall on a key tile's first and last visits, ``_PARTIAL``
+    where the query tile is the key tile's own in either half, the only
+    pairs the mask leaves partly live."""
+    tile_bits, head_bits = _visit_bits(n, group)
+    if 3 + head_bits + 2 * tile_bits > 31:
+        raise ValueError("%d tiles a half and %d heads a key head do not "
+                         "pack in 31 bits" % (n, group))
+    visits = []
+    for ki in range(2 * n):
+        seen = [ki] if ki >= n else [*range(ki, n), *range(n + ki, 2 * n)]
+        for h in range(group):
+            for i, qi in enumerate(seen):
+                flags = (_FIRST * (h == i == 0)
+                         | _LAST * (h == group - 1 and i == len(seen) - 1)
+                         | _PARTIAL * (qi in (ki, ki + n)))
+                visits.append(
+                    ((((ki << tile_bits) | qi) << head_bits | h) << 3)
+                    | flags)
+    return np.asarray(visits, np.int32)
+
+
+def _visit(entry, n, group):
+    """``(key tile, query head of the group, query tile, flags)`` of a
+    packed visit of ``_dkv_visits``."""
+    tile_bits, head_bits = _visit_bits(n, group)
+    rest = entry >> 3
+    return (rest >> (head_bits + tile_bits),
+            rest & ((1 << head_bits) - 1),
+            (rest >> head_bits) & ((1 << tile_bits) - 1), entry & 7)
+
+
+def dkv_steps(heads, kv_heads, half, tile):
+    """The dk/dv kernel's grid steps a sequence: a visit of the mask's live
+    pairs for each key head."""
+    return kv_heads * len(_dkv_visits(half // tile, heads // kv_heads))
+
+
+def _dkv_kernel(visits, qt_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dk_ref, dv_ref, dk_acc, dv_acc, *, tile, mask, group):
+    """One visit of ``_dkv_visits`` a grid step, a live (key tile, query
+    head and tile) pair: ``dk``, ``dv`` sum in float32 scratch over a key
+    tile's visits, zeroed at the first and written at the last."""
     from jax.experimental import pallas as pl
 
-    ki, j = pl.program_id(2), pl.program_id(3)
+    ki, _, qi, flags = _visit(visits[pl.program_id(2)], mask.half // tile,
+                              group)
 
-    @pl.when(j == 0)
+    @pl.when((flags & _FIRST) != 0)
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def pair(visible=None):
+    def add(visible=None):
         (dk,), dv = _flash_dkv_pair(
             (qt_ref[0],), (k_ref[0].astype(jnp.float32),),
             v_ref[0].astype(jnp.float32), do_ref[0].astype(jnp.float32),
-            lse_ref[0, 0][:, :1], delta_ref[0, 0][:, :1], ki, j % n_q,
+            lse_ref[0, 0][:, :1], delta_ref[0, 0][:, :1], ki, qi,
             block_q=tile, block_k=tile, scale=qt_ref.shape[2] ** -0.5,
             causal=False, visible=visible)
-        return dk, dv
+        dk_acc[...] += dk
+        dv_acc[...] += dv
 
-    _bd_dkv_visit(pair, dk_acc, dv_acc, ki, j % n_q, mask, tile)
+    partial_ = (flags & _PARTIAL) != 0
+    pl.when(partial_)(lambda: add(_bd_visible(qi, ki, mask, tile)))
+    pl.when(jnp.logical_not(partial_))(add)
 
-    @pl.when(j == pl.num_programs(3) - 1)
+    @pl.when((flags & _LAST) != 0)
     def _():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -262,18 +322,19 @@ def _dkv_kernel(qt_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
 
 def _specs(d, group, tile, t2, mask, dkv=False):
     """The block specs of the operands by name.  ``dkv``: the dk/dv kernel's
-    grid ``(b, key head, key tile, j)``, ``j`` the group's query head ``j //
-    n`` and its ``j mod n``-th live query tile (past the live ones the index
-    stays at the last, so nothing is fetched); else ``(b, head, query
-    tile)`` with a key head's whole keys and values as one block."""
+    grid ``(b, key head, visit)`` over the prefetched ``_dkv_visits``, which
+    give the key tile, the group's query head and the query tile; else
+    ``(b, head, query tile)`` with a key head's whole keys and values as one
+    block."""
     from jax.experimental import pallas as pl
 
-    n_q, n = t2 // tile, mask.half // tile
+    n = mask.half // tile
     if dkv:
         key_rows = tile
 
-        def where(b, c, ki, j):
-            return b, c * group + j // n_q, _bd_query_tile(ki, j % n_q, n), ki
+        def where(b, c, v, visits):
+            ki, h, qi, _ = _visit(visits[v], n, group)
+            return b, c * group + h, qi, ki
     else:
         key_rows = t2
 
@@ -340,22 +401,23 @@ def _bwd_pallas(qt, k, v, out, do, lse, cos, sin, *, heads, mask, tile,
     )(qt, k, v, out, do, lse, cos, sin)
 
     s = _specs(d, group, tile, t2, mask, dkv=True)
-    n_q = t2 // tile
+    visits = _dkv_visits(mask.half // tile, group)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, tile=tile, mask=mask, n_q=n_q),
-        grid=(b, kv, n_q, group * n_q),
-        in_specs=[s["rows"], s["keys"], s["keys"], s["rows"], s["stats"],
-                  s["stats"]],
-        out_specs=[s["keys"], s["keys"]],
+        functools.partial(_dkv_kernel, tile=tile, mask=mask, group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, kv, len(visits)),
+            in_specs=[s["rows"], s["keys"], s["keys"], s["rows"],
+                      s["stats"], s["stats"]],
+            out_specs=[s["keys"], s["keys"]],
+            scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)] * 2),
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)] * 2,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="mx_flash_bwd_dkv_bd",
-    )(qt, k, v, do, lse, delta)
+    )(jnp.asarray(visits), qt, k, v, do, lse, delta)
     return dq, dk, dv
 
 

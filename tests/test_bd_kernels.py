@@ -27,7 +27,9 @@ import mxnet_tpu as mx
 from mxnet_tpu import amp, gluon, parallel
 from mxnet_tpu.gluon.model_zoo import sdar_moe
 from mxnet_tpu.ops import bd_kernels
-from mxnet_tpu.ops.pallas_kernels import BlockDiffusion, _attention_ref
+from mxnet_tpu.ops.pallas_kernels import (BlockDiffusion, _attention_ref,
+                                          _bd_query_tile, _bd_visible,
+                                          block_diffusion_mask)
 from mxnet_tpu.telemetry import metrics
 
 D, HALF, BLOCK, THETA, EPS = 128, 256, 4, 1e6, 1e-6
@@ -151,6 +153,82 @@ def test_dead_tiles_are_never_read():
     np.testing.assert_array_equal(dk_bad[:, HALF:], dk[:, HALF:])
     np.testing.assert_array_equal(dv_bad[:, HALF:], dv[:, HALF:])
     assert np.isnan(np.asarray(dk_bad[:, :HALF])).all()
+
+
+@pytest.mark.parametrize("n", [2, 4])
+@pytest.mark.parametrize("group", [1, 2, 4])
+def test_the_dkv_grid_visits_each_live_pair_once_in_the_masks_order(n, group):
+    # the dk/dv kernel's grid is the list of the mask's live (key tile, query
+    # head, query tile) visits: each once, key tile after key tile, a key
+    # tile's heads in turn and each head's query tiles in the order that
+    # ``_bd_query_tile`` gives (the sums of dk and dv are the composition's,
+    # term for term), zeroed at a key tile's first visit and written at its
+    # last, masked exactly where the pair is partly live, by ``_bd_visible``
+    tile, block = 16, 4
+    mask = BlockDiffusion(n * tile, block)
+    visits = bd_kernels._dkv_visits(n, group)
+    assert visits.dtype == np.int32 and visits.shape == (group * n * (n + 2),)
+    ki, head, qi, flags = (np.asarray(x) for x in bd_kernels._visit(
+        visits, n, group))
+    dense = np.asarray(block_diffusion_mask(*mask))
+    seen = {(q, k): dense[q * tile:(q + 1) * tile, k * tile:(k + 1) * tile]
+            for q in range(2 * n) for k in range(2 * n)}
+    live = sorted((k, h, q) for (q, k), vis in seen.items() if vis.any()
+                  for h in range(group))
+    assert sorted(zip(ki.tolist(), head.tolist(), qi.tolist())) == live
+    assert (np.diff(ki) >= 0).all()
+    for k in range(2 * n):
+        mine = np.flatnonzero(ki == k)
+        count = 1 if k >= n else 2 * (n - k)
+        assert head[mine].tolist() == np.repeat(np.arange(group),
+                                                count).tolist()
+        assert qi[mine].tolist() == [int(_bd_query_tile(k, i, n))
+                                     for i in range(count)] * group
+        first = (flags[mine] & bd_kernels._FIRST) != 0
+        last = (flags[mine] & bd_kernels._LAST) != 0
+        assert first.tolist() == [True] + [False] * (len(mine) - 1)
+        assert last.tolist() == [False] * (len(mine) - 1) + [True]
+    partial_ = (flags & bd_kernels._PARTIAL) != 0
+    assert partial_.tolist() == [not seen[int(q), int(k)].all()
+                                 for q, k in zip(qi, ki)]
+    for q, k in zip(qi[partial_].tolist(), ki[partial_].tolist()):
+        np.testing.assert_array_equal(_bd_visible(q, k, mask, tile),
+                                      seen[q, k])
+    assert bd_kernels.dkv_steps(4 * group, 4, n * 512, 512) \
+        == 4 * len(visits)
+
+
+# sha256 of the bytes of dq, dk and dv from ``bd_flash_attention``'s
+# gradient in the interpreter, recorded from the tree whose dk/dv kernel
+# walked every (key tile, query head, query tile) of the rectangle and
+# skipped the dead ones under ``pl.when``
+_GRAD_DIGESTS = {
+    "float32": ("de0942efe9af2c44", "2b843e605d2641a3", "a1b0f066c2a077bd"),
+    "bfloat16": ("728ac1786430b08f", "75cfb29c2054f0b4", "97a51f6d4b4de07d")}
+
+
+@pytest.mark.parametrize("h, half, dtype", [
+    (4, 512, "float32"), (8, 256, "bfloat16")])
+def test_the_gradient_is_the_rectangle_walks_bit_for_bit(h, half, dtype):
+    # two key heads, four or eight query heads, 128-token tiles: the live
+    # visits add the same terms in the same order as the full grid did
+    import hashlib
+
+    kv = 2
+    rng = np.random.default_rng(44)
+    q, k, v, w = (jnp.asarray(rng.normal(size=(1, 2 * half, n * D)),
+                              jnp.float32).astype(dtype)
+                  for n in (h, kv, kv, h))
+    gq, gk = (jnp.asarray(rng.uniform(0.5, 1.5, D), jnp.float32)
+              for _ in range(2))
+    attend = functools.partial(bd_kernels._attend, q_norm=gq, k_norm=gk,
+                               heads=h, block=BLOCK, theta=THETA, eps=EPS,
+                               tile=128)
+    grads = jax.jit(jax.grad(
+        lambda q, k, v: (attend(q, k, v) * w).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))(q, k, v)
+    assert tuple(hashlib.sha256(np.asarray(g).tobytes()).hexdigest()[:16]
+                 for g in grads) == _GRAD_DIGESTS[dtype]
 
 
 def test_the_path_is_a_static_test_of_the_shapes():
@@ -277,7 +355,11 @@ def test_the_counter_counts_the_layers_read_in_place(d, kernel):
     """A train step over one block-diffusion attention layer:
     ``mxnet_bd_layers_total`` counts the layer a step,
     ``mxnet_bd_kernel_layers_total`` the same where the shapes tile and
-    nothing where the layer took the composition."""
+    nothing where the layer took the composition;
+    ``mxnet_flash_dkv_steps_total`` the dk/dv kernel's grid steps, batch x
+    key heads x the live visits in place (2 x 512 positions in 512-token
+    tiles: ``n`` 1, a group of 2, 2 x 1 x 3 visits) and batch x heads x
+    ``(2 n)^2`` tiles in the composition."""
     class Net(gluon.HybridBlock):
         def __init__(self):
             super().__init__()
@@ -288,7 +370,8 @@ def test_the_counter_counts_the_layers_read_in_place(d, kernel):
             return self.attn(x)
     metrics.snapshot()      # what earlier steps counted is not this test's
     before = [_counted("mxnet_bd_layers_total"),
-              _counted("mxnet_bd_kernel_layers_total")]
+              _counted("mxnet_bd_kernel_layers_total"),
+              _counted("mxnet_flash_dkv_steps_total")]
     net = Net()
     net.initialize()
     net.hybridize()
@@ -305,3 +388,6 @@ def test_the_counter_counts_the_layers_read_in_place(d, kernel):
     assert int(stats["bd_kernel/3"][0]) == 2 * kernel
     assert _counted("mxnet_bd_layers_total") - before[0] == 2
     assert _counted("mxnet_bd_kernel_layers_total") - before[1] == 2 * kernel
+    steps = 1 * 1 * 6 if kernel else 1 * 2 * 2 ** 2
+    assert int(stats["bd_dkv_steps/3"][0]) == 2 * steps
+    assert _counted("mxnet_flash_dkv_steps_total") - before[2] == 2 * steps

@@ -732,6 +732,46 @@ def test_the_bd_attention_layer_rewrites_no_array_over_the_heads(one_chip):
     assert c.memory_analysis().temp_size_in_bytes < 640 << 20
 
 
+def test_the_bd_dkv_kernel_walks_only_the_live_visits(one_chip):
+    # the SDAR cell's attention (32 / 4 heads of 128, 2 x 4096 positions,
+    # 512-token tiles, blocks of 4): the dk/dv kernel's grid is (batch, key
+    # head, visit), 8 query heads x 80 live tiles a key head, and not the
+    # rectangle's 8 x 16 x 16; lowered for the described chip, the visits
+    # prefetched as scalars
+    from jax._src import core
+
+    from mxnet_tpu.ops import bd_kernels
+
+    def loss(q, k, v, w):
+        ones = jnp.ones((128,), jnp.float32)
+        out = bd_kernels._attend(q, k, v, ones, ones, 32, 4, 1e6, 1e-6, 512)
+        return (out * w).astype(jnp.float32).sum()
+    shapes = [((1, 8192, 32 * 128), jnp.bfloat16),
+              ((1, 8192, 4 * 128), jnp.bfloat16),
+              ((1, 8192, 4 * 128), jnp.bfloat16),
+              ((1, 8192, 32 * 128), jnp.bfloat16)]
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    jaxpr = jax.make_jaxpr(grad)(*[jax.ShapeDtypeStruct(s, d)
+                                   for s, d in shapes]).jaxpr
+
+    def grids(j):
+        for e in j.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e.params["name"], e.params["grid_mapping"].grid
+            for p in e.params.values():
+                for x in p if isinstance(p, (tuple, list)) else [p]:
+                    if isinstance(x, (core.Jaxpr, core.ClosedJaxpr)):
+                        yield from grids(getattr(x, "jaxpr", x))
+    # each kernel twice: the TPU branch and the interpreter's
+    assert sorted(set(grids(jaxpr))) == [
+        ("mx_flash_bwd_dkv_bd", (1, 4, 640)),
+        ("mx_flash_bwd_dq_bd", (1, 32, 16)),
+        ("mx_flash_fwd_bd", (1, 32, 16))]
+    assert "mx_flash_bwd_dkv_bd" in jax.jit(grad).lower(
+        *[jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+          for s, d in shapes]).as_text()
+
+
 def test_the_sdar_step_fits_one_chip_at_the_cells_shapes(one_chip):
     # the configuration as the cell runs it (4 layers, 16 held experts, an
     # eighth of the vocabulary) under bfloat16 AMP: the model's gradient at
