@@ -33,6 +33,9 @@ TARGET_DTYPE_OPS = [
     # own layout (ops/bd_kernels.py): the qk-norm and the rotation are
     # float32 inside, the gains kept as they come (below)
     "_contrib_bd_flash_attention",
+    # plain attention under the dense block-diffusion mask, for the shapes
+    # those kernels do not take: scores and softmax in float32 inside
+    "_contrib_bd_attention",
     # the experts' grouped products; the routing weights stay as they
     # came (TARGET_DTYPE_KEEP below)
     "_contrib_moe_grouped_ffn",

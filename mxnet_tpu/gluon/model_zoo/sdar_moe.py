@@ -10,10 +10,9 @@ each token of block ``j`` is replaced by the mask id with probability
 batch, scope ``bd_noise``).  The model reads the clean and the noised copy
 side by side, ``[x_0 | x_t]``, ``2T`` positions whose RoPE positions are
 ``0 .. T-1`` in each half, under the block-diffusion mask
-(``F.contrib.flash_attention(mask=("block_diffusion", T, block_length))``:
-a noised block sees itself and the clean blocks strictly before it, a clean
-block the clean blocks up to itself), and returns the head over the noised
-half, ``(B, T, V)``.
+(``ops/bd_kernels.py:block_diffusion_mask``: a noised block sees itself and
+the clean blocks strictly before it, a clean block the clean blocks up to
+itself), and returns the head over the noised half, ``(B, T, V)``.
 
 Every layer is two sub-blocks behind pre-norm residuals, ``x = x +
 attn(RMSNorm(x)); x = x + moe(RMSNorm(x))``:
@@ -21,12 +20,13 @@ attn(RMSNorm(x)); x = x + moe(RMSNorm(x))``:
 - ``BDAttention`` (scope ``bd_attention``): GQA, ``num_heads`` query heads
   and ``num_kv_heads`` key and value heads of ``head_dim``; an RMSNorm over
   each head's channels of q and of k (qk-norm), then RoPE (``rope_theta``,
-  the rotation of halves, ``llama._rope``); the flash kernels under the
-  mask at ``head_dim ** -0.5``, query head ``h`` reading key and value head
-  ``h // (num_heads / num_kv_heads)``; ``W_o``.  Where the shapes tile
+  the rotation of halves, ``llama._rope``); attention under the mask at
+  ``head_dim ** -0.5``, query head ``h`` reading key and value head ``h //
+  (num_heads / num_kv_heads)``; ``W_o``.  Where the shapes tile
   (``ops/bd_kernels.py:tiles``, a static test: a head whole 128-lane
   tiles, the query heads a whole number of key heads' groups, a tile that
-  takes the mask, no mesh) ``F.contrib.bd_flash_attention`` takes the three
+  takes the mask; on a mesh the kernels run per shard)
+  ``F.contrib.bd_flash_attention`` takes the three
   projections' results as they lie, ``(B, 2T, heads * head_dim)``: head
   ``h`` of a query tile is lanes ``h * head_dim ..`` of its rows, and of K
   and V the lanes of head ``h // (num_heads / num_kv_heads)``, read by the
@@ -36,10 +36,12 @@ attn(RMSNorm(x)); x = x + moe(RMSNorm(x))``:
   backward kernels read the turned block it writes (the dq kernel turns
   ``dq`` back); the result is written where ``W_o`` reads it.  Every other
   shape takes the composition: heads transposed to ``(B, H, 2T, D)``,
-  ``llama._rope``, K and V repeated to the query heads,
-  ``flash_attention(mask=...)``, the result transposed back.  Five step
-  statistics: ``flash_tiles/<layer>`` (the tiles of the kernels' grid) and
-  ``bd_flash_tiles/<layer>`` (those computed), exported as
+  ``llama._rope``, K and V repeated to the query heads, plain attention
+  under the dense mask (``F.contrib.bd_attention``), the result transposed
+  back.  Five step statistics: ``flash_tiles/<layer>`` (the tiles of the
+  mask's grid, ``bd_kernels.bd_tiles``) and ``bd_flash_tiles/<layer>``
+  (those computed: the live ones in place, every one in the composition),
+  exported as
   ``mxnet_flash_tiles_total`` and ``mxnet_flash_tiles_computed_total``;
   ``bd/<layer>`` (the layer) and ``bd_kernel/<layer>`` (the layer where
   the kernels read in place), exported as ``mxnet_bd_layers_total`` and
@@ -70,7 +72,6 @@ import jax
 import jax.numpy as jnp
 
 from ...ops import bd_kernels
-from ...ops.pallas_kernels import bd_tiles
 from ...telemetry import metrics
 from .. import nn
 from ..block import HybridBlock, record_step_stat
@@ -119,11 +120,13 @@ class BDAttention(_Mixer):
         h, kv, d, theta, block, eps = self._cfg
         b, t2, _ = u.shape
         half = t2 // 2
-        grid, computed = bd_tiles(half, block)
+        grid, computed = bd_kernels.bd_tiles(half, block)
         tile = bd_kernels.tiles(h, kv, d, half, block)
         in_place = tile is not None
-        dkv_steps = bd_kernels.dkv_steps(h, kv, half, tile) if in_place \
-            else h * grid
+        if in_place:
+            dkv_steps = bd_kernels.dkv_steps(h, kv, half, tile)
+        else:       # the dense mask: every tile, the dead ones too
+            computed, dkv_steps = grid, h * grid
         record_step_stat(self._stat, jnp.full((1,), b * h * grid, jnp.uint32))
         record_step_stat(self._computed_stat,
                          jnp.full((1,), b * h * computed, jnp.uint32))
@@ -156,9 +159,7 @@ class BDAttention(_Mixer):
             if kv != h:
                 k = F.repeat(k, repeats=h // kv, axis=1)
                 v = F.repeat(v, repeats=h // kv, axis=1)
-            out = F.contrib.flash_attention(
-                q, k, v, scale=d ** -0.5,
-                mask=("block_diffusion", half, block))
+            out = F.contrib.bd_attention(q, k, v, block_length=block)
             out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                             shape=(b, t2, h * d))
             return _dense(F, out, o_proj)

@@ -1,16 +1,18 @@
-"""Block-diffusion attention through the flash kernels, its operands read
-where the projections wrote them (``_contrib_bd_flash_attention``).
+"""Block-diffusion attention: the mask, the flash kernels that compute only
+its live tiles with their operands read where the projections wrote them
+(``_contrib_bd_flash_attention``), and plain attention under the dense
+mask (``_contrib_bd_attention``).
 
 A block-diffusion layer (``gluon.model_zoo.sdar_moe.BDAttention``) reads
 ``[x_0 | x_t]``, ``2T`` positions whose rotary positions are ``0 .. T-1`` in
 each half, through GQA: ``H`` query heads and ``KV`` key and value heads of
 ``D`` channels, query head ``h`` reading key head ``h // (H / KV)``, under
-the block-diffusion mask (``pallas_kernels.block_diffusion_mask``).  The
-composition it replaces built ``(B, H, 2T, D)`` arrays for
-``flash_attention(mask=...)``: the normed queries and keys transposed and
-turned in float32, K and V repeated to the query heads, the result
-transposed back, and each of those again for the cotangents.  Here no such
-array exists:
+the block-diffusion mask (``block_diffusion_mask``).  The composition that
+the layer keeps for the shapes the kernels do not take (``tiles``) builds
+``(B, H, 2T, D)`` arrays for ``bd_attention``: the normed queries and keys
+transposed and turned in float32, K and V repeated to the query heads, the
+result transposed back, and each of those again for the cotangents.  In
+the kernels no such array exists:
 
 - ``q (B, 2T, H * D)`` is the query projection's result as it lies, normed
   over each head's lanes by XLA in that layout (``_head_norm``): head ``h``
@@ -35,15 +37,16 @@ array exists:
   output projection reads it; ``dO`` is read the same way, and ``delta =
   rowsum(dO * O)`` is made in the dq kernel, which holds both blocks.
 
-The kernels run the SAME bodies as ``flash_attention``'s under the mask
-(``_flash_fwd_head``, ``_flash_dq_head``, ``_flash_dkv_pair`` over the live
-tiles that ``_bd_visits`` and ``_dkv_visits`` walk), one head a grid step,
-under the names ``mx_flash_fwd_bd``, ``mx_flash_bwd_dq_bd`` and
-``mx_flash_bwd_dkv_bd``.  Off a TPU they run in the Pallas interpreter
-(tests).
+The kernels run the SAME bodies as ``flash_attention``'s
+(``_flash_fwd_head`` and ``_flash_dq_head`` over the live tiles that
+``_bd_visits`` walks, ``_flash_dkv_pair`` over those of ``_dkv_visits``),
+one head a grid step, under the names ``mx_flash_fwd_bd``,
+``mx_flash_bwd_dq_bd`` and ``mx_flash_bwd_dkv_bd``.  Off a TPU they run in
+the Pallas interpreter (tests).
 """
 from __future__ import annotations
 
+import collections
 import functools
 import math
 
@@ -51,17 +54,103 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
-from .pallas_kernels import (_LANES, BlockDiffusion, _bd_visible,
-                             _flash_dkv_pair, _flash_dq_head,
-                             _flash_fwd_head, _flash_lse, _lane_rows,
-                             _platform_pick, _sequence_params, _tiles,
-                             bd_tile)
+from .pallas_kernels import (_LANES, _flash_dkv_pair, _flash_dq_head,
+                             _flash_fwd_head, _flash_lse, _gspmd_axes,
+                             _lane_rows, _platform_pick, _sequence_params,
+                             _shard_axes, _tiles)
 from .registry import register
 
 # a dk/dv visit's flags: the first and the last of its key tile's visits,
 # and a query tile that sees the key tile in part
 _FIRST, _LAST, _PARTIAL = 1, 2, 4
+
+
+# -- the block-diffusion mask ------------------------------------------------
+# The sequence is ``[x_0 | x_t]``, ``half`` tokens each, cut into blocks of
+# ``block`` tokens.  A clean query ``i < half`` sees the clean keys of its
+# block and the blocks before it; a noised query ``half + p`` sees the clean
+# keys of the blocks strictly before its own and the noised keys of its own
+# block.  With tiles of ``tile`` tokens that ``block`` divides (``n = half /
+# tile`` a half), a clean query tile ``qi`` sees clean key tiles ``0 .. qi``,
+# the last one partly; a noised query tile ``n + p`` sees clean tiles ``0 ..
+# p``, the last one partly, and its own diagonal tile, partly.  Every other
+# tile is dead: ``n (n + 2)`` of the ``4 n^2`` are computed.
+
+# the static description of the block-diffusion mask
+BlockDiffusion = collections.namedtuple("BlockDiffusion", "half block")
+
+
+def block_diffusion_mask(half, block):
+    """``(2 half, 2 half)`` bool: which key each query sees (the dense form
+    of the mask, for ``bd_attention`` and the tests)."""
+    i = jnp.arange(2 * half)
+    noised, blk = i >= half, (i % half) // block
+    rn, cn = noised[:, None], noised[None, :]
+    rb, cb = blk[:, None], blk[None, :]
+    return jnp.where(cn, rn & (cb == rb), jnp.where(rn, cb < rb, cb <= rb))
+
+
+def bd_tiles(half, block, tile=None):
+    """``(tiles of the grid, tiles the kernels compute)`` under the mask
+    over ``2 half`` positions, at ``tile``-token tiles (512 or fewer, as
+    ``flash_attention`` chooses them, unless given): ``4 n^2`` and ``n (n +
+    2)``; where the kernels do not take the shape (``bd_tile``; below 512
+    positions, where no tile is given) every tile is computed."""
+    t = 2 * half
+    kernels = tile is not None or t >= 512
+    tile = _tiles(t, int(tile) if tile else min(t, 512))
+    if tile is None:
+        return 1, 1
+    n = half // tile
+    grid = (t // tile) ** 2
+    if not kernels or bd_tile(half, block, tile) is None:
+        return grid, grid
+    return grid, n * (n + 2)
+
+
+def bd_tile(half, block, tile):
+    """``tile`` where the kernels take the mask at it, else None: the tile
+    divides a half, and ``block``, a power of two, divides the tile."""
+    if tile is None or half % tile or tile % block or block & (block - 1):
+        return None
+    return tile
+
+
+def _bd_visible(qi, ki, mask, tile):
+    """Which pairs of query tile ``qi`` and key tile ``ki`` the mask leaves
+    live, ``(tile, tile)`` bool: the blocks' distance ``d = key block -
+    query block`` within the tiles (tiles start on a block's first token)
+    must lie in ``[lo, hi]``: ``(-inf, 0]`` clean to clean, ``(-inf, -1]``
+    noised to clean, ``[0, 0]`` noised to noised."""
+    n = mask.half // tile
+    shift = mask.block.bit_length() - 1
+
+    def blocks(axis):
+        return jnp.right_shift(
+            lax.broadcasted_iota(jnp.int32, (tile, tile), axis), shift)
+    d = blocks(1) - blocks(0)
+    key_noised = ki >= n
+    hi = jnp.where(key_noised | (qi < n), 0, -1)
+    lo = jnp.where(key_noised, 0, -(tile + 1))
+    return (d <= hi) & (d >= lo)
+
+
+def _bd_visits(step, carry, qi, mask, tile):
+    """The walk of the forward and dq bodies: ``step(i, carry, visible)``
+    or, where key tile ``i`` is whole, ``step(i, carry)`` over the key
+    tiles query tile ``qi`` sees: its own diagonal tile first (every row
+    sees itself there, so no row's running maximum stays -inf), for a
+    noised tile the clean tile at its position (masked), then the clean
+    tiles before that one, whole."""
+    n = mask.half // tile
+    noised = (qi >= n).astype(jnp.int32)
+    own = qi - n * noised
+    carry = step(qi, carry, _bd_visible(qi, qi, mask, tile))
+    carry = lax.fori_loop(0, noised, lambda _, c: step(
+        own, c, _bd_visible(qi, own, mask, tile)), carry)
+    return lax.fori_loop(0, own, step, carry)
 
 
 def tiles(heads, kv_heads, head_dim, half, block):
@@ -77,15 +166,10 @@ def tiles(heads, kv_heads, head_dim, half, block):
 def _fits(heads, kv_heads, head_dim, half, block, tile):
     """``tile`` where the kernels take these shapes at it, else None: a head
     whole 128-lane tiles, the query heads a whole number of key heads'
-    groups, a tile that takes the mask (``bd_tile``), and no mesh axis that
-    GSPMD would partition (a Mosaic kernel cannot be partitioned; under a
-    mesh the composition runs per shard)."""
+    groups, and a tile that takes the mask (``bd_tile``).  A mesh does not
+    matter: there the kernels run per shard (``_per_shard``)."""
     if head_dim % _LANES or heads % kv_heads \
             or bd_tile(half, block, tile) is None:
-        return None
-    mesh = jax.sharding.get_abstract_mesh()
-    if any(mesh.shape[a] > 1 for a in mesh.axis_names
-           if a not in mesh.manual_axes):
         return None
     return tile
 
@@ -209,7 +293,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, cos_ref, sin_ref, o_ref, lse_ref,
     m, l, acc = _flash_fwd_head(
         (q * q.shape[-1] ** -0.5,), *_key_blocks(k_ref, v_ref, tile),
         k_ref.shape[1], v_ref.shape[2], pl.program_id(2), block_q=tile,
-        block_k=tile, causal=False, mask=mask)
+        block_k=tile, causal=False,
+        walk=functools.partial(_bd_visits, mask=mask, tile=tile))
     safe_l = jnp.where(l == 0, 1.0, l)
     o_ref[0] = (acc / safe_l).astype(o_ref.dtype)
     lse_ref[0, 0] = _lane_rows(_flash_lse(m, l, safe_l), tile)
@@ -229,7 +314,8 @@ def _dq_kernel(qt_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, cos_ref,
         (qt_ref[0],), do, lse_ref[0, 0][:, :1], delta,
         *_key_blocks(k_ref, v_ref, tile), k_ref.shape[1], pl.program_id(2),
         block_q=tile, block_k=tile, scale=qt_ref.shape[2] ** -0.5,
-        causal=False, mask=mask)
+        causal=False, walk=functools.partial(_bd_visits, mask=mask,
+                                             tile=tile))
     dq_ref[0] = _turn_back(dq, cos_ref[...], sin_ref[...]).astype(
         dq_ref.dtype)
 
@@ -459,7 +545,8 @@ def bd_flash_attention(query, key, value, q_norm, k_norm, num_heads=1,
     inside the kernels, the key by XLA in its layout before it is rounded
     (under AMP the gains stay float32: ``amp.lists.TARGET_DTYPE_KEEP``).
     Blocks of ``block_length`` tokens, the scores at ``D ** -0.5``.  The
-    shapes have to tile (``tiles``): the caller tests them."""
+    shapes have to tile (``tiles``): the caller tests them.  Under a
+    context mesh the kernels run per shard (``_per_shard``)."""
     heads, block = int(num_heads), int(block_length)
     b, t2, width = query.shape
     d = q_norm.shape[0]
@@ -470,10 +557,34 @@ def bd_flash_attention(query, key, value, q_norm, k_norm, num_heads=1,
     if tile is None:
         raise ValueError(
             "bd_flash_attention: %d heads over %d channels, keys of %d, at "
-            "%d positions in blocks of %d do not tile, or a mesh would "
-            "partition the kernels" % (heads, width, key.shape[2], t2, block))
-    return _attend(query, key, value, q_norm, k_norm, heads, block,
-                   float(rope_theta), eps, tile)
+            "%d positions in blocks of %d do not tile"
+            % (heads, width, key.shape[2], t2, block))
+    static = (block, float(rope_theta), eps, tile)
+    mesh, axes = _gspmd_axes()
+    if axes:
+        return _per_shard(mesh, axes, query, key, value, q_norm, k_norm,
+                          heads, *static)
+    return _attend(query, key, value, q_norm, k_norm, heads, *static)
+
+
+def _per_shard(mesh, axes, query, key, value, q_norm, k_norm, heads,
+               *static):
+    """``_attend`` under ``shard_map`` over the mesh's ``axes``
+    (``pallas_kernels._shard_axes``): the batch over the step's data axis,
+    the heads' lanes over the others where the key heads divide over them
+    (a shard holds whole groups: query heads ``h`` and their key head)."""
+    d = q_norm.shape[0]
+    batch_axis, head_axes = _shard_axes("bd_flash_attention", mesh, axes,
+                                        query.shape[0], key.shape[2] // d)
+    split = math.prod(mesh.shape[a] for a in head_axes)
+    rows = P(batch_axis, None, head_axes or None)
+
+    def body(q, k, v, q_norm, k_norm):
+        return _attend(q, k, v, q_norm, k_norm, heads // split, *static)
+
+    return jax.shard_map(body, in_specs=(rows,) * 3 + (P(),) * 2,
+                         out_specs=rows, axis_names=set(axes),
+                         check_vma=False)(query, key, value, q_norm, k_norm)
 
 
 def _attend(query, key, value, q_norm, k_norm, heads, block, theta, eps,
@@ -485,3 +596,21 @@ def _attend(query, key, value, q_norm, k_norm, heads, block, theta, eps,
                    *rope_tables(t2 // 2, d, theta), d)
     return _bd_flash(q, k.astype(query.dtype), value, heads, theta,
                      BlockDiffusion(t2 // 2, block), tile)
+
+
+@register("_contrib_bd_attention", inputs=("query", "key", "value"))
+def bd_attention(query, key, value, block_length=4):
+    """Plain attention under the dense block-diffusion mask over ``[x_0 |
+    x_t]``: ``query``, ``key`` and ``value (B, H, 2T, D)``, blocks of
+    ``block_length`` tokens, the scores at ``D ** -0.5`` and their softmax
+    in float32, the result in the query's dtype.
+    ``BDAttention``'s composition for the shapes the kernels do not take
+    (``tiles``), and what the kernels are held to."""
+    t2, d = query.shape[2], query.shape[3]
+    s = jnp.einsum("bhqd,bhkd->bhqk", query.astype(jnp.float32),
+                   key.astype(jnp.float32)) * d ** -0.5
+    s = jnp.where(block_diffusion_mask(t2 // 2, int(block_length)), s,
+                  -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, value.astype(jnp.float32)) \
+        .astype(query.dtype)

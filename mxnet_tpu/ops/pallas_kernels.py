@@ -38,7 +38,6 @@ platform; a kernel the TPU compiler refuses is an error.
 """
 from __future__ import annotations
 
-import collections
 import functools
 import math
 import warnings
@@ -92,111 +91,18 @@ def _platform_pick(run, *args, off_tpu=None):
 def _blocks_seen(qi, block_q, block_k, t_kv, causal):
     """Key blocks that query block ``qi`` reads: all of them, or under the
     causal mask those that hold a column at or before its last row (a block
-    past them adds exact zeros).  Under the block-diffusion mask the blocks
-    are no range: ``_bd_visits`` walks them."""
+    past them adds exact zeros)."""
     n_k = t_kv // block_k
     if not causal or n_k == 1:
         return n_k          # static: the loop is unrolled as it always was
     return jnp.minimum(((qi + 1) * block_q + block_k - 1) // block_k, n_k)
 
 
-# -- the block-diffusion mask ------------------------------------------------
-# The sequence is ``[x_0 | x_t]``, ``half`` tokens each, cut into blocks of
-# ``block`` tokens.  A clean query ``i < half`` sees the clean keys of its
-# block and the blocks before it; a noised query ``half + p`` sees the clean
-# keys of the blocks strictly before its own and the noised keys of its own
-# block.  With tiles of ``tile`` tokens that ``block`` divides (``n = half /
-# tile`` a half), a clean query tile ``qi`` sees clean key tiles ``0 .. qi``,
-# the last one partly; a noised query tile ``n + p`` sees clean tiles ``0 ..
-# p``, the last one partly, and its own diagonal tile, partly.  Every other
-# tile is dead: ``n (n + 2)`` of the ``4 n^2`` are computed.
-
-# the static description of the block-diffusion mask
-BlockDiffusion = collections.namedtuple("BlockDiffusion", "half block")
-
-
-def _kernel_name(name, mask):
-    """A kernel's name: the mask's kernels carry ``_bd`` after it."""
-    return name if mask is None else name + "_bd"
-
-
-def block_diffusion_mask(half, block):
-    """``(2 half, 2 half)`` bool: which key each query sees (the dense form
-    of the mask, for ``_attention_ref`` and the tests)."""
-    i = jnp.arange(2 * half)
-    noised, blk = i >= half, (i % half) // block
-    rn, cn = noised[:, None], noised[None, :]
-    rb, cb = blk[:, None], blk[None, :]
-    return jnp.where(cn, rn & (cb == rb), jnp.where(rn, cb < rb, cb <= rb))
-
-
-def bd_tiles(half, block, tile=None):
-    """``(tiles of the grid, tiles the kernels compute)`` of
-    ``flash_attention`` under the mask over ``2 half`` positions, at
-    ``tile``-token tiles (the operator's own choice unless given): ``4 n^2``
-    and ``n (n + 2)``; where the kernels do not take the shape (``bd_tile``;
-    below 512 positions, where no tile is given, the operator is XLA's
-    attention) every tile is computed, by the dense fallback."""
-    t = 2 * half
-    kernels = tile is not None or t >= 512
-    tile = _tiles(t, int(tile) if tile else min(t, 512))
-    if tile is None:
-        return 1, 1
-    n = half // tile
-    grid = (t // tile) ** 2
-    if not kernels or bd_tile(half, block, tile) is None:
-        return grid, grid
-    return grid, n * (n + 2)
-
-
-def bd_tile(half, block, tile):
-    """``tile`` where the kernels take the mask at it, else None: the tile
-    divides a half, and ``block``, a power of two, divides the tile."""
-    if tile is None or half % tile or tile % block or block & (block - 1):
-        return None
-    return tile
-
-
-def _bd_visible(qi, ki, mask, tile):
-    """Which pairs of query tile ``qi`` and key tile ``ki`` the mask leaves
-    live, ``(tile, tile)`` bool: the blocks' distance ``d = key block -
-    query block`` within the tiles (tiles start on a block's first token)
-    must lie in ``[lo, hi]``: ``(-inf, 0]`` clean to clean, ``(-inf, -1]``
-    noised to clean, ``[0, 0]`` noised to noised."""
-    n = mask.half // tile
-    shift = mask.block.bit_length() - 1
-
-    def blocks(axis):
-        return jnp.right_shift(
-            lax.broadcasted_iota(jnp.int32, (tile, tile), axis), shift)
-    d = blocks(1) - blocks(0)
-    key_noised = ki >= n
-    hi = jnp.where(key_noised | (qi < n), 0, -1)
-    lo = jnp.where(key_noised, 0, -(tile + 1))
-    return (d <= hi) & (d >= lo)
-
-
-def _bd_visits(step, carry, qi, mask, tile):
-    """``step(i, carry, visible=None)`` over the key tiles query tile ``qi``
-    sees: its own diagonal tile first (every row sees itself there, so no
-    row's running maximum stays -inf), for a noised tile the clean tile at
-    its position (masked), then the clean tiles before that one, whole."""
-    n = mask.half // tile
-    noised = (qi >= n).astype(jnp.int32)
-    own = qi - n * noised
-    carry = step(qi, carry, _bd_visible(qi, qi, mask, tile))
-    carry = lax.fori_loop(0, noised, lambda _, c: step(
-        own, c, _bd_visible(qi, own, mask, tile)), carry)
-    return lax.fori_loop(0, own, step, carry)
-
-
-def _bd_query_tile(ki, i, n):
-    """The ``i``-th query tile that key tile ``ki`` is seen by (the last one
-    where ``i`` is past them): a clean key tile by the clean query tiles from
-    its own on and then the noised ones from its position on, a noised key
-    tile by its own diagonal tile alone."""
-    clean = jnp.where(i < n - ki, ki + i, jnp.minimum(2 * ki + i, 2 * n - 1))
-    return jnp.where(ki >= n, ki, clean)
+def _blocks_walk(qi, block_q, block_k, t_kv, causal):
+    """The walk of ``_flash_fwd_head`` and ``_flash_dq_head`` over the key
+    blocks that ``_blocks_seen`` counts, in order."""
+    n_k = _blocks_seen(qi, block_q, block_k, t_kv, causal)
+    return lambda step, carry, qi: lax.fori_loop(0, n_k, step, carry)
 
 
 def _scores(qs, ks):
@@ -213,16 +119,18 @@ def _scores(qs, ks):
 
 
 def _flash_fwd_head(qs, keys, value, t_kv, dv, qi, *, block_q, block_k,
-                    causal, mask=None):
+                    causal, walk=None):
     """One head's query block against the key blocks it sees, the online
     softmax: ``qs`` the scaled float32 query block, a tuple of its channel
     parts; ``keys(i)`` key block ``i`` as the same parts and ``value(i)``
     its ``dv`` values, float32.  The running maximum ``m`` and sum ``l``
     ``(bq, 1)`` and the result not yet divided by ``l`` ``(bq, dv)``.
-    ``mask`` (a ``BlockDiffusion``; ``causal`` False) walks the tiles that
-    mask leaves live (``_bd_visits``)."""
-    if mask is None:
-        n_k = _blocks_seen(qi, block_q, block_k, t_kv, causal)
+    ``walk(step, carry, qi) -> carry`` calls ``step(i, carry)`` for each
+    key block ``i`` the query block sees, or ``step(i, carry, visible)``
+    with the bool ``(bq, bk)`` of the pairs it leaves live where a block is
+    seen in part (``bd_kernels``); by default ``_blocks_walk``."""
+    if walk is None:
+        walk = _blocks_walk(qi, block_q, block_k, t_kv, causal)
     row = qi * block_q + lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
 
@@ -248,9 +156,7 @@ def _flash_fwd_head(qs, keys, value, t_kv, dv, qi, *, block_q, block_k,
     m0 = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
     acc0 = jnp.zeros((block_q, dv), jnp.float32)
-    if mask is not None:
-        return _bd_visits(body, (m0, l0, acc0), qi, mask, block_q)
-    return lax.fori_loop(0, n_k, body, (m0, l0, acc0))
+    return walk(body, (m0, l0, acc0), qi)
 
 
 def _flash_lse(m, l, safe_l):
@@ -267,7 +173,7 @@ def _lane_rows(x, block_q):
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
-                      block_k, scale, causal, mask=None):
+                      block_k, scale, causal):
     from jax.experimental import pallas as pl
 
     def block(ref, i):
@@ -276,7 +182,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
     m, l, acc = _flash_fwd_head(
         (q,), lambda i: (block(k_ref, i),), lambda i: block(v_ref, i),
         k_ref.shape[1], v_ref.shape[-1], pl.program_id(1), block_q=block_q,
-        block_k=block_k, causal=causal, mask=mask)
+        block_k=block_k, causal=causal)
     safe_l = jnp.where(l == 0, 1.0, l)
     o_ref[0] = (acc / safe_l).astype(o_ref.dtype)
     lse_ref[0] = _lane_rows(_flash_lse(m, l, safe_l), block_q)
@@ -315,7 +221,7 @@ def _sequence_params(t, lanes, itemsize, semantics=None):
 
 
 def _flash_pallas(q, k, v, scale, causal, block_q, block_k,
-                  interpret=False, mask=None):
+                  interpret=False):
     from jax.experimental import pallas as pl
 
     bh, t_q, d = q.shape
@@ -323,8 +229,6 @@ def _flash_pallas(q, k, v, scale, causal, block_q, block_k,
     kernel = functools.partial(
         _flash_fwd_kernel, block_q=block_q, block_k=block_k,
         scale=scale, causal=causal)
-    if mask is not None:
-        kernel = functools.partial(kernel, mask=mask)
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, t_q // block_q),
@@ -343,7 +247,7 @@ def _flash_pallas(q, k, v, scale, causal, block_q, block_k,
         ],
         compiler_params=_flash_params(k, v),
         interpret=interpret,
-        name=_kernel_name("mx_flash_fwd", mask),
+        name="mx_flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -354,12 +258,12 @@ def _flash_pallas(q, k, v, scale, causal, block_q, block_k,
 
 
 def _flash_dq_head(qs, do, lse, delta, keys, value, t_kv, qi, *, block_q,
-                   block_k, scale, causal, mask=None):
+                   block_k, scale, causal, walk=None):
     """One head's query block against the key blocks it sees: ``dq`` as the
     parts ``qs`` came in (float32, not scaled).  ``lse`` and ``delta``
-    ``(bq, 1)``; ``keys``, ``value`` and ``mask`` as ``_flash_fwd_head``'s."""
-    if mask is None:
-        n_k = _blocks_seen(qi, block_q, block_k, t_kv, causal)
+    ``(bq, 1)``; ``keys``, ``value`` and ``walk`` as ``_flash_fwd_head``'s."""
+    if walk is None:
+        walk = _blocks_walk(qi, block_q, block_k, t_kv, causal)
     row = qi * block_q + lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
 
@@ -384,14 +288,11 @@ def _flash_dq_head(qs, do, lse, delta, keys, value, t_kv, qi, *, block_q,
             preferred_element_type=jnp.float32) for dq, k in zip(dqs, ks))
 
     dqs = tuple(jnp.zeros((block_q, q.shape[-1]), jnp.float32) for q in qs)
-    if mask is not None:
-        return _bd_visits(body, dqs, qi, mask, block_q)
-    return lax.fori_loop(0, n_k, body, dqs)
+    return walk(body, dqs, qi)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, *, block_q, block_k, scale, causal,
-                         mask=None):
+                         dq_ref, *, block_q, block_k, scale, causal):
     from jax.experimental import pallas as pl
 
     def block(ref, i):
@@ -402,7 +303,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         lse_ref[0][:, :1], delta_ref[0][:, :1],         # (bq, 1) lane 0
         lambda i: (block(k_ref, i),), lambda i: block(v_ref, i),
         k_ref.shape[1], pl.program_id(1), block_q=block_q, block_k=block_k,
-        scale=scale, causal=causal, mask=mask)
+        scale=scale, causal=causal)
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
@@ -416,8 +317,8 @@ def _flash_dkv_pair(qs, ks, v, do, lse, delta, ki, qi, *, block_q, block_k,
     """One (key block, query block) pair of one head: ``(dks, dv)``, the
     pair's part of dk (the parts ``ks`` came in) and of dv, float32.  ``qs``
     and ``ks`` as ``_scores`` takes them, not scaled.  ``visible`` (bool
-    ``(bq, bk)``; ``causal`` False) masks a pair the block-diffusion mask
-    leaves partly live."""
+    ``(bq, bk)``; ``causal`` False) masks a pair a walk leaves partly live
+    (``bd_kernels``)."""
     s = scale * _scores(qs, ks)                         # (bq, bk)
     if causal:
         col = ki * block_k + lax.broadcasted_iota(
@@ -440,36 +341,9 @@ def _flash_dkv_pair(qs, ks, v, do, lse, delta, ki, qi, *, block_q, block_k,
         preferred_element_type=jnp.float32) for q in qs), dv    # (bk, D)
 
 
-def _bd_dkv_visit(pair, dk_acc, dv_acc, ki, i, mask, tile):
-    """Grid step ``i`` of key tile ``ki`` under the block-diffusion mask:
-    query tile ``_bd_query_tile(ki, i)`` where ``i`` is one of the tiles
-    that see it (the first ``2 (n - ki)`` steps of a clean tile, the first
-    of a noised one), masked where the pair is partly live (the query tile
-    at the key tile's position in either half), whole otherwise; past them
-    nothing."""
-    from jax.experimental import pallas as pl
-
-    n = mask.half // tile
-    qt = _bd_query_tile(ki, i, n)
-    live = i < jnp.where(ki >= n, 1, 2 * (n - ki))
-    partial_ = (qt == ki) | (qt == ki + n)
-
-    @pl.when(live & partial_)
-    def _():
-        dk, dv = pair(_bd_visible(qt, ki, mask, tile))
-        dk_acc[...] += dk
-        dv_acc[...] += dv
-
-    @pl.when(live & jnp.logical_not(partial_))
-    def _():
-        dk, dv = pair()
-        dk_acc[...] += dk
-        dv_acc[...] += dv
-
-
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc=None, dv_acc=None, *,
-                          block_q, block_k, scale, causal, mask=None):
+                          block_q, block_k, scale, causal):
     """One (key block, query block) pair a grid step: the query blocks
     stream through VMEM (whole-T operands do not fit at T=4096) and dk, dv
     accumulate in float32 scratch across the innermost grid axis.  Where T
@@ -480,15 +354,14 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     ki, qi = pl.program_id(1), pl.program_id(2)
     one_block = dk_acc is None          # T is one query block: no sum to keep
 
-    def pair(visible=None):
+    def pair():
         k = k_ref[0].astype(jnp.float32)                # (bk, D)
         v = v_ref[0].astype(jnp.float32)                # (bk, Dv)
         q = q_ref[0].astype(jnp.float32)                # (bq, D)
         do = do_ref[0].astype(jnp.float32)              # (bq, Dv)
         (dk,), dv = _flash_dkv_pair(
             (q,), (k,), v, do, lse_ref[0][:, :1], delta_ref[0][:, :1], ki,
-            qi, block_q=block_q, block_k=block_k, scale=scale, causal=causal,
-            visible=visible)
+            qi, block_q=block_q, block_k=block_k, scale=scale, causal=causal)
         return dk, dv
 
     if one_block:
@@ -502,16 +375,13 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    if mask is not None:
-        _bd_dkv_visit(pair, dk_acc, dv_acc, ki, qi, mask, block_k)
-    else:
-        # under the causal mask a query block before the key block's first
-        # row adds exact zeros: not computed
-        @pl.when(qi >= _first_block_seen(ki, block_q, block_k, causal))
-        def _():
-            dk, dv = pair()
-            dk_acc[...] += dk
-            dv_acc[...] += dv
+    # under the causal mask a query block before the key block's first
+    # row adds exact zeros: not computed
+    @pl.when(qi >= _first_block_seen(ki, block_q, block_k, causal))
+    def _():
+        dk, dv = pair()
+        dk_acc[...] += dk
+        dv_acc[...] += dv
 
     @pl.when(qi == pl.num_programs(2) - 1)
     def _():
@@ -520,7 +390,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_pallas(q, k, v, do, lse, delta, scale, causal, block_q,
-                      block_k, interpret=False, mask=None):
+                      block_k, interpret=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -528,8 +398,6 @@ def _flash_bwd_pallas(q, k, v, do, lse, delta, scale, causal, block_q,
     t_kv, dv = k.shape[1], v.shape[2]
     static = dict(block_q=block_q, block_k=block_k, scale=scale,
                   causal=causal)
-    if mask is not None:
-        static["mask"] = mask
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **static),
         grid=(bh, t_q // block_q),
@@ -545,17 +413,12 @@ def _flash_bwd_pallas(q, k, v, do, lse, delta, scale, causal, block_q,
         out_shape=jax.ShapeDtypeStruct((bh, t_q, d), q.dtype),
         compiler_params=_flash_params(k, v),
         interpret=interpret,
-        name=_kernel_name("mx_flash_bwd_dq", mask),
+        name="mx_flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     def rows(width):
         # a query block the mask hides is not fetched: the index stays at
-        # the first block that is seen (the block-diffusion mask: at the
-        # last, its blocks being visited in order, ``_bd_query_tile``)
-        if mask is not None:
-            n = mask.half // block_q
-            return pl.BlockSpec((1, block_q, width), lambda b, j, i: (
-                b, _bd_query_tile(j, i, n), 0))
+        # the first block that is seen
         return pl.BlockSpec(
             (1, block_q, width), lambda b, j, i: (b, jnp.maximum(
                 i, _first_block_seen(j, block_q, block_k, causal)), 0))
@@ -578,14 +441,13 @@ def _flash_bwd_pallas(q, k, v, do, lse, delta, scale, causal, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name=_kernel_name("mx_flash_bwd_dkv", mask),
+        name="mx_flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv_
 
 
-def _attention_ref(q, k, v, scale, causal, mask=None):
-    """Plain jnp attention (fallback for non-tiling shapes); ``mask`` a
-    ``BlockDiffusion``, as a dense boolean."""
+def _attention_ref(q, k, v, scale, causal):
+    """Plain jnp attention (fallback for non-tiling shapes)."""
     s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if causal:
@@ -593,33 +455,27 @@ def _attention_ref(q, k, v, scale, causal, mask=None):
         row = jnp.arange(t_q)[:, None]
         col = jnp.arange(t_kv)[None, :]
         s = jnp.where(col <= row, s, -jnp.inf)
-    if mask is not None:
-        s = jnp.where(block_diffusion_mask(*mask), s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bqk,bkd->bqd", p, v.astype(jnp.float32)) \
         .astype(q.dtype)
 
 
-def _pallas_static(fn, scale, causal, block_q, block_k, mask):
-    run = functools.partial(fn, scale=scale, causal=causal, block_q=block_q,
-                            block_k=block_k)
-    return run if mask is None else functools.partial(run, mask=mask)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_attention(q, k, v, scale, causal, block_q, block_k, mask=None):
-    run = _pallas_static(_flash_pallas, scale, causal, block_q, block_k, mask)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_attention(q, k, v, scale, causal, block_q, block_k):
+    run = functools.partial(_flash_pallas, scale=scale, causal=causal,
+                            block_q=block_q, block_k=block_k)
     out, _ = _platform_pick(run, q, k, v)
     return out
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, mask=None):
-    run = _pallas_static(_flash_pallas, scale, causal, block_q, block_k, mask)
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
+    run = functools.partial(_flash_pallas, scale=scale, causal=causal,
+                            block_q=block_q, block_k=block_k)
     out, lse = _platform_pick(run, q, k, v)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, mask, res, g):
+def _flash_bwd(scale, causal, block_q, block_k, res, g):
     q, k, v, out, lse = res
     # delta_i = sum_d dO_id * O_id  (rowwise), O(T*D) — the only
     # off-kernel piece of the two-pass flash backward.  Broadcast across
@@ -627,8 +483,8 @@ def _flash_bwd(scale, causal, block_q, block_k, mask, res, g):
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)
     delta = jnp.broadcast_to(delta[..., None], (*delta.shape, _LANES))
-    run = _pallas_static(_flash_bwd_pallas, scale, causal, block_q, block_k,
-                         mask)
+    run = functools.partial(_flash_bwd_pallas, scale=scale, causal=causal,
+                            block_q=block_q, block_k=block_k)
     dq, dk, dv = _platform_pick(run, q, k, v, g, lse, delta)
     return dq, dk, dv
 
@@ -636,15 +492,27 @@ def _flash_bwd(scale, causal, block_q, block_k, mask, res, g):
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _flash_per_shard(mesh, axes, q, k, v, *static):
-    """The kernel under ``shard_map`` on (B, H, T, D) over ``axes``, the
-    context mesh's axes that GSPMD would otherwise partition: batch over
-    ``sharding.batch_axis`` (the step's data axis), heads over the others
-    (where Megatron rules put them), each only where it divides.  The
-    specs decide how much GSPMD has to move, never the result; an axis
+def _gspmd_axes():
+    """``(mesh, axes)``: the context mesh and its axes of more than one
+    device that GSPMD would partition.  The TPU lowering refuses a Mosaic
+    kernel in a program GSPMD partitions ("cannot be automatically
+    partitioned. Please wrap the call in a shard_map"); axes a caller's own
+    shard_map has already made manual are not GSPMD's any more.  jax keys
+    its trace caches on the context mesh, so an eager trace of a kernel is
+    never reused inside such a program."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh, tuple(a for a in mesh.axis_names
+                       if a not in mesh.manual_axes and mesh.shape[a] > 1)
+
+
+def _shard_axes(op, mesh, axes, b, h):
+    """``(batch axis or None, head axes)`` for a kernel under ``shard_map``
+    over ``axes`` (``_gspmd_axes``) at batch ``b`` and ``h`` heads: batch
+    over ``sharding.batch_axis`` (the step's data axis), heads over the
+    others (where Megatron rules put them), each only where it divides.
+    The specs decide how much GSPMD has to move, never the result; an axis
     left out of them makes every device along it run the same shard, and
     is warned about."""
-    b, h = q.shape[:2]
     batch_axis = _sharding.batch_axis.value
     if batch_axis not in axes or b % mesh.shape[batch_axis]:
         batch_axis = None
@@ -654,10 +522,18 @@ def _flash_per_shard(mesh, axes, q, k, v, *static):
     idle = [a for a in axes if a != batch_axis and a not in head_axes]
     if idle:
         warnings.warn(
-            "flash_attention: batch %d / heads %d do not divide over mesh "
-            "axes %s of %s (batch axis %r); the kernel runs replicated "
-            "along them" % (b, h, idle, dict(mesh.shape),
-                            _sharding.batch_axis.value), stacklevel=3)
+            "%s: batch %d / heads %d do not divide over mesh axes %s of %s "
+            "(batch axis %r); the kernel runs replicated along them"
+            % (op, b, h, idle, dict(mesh.shape), _sharding.batch_axis.value),
+            stacklevel=4)
+    return batch_axis, head_axes
+
+
+def _flash_per_shard(mesh, axes, q, k, v, *static):
+    """The kernel under ``shard_map`` on (B, H, T, D) over ``axes``
+    (``_shard_axes``)."""
+    batch_axis, head_axes = _shard_axes("flash_attention", mesh, axes,
+                                        *q.shape[:2])
     spec = P(batch_axis, head_axes or None, None, None)
 
     def body(q, k, v):
@@ -684,7 +560,7 @@ def _tiles(t, preferred):
 
 @register("_contrib_flash_attention", inputs=("query", "key", "value"))
 def flash_attention(query, key, value, scale=None, causal=False,
-                    block_q=None, block_k=None, mask=None):
+                    block_q=None, block_k=None):
     """Fused multi-head attention, one Pallas kernel per (batch·head).
 
     Inputs (B, H, T, D) [or (BH, T, D)]; ``value`` may have another last
@@ -705,24 +581,7 @@ def flash_attention(query, key, value, scale=None, causal=False,
     switch is measured.  Latent attention at shapes that tile does not
     come here: ``ops/mla_kernels.py`` runs the same bodies over the
     projections' own layout (PR 39).
-
-    ``mask=("block_diffusion", T, L)`` (``causal`` False) is the mask of
-    block diffusion over a sequence laid out ``[x_0 | x_t]``, ``2 T`` query
-    and key positions, blocks of ``L`` tokens: a clean position ``i < T``
-    sees the clean keys of its block and the blocks before it, a noised one
-    ``T + p`` the clean keys of the blocks strictly before its own and the
-    noised keys of its own block.  The kernels compute no tile the mask
-    leaves dead (``n (n + 2)`` of ``4 n^2`` tiles, ``n`` a half's) where
-    the tile divides ``T`` and ``L``, a power of two, divides the tile, and
-    the query and key tiles are one size; any other shape takes plain
-    attention under the dense mask.
     """
-    if mask is not None:
-        kind, half, blk = mask
-        if kind != "block_diffusion" or causal:
-            raise ValueError("flash_attention: mask %r: only "
-                             "('block_diffusion', T, L), not causal" % (mask,))
-        mask = BlockDiffusion(half, blk)
     squeeze = query.ndim == 3
     if squeeze:
         query, key, value = (x[:, None] if x.ndim == 3 else x
@@ -739,31 +598,17 @@ def flash_attention(query, key, value, scale=None, causal=False,
     # R9: a BERT-base seq-128 guard cell would).  Explicit block sizes
     # force the kernel (tests, tuning).
     if block_q is None and block_k is None and t_q < 512 and t_kv < 512:
-        return _finish(_attention_ref(q3, k3, v3, scale, causal, mask),
+        return _finish(_attention_ref(q3, k3, v3, scale, causal),
                        b, h, t_q, squeeze)
     bq = _tiles(t_q, int(block_q) if block_q else min(t_q, 512))
     bk = _tiles(t_kv, int(block_k) if block_k else min(t_kv, 512))
-    if mask is not None and (bq != bk or t_q != t_kv or t_q != 2 * mask.half
-                             or bd_tile(mask.half, mask.block, bq) is None):
-        bq = None
     if bq is None or bk is None:
         # a shape rule, on every platform: T has no block that both
         # divides it and satisfies the sublane rule
-        out3 = _attention_ref(q3, k3, v3, scale, causal, mask)
+        out3 = _attention_ref(q3, k3, v3, scale, causal)
     else:
-        static = (float(scale), bool(causal), bq, bk) \
-            + (() if mask is None else (mask,))
-        # a program traced under a context mesh (JitTrainStep with a
-        # mesh) will be partitioned by GSPMD, and the TPU lowering
-        # refuses a Mosaic kernel there ("cannot be automatically
-        # partitioned. Please wrap the call in a shard_map").  jax keys
-        # its trace caches on the context mesh, so an eager trace of this
-        # op is never reused inside such a program.
-        # Axes a caller's own shard_map has already made manual are
-        # not GSPMD's any more: there the kernel runs as it is.
-        mesh = jax.sharding.get_abstract_mesh()
-        axes = tuple(a for a in mesh.axis_names
-                     if a not in mesh.manual_axes and mesh.shape[a] > 1)
+        static = (float(scale), bool(causal), bq, bk)
+        mesh, axes = _gspmd_axes()
         if axes:
             out3 = _flash_per_shard(mesh, axes, query, key, value, *static)
         else:

@@ -78,9 +78,6 @@ def ring_attention_sharded(q, k, v, mesh, axis_name="sp", scale=None,
                            causal=False):
     """Shard (B, T, D) [or (B, H, T, D)] on the sequence axis and run
     ring attention over ``axis_name`` of ``mesh``."""
-    from jax.sharding import PartitionSpec as P
-
-    from .mesh import shard_map
     from ..analysis.collective_check import check_axis
     from .. import sharding as _sharding
 
@@ -93,13 +90,26 @@ def ring_attention_sharded(q, k, v, mesh, axis_name="sp", scale=None,
         k = k.reshape(b * h, k.shape[2], d)
         v = v.reshape(b * h, v.shape[2], d)
 
-    spec = P(None, axis_name, None)
-    fn = shard_map(
-        functools.partial(ring_attention, axis_name=axis_name,
-                          scale=scale, causal=causal),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False)
-    out = fn(q, k, v)
+    out = _ring_program(mesh, axis_name, scale, causal)(q, k, v)
     if four_d:
         out = out.reshape(b, h, t, d)
     return out
+
+
+@functools.lru_cache(maxsize=None)
+def _ring_program(mesh, axis_name, scale, causal):
+    """The ring over ``mesh``'s ``axis_name`` as one jitted ``shard_map``
+    on (B, T, D) sharded by T.  Called outside a jit, a bare ``shard_map``
+    runs its body one operation at a time on every device (the unrolled
+    hops and their gradient: minutes on a CPU, where the program takes
+    seconds to compile)."""
+    from jax.sharding import PartitionSpec as P
+
+    from .mesh import shard_map
+
+    spec = P(None, axis_name, None)
+    return jax.jit(shard_map(
+        functools.partial(ring_attention, axis_name=axis_name,
+                          scale=scale, causal=causal),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False))

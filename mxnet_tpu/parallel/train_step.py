@@ -561,7 +561,8 @@ class JitTrainStep:
                     self._take_state(state)
             with _span("train_step.tag"):
                 self._tag_weights()
-            self._last_loss = loss
+                # releases the previous step's loss: work of the step
+                self._last_loss = loss
             return loss
 
     def _first_call(self, fn, key, lr, t, arrays):
@@ -634,8 +635,8 @@ class JitTrainStep:
                     self._take_state(state)
             with _span("train_step.tag"):
                 self._tag_weights()
+                self._last_loss = loss
             self._t += n
-            self._last_loss = loss
             return loss
 
     def _step_n_fn(self, n, sched, sched_traced, arrays):

@@ -1,17 +1,18 @@
-"""Block-diffusion attention through the flash kernels with its operands read
-where the projections wrote them (``ops/bd_kernels.py``: ``mx_flash_fwd_bd``,
-``mx_flash_bwd_dq_bd``, ``mx_flash_bwd_dkv_bd`` over ``(B, 2T, H * 128)``) in
-the interpreter, at lane-tiling shapes kept small (head_dim 128, 2 x 256
-positions in 128-token tiles, blocks of 4), against the composition it
-replaces: heads transposed to ``(B, H, 2T, D)``, ``RMSNorm`` over each head
-of q and k, the rotation of halves at positions ``0 .. T-1`` of each half
-(``llama._rope``'s angles), K and V repeated to the query heads, attention
-under the dense block-diffusion mask.
+"""Block-diffusion attention (``ops/bd_kernels.py``): the mask, and the flash
+kernels with their operands read where the projections wrote them
+(``mx_flash_fwd_bd``, ``mx_flash_bwd_dq_bd``, ``mx_flash_bwd_dkv_bd`` over
+``(B, 2T, H * 128)``) in the interpreter, at lane-tiling shapes kept small
+(head_dim 128, two tiles a half, so that every kind of tile is there: whole,
+partly live and dead), against the composition: heads transposed to ``(B,
+H, 2T, D)``, ``RMSNorm`` over each head of q and k, the rotation of halves
+at positions ``0 .. T-1`` of each half (``llama._rope``'s angles), K and V
+repeated to the query heads, attention under the dense block-diffusion mask
+(``bd_attention``).
 
 Tolerances.  In float32 the two are the same sums in another order: 2e-5 of
 the largest value.  In bfloat16 the composition rounds the turned queries to
-bfloat16 before its kernels read them and the in-place kernels turn a
-bfloat16 block in float32: 2e-2.
+bfloat16 before it reads them and the in-place kernels turn a bfloat16 block
+in float32: 2e-2.
 """
 import functools
 import math
@@ -27,9 +28,9 @@ import mxnet_tpu as mx
 from mxnet_tpu import amp, gluon, parallel
 from mxnet_tpu.gluon.model_zoo import sdar_moe
 from mxnet_tpu.ops import bd_kernels
-from mxnet_tpu.ops.pallas_kernels import (BlockDiffusion, _attention_ref,
-                                          _bd_query_tile, _bd_visible,
-                                          block_diffusion_mask)
+from mxnet_tpu.ops.bd_kernels import (BlockDiffusion, _bd_visible,
+                                      bd_attention, bd_tiles,
+                                      block_diffusion_mask)
 from mxnet_tpu.telemetry import metrics
 
 D, HALF, BLOCK, THETA, EPS = 128, 256, 4, 1e6, 1e-6
@@ -48,33 +49,33 @@ def _operands(h, kv, half=HALF, dtype=jnp.float32, seed=0):
 
 
 def _rotated(x, gain, n, half):
-    """``x (B, 2T, n * D)`` as ``(B, n, 2T, D)``, each head normed as
-    ``RMSNorm`` norms it and turned as ``llama._rope`` turns it at positions
-    ``0 .. T-1`` of each half, float32."""
-    b = x.shape[0]
-    x = x.astype(jnp.float32).reshape(b, 2, half, n, D) \
+    """``x (B, 2T, n * d)`` as ``(B, n, 2T, d)``, ``d`` the gain's, each
+    head normed as ``RMSNorm`` norms it and turned as ``llama._rope`` turns
+    it at positions ``0 .. T-1`` of each half, float32."""
+    b, d = x.shape[0], gain.shape[0]
+    x = x.astype(jnp.float32).reshape(b, 2, half, n, d) \
         .transpose(0, 3, 1, 2, 4)
     x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + EPS) \
         * gain
-    rate = jnp.exp(jnp.arange(D // 2, dtype=jnp.float32) * (-2.0 / D)
+    rate = jnp.exp(jnp.arange(d // 2, dtype=jnp.float32) * (-2.0 / d)
                    * math.log(THETA))
     phi = jnp.arange(half, dtype=jnp.float32)[:, None] * rate[None, :]
     c, s = jnp.cos(phi), jnp.sin(phi)
-    x1, x2 = x[..., :D // 2], x[..., D // 2:]
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1) \
-        .reshape(b, n, 2 * half, D)
+        .reshape(b, n, 2 * half, d)
 
 
-def _composition(q, k, v, q_norm, k_norm, h, kv, half=HALF):
+def _composition(q, k, v, q_norm, k_norm, h, kv, half=HALF, block=BLOCK):
     b, t2, _ = q.shape
+    d = q_norm.shape[0]
     qh = _rotated(q, q_norm, h, half).astype(q.dtype)
     kh = jnp.repeat(_rotated(k, k_norm, kv, half).astype(q.dtype), h // kv,
                     axis=1)
-    vh = jnp.repeat(v.reshape(b, t2, kv, D).transpose(0, 2, 1, 3), h // kv,
+    vh = jnp.repeat(v.reshape(b, t2, kv, d).transpose(0, 2, 1, 3), h // kv,
                     axis=1)
-    out = _attention_ref(*(x.reshape(b * h, t2, D) for x in (qh, kh, vh)),
-                         D ** -0.5, False, BlockDiffusion(half, BLOCK))
-    return out.reshape(b, h, t2, D).transpose(0, 2, 1, 3).reshape(b, t2, h * D)
+    out = bd_attention(qh, kh, vh, block_length=block)
+    return out.transpose(0, 2, 1, 3).reshape(b, t2, h * d)
 
 
 def _close(a, b, tol):
@@ -84,38 +85,125 @@ def _close(a, b, tol):
         (np.abs(a - b).max(), np.abs(b).max())
 
 
-@pytest.mark.parametrize("h, kv, dtype", [
-    (2, 2, "float32"), (4, 2, "float32"), (8, 1, "float32"),
-    (4, 2, "bfloat16")], ids=["mha", "two_a_key", "eight_a_key", "bfloat16"])
-def test_the_operator_is_the_composition(h, kv, dtype):
-    # two halves of two 128-token tiles, blocks of 4: the result and the
-    # gradient of q, k, v and the two head norms' gains, K and V read at
-    # their own heads
-    *args, w = _operands(h, kv, dtype=jnp.dtype(dtype))
+def test_the_dense_mask_is_the_three_rules():
+    # blocks of 2 over [x_0 | x_t] of 4 tokens each: a clean token sees its
+    # block and the blocks before; a noised one the clean blocks strictly
+    # before its own and its own noised block
+    m = np.asarray(block_diffusion_mask(4, 2)).astype(int)
+    assert m.tolist() == [
+        [1, 1, 0, 0, 0, 0, 0, 0],
+        [1, 1, 0, 0, 0, 0, 0, 0],
+        [1, 1, 1, 1, 0, 0, 0, 0],
+        [1, 1, 1, 1, 0, 0, 0, 0],
+        [0, 0, 0, 0, 1, 1, 0, 0],
+        [0, 0, 0, 0, 1, 1, 0, 0],
+        [1, 1, 0, 0, 0, 0, 1, 1],
+        [1, 1, 0, 0, 0, 0, 1, 1]]
+
+
+def test_the_tile_counts():
+    # the cell's shape: 2 x 4096 positions in 512-token tiles, 80 of 256
+    assert bd_tiles(4096, 4) == (256, 80)
+    assert bd_tiles(4096, 16, 128) == (4096, 32 * 34)
+    # blocks that do not divide the tile, or are no power of two, and a
+    # sequence below the kernels' 512: every tile
+    assert bd_tiles(4096, 3) == (256, 256)
+    assert bd_tiles(4096, 1024) == (256, 256)
+    assert bd_tiles(128, 4) == (1, 1)
+
+
+def _dense_mask_cases():
+    """``(h, kv, block, tile, dtype)``: GQA at blocks of 4 and 128-token
+    tiles, and one head over blocks of 4 and 16 in tiles of 128 and 512."""
+    yield from [(2, 2, 4, 128, "float32"), (4, 2, 4, 128, "float32"),
+                (8, 1, 4, 128, "float32"), (4, 2, 4, 128, "bfloat16")]
+    for dtype in ("float32", "bfloat16"):
+        for block, tile in [(4, 128), (16, 128), (4, 512), (16, 512)]:
+            yield 1, 1, block, tile, dtype
+
+
+@pytest.mark.parametrize("h, kv, block, tile, dtype",
+                         list(_dense_mask_cases()),
+                         ids=["mha", "two_a_key", "eight_a_key", "bfloat16"]
+                         + ["one_head-%d-%d-%s" % (b, t, d) for d in (
+                             "float32", "bfloat16") for b, t in [
+                             (4, 128), (16, 128), (4, 512), (16, 512)]])
+def test_the_operator_is_the_composition(h, kv, block, tile, dtype):
+    # two halves of two tiles: the result and the gradient of q, k, v and the
+    # two head norms' gains, K and V read at their own heads
+    *args, w = _operands(h, kv, half=2 * tile, dtype=jnp.dtype(dtype))
     tol = 2e-5 if dtype == "float32" else 2e-2
 
-    def value_and_grads(fn):
-        return jax.jit(jax.value_and_grad(
-            lambda *a: (fn(*a) * w).astype(jnp.float32).sum(),
-            argnums=range(5)))(*args)
-    new = functools.partial(bd_kernels._attend, heads=h, block=BLOCK,
-                            theta=THETA, eps=EPS, tile=128)
-    old = functools.partial(_composition, h=h, kv=kv)
+    def result_value_and_grads(fn):
+        def loss(*a):
+            out = fn(*a)
+            return (out * w).astype(jnp.float32).sum(), out
+        (value, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=range(5), has_aux=True))(*args)
+        return out, value, grads
+    new = functools.partial(bd_kernels._attend, heads=h, block=block,
+                            theta=THETA, eps=EPS, tile=tile)
+    old = functools.partial(_composition, h=h, kv=kv, half=2 * tile,
+                            block=block)
     text = str(jax.make_jaxpr(jax.grad(
         lambda *a: new(*a).astype(jnp.float32).sum(), argnums=range(5)))(
         *args))
     for name in ("mx_flash_fwd_bd", "mx_flash_bwd_dq_bd",
                  "mx_flash_bwd_dkv_bd"):
         assert name in text
-    got = new(*args)
+    (got, value, grads), (want_out, want_value, want) = \
+        result_value_and_grads(new), result_value_and_grads(old)
     assert got.dtype == args[0].dtype and got.shape == w.shape
-    _close(got, old(*args), tol)
-    (value, grads), (want_value, want) = value_and_grads(new), \
-        value_and_grads(old)
+    _close(got, want_out, tol)
     _close(value, want_value, tol)
     for a, g in zip(grads, want):
         assert a.dtype == g.dtype and a.shape == g.shape
         _close(a, g, tol)
+
+
+def test_no_dead_tile_is_read():
+    # NaN in every row of one tile of q, k and v: a tile of outputs (and of
+    # each gradient) that the mask does not tie to it stays finite, in the
+    # forward, dq and dk/dv kernels alike (a dead tile read would spread the
+    # NaN through a product; one inside a partly live tile through 0 * nan)
+    tile, h = 128, 2
+    n = HALF // tile
+    q, k, v, gq, gk, w = _operands(h, 1, seed=1)
+    attend = jax.jit(functools.partial(
+        bd_kernels._attend, q_norm=gq, k_norm=gk, heads=h, block=BLOCK,
+        theta=THETA, eps=EPS, tile=tile))
+    grad = jax.jit(jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v) * w),
+                            (0, 1, 2)))
+    tiles = np.arange(2 * n)
+    # tile a of the queries reads key tile b
+    live = np.zeros((2 * n, 2 * n), bool)
+    mask = np.asarray(block_diffusion_mask(HALF, BLOCK))
+    for a in tiles:
+        for b in tiles:
+            live[a, b] = mask[a * tile:(a + 1) * tile,
+                              b * tile:(b + 1) * tile].any()
+    assert live.sum() == n * (n + 2)
+
+    def rows(x, a):
+        return np.asarray(x[:, a * tile:(a + 1) * tile])
+    for planted in tiles:
+        def plant(x):
+            return x.at[:, planted * tile:(planted + 1) * tile].set(jnp.nan)
+        out = attend(q, plant(k), plant(v))
+        for a in tiles:
+            finite = np.isfinite(rows(out, a)).all()
+            assert finite == (not live[a, planted]), (planted, a)
+        dq, dk, dv = grad(plant(q), k, v)
+        # the planted query tile reaches only the key tiles it reads (dk
+        # there takes the NaN; dv does not, its probabilities being 0 where
+        # the logsumexp is not finite); no other query tile's dq is touched
+        for b in tiles:
+            finite = [np.isfinite(rows(g, b)).all() for g in (dk, dv)]
+            assert finite[0] == (not live[planted, b]), (planted, b)
+            assert finite[1] or live[planted, b], (planted, b)
+        for a in tiles:
+            if a != planted:
+                assert np.isfinite(rows(dq, a)).all()
 
 
 def test_dead_tiles_are_never_read():
@@ -160,10 +248,11 @@ def test_dead_tiles_are_never_read():
 def test_the_dkv_grid_visits_each_live_pair_once_in_the_masks_order(n, group):
     # the dk/dv kernel's grid is the list of the mask's live (key tile, query
     # head, query tile) visits: each once, key tile after key tile, a key
-    # tile's heads in turn and each head's query tiles in the order that
-    # ``_bd_query_tile`` gives (the sums of dk and dv are the composition's,
-    # term for term), zeroed at a key tile's first visit and written at its
-    # last, masked exactly where the pair is partly live, by ``_bd_visible``
+    # tile's heads in turn and each head's query tiles in ascending order
+    # (the order of the rectangle's grid, so that the sums of dk and dv are
+    # the same terms in the same order), zeroed at a key tile's first visit
+    # and written at its last, masked exactly where the pair is partly live,
+    # by ``_bd_visible``
     tile, block = 16, 4
     mask = BlockDiffusion(n * tile, block)
     visits = bd_kernels._dkv_visits(n, group)
@@ -182,8 +271,8 @@ def test_the_dkv_grid_visits_each_live_pair_once_in_the_masks_order(n, group):
         count = 1 if k >= n else 2 * (n - k)
         assert head[mine].tolist() == np.repeat(np.arange(group),
                                                 count).tolist()
-        assert qi[mine].tolist() == [int(_bd_query_tile(k, i, n))
-                                     for i in range(count)] * group
+        assert qi[mine].tolist() == [q for q in range(2 * n)
+                                     if seen[q, k].any()] * group
         first = (flags[mine] & bd_kernels._FIRST) != 0
         last = (flags[mine] & bd_kernels._LAST) != 0
         assert first.tolist() == [True] + [False] * (len(mine) - 1)
@@ -249,16 +338,16 @@ def test_the_path_is_a_static_test_of_the_shapes():
     assert tiles(4, 2, 128, 512, 3) is None
     assert fits(4, 2, 128, 256, 256, 128) is None
     assert fits(4, 2, 128, 256, 4, 96) is None
-    # a context mesh: GSPMD cannot partition a Mosaic kernel
+    # a context mesh changes nothing: there the kernels run per shard
     with jax.set_mesh(Mesh(np.array(jax.devices()[:2]), ("data",))):
-        assert tiles(32, 4, 128, 4096, 4) is None
+        assert tiles(32, 4, 128, 4096, 4) == 512
     with pytest.raises(ValueError, match="do not tile"):
         bd_kernels.bd_flash_attention(*_operands(4, 2, half=64)[:5],
                                       num_heads=4)
 
 
-def _layer(h, kv, d, units=64, layer=0, seed=3):
-    attn = sdar_moe.BDAttention(units, h, kv, d, THETA, BLOCK, layer=layer)
+def _layer(h, kv, d, units=64, layer=0, seed=3, block=BLOCK):
+    attn = sdar_moe.BDAttention(units, h, kv, d, THETA, block, layer=layer)
     attn.initialize(mx.init.Xavier())
     rng = np.random.default_rng(seed)
     for p in attn.collect_params().values():
@@ -300,7 +389,7 @@ def test_the_layer_reads_in_place_what_the_composition_copies(monkeypatch):
     out, grads = _layer_grads(attn, u, w)
     monkeypatch.setattr(bd_kernels, "tiles", lambda *a, **k: None)
     text = _ops_traced(attn, u)
-    assert "mx_flash_fwd_bd" in text and "bd_flash_attention" not in text
+    assert "mx_flash" not in text and "bd_attention" in text
     want_out, want = _layer_grads(attn, u, w)
     _close(out, want_out, 2e-5)
     assert sorted(grads) == sorted(want) and len(grads) == 7
@@ -309,14 +398,72 @@ def test_the_layer_reads_in_place_what_the_composition_copies(monkeypatch):
         _close(grads[name], want[name], 5e-5)
 
 
+@pytest.mark.parametrize("d, half, block", [(8, 512, 4), (D, 96, 4),
+                                             (D, 256, 3)],
+                         ids=["tiny_widths", "few_positions", "odd_block"])
+def test_shapes_the_kernels_do_not_take_fall_back_to_the_dense_mask(
+        d, half, block):
+    # heads that are not whole 128-lane tiles, fewer than 512 positions, and
+    # blocks that are no power of two: the layer's composition, no flash
+    # kernel, against the projections under the composition written here
+    h, kv = 4, 2
+    assert bd_kernels.tiles(h, kv, d, half, block) is None
+    attn = _layer(h, kv, d, block=block)
+    u = np.random.default_rng(17).normal(size=(1, 2 * half, 64)) \
+        .astype(np.float32)
+    text = _ops_traced(attn, u)
+    assert "mx_flash" not in text and "bd_attention" in text
+    w = {name.split("_", 1)[1]: jnp.asarray(p.data().asnumpy())
+         for name, p in attn.collect_params().items()}
+    x = jnp.asarray(u)
+    want = _composition(x @ w["q_proj"].T, x @ w["k_proj"].T,
+                        x @ w["v_proj"].T, w["q_norm"], w["k_norm"], h, kv,
+                        half=half, block=block) @ w["o_proj"].T
+    _close(attn(mx.nd.array(u)).asnumpy(), want, 2e-5)
+
+
 def test_the_tiny_widths_and_a_mesh_take_the_composition():
+    # the tiny widths take the composition; on a mesh the layer takes the
+    # block-diffusion kernels, under shard_map (GSPMD cannot partition a
+    # Mosaic kernel)
     u = np.zeros((1, 1024, 64), np.float32)
     assert "bd_flash_attention" not in _ops_traced(_layer(4, 2, 8), u)
     attn = _layer(4, 2, D)
     assert "bd_flash_attention" in _ops_traced(attn, u)
     with jax.set_mesh(Mesh(np.array(jax.devices()[:2]), ("data",))):
         text = _ops_traced(attn, np.zeros((2, 1024, 64), np.float32))
-    assert "bd_flash_attention" not in text and "mx_flash_fwd_bd" in text
+    assert "shard_map" in text and "mx_flash_fwd_bd" in text
+    assert "bd_attention" not in text.replace("bd_flash_attention", "")
+
+
+def test_on_a_mesh_the_kernels_run_per_shard():
+    # a 2 x 2 mesh: the batch over "data", the heads' lanes over "model"
+    # (a shard holds one key head and its two query heads): the result
+    # and the gradients are the kernels' over the whole arrays
+    h, kv = 4, 2
+    rng = np.random.default_rng(29)
+    q, k, v, w = (jnp.asarray(rng.normal(size=(2, 2 * HALF, n * D)),
+                              jnp.float32) for n in (h, kv, kv, h))
+    gq, gk = _operands(h, kv)[3:5]
+    static = (BLOCK, THETA, EPS, 128)
+
+    def run(attend):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: (attend(q, k, v) * w).sum(), argnums=(0, 1, 2)))
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+    with jax.set_mesh(mesh):
+        def shards(q, k, v):
+            return bd_kernels._per_shard(mesh, ("data", "model"), q, k, v,
+                                         gq, gk, h, *static)
+        text = str(jax.make_jaxpr(shards)(q, k, v))
+        got = run(shards)(q, k, v)
+    assert "shard_map" in text and "mx_flash_fwd_bd" in text
+    want = run(lambda q, k, v: bd_kernels._attend(q, k, v, gq, gk, h,
+                                                  *static))(q, k, v)
+    _close(got[0], want[0], 2e-5)
+    for g, ref in zip(got[1], want[1]):
+        assert np.abs(np.asarray(ref)).max() > 0
+        _close(g, ref, 2e-5)
 
 
 def test_under_amp_the_operator_takes_bfloat16_and_the_gains_in_float32():
@@ -391,3 +538,7 @@ def test_the_counter_counts_the_layers_read_in_place(d, kernel):
     steps = 1 * 1 * 6 if kernel else 1 * 2 * 2 ** 2
     assert int(stats["bd_dkv_steps/3"][0]) == 2 * steps
     assert _counted("mxnet_flash_dkv_steps_total") - before[2] == 2 * steps
+    # the tiles of the mask's 2 x 2 grid a head, and those computed: the
+    # three live ones in place, all four under the composition's dense mask
+    assert int(stats["flash_tiles/3"][0]) == 2 * 2 * 4
+    assert int(stats["bd_flash_tiles/3"][0]) == 2 * 2 * (3 if kernel else 4)

@@ -8,16 +8,14 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _smoke(*flags):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)   # the children need one device, not eight
+def _smoke(env, *flags):
     return subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py"), *flags],
         cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
 
 
-def test_rehearsal_runs_every_phase_and_prints_no_result_line():
-    r = _smoke("--rehearse")
+def test_rehearsal_runs_every_phase_and_prints_no_result_line(one_device_env):
+    r = _smoke(one_device_env, "--rehearse")
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     out = r.stdout
     for line in ("train: done", "train again: done", "serve export: done",
@@ -30,8 +28,8 @@ def test_rehearsal_runs_every_phase_and_prints_no_result_line():
     assert out.strip().splitlines()[-1].startswith("[chip_smoke] rehearsal")
 
 
-def test_without_the_switch_it_fails_off_the_chip():
-    r = _smoke()
+def test_without_the_switch_it_fails_off_the_chip(one_device_env):
+    r = _smoke(one_device_env)
     assert r.returncode != 0
     assert "no TPU" in r.stderr
     # the first failed phase ends the run: nothing after train started

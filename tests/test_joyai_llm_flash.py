@@ -14,6 +14,7 @@ softmax).  Under ``amp.init('bfloat16')`` every matrix product rounds its
 operands to 8 bits of mantissa: 2e-2 of the loss.
 """
 import contextlib
+import functools
 import json
 import os
 import sys
@@ -64,8 +65,16 @@ def _drawn_bias(cfg, weights, seed):
             for (name, shape), w in zip(ARCH.leaf_specs(cfg), weights)]
 
 
+@functools.lru_cache(maxsize=None)
+def _made_weights(cfg_json, seed):
+    # the reference's seeded leaves, made (and their program compiled) once
+    # a module for each configuration and seed
+    return reference.make_weights(json.loads(cfg_json), seed)
+
+
 def _net_and_weights(cfg=TINY, seed=5):
-    weights = _drawn_bias(cfg, reference.make_weights(cfg, seed), seed)
+    weights = _drawn_bias(
+        cfg, _made_weights(json.dumps(cfg, sort_keys=True), seed), seed)
     net = ARCH.build(cfg, mx.cpu())
     params = list(net.collect_params().values())
     assert [tuple(p.shape) for p in params] == \
@@ -73,6 +82,13 @@ def _net_and_weights(cfg=TINY, seed=5):
     for p, w in zip(params, weights):
         p.set_data(w)
     return net, params, weights
+
+
+def _reference_forward(cfg, weights, toks):
+    # the reference as one program: its primitives one at a time would each
+    # be compiled and dispatched alone
+    return jax.jit(lambda ws, t: reference.forward(cfg, ws, t))(
+        weights, jnp.asarray(toks))
 
 
 def _batch(cfg, b, t, seed=0):
@@ -215,7 +231,8 @@ def test_the_mla_mixer_matches_the_reference_forward_and_every_gradient(side):
     def ref(u, lw):
         return jnp.sum(ARCH._mla(TINY, lw, u, EIN) * w)
     _close(out, ARCH._mla(TINY, lw, jnp.asarray(u), EIN), 2e-4)
-    want_u, want_w = jax.grad(ref, argnums=(0, 1))(jnp.asarray(u), lw)
+    want_u, want_w = jax.jit(jax.grad(ref, argnums=(0, 1)))(
+        jnp.asarray(u), lw)
     _close(du, want_u, 2e-4)
     assert list(lw) == ["q_a", "q_a_norm", "q_b", "kv_a", "kv_a_norm",
                         "kv_b", "o"]
@@ -324,7 +341,7 @@ def test_a_planted_fault_of_the_rotation_moves_the_logits(plant):
     agree to; the program as it is agrees before and after."""
     net, _, weights = _net_and_weights()
     toks, _ = _batch(TINY, 2, 24)
-    want = np.asarray(reference.forward(TINY, weights, jnp.asarray(toks)))
+    want = np.asarray(_reference_forward(TINY, weights, toks))
     _close(net(mx.nd.array(toks, dtype="int32")).asnumpy(), want)
     with _planted(**PLANTS[plant]):
         got = net(mx.nd.array(toks, dtype="int32")).asnumpy()
@@ -337,7 +354,7 @@ def test_positions_shifted_alike_are_the_same_rotation():
     # give the same scores, so that is no fault to plant
     net, _, weights = _net_and_weights()
     toks, _ = _batch(TINY, 2, 24)
-    want = np.asarray(reference.forward(TINY, weights, jnp.asarray(toks)))
+    want = np.asarray(_reference_forward(TINY, weights, toks))
     with _planted(rope_angles=_angles_from(1)):
         _close(net(mx.nd.array(toks, dtype="int32")).asnumpy(), want)
 
@@ -444,10 +461,10 @@ def test_swiglu_experts_with_an_expert_that_gets_no_row(first):
         out = jnp.einsum("sef,edf,se->sd", hid, down, gate)
         return jnp.sum(jnp.sin(out)), out
     args = (flat, wt, lw["gate_up"], lw["down"])
-    (_, (out, counts)), got = jax.value_and_grad(
-        ours, argnums=(0, 1, 2, 3), has_aux=True)(*args)
-    (_, want_out), want = jax.value_and_grad(
-        dense, argnums=(0, 1, 2, 3), has_aux=True)(*args)
+    (_, (out, counts)), got = jax.jit(jax.value_and_grad(
+        ours, argnums=(0, 1, 2, 3), has_aux=True))(*args)
+    (_, want_out), want = jax.jit(jax.value_and_grad(
+        dense, argnums=(0, 1, 2, 3), has_aux=True))(*args)
     _close(out, want_out)
     for a, b in zip(got, want):
         _close(a, b)

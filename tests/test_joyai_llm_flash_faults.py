@@ -18,9 +18,7 @@ CHIP = os.path.join(ROOT, "benchmark", "chip")
 NEVER = 1e30        # compare.NEVER: what a missing reading counts as
 
 
-def _rehearse(*script_and_fault):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)
+def _rehearse(env, *script_and_fault):
     proc = subprocess.run(
         [sys.executable, *script_and_fault, "--workload",
          "train_joyai_p5_b1s8192", "--seed", "11", "--seconds", "1",
@@ -30,17 +28,17 @@ def _rehearse(*script_and_fault):
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
-def test_the_cell_rehearses_correct():
-    doc = _rehearse(os.path.join(CHIP, "run.py"))
+def test_the_cell_rehearses_correct(rehearsal_env):
+    doc = _rehearse(rehearsal_env, os.path.join(CHIP, "run.py"))
     assert doc["correct"] is True and doc["failed"] == 0
 
 
 @pytest.mark.parametrize("fault", ["no_query_norm", "no_kv_norm",
                                    "key_a_head", "ninth_expert",
                                    "not_normalised", "scale_nope"])
-def test_a_planted_fault_comes_out_not_correct(fault):
-    doc = _rehearse(os.path.join(CHIP, "tests", "faults_joyai_llm_flash.py"),
-                    fault)
+def test_a_planted_fault_comes_out_not_correct(fault, rehearsal_env):
+    doc = _rehearse(rehearsal_env, os.path.join(
+        CHIP, "tests", "faults_joyai_llm_flash.py"), fault)
     assert doc["correct"] is False
     assert [k for k, (v, lim) in doc["check"].items() if v > lim]
     assert all(v < NEVER for v, _ in doc["check"].values())

@@ -15,7 +15,8 @@ from mxnet_tpu import amp, autograd, gluon, parallel
 from mxnet_tpu.gluon.model_zoo import joyai_llm_flash
 
 from test_joyai_llm_flash import (ARCH, EIN, OPT, TINY, _batch, _close,
-                                  _net_and_weights, reference)
+                                  _net_and_weights, _reference_forward,
+                                  reference)
 
 WITH_MODULE = dict(TINY, num_nextn_predict_layers=1)
 
@@ -34,20 +35,25 @@ def test_logits_loss_and_every_leafs_gradient_match_the_reference(forced):
             mx.nd.reshape(logits, shape=(-1, cfg["vocab_size"])),
             mx.nd.array(labels.astype(np.float32))).mean()
     loss.backward()
-    ref_loss, ref_grads = jax.value_and_grad(
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
         lambda ws: reference.loss_fn(cfg, ws, jnp.asarray(toks),
-                                     jnp.asarray(labels)))(weights)
-    _close(logits.asnumpy(), reference.forward(cfg, weights,
-                                               jnp.asarray(toks)))
+                                     jnp.asarray(labels))))(weights)
+    _close(logits.asnumpy(), _reference_forward(cfg, weights, toks))
     _close(loss.asnumpy(), ref_loss)
     for (name, _), p, g in zip(ARCH.leaf_specs(cfg), params, ref_grads):
         _close(p.grad().asnumpy(), g)
         if name.endswith("router_bias"):
             assert not np.asarray(g).any() and not p.grad().asnumpy().any()
     if forced:
-        free = reference.forward(TINY, weights, jnp.asarray(toks))
+        free = _reference_forward(TINY, weights, toks)
         assert np.abs(np.asarray(free) - logits.asnumpy()).max() > 1e-3
 
+
+def _reference_forward(cfg, weights, toks):
+    # the reference as one program: its primitives one at a time would each
+    # be compiled and dispatched alone
+    return jax.jit(lambda ws, t: reference.forward(cfg, ws, t))(
+        weights, jnp.asarray(toks))
 
 def _lm(net, vocab):
     class LM(gluon.HybridBlock):
@@ -217,17 +223,17 @@ def test_the_embedding_and_the_head_take_the_sum_of_their_two_uses():
             + 0.3 * ARCH._cross_entropy(ahead, lab[:, 1:])
     _close(loss.asnumpy(), parts(w["embed"], w["embed"], w["head"],
                                  w["head"]))
-    d_trunk, d_ahead, d_main, d_module = jax.grad(
-        parts, argnums=(0, 1, 2, 3))(w["embed"], w["embed"], w["head"],
-                                     w["head"])
+    d_trunk, d_ahead, d_main, d_module = jax.jit(jax.grad(
+        parts, argnums=(0, 1, 2, 3)))(w["embed"], w["embed"], w["head"],
+                                      w["head"])
     got = dict(zip(names, (p.grad().asnumpy() for p in params)))
     for use in (d_trunk, d_ahead, d_main, d_module):
         assert np.abs(np.asarray(use)).max() > 1e-4     # each use counts
     _close(got["embed"], d_trunk + d_ahead, 1e-5)
     _close(got["head"], d_main + d_module, 1e-5)
     # and every leaf's gradient is the reference's
-    ref = jax.grad(lambda ws: ARCH.mtp_loss(cfg, ws, toks_j, lab, EIN))(
-        weights)
+    ref = jax.jit(jax.grad(
+        lambda ws: ARCH.mtp_loss(cfg, ws, toks_j, lab, EIN)))(weights)
     for name, g in zip(names, ref):
         _close(got[name], g)
 

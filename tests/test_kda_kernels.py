@@ -262,12 +262,24 @@ def _recurrent(*args):
         return _composed(*args)
 
 
+@functools.lru_cache(maxsize=None)
+def _mixer_program(fn, dot):
+    """``fn``'s result, and the value and the gradient of a loss over it,
+    as one program for each form of the mixer and rule of the products
+    (``kda_kernels._dot``): the cases of one shape compile it once."""
+    def loss(w, *a):
+        out = fn(*a)
+        return jnp.sum(jnp.sin(out) * w), out
+    return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(1, 13)),
+                                      has_aux=True))
+
+
 def _mixer_grads(fn, args):
+    """``(value, gradients, result)``."""
     w = jnp.asarray(np.random.default_rng(1).normal(
         size=args[0].shape[:2] + (args[2].shape[-1],)), jnp.float32)
-    return jax.jit(jax.value_and_grad(
-        lambda *a: jnp.sum(jnp.sin(fn(*a)) * w),
-        argnums=range(len(args))))(*args)
+    (value, out), grads = _mixer_program(fn, kda_kernels._dot)(w, *args)
+    return value, grads, out
 
 
 @pytest.mark.parametrize("b, t, decay", [
@@ -289,10 +301,10 @@ def test_the_fused_mixer_is_the_composition_and_the_recurrence(
     ``ops/kda.py`` wrote -5.8)."""
     args = _mixer_inputs(b, t, decay=decay)
     assert "mx_kda_fwd" in str(jax.make_jaxpr(_fused)(*args))
-    value, grads = _mixer_grads(_fused, args)
+    value, grads, out = _mixer_grads(_fused, args)
     assert bool(jnp.isfinite(value))
     for other in (_composed, _recurrent):
-        want_value, want = _mixer_grads(other, args)
+        want_value, want, want_out = _mixer_grads(other, args)
         _close(value, want_value, 1e-4)
         for name, got, expect in zip(_MIXER_ARGS, grads, want):
             assert got.shape == expect.shape, name
@@ -300,15 +312,15 @@ def test_the_fused_mixer_is_the_composition_and_the_recurrence(
             # cancel, as near the recurrence in the composition
             _close(got, expect, 1e-3 if name in ("A_log", "dt_bias")
                    else 1e-4)
-    _close(_fused(*args), _recurrent(*args), 1e-4)
+    _close(out, want_out, 1e-4)      # the recurrence's
 
 
 def test_the_fused_mixer_with_bfloat16_products_is_the_composition():
     # the kernels as the chip runs them: the same rounded products as the
     # composition's scan, and the prologue and epilogue in float32
     args = _mixer_inputs(2, 100, h=16, seed=4)
-    value, grads = _mixer_grads(_fused, args)
-    want_value, want = _mixer_grads(_composed, args)
+    value, grads, _ = _mixer_grads(_fused, args)
+    want_value, want, _ = _mixer_grads(_composed, args)
     _close(value, want_value, 2e-3)
     for got, expect in zip(grads, want):
         _close(got, expect, 2e-3)

@@ -13,6 +13,7 @@ against dense experts): 1e-4 of the largest value.  Under
 ``amp.init('bfloat16')`` every matrix product rounds its operands to 8 bits
 of mantissa: 2e-2 of the loss.
 """
+import functools
 import json
 import os
 import sys
@@ -73,8 +74,16 @@ def _published_ranges(cfg, weights, seed):
     return out
 
 
+@functools.lru_cache(maxsize=None)
+def _made_weights(cfg_json, seed):
+    # the reference's seeded leaves, made (and their program compiled) once
+    # a module for each configuration and seed
+    return reference.make_weights(json.loads(cfg_json), seed)
+
+
 def _net_and_weights(cfg=TINY, seed=5):
-    weights = _published_ranges(cfg, reference.make_weights(cfg, seed), seed)
+    weights = _published_ranges(
+        cfg, _made_weights(json.dumps(cfg, sort_keys=True), seed), seed)
     net = ARCH.build(cfg, mx.cpu())
     params = list(net.collect_params().values())
     assert [tuple(p.shape) for p in params] == \
@@ -114,10 +123,13 @@ def _scan_against(other, t, chunk, **kw):
     w = jnp.asarray(np.random.default_rng(1).normal(size=args[2].shape),
                     jnp.float32)
     _close(kda.kda_scan(*args, chunk=chunk), other(*args))
-    got = jax.grad(lambda *a: jnp.sum(kda.kda_scan(*a, chunk=chunk) * w),
-                   argnums=range(5))(*args)
-    want = jax.grad(lambda *a: jnp.sum(other(*a) * w),
-                    argnums=range(5))(*args)
+    # each gradient one program (one primitive at a time, each is compiled
+    # and dispatched alone)
+    got = jax.jit(jax.grad(
+        lambda *a: jnp.sum(kda.kda_scan(*a, chunk=chunk) * w),
+        argnums=range(5)))(*args)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(other(*a) * w),
+                            argnums=range(5)))(*args)
     for a, b in zip(got, want):
         assert a.shape == b.shape
         _close(a, b)
@@ -148,10 +160,11 @@ def test_the_published_decay_range_overflows_nothing(chunk):
         * jnp.ones_like(args[3])
     out = kda.kda_scan(*args, chunk=chunk)
     _close(out, kda.kda_recurrence(*args))
-    grads = jax.grad(lambda *a: jnp.sum(jnp.sin(kda.kda_scan(
-        *a, chunk=chunk))), argnums=range(5))(*args)
-    want = jax.grad(lambda *a: jnp.sum(jnp.sin(kda.kda_recurrence(*a))),
-                    argnums=range(5))(*args)
+    grads = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(kda.kda_scan(
+        *a, chunk=chunk))), argnums=range(5)))(*args)
+    want = jax.jit(jax.grad(
+        lambda *a: jnp.sum(jnp.sin(kda.kda_recurrence(*a))),
+        argnums=range(5)))(*args)
     for a, b in zip(grads, want):
         _close(a, b)
 
@@ -204,9 +217,9 @@ def test_heads_in_groups_give_what_all_heads_at_once_give(monkeypatch):
 
     def value_and_grads(at_once):
         monkeypatch.setattr(kda, "HEADS_AT_ONCE", at_once)
-        return jax.value_and_grad(
+        return jax.jit(jax.value_and_grad(
             lambda *a: jnp.sum(jnp.sin(kda.kda_attention(*a, chunk=16))),
-            argnums=range(12))(*args)
+            argnums=range(12)))(*args)
     whole, grads = value_and_grads(4)
     for at_once in (2, 3, 1):
         value, other = value_and_grads(at_once)
@@ -271,10 +284,10 @@ def test_attention_with_values_narrower_than_keys(blocks):
     out = ours(q, k, v)
     assert out.shape == (2, 3, 32, 8)
     _close(out, _plain_attention(q, k, v, 12 ** -0.5), 2e-4)
-    got = jax.grad(lambda *a: jnp.sum(ours(*a) * w), argnums=(0, 1, 2))(
-        q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(_plain_attention(
-        *a, 12 ** -0.5) * w), argnums=(0, 1, 2))(q, k, v)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(ours(*a) * w),
+                           argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(_plain_attention(
+        *a, 12 ** -0.5) * w), argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(got, want):
         assert a.shape == b.shape
         _close(a, b, 2e-4)
@@ -287,7 +300,8 @@ def _one_block(cls_args, kind, seed=3):
                    TINY["linear_attn_config"],
                    kda_layers=[1] if kind == "kda" else [],
                    full_attn_layers=[1] if kind == "mla" else []))
-    weights = _published_ranges(cfg, reference.make_weights(cfg, seed), seed)
+    weights = _published_ranges(
+        cfg, _made_weights(json.dumps(cfg, sort_keys=True), seed), seed)
     w = dict(zip([n for n, _ in ARCH.leaf_specs(cfg)], weights))
     return cfg, {k[len("layer1.mixer."):]: v for k, v in w.items()
                  if k.startswith("layer1.mixer.")}
@@ -367,9 +381,10 @@ def test_swiglu_experts_match_the_dense_experts(load):
             x, idx, jnp.where(mine, w, 0.0), gate_up, down)))
     value, counts = ours(x, w, gate_up, down)
     _close(value, dense(x, w, gate_up, down))
-    got = jax.grad(ours, argnums=(0, 1, 2, 3), has_aux=True)(
+    got = jax.jit(jax.grad(ours, argnums=(0, 1, 2, 3), has_aux=True))(
         x, w, gate_up, down)[0]
-    want = jax.grad(dense, argnums=(0, 1, 2, 3))(x, w, gate_up, down)
+    want = jax.jit(jax.grad(dense, argnums=(0, 1, 2, 3)))(
+        x, w, gate_up, down)
     for a, b in zip(got, want):
         assert a.shape == b.shape and a.dtype == b.dtype
         _close(a, b)

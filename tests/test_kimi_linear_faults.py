@@ -20,14 +20,13 @@ NEVER = 1e30        # compare.NEVER: what a missing reading counts as
 
 @pytest.mark.parametrize("fault", ["no_carry", "decay_per_head", "no_beta",
                                    "ninth_expert", "not_normalised"])
-def test_a_planted_fault_comes_out_not_correct(fault):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)
+def test_a_planted_fault_comes_out_not_correct(fault, rehearsal_env):
     proc = subprocess.run(
         [sys.executable, os.path.join(CHIP, "tests", "faults_kimi_linear.py"),
          fault, "--workload", "train_kimilinear_p5_b1s4096", "--seed", "11",
          "--seconds", "1", "--trace", "0", "--rehearse"],
-        cwd=ROOT, env=env, capture_output=True, text=True, timeout=900)
+        cwd=ROOT, env=rehearsal_env, capture_output=True, text=True,
+        timeout=900)
     assert proc.returncode == 0, proc.stderr[-3000:]
     doc = json.loads(proc.stdout.strip().splitlines()[-1])
     assert doc["correct"] is False

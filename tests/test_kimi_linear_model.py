@@ -23,6 +23,12 @@ from test_kimi_linear import (ARCH, CHIP, OPT, TINY, _batch, _close,
 
 # -- the whole model against the reference ----------------------------------------------
 
+def _reference_forward(cfg, weights, toks):
+    # the reference as one program: its primitives one at a time would each
+    # be compiled and dispatched alone
+    return jax.jit(lambda ws, t: reference.forward(cfg, ws, t))(
+        weights, jnp.asarray(toks))
+
 def _loss_and_grads(cfg, net, params, weights, toks, labels):
     loss_block = gluon.loss.SoftmaxCrossEntropyLoss()
     with autograd.record():
@@ -31,11 +37,10 @@ def _loss_and_grads(cfg, net, params, weights, toks, labels):
             mx.nd.reshape(logits, shape=(-1, cfg["vocab_size"])),
             mx.nd.array(labels.astype(np.float32))).mean()
     loss.backward()
-    ref_loss, ref_grads = jax.value_and_grad(
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
         lambda ws: reference.loss_fn(cfg, ws, jnp.asarray(toks),
-                                     jnp.asarray(labels)))(weights)
-    _close(logits.asnumpy(), reference.forward(cfg, weights,
-                                               jnp.asarray(toks)))
+                                     jnp.asarray(labels))))(weights)
+    _close(logits.asnumpy(), _reference_forward(cfg, weights, toks))
     _close(loss.asnumpy(), ref_loss)
     for (name, _), p, g in zip(ARCH.leaf_specs(cfg), params, ref_grads):
         _close(p.grad().asnumpy(), g)
@@ -51,7 +56,7 @@ def test_logits_loss_and_every_leafs_gradient_match_the_reference(forced):
     toks, labels = _batch(cfg, 2, 37)         # 2.3 chunks of 16
     logits = _loss_and_grads(cfg, net, params, weights, toks, labels)
     if forced:
-        free = reference.forward(TINY, weights, jnp.asarray(toks))
+        free = _reference_forward(TINY, weights, toks)
         assert np.abs(np.asarray(free) - logits).max() > 1e-3
 
 
@@ -173,7 +178,7 @@ def test_a_planted_rotary_embedding_shows_in_the_logits():
     forward = reg.forward
     net, _, weights = _net_and_weights()
     toks, _ = _batch(TINY, 2, 24)
-    want = reference.forward(TINY, weights, jnp.asarray(toks))
+    want = _reference_forward(TINY, weights, toks)
     try:
         faults_kimi_linear.plant("rotary")
         got = net(mx.nd.array(toks, dtype="int32")).asnumpy()
@@ -183,4 +188,4 @@ def test_a_planted_rotary_embedding_shows_in_the_logits():
         mx.ops.registry._jitted.cache_clear()
     assert np.abs(got - np.asarray(want)).max() > 1e-2 * np.abs(want).max()
     _close(net(mx.nd.array(toks[:, :20], dtype="int32")).asnumpy(),
-           reference.forward(TINY, weights, jnp.asarray(toks[:, :20])))
+           _reference_forward(TINY, weights, toks[:, :20]))

@@ -63,7 +63,10 @@ def test_sharded_parity_and_errors():
     x, rw, wi, wo = _inputs()
     y_ref, aux_ref = parallel.moe_ffn(x, rw, wi, wo)
     mesh = Mesh(np.array(jax.devices()[:4]), ("expert",))
-    y_sh, aux_sh = parallel.moe_ffn_sharded(x, rw, wi, wo, mesh)
+    # the sharded layer as one program: a bare shard_map called eagerly runs
+    # its body one operation at a time on every device
+    sharded = jax.jit(lambda *a: parallel.moe_ffn_sharded(*a, mesh))
+    y_sh, aux_sh = sharded(x, rw, wi, wo)
     np.testing.assert_allclose(np.asarray(y_sh), np.asarray(y_ref),
                                rtol=1e-5, atol=1e-6)
     assert abs(float(aux_sh) - float(aux_ref)) < 1e-6
@@ -73,11 +76,11 @@ def test_sharded_parity_and_errors():
         return jnp.sum(y * y) + 0.01 * aux
 
     def loss_sharded(wi_, wo_, rw_):
-        y, aux = parallel.moe_ffn_sharded(x, rw_, wi_, wo_, mesh)
+        y, aux = sharded(x, rw_, wi_, wo_)
         return jnp.sum(y * y) + 0.01 * aux
 
     gd = jax.grad(loss_dense, argnums=(0, 1, 2))(wi, wo, rw)
-    gs = jax.grad(loss_sharded, argnums=(0, 1, 2))(wi, wo, rw)
+    gs = jax.jit(jax.grad(loss_sharded, argnums=(0, 1, 2)))(wi, wo, rw)
     for a, b in zip(gd, gs):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                    rtol=1e-5, atol=1e-6)

@@ -68,8 +68,16 @@ def _published_ranges(cfg, weights, seed):
     return out
 
 
+@functools.lru_cache(maxsize=None)
+def _made_weights(cfg_json, seed):
+    # the reference's seeded leaves, made (and their program compiled) once
+    # a module for each configuration and seed
+    return reference.make_weights(json.loads(cfg_json), seed)
+
+
 def _net_and_weights(cfg=TINY, seed=5):
-    weights = _published_ranges(cfg, reference.make_weights(cfg, seed), seed)
+    weights = _published_ranges(
+        cfg, _made_weights(json.dumps(cfg, sort_keys=True), seed), seed)
     net = ARCH.build(cfg, mx.cpu())
     params = list(net.collect_params().values())
     assert [tuple(p.shape) for p in params] == \
@@ -78,6 +86,12 @@ def _net_and_weights(cfg=TINY, seed=5):
         p.set_data(w)
     return net, params, weights
 
+
+def _reference_forward(cfg, weights, toks):
+    # the reference as one program: its primitives one at a time would each
+    # be compiled and dispatched alone
+    return jax.jit(lambda ws, t: reference.forward(cfg, ws, t))(
+        weights, jnp.asarray(toks))
 
 def _batch(cfg, b, t, seed=0):
     rng = np.random.default_rng(seed)
@@ -101,10 +115,10 @@ def test_logits_loss_and_every_leafs_gradient_match_the_reference():
         loss = loss_block(mx.nd.reshape(logits, shape=(-1, 64)),
                           mx.nd.array(labels.astype(np.float32))).mean()
     loss.backward()
-    ref_logits = reference.forward(TINY, weights, jnp.asarray(toks))
-    ref_loss, ref_grads = jax.value_and_grad(
+    ref_logits = _reference_forward(TINY, weights, toks)
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
         lambda ws: reference.loss_fn(TINY, ws, jnp.asarray(toks),
-                                     jnp.asarray(labels)))(weights)
+                                     jnp.asarray(labels))))(weights)
     _close(logits.asnumpy(), ref_logits)
     _close(loss.asnumpy(), ref_loss)
     for (name, _), p, g in zip(ARCH.leaf_specs(TINY), params, ref_grads):
@@ -259,10 +273,13 @@ def test_chunked_scan_matches_the_token_by_token_recurrence(chunks):
 
         def loss(fn):
             return lambda *a: jnp.sum(jnp.sin(fn(*a)))
-        got = jax.grad(loss(functools.partial(ssm.ssd_scan, chunk=chunk)),
-                       argnums=tuple(range(7)))(*args)
-        ref = jax.grad(loss(ssm.ssd_recurrence),
-                       argnums=tuple(range(7)))(*args)
+        # each gradient one program (one primitive at a time, each is
+        # compiled and dispatched alone)
+        got = jax.jit(jax.grad(
+            loss(functools.partial(ssm.ssd_scan, chunk=chunk)),
+            argnums=tuple(range(7))))(*args)
+        ref = jax.jit(jax.grad(loss(ssm.ssd_recurrence),
+                               argnums=tuple(range(7))))(*args)
     for a, b in zip(got, ref):
         _close(a, b, 1e-5)
 
@@ -552,15 +569,14 @@ def test_the_model_under_a_forced_balance_matches_the_reference():
         loss = loss_block(mx.nd.reshape(logits, shape=(-1, 64)),
                           mx.nd.array(labels.astype(np.float32))).mean()
     loss.backward()
-    ref_loss, ref_grads = jax.value_and_grad(
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
         lambda ws: reference.loss_fn(cfg, ws, jnp.asarray(toks),
-                                     jnp.asarray(labels)))(weights)
-    _close(logits.asnumpy(), reference.forward(cfg, weights,
-                                               jnp.asarray(toks)))
+                                     jnp.asarray(labels))))(weights)
+    _close(logits.asnumpy(), _reference_forward(cfg, weights, toks))
     _close(loss.asnumpy(), ref_loss)
     for p, g in zip(params, ref_grads):
         _close(p.grad().asnumpy(), g)
-    free = reference.forward(TINY, weights, jnp.asarray(toks))
+    free = _reference_forward(TINY, weights, toks)
     assert np.abs(np.asarray(free) - logits.asnumpy()).max() > 1e-3
 
 
@@ -675,17 +691,16 @@ def test_flops_a_token_follow_the_stated_rule():
 
 @pytest.mark.parametrize("fault", ["no_carry", "seventh_expert",
                                    "not_normalised", "dropped"])
-def test_a_planted_fault_comes_out_not_correct(fault):
+def test_a_planted_fault_comes_out_not_correct(fault, rehearsal_env):
     """Through the benchmark's own rehearsal of the cell at its tiny size
     (``run.py`` -> driver -> ``compare.train_numbers`` -> the cell's
     ``tiny.limits``), the fault planted under it in the program."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
         [sys.executable, os.path.join(CHIP, "tests", "faults_nemotron_h.py"),
          fault, "--workload", CELL, "--seed", "11", "--seconds", "1",
          "--trace", "0", "--rehearse"],
-        cwd=ROOT, env=env, capture_output=True, text=True, timeout=900)
+        cwd=ROOT, env=rehearsal_env, capture_output=True, text=True,
+        timeout=900)
     assert proc.returncode == 0, proc.stderr[-3000:]
     doc = json.loads(proc.stdout.strip().splitlines()[-1])
     assert doc["correct"] is False

@@ -809,6 +809,33 @@ SPECS["_contrib_mla_flash_attention"] = S(
     ref=_mla_flash_ref, rtol=1e-3, atol=1e-4)
 
 
+def _bd_seen(t2, block):
+    """Which key each query of ``[x_0 | x_t]`` sees under the
+    block-diffusion mask: a clean query its block and the clean blocks
+    before, a noised one the clean blocks strictly before its own and its
+    own noised block."""
+    half = t2 // 2
+    i = np.arange(t2)
+    noised, blk = i >= half, (i % half) // block
+    return np.where(noised[None, :], noised[:, None]
+                    & (blk[None, :] == blk[:, None]),
+                    np.where(noised[:, None], blk[None, :] < blk[:, None],
+                             blk[None, :] <= blk[:, None]))
+
+
+def _bd_attention_ref(q, k, v):
+    s = np.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    s = np.where(_bd_seen(q.shape[2], 2), s, -np.inf)
+    return np.einsum("bhqk,bhkd->bhqd", _softmax_ref(s), v)
+
+
+SPECS["_contrib_bd_attention"] = S(
+    [randn((1, 2, 16, 4), 187), randn((1, 2, 16, 4), 188),
+     randn((1, 2, 16, 4), 189)],
+    {"block_length": 2},
+    ref=_bd_attention_ref, grad=True, rtol=1e-3, atol=1e-4)
+
+
 def _bd_flash_ref(q, k, v, q_norm, k_norm):
     """Two query heads over one key head of 128 channels, ``[x_0 | x_t]`` of
     512 positions each, blocks of 4: every head of q and k normed (eps
@@ -826,12 +853,7 @@ def _bd_flash_ref(q, k, v, q_norm, k_norm):
         return np.concatenate([a * np.cos(phi) - b * np.sin(phi),
                                b * np.cos(phi) + a * np.sin(phi)], -1)
     qs, ks = normed_turned(q[0], q_norm), normed_turned(k[0], k_norm)[:, 0]
-    i = np.arange(t2)
-    noised, blk = i >= half, (i % half) // 4
-    seen = np.where(noised[None, :], noised[:, None]
-                    & (blk[None, :] == blk[:, None]),
-                    np.where(noised[:, None], blk[None, :] < blk[:, None],
-                             blk[None, :] <= blk[:, None]))
+    seen = _bd_seen(t2, 4)
     out = np.empty((t2, h, d))
     for j in range(h):
         s = qs[:, j] @ ks.T * d ** -0.5

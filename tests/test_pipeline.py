@@ -55,7 +55,9 @@ def test_gpipe_backward_matches_sequential(mesh4):
 
     loss_pipe = parallel.gpipe_loss_fn(
         _stage, lambda o, t: jnp.mean((o - t) ** 2), mesh4)
-    gp = jax.grad(loss_pipe)( (w, b), x, y)
+    # one program: a bare shard_map differentiated eagerly runs its body
+    # one operation at a time on every device
+    gp = jax.jit(jax.grad(loss_pipe))((w, b), x, y)
 
     def loss_seq(params, x, y):
         wv, bv = params

@@ -1,7 +1,8 @@
 """SDAR-MoE trained as a block-diffusion model: the program
 (``gluon.model_zoo.sdar_moe``: the noising in the program, the ``[x_0 |
-x_t]`` pass through the flash kernels under the block-diffusion mask,
-qk-norm, the rotation of halves, Qwen3-MoE's softmax routing over
+x_t]`` pass under the block-diffusion mask (at these widths the layer's
+composition, plain attention under the dense mask), qk-norm, the rotation
+of halves, Qwen3-MoE's softmax routing over
 ``grouped_ffn(activation="swiglu")``) against the benchmark's plain
 reference (``benchmark/chip/archs/sdar_moe.py``) on seeded weights at tiny
 widths, on the CPU: logits, loss and every gradient with the router free and
@@ -10,11 +11,12 @@ against a plain reference of the masked ``1/t`` objective, the noise, the
 share of the experts, ``router_topk(scoring="softmax")`` and the counters.
 
 Tolerances.  Without AMP both sides are float32 and differ only in the
-order of their sums (the flash kernels' online softmax against whole rows,
-grouped against dense experts): 1e-4 of the largest value.  Under
-``amp.init('bfloat16')`` every matrix product rounds its operands to 8
-bits of mantissa: 2e-2 of the loss.
+order of their sums (grouped against dense experts): 1e-4 of the largest
+value.  Under ``amp.init('bfloat16')`` every matrix product rounds its
+operands to 8 bits of mantissa: 2e-2 of the loss.
 """
+import functools
+import json
 import os
 import sys
 
@@ -57,8 +59,15 @@ def _close(ours, ref, tol=1e-4):
     assert np.abs(ours - ref).max() <= tol * max(1.0, np.abs(ref).max())
 
 
+@functools.lru_cache(maxsize=None)
+def _made_weights(cfg_json, seed):
+    # the reference's seeded leaves, made (and their program compiled) once
+    # a module for each configuration and seed
+    return reference.make_weights(json.loads(cfg_json), seed)
+
+
 def _net_and_weights(cfg=TINY, seed=5):
-    weights = reference.make_weights(cfg, seed)
+    weights = _made_weights(json.dumps(cfg, sort_keys=True), seed)
     net = ARCH.build(cfg, mx.cpu())
     params = list(net.collect_params().values())
     assert [tuple(p.shape) for p in params] == \
@@ -66,6 +75,11 @@ def _net_and_weights(cfg=TINY, seed=5):
     for p, w in zip(params, weights):
         p.set_data(w)
     return net, params, weights
+
+
+def _reference_forward(cfg, weights, toks):
+    return jax.jit(lambda ws, t: reference.forward(cfg, ws, t))(
+        weights, jnp.asarray(toks))
 
 
 def _batch(cfg, b, t, seed=0):
@@ -89,18 +103,19 @@ def test_logits_loss_and_every_leafs_gradient_match_the_reference(forced):
             mx.nd.reshape(logits, shape=(-1, cfg["vocab_size"])),
             mx.nd.array(labels.astype(np.float32))).mean()
     loss.backward()
-    ref_loss, ref_grads = jax.value_and_grad(
+    # the reference as whole programs: one at a time, its primitives would
+    # each be compiled and dispatched alone
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
         lambda ws: reference.loss_fn(cfg, ws, jnp.asarray(toks),
-                                     jnp.asarray(labels)))(weights)
+                                     jnp.asarray(labels))))(weights)
     assert logits.shape == (2, 24, 64)
-    _close(logits.asnumpy(), reference.forward(cfg, weights,
-                                               jnp.asarray(toks)))
+    _close(logits.asnumpy(), _reference_forward(cfg, weights, toks))
     _close(loss.asnumpy(), ref_loss)
     for (name, _), p, g in zip(ARCH.leaf_specs(cfg), params, ref_grads):
         assert np.abs(np.asarray(g)).max() > 0, name    # every leaf counts
         _close(p.grad().asnumpy(), g)
     if forced:
-        free = reference.forward(TINY, weights, jnp.asarray(toks))
+        free = _reference_forward(TINY, weights, toks)
         assert np.abs(np.asarray(free) - logits.asnumpy()).max() > 1e-3
 
 
@@ -217,7 +232,7 @@ def test_trains_under_amp_with_the_attention_in_bfloat16():
     assert np.allclose(losses, ref_losses, rtol=2e-2)
     f32, bf16 = jnp.dtype("float32"), jnp.dtype("bfloat16")
     assert all(d == f32 for d in seen["_contrib_moe_router_topk"])
-    assert seen["_contrib_flash_attention"] == [bf16] * 3
+    assert seen["_contrib_bd_attention"] == [bf16] * 3
     assert all(d == f32 for d in seen["RMSNorm"])
     data, idx, weight, up, down = seen["_contrib_moe_grouped_ffn"]
     assert (data, up, down) == (bf16, bf16, bf16) and weight == f32
@@ -228,8 +243,7 @@ def test_trains_under_amp_with_the_attention_in_bfloat16():
 def _plain_bd_loss(cfg, weights, toks):
     """The masked 1/t objective written out by hand from the reference's
     logits and noise."""
-    logits = np.asarray(reference.forward(cfg, weights, jnp.asarray(toks)),
-                        np.float64)
+    logits = np.asarray(_reference_forward(cfg, weights, toks), np.float64)
     _, masked, level = (np.asarray(x) for x in ARCH.noise(
         cfg, jnp.asarray(toks)))
     logp = logits - np.log(np.exp(logits - logits.max(-1, keepdims=True))
@@ -253,9 +267,9 @@ def test_the_block_diffusion_loss_is_the_masked_one_over_t_objective():
     loss.backward()
     want = _plain_bd_loss(TINY, weights, toks)
     _close(loss.asnumpy(), want)
-    _close(ARCH.bd_loss(TINY, weights, jnp.asarray(toks), EIN), want)
-    grads = jax.grad(lambda ws: ARCH.bd_loss(TINY, ws, jnp.asarray(toks),
-                                             EIN))(weights)
+    ref_loss, grads = jax.jit(jax.value_and_grad(
+        lambda ws: ARCH.bd_loss(TINY, ws, jnp.asarray(toks), EIN)))(weights)
+    _close(ref_loss, want)
     for p, g in zip(params, grads):
         _close(p.grad().asnumpy(), g)
     # trains through the step that has no loss of its own

@@ -131,6 +131,16 @@ def test_an_exception_inside_a_phase_closes_the_span(tmp_path):
 
 # -- the sites in JitTrainStep ------------------------------------------------------
 
+def _run_delay_ns():
+    """Nanoseconds this thread has so far waited for a CPU while it could
+    run (the kernel's schedstat), or 0 where the kernel does not say."""
+    try:
+        with open("/proc/thread-self/schedstat") as f:
+            return int(f.read().split()[1])
+    except (OSError, IndexError, ValueError):
+        return 0
+
+
 @pytest.mark.parametrize("mesh", [None, {"data": 2, "model": 2}],
                          ids=["one_device", "mesh_2x2"])
 def test_three_steps_give_three_spans_of_four_phases(tmp_path, mesh):
@@ -138,18 +148,26 @@ def test_three_steps_give_three_spans_of_four_phases(tmp_path, mesh):
     x, y = _batch()
     float(step.step(x, y))       # compiled outside the trace
 
+    waited = []
+
     def work():
         for _ in range(3):
+            before = _run_delay_ns()
             step.step(x, y)
+            waited.append(_run_delay_ns() - before)
         float(step._last_loss)
     trace = _traced(tmp_path, work)
     steps = span_reduce.steps(trace)
     assert len(steps) == 3
-    for st in steps:
+    for st, wait in zip(steps, waited):
         assert [p[0] for p in st["phases"]] == PHASES     # once, in order
         ends = [s + d for _, s, d in st["phases"]]
         assert all(e <= p[1] for e, p in zip(ends, st["phases"][1:]))
-        assert span_reduce.coverage(st) >= 0.95
+        # the phases cover the step's work: what they leave is at most 5%
+        # of it and the time the thread waited for a CPU (a loaded machine
+        # takes a thread off for a timeslice, a few ms, where it may be)
+        assert (1 - span_reduce.coverage(st)) * st["duration"] \
+            <= 0.05 * st["duration"] + wait, (st, wait)
     assert all(a["start"] + a["duration"] <= b["start"]
                for a, b in zip(steps, steps[1:]))
     # a CPU trace has no device operation: every reader stays silent

@@ -148,11 +148,11 @@ def test_bfloat16_operands_are_read_and_written_as_they_are():
     def f(*a):
         return jnp.sum(jnp.sin(ssm.ssd_scan(*a).astype(jnp.float32)))
     y = ssm.ssd_scan(*low)
-    grads = jax.grad(f, argnums=range(7))(*low)
+    grads = jax.jit(jax.grad(f, argnums=range(7)))(*low)
     assert y.dtype == jnp.bfloat16
     assert [g.dtype for g in grads] == [a.dtype for a in low]
     _close(y.astype(jnp.float32), ssm.ssd_scan(*args), 3e-2)
-    for a, b in zip(grads, jax.grad(f, argnums=range(7))(*args)):
+    for a, b in zip(grads, jax.jit(jax.grad(f, argnums=range(7)))(*args)):
         _close(a.astype(jnp.float32), b, 5e-2)
 
 

@@ -140,6 +140,37 @@ def test_flash_on_a_mesh_runs_per_shard(topo):
     assert "bf16[32,512,128]" in text and "all-gather" not in text
 
 
+def test_the_bd_kernels_on_a_mesh_run_per_shard(topo):
+    # the block-diffusion kernels traced under a 2 x 2 mesh run inside
+    # shard_map: the batch over `data`, the heads' lanes over `model` (8 / 2
+    # heads of 128: 4 / 1 a shard), 2 x 1024 positions in 512-token tiles
+    from mxnet_tpu.ops import bd_kernels
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    rows = NamedSharding(mesh, P("data", None, "model"))
+    gains = NamedSharding(mesh, P())
+
+    def loss(q, k, v, gq, gk, w):
+        out = bd_kernels.bd_flash_attention(q, k, v, gq, gk, num_heads=8,
+                                            block_length=4, rope_theta=1e6)
+        return (out * w).astype(jnp.float32).sum()
+    bf16 = jnp.bfloat16
+    shapes = [((2, 2048, 8 * 128), bf16, rows),
+              *[((2, 2048, 2 * 128), bf16, rows)] * 2,
+              *[((128,), jnp.float32, gains)] * 2,
+              ((2, 2048, 8 * 128), bf16, rows)]
+    with jax.set_mesh(mesh):
+        c = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            *[jax.ShapeDtypeStruct(s, d, sharding=sh)
+              for s, d, sh in shapes]).compile()
+    assert _kernels(c) == ["mx_flash_bwd_dkv_bd", "mx_flash_bwd_dq_bd",
+                           "mx_flash_fwd_bd"]
+    # each device runs the kernels on its own batch row and key head
+    text = c.as_text()
+    assert re.search(r"%mx_flash_bwd_dkv_bd\S* = \(bf16\[1,2048,128\]", text)
+    assert "all-gather" not in text
+
+
 def _mlp_train_step(ws, ms, x):
     # three residual MLP blocks as JitTrainStep runs a decoder's: float32
     # master weights, bfloat16 products, a momentum update of every weight
@@ -497,7 +528,19 @@ def _kernels(compiled):
                              compiled.as_text()))
 
 
-def test_the_latent_attention_mixer_fits_at_8192_tokens(one_chip):
+@pytest.fixture(scope="module")
+def joyai_mixer(one_chip):
+    """JoyAI-LLM Flash's mixer at its published sizes, compiled once for
+    one layer and once for two over 8192 tokens: two tests read the
+    first."""
+    from mxnet_tpu.gluon.model_zoo import kimi_linear
+
+    mixer = kimi_linear.MLAMixer(2048, 32, 512, 128, 64, 128, 1e-6,
+                                 q_lora_rank=1536, rope_theta=32000000)
+    return _mixer_grads(mixer, one_chip, 8192, layers=(1, 2))
+
+
+def test_the_latent_attention_mixer_fits_at_8192_tokens(joyai_mixer):
     # JoyAI-LLM Flash's mixer at its published sizes under bfloat16 AMP,
     # forward and backward over one sequence of 8192 tokens: the three
     # flash kernels once each, nothing made again.  What the first of two
@@ -505,11 +548,7 @@ def test_the_latent_attention_mixer_fits_at_8192_tokens(one_chip):
     # sized on: W_kvb's result as it lies, the two query parts, the rope
     # key, the result and its logsumexp (PR 39; q, k, v and W_kvb's result,
     # 0.69 GB, before it)
-    from mxnet_tpu.gluon.model_zoo import kimi_linear
-
-    mixer = kimi_linear.MLAMixer(2048, 32, 512, 128, 64, 128, 1e-6,
-                                 q_lora_rank=1536, rope_theta=32000000)
-    one, two = _mixer_grads(mixer, one_chip, 8192, layers=(1, 2))
+    one, two = joyai_mixer
     assert _kernels(one) == ["mx_flash_bwd_dkv_mla", "mx_flash_bwd_dq_mla",
                              "mx_flash_fwd_mla"]
     temp = one.memory_analysis().temp_size_in_bytes
@@ -520,7 +559,7 @@ def test_the_latent_attention_mixer_fits_at_8192_tokens(one_chip):
 
 @pytest.mark.parametrize("model", ["joyai", "kimi"])
 def test_the_latent_attention_mixer_rewrites_no_array_over_the_heads(
-        one_chip, model):
+        request, one_chip, model):
     # the mixer's path by its shapes (JoyAI-LLM Flash at (1, 8192), a
     # low-rank query and a rotation; Kimi Linear at (1, 4096), neither):
     # the kernels read W_kvb's and W_qb's results where the products wrote
@@ -530,13 +569,12 @@ def test_the_latent_attention_mixer_rewrites_no_array_over_the_heads(
     from mxnet_tpu.gluon.model_zoo import kimi_linear
 
     if model == "joyai":
-        tokens, mixer = 8192, kimi_linear.MLAMixer(
-            2048, 32, 512, 128, 64, 128, 1e-6, q_lora_rank=1536,
-            rope_theta=32000000)
+        tokens = 8192
+        compiled = request.getfixturevalue("joyai_mixer")[0]
     else:
-        tokens, mixer = 4096, kimi_linear.MLAMixer(2304, 32, 512, 128, 64,
-                                                   128, 1e-5)
-    compiled, = _mixer_grads(mixer, one_chip, tokens)
+        tokens = 4096
+        compiled, = _mixer_grads(kimi_linear.MLAMixer(
+            2304, 32, 512, 128, 64, 128, 1e-5), one_chip, tokens)
     assert _kernels(compiled) == ["mx_flash_bwd_dkv_mla",
                                   "mx_flash_bwd_dq_mla", "mx_flash_fwd_mla"]
     over_heads = re.compile(r"bf16\[(?:1,)?(?:32,%d|%d,32),\d+\]"
@@ -640,11 +678,12 @@ def test_flash_mla_and_the_sigmoid_router_trace_as_they_did():
     # attention) and their sigmoid router, free and forced, forward and
     # backward, trace to the jaxpr text they had before the mask came
     # (sha256 of ``jax.make_jaxpr`` at small shapes, recorded from the tree
-    # without it; nothing compiles here).  The mask's own composition,
-    # which every shape that ``ops/bd_kernels.py`` does not take keeps,
-    # traces as it did before those kernels came
+    # without it; nothing compiles here).  The mask's own kernels
+    # (``ops/bd_kernels.py``) trace as they did while the mask's code lived
+    # in ``pallas_kernels``
     import hashlib
 
+    from mxnet_tpu.ops.bd_kernels import _attend
     from mxnet_tpu.ops.mla_kernels import mla_flash_attention
     from mxnet_tpu.ops.moe import router_topk
 
@@ -670,27 +709,11 @@ def test_flash_mla_and_the_sigmoid_router_trace_as_they_did():
         qn, qr, kv, kr, num_heads=2, rope_theta=10000.0),
         (1, 512, 256), (1, 512, 128), (1, 512, 512), (1, 512, 64)) \
         == "4b7ea19e8ec2ed71"
-    assert digest(lambda q, k, v: flash_attention(
-        q, k, v, block_q=128, block_k=128, mask=("block_diffusion", 256, 4)),
-        *[(1, 2, 512, 128)] * 3) == "a94c6797721db69f"
+    assert digest(lambda q, k, v, gq, gk: _attend(
+        q, k, v, gq, gk, 4, 4, 1e6, 1e-6, 128), (1, 512, 512),
+        (1, 512, 256), (1, 512, 256), (128,), (128,)) == "aed79dbe6fca5eb5"
     assert digest(router(None), (16, 32), (8, 32)) == "d4349856736fb737"
     assert digest(router(3), (16, 32), (8, 32)) == "506bff14e050d278"
-
-
-@pytest.mark.parametrize("block, tile", [(4, 512), (16, 128)])
-def test_flash_under_the_block_diffusion_mask_at_8192_positions(
-        one_chip, block, tile):
-    # the SDAR cell's attention: 32 heads x 2 x 4096 positions x 128, the
-    # three kernels of the mask (no dead tile computed) forward and
-    # backward; the forward and dq kernels hold a head's K and V in VMEM
-    def loss(q, k, v, w):
-        out = flash_attention(q, k, v, block_q=tile, block_k=tile,
-                              mask=("block_diffusion", 4096, block))
-        return (out * w).astype(jnp.float32).sum()
-    c = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
-                 *[((1, 32, 8192, 128), jnp.bfloat16)] * 4)
-    assert _kernels(c) == ["mx_flash_bwd_dkv_bd", "mx_flash_bwd_dq_bd",
-                           "mx_flash_fwd_bd"]
 
 
 def test_the_bd_attention_layer_rewrites_no_array_over_the_heads(one_chip):
