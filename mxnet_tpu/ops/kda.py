@@ -35,12 +35,18 @@ published range (``g`` down to -1.6 a token).  So a chunk is cut into
 sub-chunks of ``SUB = 16`` tokens and the rows of sub-chunk ``I`` take ``r
 = G`` at its first token: the row factor is at most 1, the column factor
 at most 1 for every earlier sub-chunk and at most ``e^{15 |g|}`` inside
-the row's own (``e^24`` at the published range; float32 holds ``e^88``,
-``g`` down to -5.8 a token in range, but the products of such factors
+the row's own (``e^24`` at the published range).  **The edge is ``g`` =
+-5.0 a token**: float32 holds ``e^88``, -5.8 a token over 15 tokens, but the
+products of a row factor near ``e^-80`` and a column factor near ``e^80``
 lose their digits sooner: against the recurrence, at float32 products, the
 chunked form holds 1e-4 of the largest value to -5.0 a token and reads
-0.12 at -5.5, PR 41).  Columns of later sub-chunks are masked before the
-exponential is taken.
+0.12 at -5.5.  Gated DeltaNet's published decays lie inside it:
+``A = exp(A_log)`` drawn in [1, 16] and ``softplus(dt_bias)`` in [0.001,
+0.1] at initialisation give ``g`` down to -1.6 a token where ``a`` is near
+0; a trained ``A`` or ``dt`` past that product of 5 is not (nor a random
+``W_ba`` that spreads ``a`` by a few units: -125 a token at the Qwen3-Next
+cell's widths), and its form does without the rule (below).  Columns of later sub-chunks are masked before the exponential is
+taken.
 
 Everything is float32 whatever the inputs are (the operator is in
 ``amp.lists.FP32_OPS``).  No state a token ``(B, T, H, d, e)`` ever exists.
@@ -91,6 +97,24 @@ the projections' cotangents, the taps', and a chunk's token sums for
 array of the mixer's per-token work exists in HBM.  Every other shape keeps
 the composition: the same steps in XLA around ``_kda_chunked``, a group of
 ``HEADS_AT_ONCE`` heads at a time.
+
+``_contrib_gdn_attention`` is the same for a Gated DeltaNet mixer (Yang et
+al., "Gated Delta Networks", arXiv:2412.06464; Qwen3-Next's linear
+attention), which differs from KDA in three things: the decay is a head's
+own, ``g = -exp(A_log[h]) * softplus(a + dt_bias[h])`` from a ``(B, T, H)``
+operand; ``q`` and ``k`` have fewer heads than ``v`` (value head ``j`` reads
+key head ``j // (H_v / H_k)``); and the output gate is ``silu(z)``.  It takes
+the two projections' results whole, ``[q | k | v | z]`` and ``[b | a]``, and
+where the shapes tile (``gdn_fused``) the same two kernels run it
+(``kda_kernels.gdn_mixer``: the form is a static choice of code), reading
+the key heads' blocks by the index maps, broadcasting the decay over a
+head's channels in VMEM and adding the cotangents of the value heads that
+share a key head.  A decay a head needs no overflow rule: the pair products
+are ``exp(G[t] - G[i])`` times ``k[t] . k[i]``, the exponential at most 1
+(``_head_pair_products``; ``kda_kernels._head_pairs``), so the GDN form holds
+at any rate (the drawn weights of the Qwen3-Next cell reach -21 a token and
+-83 over 15 tokens); the rest of the algebra, a channel at a time, is
+KDA's.  Every other shape takes the composition around ``_kda_chunked``.
 
 Shapes: ``q, k (B, T, H, d)``, ``v (B, T, H, e)``, ``g (B, T, H, d)``
 (log-decay, at most 0), ``beta (B, T, H)``; returns ``(B, T, H, e)`` in
@@ -235,7 +259,21 @@ def _pair_products(q, k, cum):
     return a.reshape(b, h, n, c, c), bm.reshape(b, h, n, c, c)
 
 
-def _kda_chunked(q, k, v, g, beta, chunk):
+@jax.checkpoint
+def _head_pair_products(q, k, cum):
+    """``_pair_products`` for a decay that is one number a head (every
+    channel of ``cum`` alike): ``exp(G[t] - G[i])`` times ``k[t] . k[i]``
+    (``q[t] . k[i]``) for ``i <= t``, the exponential taken where it is at
+    most 1, so no decay overflows whatever its rate."""
+    head = cum[..., :1]
+    c = cum.shape[-2]
+    decay = jnp.exp(jnp.where(jnp.tril(jnp.ones((c, c), bool)),
+                              head - jnp.swapaxes(head, -1, -2), -jnp.inf))
+    return (decay * jnp.einsum("...tc,...ic->...ti", k, k),
+            decay * jnp.einsum("...tc,...ic->...ti", q, k))
+
+
+def _kda_chunked(q, k, v, g, beta, chunk, per_head=False):
     f32 = jnp.float32
     b, t, h, d = q.shape
     e = v.shape[-1]
@@ -252,7 +290,7 @@ def _kda_chunked(q, k, v, g, beta, chunk):
             x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
         x = jnp.moveaxis(x, 2, 1)
         return x.reshape((b, h, n, c) + x.shape[3:])
-    if kda_kernels.tiles(d, e, c):
+    if kda_kernels.tiles(d, e, c) and not per_head:
         # the same algebra with a chunk's factors made, used and dropped in
         # VMEM: a static test of the shapes, on every platform.  The
         # kernels read the heads as lanes, (B, T, H * d): no copy
@@ -264,7 +302,7 @@ def _kda_chunked(q, k, v, g, beta, chunk):
     q, k, v, g = chunks(q), chunks(k), chunks(v), chunks(g)
     beta = chunks(beta)[..., None]                     # (B, H, n, C, 1)
     cum = jnp.cumsum(g, axis=-2)                       # G, inclusive
-    a, bm = _pair_products(q, k, cum)
+    a, bm = (_head_pair_products if per_head else _pair_products)(q, k, cum)
     lower = jnp.tril(jnp.ones((c, c), bool))
     bm = jnp.where(lower, bm, 0)
     tinv = _unit_lower_inverse(jnp.where(lower & ~jnp.eye(c, dtype=bool),
@@ -422,6 +460,117 @@ def kda_attention(q, k, v, decay, beta, gate, q_conv, k_conv, v_conv, A_log,
              own(q_conv), own(k_conv), own(v_conv), own(A_log.reshape(-1)),
              own(dt_bias.reshape(-1))))
         return jnp.moveaxis(out, 0, 2).reshape(b, t, inner)
+
+
+def _gdn_sizes(qkvz, A_log, o_norm):
+    """``(key heads, value heads, d)`` of a GDN mixer: ``qkvz`` holds ``2 H_k
+    d + 2 H_v d`` lanes, ``A_log`` one element a value head, ``o_norm`` one a
+    channel of a head."""
+    hv, d = A_log.size, o_norm.size
+    return (qkvz.shape[-1] - 2 * hv * d) // (2 * d), hv, d
+
+
+def _gdn_fused(qkvz, ba, conv, A_log, dt_bias, o_norm, chunk, eps):
+    """``gdn_attention`` where the shapes tile: the kernels read the two
+    projections' results where they lie and do the rest."""
+    f32 = jnp.float32
+    t = qkvz.shape[1]
+    hk, hv, d = _gdn_sizes(qkvz, A_log, o_norm)
+    shared = hv // hk
+    c = _chunk_size(t, chunk)
+    pad = -t % c
+
+    def whole(x):
+        # whole chunks; the kernels mask the tokens past ``t`` by position
+        return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
+
+    def channels(x):
+        # one a value head -> (1, H_v * d), over its channels
+        return jnp.repeat(x.astype(f32).reshape(-1), d)[None]
+
+    def value_heads(w):
+        # a key head's taps for each of its value heads
+        return jnp.repeat(w.reshape(hk, d, -1), shared, axis=0).reshape(
+            hv * d, -1)
+    taps = conv.astype(f32)
+    with jax.named_scope("gdn_scan"):
+        o = kda_kernels.gdn_mixer(
+            whole(qkvz), whole(ba), channels(A_log), channels(dt_bias),
+            jnp.tile(o_norm.astype(f32).reshape(-1), hv)[None],
+            jnp.stack([value_heads(taps[:hk * d]),
+                       value_heads(taps[hk * d:2 * hk * d]),
+                       taps[2 * hk * d:]]).transpose(0, 2, 1),
+            c, t if pad else None, eps, shared)
+    return o[:, :t] if pad else o
+
+
+def _gdn_attention(qkvz, ba, conv, A_log, dt_bias, o_norm, chunk, eps):
+    """``gdn_attention`` as XLA's steps around ``_kda_chunked``."""
+    f32 = jnp.float32
+    b, t, _ = qkvz.shape
+    hk, hv, d = _gdn_sizes(qkvz, A_log, o_norm)
+    x, gates = qkvz.astype(f32), ba.astype(f32)
+    mixed = jax.nn.silu(causal_conv1d(x[..., :2 * hk * d + hv * d], conv))
+
+    def unit(x):
+        return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+    def key_heads(x):
+        # the key heads, each read by its H_v / H_k value heads
+        return jnp.repeat(unit(x.reshape(b, t, hk, d)), hv // hk, axis=2)
+    g = -jnp.exp(A_log.astype(f32).reshape(hv)) * jax.nn.softplus(
+        gates[..., hv:] + dt_bias.astype(f32).reshape(hv))
+    with jax.named_scope("gdn_scan"):
+        o = _kda_chunked(
+            key_heads(mixed[..., :hk * d]) * d ** -0.5,
+            key_heads(mixed[..., hk * d:2 * hk * d]),
+            mixed[..., 2 * hk * d:].reshape(b, t, hv, d),
+            jnp.broadcast_to(g[..., None], (b, t, hv, d)),
+            jax.nn.sigmoid(gates[..., :hv]), chunk, per_head=True)
+    o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps) \
+        * o_norm.astype(f32) \
+        * jax.nn.silu(x[..., 2 * hk * d + hv * d:]).reshape(b, t, hv, d)
+    return o.reshape(b, t, hv * d)
+
+
+@register("_contrib_gdn_attention",
+          inputs=("qkvz", "ba", "conv", "A_log", "dt_bias", "o_norm"))
+def gdn_attention(qkvz, ba, conv, A_log, dt_bias, o_norm, chunk=64,
+                  eps=1e-6):
+    """A Gated DeltaNet mixer between its projections, under ONE checkpoint
+    (``_made_again``): from ``qkvz (B, T, 2 H_k d + 2 H_v d)``, the lanes of
+    ``[q | k | v | z]`` (``q, k`` of ``H_k`` heads, ``v, z`` of ``H_v``), and
+    ``ba (B, T, 2 H_v)``, ``[b | a]``, in any float dtype ::
+
+        q, k, v = silu(causal_conv1d([q | k | v], conv))   conv (C, K)
+        q, k    = x / sqrt(sum x^2 + 1e-6) a head;  q *= d ** -0.5
+        g       = -exp(A_log[h]) * softplus(a + dt_bias[h]), a head's own
+        o       = kda_scan(q, k, v, g, sigmoid(b)), value head j reading
+                  key head j // (H_v / H_k)
+        o       = RMSNorm_head(o; o_norm, eps) * silu(z)
+
+    ``A_log`` and ``dt_bias`` hold ``H_v`` elements, ``o_norm`` ``d``.
+    Returns ``(B, T, H_v * d)`` float32.  Where the shapes tile
+    (``gdn_fused``) every step is the kernels' (``_gdn_fused``); every other
+    shape is the composition (``_gdn_attention``), its scan padded after the
+    gates."""
+    with jax.named_scope("gdn_attention"):
+        hk, hv, d = _gdn_sizes(qkvz, A_log, o_norm)
+        fn = _gdn_fused if gdn_fused(qkvz.shape[1], d, hk, hv,
+                                     conv.shape[1], chunk) \
+            else _gdn_attention
+        return _made_again(functools.partial(
+            fn, chunk=int(chunk), eps=float(eps)))(
+            qkvz, ba, conv, A_log, dt_bias, o_norm)
+
+
+def gdn_fused(t, d, key_heads, value_heads, taps, chunk=64):
+    """Whether a GDN mixer of ``t`` tokens, heads of ``d`` channels and
+    convolutions of ``taps`` takes the kernels whole (``gdn_attention``'s
+    static test of the shapes)."""
+    return kda_kernels.gdn_tiles(d, key_heads, value_heads,
+                                 _chunk_size(t, chunk)) \
+        and taps <= kda_kernels.SPAN + 1
 
 
 def kda_chunks(t, chunk=64):

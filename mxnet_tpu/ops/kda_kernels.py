@@ -169,6 +169,44 @@ def _factors(q, k, g):
                 total=jnp.exp(last))
 
 
+def _head_factors(g):
+    """``_factors`` for a decay that is one number a head (every channel of
+    ``g (., C, d)`` alike), which needs no split into row and column factors:
+    ``decay[t, i] = exp(cum[t] - cum[i])`` for ``i <= t`` (0 above), at most 1
+    however fast the decay; ``exp(cum)``, ``exp(cum_last - cum)`` as
+    ``_factors`` has them."""
+    c = g.shape[-2]
+    row, col = _iota(c)
+    cum = _running_sum(col <= row, g)
+    head = cum[..., :1]                                 # (., C, 1)
+    across = jnp.sum(jnp.where(row == col, head, 0.0), axis=-2,
+                     keepdims=True)                     # (., 1, C)
+    last = cum[..., c - 1:c, :]
+    return dict(decay=jnp.exp(jnp.where(col <= row, head - across, -jnp.inf)),
+                grow=jnp.exp(cum), ef=jnp.exp(last - cum),
+                total=jnp.exp(last))
+
+
+def _head_pairs(f, q, k):
+    """``_pairs`` from ``_head_factors``: ``decay * (k k^T)`` and ``decay *
+    (q k^T)``."""
+    return f["decay"] * _dot(k, k, "nt"), f["decay"] * _dot(q, k, "nt")
+
+
+def _head_pairs_bwd(f, q, k, a, bm, da, dbm):
+    """The cotangents of ``_head_pairs``' two results back to ``k`` and
+    ``q``, ``(., C, d)`` each, and to a head's running sum of the decay,
+    ``(., C, 1)``: ``cum[t] - cum[i]`` takes ``da a + dbm bm`` at ``[t,
+    i]``."""
+    ea, eb = da * f["decay"], dbm * f["decay"]
+    dk = _dot(ea, k) + _dot(ea, k, "tn") + _dot(eb, q, "tn")
+    ds = da * a + dbm * bm
+    row, col = _iota(ds.shape[-1])
+    dcum = jnp.sum(ds, axis=-1, keepdims=True) - _column(
+        jnp.sum(ds, axis=-2, keepdims=True), row == col)
+    return dk, _dot(eb, k), dcum
+
+
 def _pairs(f):
     """``A[t, i] = sum_c k[t] k[i] exp(cum[t] - cum[i])`` and ``B`` the same
     with ``q[t]``, unmasked ``(., C, C)`` each: a sub-chunk's rows of both
@@ -249,10 +287,32 @@ def _store(ref, x, *lead):
             x[h].astype(ref.dtype)
 
 
-def _head_lanes(shape, grp, heads):
-    """Of a ``(C, H)`` block, the lane of each head of group ``grp``."""
+def _head_lanes(shape, grp, heads, first=0):
+    """Of a ``(C, H)`` block, the lane of each head of group ``grp`` (past
+    ``first`` lanes)."""
     lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+    if first:
+        lane = lane - first
     return [lane == grp * heads + h for h in range(heads)]
+
+
+def _lane_heads(rows, lanes):
+    """``(G, C, 1)``: the lane of each head of ``rows (C, .)``."""
+    return jnp.stack([jnp.sum(jnp.where(lane, rows, 0.0), axis=-1,
+                              keepdims=True) for lane in lanes])
+
+
+def _shared(x, r):
+    """Key heads ``(G / r, ., .)`` -> value heads ``(G, ., .)``: value head
+    ``j`` reads key head ``j // r``."""
+    return jnp.stack([x[h // r] for h in range(x.shape[0] * r)])
+
+
+def _pair_sums(x, r):
+    """The transpose of ``_shared``: a key head's cotangent is the sum of
+    its ``r`` value heads'."""
+    return jnp.stack([sum(x[h * r + i] for i in range(r))
+                      for h in range(x.shape[0] // r)])
 
 
 def _sigmoid(x):
@@ -284,31 +344,48 @@ def _after(x, nxt, s):
     return pltpu.roll(jnp.where(row < s, nxt, x), c - s, 1)
 
 
-def _operands(refs, grp, at, heads, tokens, mixer):
+def _operands(refs, grp, at, heads, tokens, mixer, gdn=0):
     """What a grid step's chunk is made of, from the group's blocks: ``q, k,
     g (G, C, d)``, ``v (G, C, e)`` and ``beta (G, C, 1)`` as the algebra
     reads them.  In the mixer's form they are made here from the
     projections' results (the convolutions and SiLU, the unit norms, the
     decay gate, the sigmoid), with what their gradients read beside them.
     ``tokens`` (when the sequence was padded to whole chunks) masks the
-    tokens past it by position: they neither decay nor write."""
+    tokens past it by position: they neither decay nor write.
+
+    ``gdn`` (the GDN form: value heads a key head) reads the group's ``G /
+    gdn`` key heads of ``q`` and ``k`` and hands value head ``j`` key head
+    ``j // gdn``; ``beta`` and the decay are the first and the second half
+    of one ``(C, 2 H)`` block, one decay a head, broadcast over its
+    channels."""
     f32 = jnp.float32
     q_ref, k_ref, v_ref, g_ref, beta_ref = refs[:5]
-    d, e = q_ref.shape[-1] // heads, v_ref.shape[-1] // heads
+    e = v_ref.shape[-1] // heads
+    d = q_ref.shape[-1] * max(gdn, 1) // heads
     rows = beta_ref[...].astype(f32)                    # (C, H)
     x = dict(lanes=_head_lanes(rows.shape, grp, heads))
-    beta = jnp.stack([jnp.sum(jnp.where(lane, rows, 0.0), axis=-1,
-                              keepdims=True) for lane in x["lanes"]])
-    q, k, v, g = (_heads(r[...].astype(f32), w)
-                  for r, w in zip(refs[:4], (d, d, e, d)))
+    beta = _lane_heads(rows, x["lanes"])
+    if gdn:
+        q, k = (_shared(_heads(r[...].astype(f32), d), gdn)
+                for r in (q_ref, k_ref))
+        v = _heads(v_ref[...].astype(f32), e)
+        x["decay_lanes"] = _head_lanes(rows.shape, grp, heads,
+                                       rows.shape[-1] // 2)
+        g = _lane_heads(rows, x["decay_lanes"])         # (G, C, 1)
+    else:
+        q, k, v, g = (_heads(r[...].astype(f32), w)
+                      for r, w in zip(refs[:4], (d, d, e, d)))
     if mixer:
         a_ref, dt_ref, _, taps_ref = refs[6:10]
         taps, x["conv"] = taps_ref[...], []
         for i, (raw, prev_ref) in enumerate(zip((q, k, v), refs[10:])):
             # the causal convolution over the tokens before the chunk too;
             # there are none before the first
-            prev = jnp.where(at == 0, 0.0,
-                             _heads(prev_ref[...].astype(f32), d))
+            first = at == 0
+            prev = _heads(prev_ref[...].astype(f32), d)
+            if gdn and i < 2:
+                prev = _shared(prev, gdn)
+            prev = jnp.where(first, 0.0, prev)
             w = _heads(taps[i], d)                      # (G, K, d)
             n = w.shape[1]
             shifted = [_before(raw, prev, s) for s in range(n)]
@@ -336,15 +413,19 @@ def _operands(refs, grp, at, heads, tokens, mixer):
     return x
 
 
-def _epilogue(o, gate, norm, eps):
+def _epilogue(o, gate, norm, eps, gdn=0):
     """The mixer's output from the scan's ``o (G, C, e)``: a head's RMSNorm
-    times ``norm``, times ``sigmoid(gate)``; and what its gradient reads."""
+    times ``norm``, times ``sigmoid(gate)`` (the GDN form: ``silu(gate)``);
+    and what its gradient reads: ``ds``, the derivative of that factor."""
     scale = lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
     unit, s = o * scale, _sigmoid(gate)
+    if gdn:
+        return dict(scale=scale, unit=unit, s=gate * s,
+                    ds=s * (1.0 + gate * (1.0 - s)), y=unit * norm * gate * s)
     return dict(scale=scale, unit=unit, s=s, y=unit * norm * s)
 
 
-def _fwd_kernel(*refs, heads, mixer, keep, tokens, eps):
+def _fwd_kernel(*refs, heads, mixer, keep, tokens, eps, gdn=0):
     from jax.experimental import pallas as pl
 
     n_in = MIXER_OPERANDS if mixer else 5
@@ -355,12 +436,12 @@ def _fwd_kernel(*refs, heads, mixer, keep, tokens, eps):
     def _():
         state[grp] = jnp.zeros(state.shape[1:], state.dtype)
 
-    x = _operands(ins, grp, j, heads, tokens, mixer)
+    x = _operands(ins, grp, j, heads, tokens, mixer, gdn)
     q, k, v, beta = x["q"], x["k"], x["v"], x["beta"]
     row, col = _iota(q.shape[1])
-    f = _factors(q, k, x["g"])
+    f = _head_factors(x["g"]) if gdn else _factors(q, k, x["g"])
     st = state[grp]                                     # (G, e, d)
-    a, bm = _pairs(f)
+    a, bm = _head_pairs(f, q, k) if gdn else _pairs(f)
     t = _inverse(jnp.where(col < row, beta * a, 0.0), row, col)
     u = _dot32(t, beta * (v - _dot(k * f["grow"], st, "nt")))
     o = _dot(q * f["grow"], st, "nt") \
@@ -369,7 +450,7 @@ def _fwd_kernel(*refs, heads, mixer, keep, tokens, eps):
     if mixer:
         e = v.shape[-1]
         o = _epilogue(o, _heads(ins[5][...].astype(jnp.float32), e),
-                      _heads(ins[8][...], e), eps)["y"]
+                      _heads(ins[8][...], e), eps, gdn)["y"]
     _store(outs[0], o)
     if keep:
         st_ref, t_ref, u_ref = outs[1:]
@@ -378,7 +459,7 @@ def _fwd_kernel(*refs, heads, mixer, keep, tokens, eps):
         u_ref[...] = u
 
 
-def _bwd_kernel(*refs, heads, mixer, tokens, eps):
+def _bwd_kernel(*refs, heads, mixer, tokens, eps, gdn=0):
     from jax.experimental import pallas as pl
 
     f32 = jnp.float32
@@ -394,14 +475,15 @@ def _bwd_kernel(*refs, heads, mixer, tokens, eps):
         for ref in scratch:
             ref[grp] = jnp.zeros(ref.shape[1:], ref.dtype)
 
-    x = _operands(ins, grp, pl.num_programs(1) - 1 - j, heads, tokens, mixer)
+    x = _operands(ins, grp, pl.num_programs(1) - 1 - j, heads, tokens, mixer,
+                  gdn)
     q, k, v, beta = x["q"], x["k"], x["v"], x["beta"]
     c, e = q.shape[1], v.shape[-1]
     row, col = _iota(c)
     st, t, u = st_ref[...], t_ref[...], u_ref[...]
     dst = dstate[grp]                                   # (G, e, d)
-    f = _factors(q, k, x["g"])
-    a, bm = _pairs(f)
+    f = _head_factors(x["g"]) if gdn else _factors(q, k, x["g"])
+    a, bm = _head_pairs(f, q, k) if gdn else _pairs(f)
     bm = jnp.where(col <= row, bm, 0.0)
     kg, qg, kend = k * f["grow"], q * f["grow"], k * f["ef"]
     do = _heads(dout_ref[...].astype(f32), e)
@@ -409,9 +491,12 @@ def _bwd_kernel(*refs, heads, mixer, tokens, eps):
         # the epilogue made again from the scan's result, and its gradient
         norm = _heads(ins[8][...], e)
         y = _epilogue(_dot(qg, st, "nt") + _dot(bm, u),
-                      _heads(ins[5][...].astype(f32), e), norm, eps)
+                      _heads(ins[5][...].astype(f32), e), norm, eps, gdn)
         dunit = do * norm * y["s"]
-        dgate = do * y["unit"] * norm * y["s"] * (1.0 - y["s"])
+        if gdn:
+            dgate = do * y["unit"] * norm * y["ds"]
+        else:
+            dgate = do * y["unit"] * norm * y["s"] * (1.0 - y["s"])
         dnorm = jnp.sum(do * y["unit"] * y["s"], axis=1, keepdims=True)
         do = y["scale"] * (dunit - y["unit"] * jnp.mean(
             dunit * y["unit"], axis=-1, keepdims=True))
@@ -429,14 +514,26 @@ def _bwd_kernel(*refs, heads, mixer, tokens, eps):
     da = beta * dn
     dkg = -_dot(dp, st)
     dstate[grp] = dst * f["total"] + _dot(do, qg, "tn") - _dot(dp, kg, "tn")
-    dk_row, dq_row, dk_col, dfirsts = _pairs_bwd(f, da, dbm)
-    dq = dq_row + dqg * f["grow"]
-    dk_end = dkend * f["ef"]
-    dk = dk_row + dk_col + dkg * f["grow"] + dk_end
-    # every factor is k or q times an exponential of cum: its share of cum's
-    # cotangent is the operand times the operand's own share; cum_last and
-    # the sub-chunks' reference points take the rest
-    dcum = k * (dk - 2.0 * (dk_col + dk_end)) + q * dq
+    if gdn:
+        dk_pairs, dq, dcum_pairs = _head_pairs_bwd(f, q, k, a, bm, da, dbm)
+        dq = dq + dqg * f["grow"]
+        dk_end = dkend * f["ef"]
+        dk = dk_pairs + dkg * f["grow"] + dk_end
+        # the channels' running sums are one, so the pairs' share of it
+        # goes to the first channel alone
+        lane = lax.broadcasted_iota(jnp.int32, k.shape, k.ndim - 1)
+        dcum = k * (dkg * f["grow"] - dk_end) + q * dqg * f["grow"] \
+            + jnp.where(lane == 0, dcum_pairs, 0.0)
+        dfirsts = []
+    else:
+        dk_row, dq_row, dk_col, dfirsts = _pairs_bwd(f, da, dbm)
+        dq = dq_row + dqg * f["grow"]
+        dk_end = dkend * f["ef"]
+        dk = dk_row + dk_col + dkg * f["grow"] + dk_end
+        # every factor is k or q times an exponential of cum: its share of
+        # cum's cotangent is the operand times the operand's own share;
+        # cum_last and the sub-chunks' reference points take the rest
+        dcum = k * (dk - 2.0 * (dk_col + dk_end)) + q * dq
     dlast = jnp.sum(k * dk_end, axis=-2, keepdims=True) \
         + jnp.sum(st * dst, axis=-2, keepdims=True) * f["total"]
     token = lax.broadcasted_iota(jnp.int32, dcum.shape, dcum.ndim - 2)
@@ -459,6 +556,8 @@ def _bwd_kernel(*refs, heads, mixer, tokens, eps):
         sums = [jnp.sum(dg * x["g"], axis=1, keepdims=True)]   # A_log's
         dg = dg * x["rate"] * _sigmoid(x["z"])
         sums += [jnp.sum(dg, axis=1, keepdims=True), dnorm]    # dt_bias's
+        if gdn:         # one decay a head: its channels' cotangents
+            dg = jnp.sum(dg, axis=-1, keepdims=True)
         dbeta = dbeta * beta * (1.0 - beta)
         raw, taps = [], []
         for i, (dm, cv) in enumerate(zip((dq, dk, dp), x["conv"])):
@@ -474,26 +573,35 @@ def _bwd_kernel(*refs, heads, mixer, tokens, eps):
             taps.append([jnp.sum(dy * xs, axis=1, keepdims=True)
                          for xs in cv["shifted"]])
         dq, dk, dp = raw
-    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref = outs[:5]
+        if gdn:         # a key head's, from its value heads'
+            dq, dk = _pair_sums(dq, gdn), _pair_sums(dk, gdn)
+    if gdn:
+        (dq_ref, dk_ref, dv_ref, dbeta_ref), rest = outs[:4], outs[4:]
+    else:
+        (dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref), rest = outs[:5], outs[5:]
     _store(dq_ref, dq)
     _store(dk_ref, dk)
     _store(dv_ref, dp)
-    _store(dg_ref, dg)
+    if not gdn:
+        _store(dg_ref, dg)
     # the group's lanes of (C, H); the block stays in VMEM while the group
-    # axis, the innermost, walks the heads of the chunk
+    # axis, the innermost, walks the heads of the chunk (the GDN form: of
+    # (C, 2 H), beta's lanes and the decay's)
     merged = dbeta_ref[...].astype(f32)
     for h, lane in enumerate(x["lanes"]):
         merged = jnp.where(lane, dbeta[h], merged)
+    for h, lane in enumerate(x.get("decay_lanes", ())):
+        merged = jnp.where(lane, dg[h], merged)
     dbeta_ref[...] = merged.astype(dbeta_ref.dtype)
     if mixer:
-        _store(outs[5], dgate)
-        for ref, total in zip(outs[6:9], sums):
+        _store(rest[0], dgate)
+        for ref, total in zip(rest[1:4], sums):
             _store(ref, total)
         for i, by_shift in enumerate(taps):
             n = len(by_shift)
             for s, total in enumerate(by_shift):
                 tap = n - 1 - s
-                _store(outs[9], total, i, slice(tap, tap + 1))
+                _store(rest[4], total, i, slice(tap, tap + 1))
 
 
 def _specs(n, c, heads, reverse):
@@ -509,8 +617,12 @@ def _specs(n, c, heads, reverse):
     def at(j):
         return n - 1 - j if reverse else j
 
-    def cols(width):
-        return pl.BlockSpec((None, c, heads * width),
+    def cols(width, first=0, count=heads):
+        # ``count`` heads a group, ``first`` blocks of them into the lanes
+        if first:
+            return pl.BlockSpec((None, c, count * width),
+                                lambda i, j, g: (i, at(j), g + first))
+        return pl.BlockSpec((None, c, count * width),
                             lambda i, j, g: (i, at(j), g))
 
     def rows(h):
@@ -529,8 +641,12 @@ def _specs(n, c, heads, reverse):
             (None, None) + lead + (heads * width,),
             lambda i, j, g: (i, at(j)) + (0,) * len(lead) + (g,))
 
-    def before(width):
-        return pl.BlockSpec((None, c, heads * width),
+    def before(width, first=0, count=heads):
+        if first:
+            return pl.BlockSpec(
+                (None, c, count * width),
+                lambda i, j, g: (i, jnp.maximum(at(j) - 1, 0), g + first))
+        return pl.BlockSpec((None, c, count * width),
                             lambda i, j, g: (i, jnp.maximum(at(j) - 1, 0), g))
     return dict(cols=cols, rows=rows, lanes=lanes, kept=kept, sums=sums,
                 before=before)
@@ -551,32 +667,55 @@ def _heads_a_step(h):
     return max(g for g in range(1, HEADS_A_STEP + 1) if h % g == 0)
 
 
-def _sizes(ops):
-    """``(B, T, H, d, e, G)``: a head's channels from the lanes."""
+def _sizes(ops, gdn=0):
+    """``(B, T, H, d, e, G)``: a head's channels from the lanes (the GDN
+    form: ``H`` value heads from the ``(B, T, 2 H)`` block of ``beta`` and
+    the decay, ``d`` and ``e`` from ``A_log``'s and the norm's lanes)."""
     b, t, hd = ops[0].shape
+    if gdn:
+        h = ops[4].shape[-1] // 2
+        return (b, t, h, ops[6].shape[-1] // h, ops[8].shape[-1] // h,
+                _heads_a_step(h))
     h = ops[4].shape[-1]
     return b, t, h, hd // h, ops[2].shape[-1] // h, _heads_a_step(h)
 
 
-def _in_specs(specs, ops, d, e, h, heads):
+def _gdn_blocks(h, d, e, heads, gdn):
+    """Where a group's blocks lie in the lanes of ``[q | k | v | z]``, in
+    blocks of their own width: ``(key heads a group, k's, v's, z's)``."""
+    hk, gk = h // gdn, heads // gdn
+    return gk, hk // gk, 2 * hk * d // (heads * e), \
+        (2 * hk * d + h * e) // (heads * e)
+
+
+def _in_specs(specs, ops, d, e, h, heads, gdn=0):
     cols = specs["cols"]
-    out = [cols(d), cols(d), cols(e), cols(d), specs["rows"](h)]
+    if gdn:
+        gk, k_at, v_at, z_at = _gdn_blocks(h, d, e, heads, gdn)
+        out = [cols(d, 0, gk), cols(d, k_at, gk), cols(e, v_at),
+               specs["rows"](2 * h), specs["rows"](2 * h)]
+    else:
+        out = [cols(d), cols(d), cols(e), cols(d), specs["rows"](h)]
     if len(ops) > 5:
         from jax.experimental import pallas as pl
 
         taps = pl.BlockSpec((3, ops[9].shape[1], heads * d),
                             lambda i, j, g: (0, 0, g))
-        out += [cols(e), specs["lanes"](d), specs["lanes"](d),
-                specs["lanes"](e), taps, specs["before"](d),
-                specs["before"](d), specs["before"](e)]
+        before = specs["before"]
+        out += [cols(e, z_at) if gdn else cols(e), specs["lanes"](d),
+                specs["lanes"](d), specs["lanes"](e), taps]
+        if gdn:
+            out += [before(d, 0, gk), before(d, k_at, gk), before(e, v_at)]
+        else:
+            out += [before(d), before(d), before(e)]
     return out
 
 
-def _fwd_pallas(*ops, c, tokens, eps, keep, interpret=False):
+def _fwd_pallas(*ops, c, tokens, eps, keep, gdn=0, interpret=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, t, h, d, e, heads = _sizes(ops)
+    b, t, h, d, e, heads = _sizes(ops, gdn)
     n = t // c
     specs = _specs(n, c, heads, False)
     f32 = jnp.float32
@@ -590,21 +729,21 @@ def _fwd_pallas(*ops, c, tokens, eps, keep, interpret=False):
                       jax.ShapeDtypeStruct((b, n, h, c, e), f32)]
     return pl.pallas_call(
         functools.partial(_fwd_kernel, heads=heads, mixer=len(ops) > 5,
-                          keep=keep, tokens=tokens, eps=eps),
+                          keep=keep, tokens=tokens, eps=eps, gdn=gdn),
         grid=(b, n, h // heads),
-        in_specs=_in_specs(specs, ops, d, e, h, heads),
+        in_specs=_in_specs(specs, ops, d, e, h, heads, gdn),
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((h // heads, heads, e, d), f32)],
         compiler_params=_params(), interpret=interpret, name="mx_kda_fwd",
     )(*ops)
 
 
-def _bwd_pallas(*ops, c, tokens, eps, mixer, interpret=False):
+def _bwd_pallas(*ops, c, tokens, eps, mixer, gdn=0, interpret=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     ins = ops[:MIXER_OPERANDS if mixer else 5]
-    b, t, h, d, e, heads = _sizes(ins)
+    b, t, h, d, e, heads = _sizes(ins, gdn)
     n = t // c
     specs = _specs(n, c, heads, True)
     cols, kept, sums = specs["cols"], specs["kept"], specs["sums"]
@@ -612,42 +751,56 @@ def _bwd_pallas(*ops, c, tokens, eps, mixer, interpret=False):
 
     def like(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype)
-    out_specs = [cols(d), cols(d), cols(e), cols(d), specs["rows"](h)]
-    out_shape = [like(x) for x in ins[:5]]
+    if gdn:
+        # dq, dk (B, T, H / gdn * d), dv, beta's and the decay's, the gate's
+        gk = _gdn_blocks(h, d, e, heads, gdn)[0]
+        qkvz = ins[0].dtype
+        out_specs = [cols(d, 0, gk), cols(d, 0, gk), cols(e),
+                     specs["rows"](2 * h), cols(e)]
+        out_shape = [jax.ShapeDtypeStruct((b, t, h // gdn * d), qkvz)] * 2 \
+            + [jax.ShapeDtypeStruct((b, t, h * e), qkvz), like(ins[4]),
+               jax.ShapeDtypeStruct((b, t, h * e), qkvz)]
+    else:
+        out_specs = [cols(d), cols(d), cols(e), cols(d), specs["rows"](h)]
+        out_shape = [like(x) for x in ins[:5]]
     scratch = [pltpu.VMEM((h // heads, heads, e, d), f32)]
     if mixer:
         k = ins[9].shape[1]
-        out_specs += [cols(e), sums(1, d), sums(1, d), sums(1, e),
-                      sums(3, k, d)]
-        out_shape += [like(ins[5])] + [
+        if not gdn:
+            out_specs.append(cols(e))
+            out_shape.append(like(ins[5]))
+        out_specs += [sums(1, d), sums(1, d), sums(1, e), sums(3, k, d)]
+        out_shape += [
             jax.ShapeDtypeStruct((b, n, 1, h * w), f32) for w in (d, d, e)] \
             + [jax.ShapeDtypeStruct((b, n, 3, k, h * d), f32)]
         scratch += [pltpu.VMEM((h // heads, 3, heads, c, d), f32)]
     return pl.pallas_call(
         functools.partial(_bwd_kernel, heads=heads, mixer=mixer,
-                          tokens=tokens, eps=eps),
+                          tokens=tokens, eps=eps, gdn=gdn),
         grid=(b, n, h // heads),
-        in_specs=_in_specs(specs, ins, d, e, h, heads)
+        in_specs=_in_specs(specs, ins, d, e, h, heads, gdn)
         + [kept(e, d), kept(c, c), kept(c, e), cols(e)],
         out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
         compiler_params=_params(), interpret=interpret, name="mx_kda_bwd",
     )(*ops)
 
 
-def _forward(ops, c, tokens, eps, keep):
+def _forward(ops, c, tokens, eps, keep, gdn=0):
     if len(ops) > 5:
         ops = ops + ops[:3]     # the tokens before a chunk: the same arrays
     return _platform_pick(functools.partial(
-        _fwd_pallas, c=c, tokens=tokens, eps=eps, keep=keep), *ops)
+        _fwd_pallas, c=c, tokens=tokens, eps=eps, keep=keep, gdn=gdn), *ops)
 
 
-def _backward(ops, kept, dout, c, tokens, eps):
+def _backward(ops, kept, dout, c, tokens, eps, gdn=0):
     mixer = len(ops) > 5
     grads = _platform_pick(functools.partial(
-        _bwd_pallas, c=c, tokens=tokens, eps=eps, mixer=mixer),
+        _bwd_pallas, c=c, tokens=tokens, eps=eps, mixer=mixer, gdn=gdn),
         *ops, *(ops[:3] if mixer else ()), *kept, dout)
     # the vectors' and the taps' gradients: a chunk's token sums, added
-    return tuple(grads[:6]) + tuple(x.sum(axis=(0, 1)) for x in grads[6:])
+    lead = 5 if gdn else 6
+    return tuple(grads[:lead]) \
+        + tuple(x.sum(axis=(0, 1)) for x in grads[lead:])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -705,3 +858,55 @@ def _mixer_bwd(c, tokens, eps, res, dout):
 
 
 mixer.defvjp(_mixer_fwd, _mixer_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def gdn_mixer(qkvz, ba, a, dt_bias, norm, taps, c, tokens, eps, shared):
+    """A Gated DeltaNet mixer between its two projections, over whole chunks
+    of ``c`` tokens, in the kernels ``mixer`` runs (the GDN form): ``qkvz (B,
+    T, 2 H / shared * d + 2 H * e)``, the lanes of ``[q | k | v | z]`` with
+    ``H / shared`` key heads of ``d`` and ``H`` value heads of ``e = d``;
+    ``ba (B, T, 2 H)``, ``[b | a]``; ``a`` and ``dt_bias (1, H * d)``
+    (``A_log`` and ``dt_bias`` a value head, repeated over its channels),
+    ``norm (1, H * e)``, ``taps (3, K, H * d)`` (the convolutions' of q, k,
+    v, q's and k's repeated to the value heads); ``shared`` value heads a key
+    head.  Returns ``(B, T, H * e)`` float32::
+
+        q, k, v = silu(causal_conv1d(x, taps)), in float32
+        q, k    = x / sqrt(sum x^2 + 1e-6) a head;  q *= d ** -0.5
+        g       = -exp(a) * softplus(a_h + dt_bias), one a head and token
+        beta    = sigmoid(b);  value head j reads key head j // shared
+        o       = the delta rule
+        out     = o / sqrt(mean o^2 + eps) * norm * silu(z), a head
+
+    The blocks are read where the projections wrote them (index maps into
+    the lanes of ``qkvz``, ``ba`` whole); the backward kernel writes the
+    cotangents of q, k (a key head's, its value heads' added), v and z,
+    which are joined into ``qkvz``'s, and ``ba``'s whole."""
+    ops = (qkvz, qkvz, qkvz, ba, ba, qkvz, a, dt_bias, norm, taps)
+    return _forward(ops, c, tokens, eps, False, shared)[0]
+
+
+def _gdn_mixer_fwd(qkvz, ba, a, dt_bias, norm, taps, c, tokens, eps, shared):
+    ops = (qkvz, qkvz, qkvz, ba, ba, qkvz, a, dt_bias, norm, taps)
+    out, *kept = _forward(ops, c, tokens, eps, True, shared)
+    return out, (ops, kept)
+
+
+def _gdn_mixer_bwd(c, tokens, eps, shared, res, dout):
+    dq, dk, dv, dba, dz, *rest = _backward(*res, dout, c, tokens, eps, shared)
+    return (jnp.concatenate([dq, dk, dv, dz], axis=-1), dba, *rest)
+
+
+gdn_mixer.defvjp(_gdn_mixer_fwd, _gdn_mixer_bwd)
+
+
+def gdn_tiles(d, key_heads, value_heads, c):
+    """Whether ``gdn_mixer`` takes these shapes: ``tiles`` for heads of
+    ``d`` channels, value heads a whole number of key heads' (``shared``
+    each), a step's value heads a whole number of key heads, and the
+    blocks of ``[q | k | v | z]`` whole blocks of their own widths."""
+    if not tiles(d, d, c) or value_heads % key_heads:
+        return False
+    shared, heads = value_heads // key_heads, _heads_a_step(value_heads)
+    return heads % shared == 0 and (2 * key_heads) % heads == 0

@@ -14,6 +14,11 @@ pairs turns 5.4e-8 rad a token and the fastest one radian, which bfloat16
 positions or angles would lose.  The rotation itself is float32 and the
 result has the data's dtype.  (``gluon.model_zoo.llama`` rotates halves, as
 its family is published, from ``F`` operators.)
+
+``halves=True`` is the other convention, that of a partial rotary factor in
+Qwen3-Next's gated attention: the FIRST ``rotary_dim`` channels turn,
+channel ``i`` paired with ``i + rotary_dim / 2`` at the same rate ``theta **
+(-2i / rotary_dim)``, and the channels after them pass as they are.
 """
 from __future__ import annotations
 
@@ -45,18 +50,35 @@ def rotate_pairs(x, cos, sin):
     return (f * cos + partner * sin).astype(x.dtype)
 
 
+def _rotate_halves(data, width, theta):
+    """The first ``width`` channels of ``data (..., T, D)`` turned, channel
+    ``i`` with ``i + width / 2`` at ``rope_angles``' rate of pair ``i``;
+    float32 inside."""
+    half = width // 2
+    cos, sin = rope_angles(data.shape[-2], width, theta)
+    cos, sin = cos[:, ::2], sin[:, 1::2]        # one column a pair
+    a = data[..., :half].astype(jnp.float32)
+    b = data[..., half:width].astype(jnp.float32)
+    turned = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+    return jnp.concatenate([turned.astype(data.dtype), data[..., width:]],
+                           axis=-1)
+
+
 @register("_contrib_rotary_embedding")
-def rotary_embedding(data, theta=10000.0, rotary_dim=None):
+def rotary_embedding(data, theta=10000.0, rotary_dim=None, halves=False):
     """``data (..., T, D)`` with the last ``rotary_dim`` channels (all of
     them by default; an even number) of every row rotated at the row's
     position ``0 .. T - 1`` along the second-last axis, adjacent channels a
-    pair; the channels before them pass as they are.  The same dtype
-    out."""
+    pair; the channels before them pass as they are.  ``halves``: the first
+    ``rotary_dim`` channels, in halves (the module's docstring).  The same
+    dtype out."""
     d = data.shape[-1]
     width = d if rotary_dim is None else int(rotary_dim)
     if width % 2 or not 0 < width <= d:
         raise ValueError("rotary_embedding: rotary_dim %r of %d channels"
                          % (rotary_dim, d))
+    if halves:
+        return _rotate_halves(data, width, float(theta))
     cos, sin = rope_angles(data.shape[-2], width, float(theta))
     turned = rotate_pairs(data[..., d - width:], cos, sin)
     if width == d:

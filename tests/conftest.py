@@ -55,7 +55,8 @@ LONGEST_FIRST = (
     "test_kimi_linear_model.py", "test_joyai_llm_flash_faults.py",
     "test_nemotron_h.py", "test_joyai_llm_flash_model.py",
     "test_kimi_linear.py", "test_joyai_llm_flash.py", "test_kda_kernels.py",
-    "test_serve_e2e.py", "test_sdar_moe.py", "test_bd_kernels.py",
+    "test_serve_e2e.py", "test_qwen3_next.py", "test_sdar_moe.py",
+    "test_bd_kernels.py",
     "test_mla_flash_kernels.py",
 )
 

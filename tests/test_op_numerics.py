@@ -759,6 +759,39 @@ SPECS["_contrib_kda_attention"] = S(
     {"chunk": 16}, ref=_kda_attention_ref, rtol=1e-3, atol=1e-4)
 
 
+def _gdn_attention_ref(qkvz, ba, conv, a_log, dt_bias, o_norm):
+    """A Gated DeltaNet mixer between its projections: one convolution over
+    ``[q | k | v]``, a decay a head, value head ``j`` reading key head ``j //
+    (H_v / H_k)``, the head-wise norm and ``silu(z)``."""
+    b, t, _ = qkvz.shape
+    hv, d = a_log.size, o_norm.size
+    hk = (qkvz.shape[-1] - 2 * hv * d) // (2 * d)
+    x = _causal_conv_ref(qkvz[..., :2 * hk * d + hv * d], conv, 0.0)
+    x = x / (1 + np.exp(-x))
+
+    def key_heads(y):
+        y = y.reshape(b, t, hk, d)
+        y = y / np.sqrt((y * y).sum(-1, keepdims=True) + 1e-6)
+        return np.repeat(y, hv // hk, axis=2)
+    g = -np.exp(a_log.reshape(hv)) * np.log1p(np.exp(
+        ba[..., hv:] + dt_bias.reshape(hv)))
+    o = _delta_rule_ref(key_heads(x[..., :hk * d]) * d ** -0.5,
+                        key_heads(x[..., hk * d:2 * hk * d]),
+                        x[..., 2 * hk * d:].reshape(b, t, hv, d),
+                        np.broadcast_to(g[..., None], (b, t, hv, d)),
+                        1 / (1 + np.exp(-ba[..., :hv])))
+    o = o / np.sqrt((o * o).mean(-1, keepdims=True) + 1e-6) * o_norm
+    z = qkvz[..., 2 * hk * d + hv * d:].reshape(b, t, hv, d)
+    return (o * z / (1 + np.exp(-z))).reshape(b, t, hv * d)
+
+
+SPECS["_contrib_gdn_attention"] = S(
+    [randn((1, 19, 24), 180), randn((1, 19, 4), 181),
+     randn((16, 4), 182, 0.5), randn((1, 2), 183, 0.3),
+     randn((1, 2), 184, 0.3), pos((4,), 185)],
+    {"chunk": 16}, ref=_gdn_attention_ref, rtol=1e-3, atol=1e-4)
+
+
 def _rotary_ref(x):
     """Adjacent channel pairs of the last 4 of 6 channels turned at the
     row's position, theta 100."""

@@ -846,3 +846,94 @@ def test_the_sdar_step_fits_one_chip_at_the_cells_shapes(one_chip):
     assert 20 * n + c.memory_analysis().temp_size_in_bytes < 15.75e9
     assert {"mx_flash_fwd_bd", "mx_flash_bwd_dq_bd", "mx_flash_bwd_dkv_bd",
             "mx_gmm", "mx_rows_swiglu"} <= set(_kernels(c))
+
+
+# -- the Qwen3-Next cell: Gated DeltaNet on the delta-rule kernels -----------
+
+def test_the_kda_mixer_and_scan_trace_as_they_did():
+    # the GDN form of ``mx_kda_fwd`` / ``mx_kda_bwd`` is a static choice of
+    # code: the Kimi cell's mixer (fused, a padded tail, and the composition
+    # at 8 channels) and the scan alone, forward and backward, trace to the
+    # jaxpr text they had before the form came (sha256 of ``jax.make_jaxpr``
+    # at small shapes, recorded from the tree without it; nothing compiles)
+    import hashlib
+
+    from mxnet_tpu.ops.kda import kda_attention
+
+    f32 = jnp.float32
+
+    def digest(fn, *shapes):
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(jnp.square(fn(*a))),
+            argnums=tuple(range(len(shapes)))))(
+            *[jnp.zeros(s, f32) for s in shapes]))
+        return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+    def mixer(h, d, t):
+        i = h * d
+        return [(1, t, i)] * 4 + [(1, t, h), (1, t, i)] + [(i, 4)] * 3 \
+            + [(1, h), (h, d), (d,)]
+    want = {(2, 128, 128): "b80b9a3726682714", (2, 128, 100): "789e9bc7d1bd98f5",
+            (4, 8, 40): "9f60fb3aae87c6de"}
+    for shape, hexdigest in want.items():
+        assert digest(lambda *a: kda_attention(*a, chunk=64),
+                      *mixer(*shape)) == hexdigest, shape
+    assert digest(lambda *a: kda_scan(*a, chunk=64),
+                  *[(1, 128, 2, 128)] * 4, (1, 128, 2)) == "bbf6de4696f1cc82"
+
+
+def test_the_qwen3_next_step_fits_one_chip_at_the_cells_shapes(one_chip):
+    # the configuration as the cell runs it (GDN, GDN, GDN, gated attention;
+    # 16 of 512 experts held, an eighth of the vocabulary) under bfloat16
+    # AMP: the model's gradient at one 8192-token sequence and an Adam
+    # update, the state donated; beside it the state at 20 B a parameter
+    # fits 15.75 GB (PERF.md 4: 4.78 GB of temporaries beside 8.49)
+    import json
+    import sys
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import amp
+
+    chip = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "chip")
+    sys.path.insert(0, chip)
+    import archs
+
+    with open(os.path.join(chip, "configs",
+                           "qwen3_next_80b_p4_e16.json")) as f:
+        cfg = json.load(f)
+    net = archs.of(cfg).build(cfg, mx.cpu())
+    fn, params = _traced(net)
+    vocab = cfg["vocab_size"]
+
+    def loss(weights, toks, labels):
+        logp = jax.nn.log_softmax(
+            fn(weights, toks).reshape(-1, vocab).astype(jnp.float32), -1)
+        return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], 1))
+
+    def step(ws, ms, vs, toks, labels):
+        g = jax.grad(loss)(ws, toks, labels)
+        ms = [0.9 * m + 0.1 * d for m, d in zip(ms, g)]
+        vs = [0.999 * v + 0.001 * d * d for v, d in zip(vs, g)]
+        return ([w - 1e-3 * m / (jnp.sqrt(v) + 1e-8)
+                 for w, m, v in zip(ws, ms, vs)], ms, vs)
+    amp.init("bfloat16")
+    try:
+        state = [jax.ShapeDtypeStruct(p.shape, jnp.float32,
+                                      sharding=one_chip) for p in params]
+        c = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+            state, state, state,
+            jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((8192,), jnp.int32, sharding=one_chip)) \
+            .compile()
+    finally:
+        amp.turn_off()
+    n = sum(p.size for p in state)
+    assert n == 424340544
+    assert 20 * n + c.memory_analysis().temp_size_in_bytes < 15.75e9
+    # three GDN layers: the forward, the forward again and the backward
+    # kernel each; the attention layer's flash kernels
+    assert _launches(c).count("mx_kda_fwd") == 6
+    assert _launches(c).count("mx_kda_bwd") == 3
+    assert {"mx_flash_fwd", "mx_flash_bwd_dq", "mx_flash_bwd_dkv", "mx_gmm",
+            "mx_rows_swiglu"} <= set(_launches(c))
